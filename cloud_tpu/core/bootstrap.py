@@ -85,10 +85,11 @@ def main(argv=None) -> None:
         logger.exception("metrics exporter failed to start")
     monitoring.profiler.maybe_start_server_from_env()
 
-    # Env-gated persistent compile cache (CLOUD_TPU_COMPILE_CACHE, forwarded
-    # by deploy's startup script): probe + enable BEFORE the user script
-    # compiles anything, so a preemption-restarted container warm-starts
-    # its step executables from disk instead of recompiling from scratch.
+    # Persistent compile cache (JAX_COMPILATION_CACHE_DIR, else
+    # CLOUD_TPU_COMPILE_CACHE as forwarded by deploy's startup script):
+    # enabled BEFORE the user script compiles anything, so a
+    # preemption-restarted container warm-starts its step executables
+    # from disk instead of recompiling from scratch.
     try:
         from cloud_tpu.training import compile_cache
 

@@ -218,6 +218,8 @@ def _paged_attended(kind, q, cache_l, cur_len, paged):
         block_table=paged["block_table"],
         use_pallas=paged.get("use_pallas"),
         partitioned=paged.get("partitioned", False),
+        mesh=paged.get("mesh"),
+        head_axes=paged.get("head_axes"),
     )
 
 
@@ -290,7 +292,9 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, config, rules,
                                       config)
     attended = ops.flash_attention(
         q, k, v, causal=True, mask=prompt_mask,
-        partitioned=mesh is not None,
+        partitioned=mesh is not None, mesh=mesh,
+        batch_axes=rules.assignment("batch"),
+        head_axes=rules.assignment("heads"),
     )
     att_out = layers.dense_apply(
         layer_params["att"]["out"], attended.reshape(b, t, -1)
@@ -410,7 +414,8 @@ def _decode_step(params, cache, token, cur_len, config, rules, mesh,
     paged_base = None
     if block_table is not None:
         paged_base = {"block_table": block_table, "use_pallas": use_pallas,
-                      "partitioned": mesh is not None}
+                      "partitioned": mesh is not None, "mesh": mesh,
+                      "head_axes": rules.assignment("heads")}
 
     def layer_body(x, layer_slice):
         if pool is None:
@@ -1158,7 +1163,8 @@ def prefill_chunk_program(
                 "chunk", q, row, jnp.reshape(start + 1, (1,)),
                 {"pool_l": pool_l, "block_table": table_row,
                  "use_pallas": use_pallas,
-                 "partitioned": mesh is not None},
+                 "partitioned": mesh is not None, "mesh": mesh,
+                 "head_axes": rules.assignment("heads")},
             )
         att_out = layers.dense_apply(
             layer_params["att"]["out"], attended.reshape(1, c, -1)
@@ -1376,7 +1382,8 @@ def verify_chunk_program(
                 "verify", q, cache_l, pos + 1,
                 {"pool_l": pool_l, "block_table": block_table,
                  "use_pallas": use_pallas,
-                 "partitioned": mesh is not None},
+                 "partitioned": mesh is not None, "mesh": mesh,
+                 "head_axes": rules.assignment("heads")},
             )
         att_out = layers.dense_apply(
             layer_params["att"]["out"], attended.reshape(num_slots, k, -1)

@@ -260,12 +260,14 @@ def sharded_attention(q, k, v, *, causal: bool,
     The single routing point shared by CloudLM and BERT:
 
     - inside a partial-manual region (the pp pipeline body):
-      ``partitioned=True`` dispatch — the kernels go through
-      ``custom_partitioning`` so the partitioner places them over the
-      remaining auto axes itself.  (A nested shard_map verify-fails at the
-      sdy level there — "manual axis after free axis" — and an unwrapped
-      pallas_call would be fully replicated; custom_partitioning is the
-      route that keeps pipelined attention O(T), VERDICT r2 weak #5.)
+      ``partitioned=True`` dispatch, which there is the direct call (the
+      shapes are the region's own).  No route puts the COMPILED kernel
+      there yet (a nested shard_map verify-fails at the sdy level —
+      "manual axis after free axis" — JAX refuses an unwrapped Mosaic
+      call, libtpu refuses ``custom_partitioning`` on more than one
+      chip), so on the chip auto-dispatch takes the jnp reference with a
+      warning (ROADMAP S8); the interpreter's kernel is plain HLO and
+      runs there as it is.
     - ``sp`` > 1 and ``ulysses``: sequence<->head re-sharding all-to-all
       (the DeepSpeed-Ulysses pattern) — each rank attends over the FULL
       sequence for its head group, so there are no ring hops at all:
@@ -273,9 +275,8 @@ def sharded_attention(q, k, v, *, causal: bool,
       the ring's O(sp) K/V hops.  Requires local heads (H / tp) to
       divide by sp; indivisible head counts fall back to the ring.
     - ``sp`` > 1 otherwise: ring attention over the sequence axis
-    - mesh present: ``partitioned=True`` dispatch here too — measured
-      ~11% faster than the former full-manual shard_map wrapper on a v5e
-      chip (B2 T2048 H8 D64 value+grad) and one code path instead of two
+    - mesh present: ``partitioned=True`` dispatch — the kernels per
+      (batch, heads) shard, split over the axes ``rules`` assign to them
     - otherwise: direct dispatch (kernel on TPU, jnp reference elsewhere)
 
     ``mask`` is a [B, T_k] valid-token padding mask; the flash kernels
@@ -389,8 +390,11 @@ def sharded_attention(q, k, v, *, causal: bool,
             check_vma=False,
         )(*args)
     if mesh is not None and sp_size == 1:
-        return ops.flash_attention(q, k, v, causal=causal, mask=mask,
-                                   partitioned=True)
+        return ops.flash_attention(
+            q, k, v, causal=causal, mask=mask, partitioned=True, mesh=mesh,
+            batch_axes=rules.assignment("batch"),
+            head_axes=rules.assignment("heads"),
+        )
     # No mesh at all: direct dispatch.
     return ops.flash_attention(q, k, v, causal=causal, mask=mask)
 

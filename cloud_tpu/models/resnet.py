@@ -15,12 +15,14 @@ the BASELINE.json north-star "Keras ResNet50 steps/sec/chip".
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from cloud_tpu.models import layers
+from cloud_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,7 +69,8 @@ def _gn_init(c):
     return {"scale": jnp.ones((c,), jnp.float32), "bias": jnp.zeros((c,), jnp.float32)}
 
 
-def _gn(params, x, num_groups, activation=None, residual=None):
+def _gn(params, x, num_groups, activation=None, residual=None, *,
+        mesh=None, batch_axes=None):
     # Dispatches to the fused Pallas kernel on TPU (one HBM read for
     # stats+normalize+affine, custom VJP); the jnp fallback inside is the
     # one-pass shifted-moments implementation this model used previously
@@ -79,6 +82,7 @@ def _gn(params, x, num_groups, activation=None, residual=None):
     return ops.group_norm(
         x, params["scale"], params["bias"], num_groups=num_groups,
         activation=activation, residual=residual,
+        mesh=mesh, batch_axes=batch_axes,
     )
 
 
@@ -99,21 +103,21 @@ def _bottleneck_init(rng, cin, cmid, stride):
     return block
 
 
-def _bottleneck(params, x, cfg, stride):
+def _bottleneck(params, x, cfg, stride, gn):
     residual = x
-    y = _gn(params["gn1"], _conv(params["conv1"], x), cfg.num_groups,
-            activation="relu")
-    y = _gn(params["gn2"], _conv(params["conv2"], y, stride=stride),
-            cfg.num_groups, activation="relu")
+    y = gn(params["gn1"], _conv(params["conv1"], x), cfg.num_groups,
+           activation="relu")
+    y = gn(params["gn2"], _conv(params["conv2"], y, stride=stride),
+           cfg.num_groups, activation="relu")
     if "proj" in params:
-        residual = _gn(
+        residual = gn(
             params["gn_proj"], _conv(params["proj"], x, stride=stride),
             cfg.num_groups,
         )
     # Tail fusion: relu(gn3(conv3) + residual) in one kernel pass — the
     # separate add+relu re-read both [B,H,W,C] tensors from HBM.
-    return _gn(params["gn3"], _conv(params["conv3"], y), cfg.num_groups,
-               activation="relu", residual=residual)
+    return gn(params["gn3"], _conv(params["conv3"], y), cfg.num_groups,
+              activation="relu", residual=residual)
 
 
 def init(rng, config: ResNetConfig = RESNET50) -> Dict[str, Any]:
@@ -147,25 +151,35 @@ def param_logical_axes(config: ResNetConfig = RESNET50):
     return jax.tree_util.tree_map(lambda leaf: (None,) * leaf.ndim, params)
 
 
-def apply(params, images: jnp.ndarray, config: ResNetConfig = RESNET50):
-    """images [B, H, W, 3] -> logits [B, num_classes]."""
+def apply(params, images: jnp.ndarray, config: ResNetConfig = RESNET50, *,
+          rules: ShardingRules = DEFAULT_RULES, mesh=None):
+    """images [B, H, W, 3] -> logits [B, num_classes].
+
+    Under a mesh (``mesh``, default the framework's global one) the fused
+    GroupNorm kernel runs per batch shard: ``rules`` — the table the
+    batch was sharded by — says over which mesh axes."""
+    gn = functools.partial(_gn, mesh=mesh,
+                           batch_axes=rules.assignment("batch"))
     x = images.astype(config.dtype)
     x = _conv(params["stem"], x, stride=2)
-    x = _gn(params["gn_stem"], x, config.num_groups, activation="relu")
+    x = gn(params["gn_stem"], x, config.num_groups, activation="relu")
     x = jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME"
     )
     for stage, num_blocks in enumerate(config.stage_sizes):
         for block in range(num_blocks):
             stride = 2 if (block == 0 and stage > 0) else 1
-            x = _bottleneck(params[f"stage{stage}_block{block}"], x, config, stride)
+            x = _bottleneck(params[f"stage{stage}_block{block}"], x, config,
+                            stride, gn)
     x = jnp.mean(x, axis=(1, 2))
     return layers.dense_apply(params["head"], x, dtype=jnp.float32)
 
 
 def loss_fn(params, batch: Dict[str, jnp.ndarray],
-            config: ResNetConfig = RESNET50) -> Tuple[jnp.ndarray, Dict]:
-    logits = apply(params, batch["image"], config)
+            config: ResNetConfig = RESNET50, *,
+            rules: ShardingRules = DEFAULT_RULES,
+            mesh=None) -> Tuple[jnp.ndarray, Dict]:
+    logits = apply(params, batch["image"], config, rules=rules, mesh=mesh)
     labels = batch["label"]
     log_probs = jax.nn.log_softmax(logits)
     loss = -jnp.mean(jnp.take_along_axis(log_probs, labels[:, None], axis=-1))

@@ -7,7 +7,6 @@ implementation used off-TPU (and as the ground truth in tests); dispatch
 is automatic.
 """
 
-from cloud_tpu.utils import jax_compat as _jax_compat  # noqa: F401  (shims)
 from cloud_tpu.ops.flash_attention import flash_attention
 from cloud_tpu.ops.fused_cross_entropy import fused_linear_cross_entropy
 from cloud_tpu.ops.group_norm import group_norm

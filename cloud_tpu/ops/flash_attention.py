@@ -148,10 +148,10 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_mask):
             )
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if mask_ref is not None:
-            # Key-side padding mask [block_k] (nonzero = valid token),
+            # Key-side padding mask [1, block_k] (nonzero = valid token),
             # broadcast over query rows — matches the reference path's
             # mask[:, None, None, :] semantics.
-            s = jnp.where(mask_ref[0][None, :] != 0, s, NEG_INF)
+            s = jnp.where(mask_ref[0] != 0, s, NEG_INF)
 
         m_prev = m_scr[:, :1]  # [block_q, 1] (value replicated over lanes)
         l_prev = l_scr[:, :1]
@@ -228,9 +228,9 @@ def _fwd_pallas(q, k, v, mask, *, causal, block_q, block_k, interpret):
     operands = [q, k, v]
     if mask is not None:
         in_specs.append(
-            pl.BlockSpec((1, block_k), lambda b_, h_, qi, ki: (b_, ki))
+            pl.BlockSpec((1, 1, block_k), lambda b_, h_, qi, ki: (b_, 0, ki))
         )
-        operands.append(mask)
+        operands.append(_mask_rows(mask))
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
@@ -256,6 +256,14 @@ def _fwd_pallas(q, k, v, mask, *, causal, block_q, block_k, interpret):
         interpret=interpret,
     )(*operands)
     return out, lse
+
+
+def _mask_rows(mask):
+    """[B, T] -> [B, 1, T]: a (1, block_k) block of the rank-2 mask has a
+    second-to-last dim of 1, neither a multiple of 8 nor the array's own,
+    and Mosaic refuses it; with a unit middle axis the block (1, 1,
+    block_k) takes the array's own there."""
+    return mask[:, None, :]
 
 
 def _vmem(shape, dtype):
@@ -316,7 +324,7 @@ def _bwd_dq_kernel(*refs, scale, causal, block_q, block_k, use_mask,
             k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if mask_ref is not None:
-            s = jnp.where(mask_ref[0][None, :] != 0, s, NEG_INF)
+            s = jnp.where(mask_ref[0] != 0, s, NEG_INF)
         p = jnp.exp(s - lse)  # [block_q, block_k]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -378,7 +386,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, use_mask,
         if mask_ref is not None:
             # This grid walks key blocks in dim 2: the mask block is the
             # one covering this kernel's key rows (index i, not j).
-            s = jnp.where(mask_ref[0][None, :] != 0, s, NEG_INF)
+            s = jnp.where(mask_ref[0] != 0, s, NEG_INF)
         p = jnp.exp(s - lse)  # [block_q, block_k]
         dv_scr[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -433,9 +441,9 @@ def _bwd_pallas(q, k, v, mask, do, out, lse, *, causal, block_q, block_k,
         dq_operands.append(g_lse)
     if use_mask:
         dq_in_specs.append(
-            pl.BlockSpec((1, block_k), lambda b_, h_, i, j: (b_, j))
+            pl.BlockSpec((1, 1, block_k), lambda b_, h_, i, j: (b_, 0, j))
         )
-        dq_operands.append(mask)
+        dq_operands.append(_mask_rows(mask))
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, scale=scale, causal=causal,
@@ -466,9 +474,9 @@ def _bwd_pallas(q, k, v, mask, do, out, lse, *, causal, block_q, block_k,
         dkv_operands.append(g_lse)
     if use_mask:
         dkv_in_specs.append(
-            pl.BlockSpec((1, block_k), lambda b_, h_, i, j: (b_, i))
+            pl.BlockSpec((1, 1, block_k), lambda b_, h_, i, j: (b_, 0, i))
         )
-        dkv_operands.append(mask)
+        dkv_operands.append(_mask_rows(mask))
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, scale=scale, causal=causal,
@@ -570,119 +578,58 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Partitioner-visible kernels (custom_partitioning)
+# Mesh route
 # ---------------------------------------------------------------------------
 #
-# ``pallas_call`` lowers to a custom call GSPMD cannot partition: in an
-# auto-sharded context an unwrapped kernel would replicate every operand,
-# and a nested shard_map inside the pipeline's partial-manual region fails
-# sdy verification ("manual axis after free axis" — models/layers.py).
-# ``custom_partitioning`` is the third route: declare a Shardy sharding
-# rule (batch/heads shardable, sequence/depth need-replication) and hand
-# the partitioner a per-shard lowering.  This is what lets the flash
-# kernel run INSIDE pipeline stages (VERDICT r2 weak #5).
+# ``pallas_call`` lowers to a custom call the partitioner cannot split: in
+# an auto-sharded context an unwrapped kernel would replicate every
+# operand.  ``partitioned=True`` under a mesh of more than one device runs
+# the kernels per (batch, heads) shard in a full-manual shard_map
+# (ops/dispatch.py says why not ``custom_partitioning``).  INSIDE a
+# partial-manual region (the pp pipeline body) there is no route the
+# chip's compiler takes yet — a nested shard_map fails sdy verification
+# ("manual axis after free axis"), JAX refuses a Mosaic call there, libtpu
+# refuses ``custom_partitioning`` on more than one chip — so there the
+# compiled kernel is not offered (ROADMAP S8); the interpreter, whose
+# kernel is plain HLO the partitioner splits itself, is called directly.
 
 
-@functools.lru_cache(maxsize=None)
-def _cp_fwd_call(causal, block_q, block_k, interpret, use_mask):
-    """Forward kernel wrapped for the partitioner ([B,H,T,D] layout)."""
-    from jax.experimental.custom_partitioning import (
-        SdyShardingRule,
-        custom_partitioning,
+def _flash_sharded(mesh, batch_axes, head_axes, q, k, v, mask_i32, *,
+                   causal, block_q, block_k, interpret):
+    """The kernels per (batch, heads) shard of ``mesh``; [B, T, H, D] in and
+    out.  Sequence and depth are whole in every shard."""
+    from jax.sharding import PartitionSpec as P
+
+    batch = dispatch_lib.dividing_axes(mesh, batch_axes, q.shape[0])
+    heads = dispatch_lib.dividing_axes(mesh, head_axes, q.shape[2])
+    bthd = P(batch, None, heads, None)
+
+    def local(q, k, v, *mask):
+        out = _flash(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), mask[0] if mask else None,
+            causal, block_q, block_k, interpret,
+        )
+        return out.transpose(0, 2, 1, 3)
+
+    masks = () if mask_i32 is None else (mask_i32,)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(bthd,) * 3 + (P(batch, None),) * len(masks),
+        out_specs=bthd, check_vma=False,
+    )(q, k, v, *masks)
+
+
+def _in_partial_manual_region() -> bool:
+    """Inside a shard_map that is manual over SOME mesh axes (the pp
+    pipeline body) — not a full-manual one (ring attention, Ulysses, the
+    mesh route above), where a kernel call is one device's own."""
+    from cloud_tpu.parallel import sharding as sharding_lib
+
+    am = sharding_lib.manual_context_mesh()
+    return am is not None and any(
+        t != jax.sharding.AxisType.Manual for t in am.axis_types
     )
-
-    def impl(*args):
-        q, k, v = args[:3]
-        mask = args[3] if use_mask else None
-        return _fwd_pallas(q, k, v, mask, causal=causal, block_q=block_q,
-                           block_k=block_k, interpret=interpret)
-
-    fn = custom_partitioning(impl)
-
-    # t/d are need-replication factors, so q's sharding tiles only (b, h)
-    # — and lse [B,H,T,1] (rank 4, same leading dims) therefore shards
-    # identically to out; both reuse q's sharding.
-    infer, part = dispatch_lib.passthrough_callbacks(impl, 2)
-
-    bhtd = ("b", "h", "t", "d")
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=part,
-        sharding_rule=SdyShardingRule(
-            operand_mappings=((bhtd,) * 3
-                              + ((("b", "t"),) if use_mask else ())),
-            result_mappings=(bhtd, ("b", "h", "t2", "d2")),
-            need_replication_factors=("t", "d", "t2", "d2"),
-        ),
-    )
-    return fn
-
-
-@functools.lru_cache(maxsize=None)
-def _cp_bwd_call(causal, block_q, block_k, interpret, use_mask):
-    """Backward kernels wrapped for the partitioner: (q, k, v, do, out,
-    lse[, mask]) -> (dq, dk, dv)."""
-    from jax.experimental.custom_partitioning import (
-        SdyShardingRule,
-        custom_partitioning,
-    )
-
-    def impl(*args):
-        q, k, v, do, out, lse = args[:6]
-        mask = args[6] if use_mask else None
-        return _bwd_pallas(q, k, v, mask, do, out, lse, causal=causal,
-                           block_q=block_q, block_k=block_k,
-                           interpret=interpret)
-
-    fn = custom_partitioning(impl)
-
-    # dq/dk/dv all shard like q ([B,H,T,D], t/d replicated by the rule).
-    infer, part = dispatch_lib.passthrough_callbacks(impl, 3)
-
-    bhtd = ("b", "h", "t", "d")
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=part,
-        sharding_rule=SdyShardingRule(
-            operand_mappings=((bhtd,) * 5 + (("b", "h", "t2", "d2"),)
-                              + ((("b", "t"),) if use_mask else ())),
-            result_mappings=(bhtd,) * 3,
-            need_replication_factors=("t", "d", "t2", "d2"),
-        ),
-    )
-    return fn
-
-
-@functools.lru_cache(maxsize=None)
-def _flash_partitioned(causal, block_q, block_k, interpret, use_mask):
-    """custom_vjp around the partitioner-visible kernels.  The vjp sits
-    OUTSIDE custom_partitioning (which has no autodiff rules): the forward
-    cp call appears in the primal HLO, the backward cp call in the
-    cotangent HLO, and each is partitioned independently."""
-    fwd_call = _cp_fwd_call(causal, block_q, block_k, interpret, use_mask)
-    bwd_call = _cp_bwd_call(causal, block_q, block_k, interpret, use_mask)
-
-    @jax.custom_vjp
-    def f(*args):  # (q, k, v[, mask_i32])
-        out, _ = fwd_call(*args)
-        return out
-
-    def f_fwd(*args):
-        out, lse = fwd_call(*args)
-        return out, args + (out, lse)
-
-    def f_bwd(res, g):
-        args, out, lse = res[:-2], res[-2], res[-1]
-        q, k, v = args[:3]
-        grads = bwd_call(q, k, v, g, out, lse, *args[3:])
-        if use_mask:
-            return tuple(grads) + (
-                np.zeros(args[3].shape, jax.dtypes.float0),
-            )
-        return tuple(grads)
-
-    f.defvjp(f_fwd, f_bwd)
-    return f
 
 
 def _score_bytes(q, k) -> int:
@@ -711,8 +658,9 @@ def _warn_partitioned_fallback(q, k, mask):
     """One-time warning when a ``partitioned=True`` caller (the pipeline
     region / mesh-auto path, which EXPECTS the O(T) kernel) falls back to
     the O(T^2) reference at a size where that hurts — ineligible shapes
-    (unalignable T, head_dim > 256, mask shape mismatch) reach here with
-    no other signal."""
+    (unalignable T, head_dim > 256, mask shape mismatch) and the compiled
+    kernel inside a partial-manual region reach here with no other
+    signal."""
     global _partitioned_fallback_warned
     if _partitioned_fallback_warned:
         return
@@ -725,37 +673,52 @@ def _warn_partitioned_fallback(q, k, mask):
 
     logging.getLogger(__name__).warning(
         "partitioned attention dispatch at q shape %s fell back to the "
-        "O(T^2) jnp reference (shape not kernel-eligible: unalignable T, "
-        "head_dim > 256, or mask shape mismatch). Expect per-layer score "
-        "residual memory; pad T to an 8-aligned size to restore the "
-        "flash kernel.",
+        "O(T^2) jnp reference (%s). Expect per-layer score residual "
+        "memory.",
         tuple(q.shape),
+        "the compiled kernel has no route inside a partial-manual region "
+        "(the pp pipeline body) yet"
+        if _in_partial_manual_region() else
+        "shape not kernel-eligible: unalignable T, head_dim > 256, or mask "
+        "shape mismatch; pad T to an 8-aligned size to restore the kernel",
     )
 
 
 def _dispatch(q, k, v, *, causal, mask, block_q, block_k, use_pallas,
-              interpret, with_lse, partitioned=False):
+              interpret, with_lse, partitioned=False, mesh=None,
+              batch_axes=None, head_axes=None):
     """Shared fit/dispatch/transpose wrapper for both public entry points
     (kept in ONE place so mask/fit rules can't drift between them)."""
     explicit_opt_out = use_pallas is False
     if not interpret and dispatch_lib.force_interpret():
         interpret = True
     fitted_q = _fit_block(q.shape[1], block_q)
-    fitted_k = _fit_block(k.shape[1], block_k)
-    mask_ok = mask is None or (
-        mask.ndim == 2
-        and mask.shape[0] == q.shape[0]
-        and mask.shape[1] == k.shape[1]
-    )
+    fitted_k = _fit_block(k.shape[1], block_k, lane_aligned=mask is not None)
+    shape_ok = _mask_ok(q, k, mask) and _shape_eligible(q, k)
+    if use_pallas and not shape_ok:
+        # An explicit request is never answered with the jnp reference.
+        # (A T no block fits is _check_divisible's error, further down.)
+        raise ValueError(
+            "flash_attention(use_pallas=True): the kernel cannot take "
+            f"q{tuple(q.shape)} k{tuple(k.shape)} "
+            f"mask{None if mask is None else tuple(mask.shape)} (needs "
+            "[B,T,H,D] q and k of one shape, head_dim <= 256, mask [B,T])"
+        )
+    if use_pallas and not interpret and _in_partial_manual_region():
+        raise NotImplementedError(
+            "flash_attention(use_pallas=True) inside a partial-manual "
+            "region (the pp pipeline body): no route compiles for the "
+            "chip there yet (ROADMAP S8)"
+        )
     if use_pallas is None:
-        use_pallas = would_use_kernel(q, k, mask, block_q=block_q,
-                                      block_k=block_k)
-    if interpret and _kernel_eligible(q, k, fitted_q, fitted_k):
-        # Force the interpreter ONLY where the kernels apply — shapes the
-        # kernels can't express (rectangular q/k, oversize head_dim,
-        # unalignable T) must still fall through to the reference.
-        use_pallas = True
-    if not use_pallas or not mask_ok:
+        # Auto: by shape on TPU; under the interpreter wherever the
+        # kernels apply — shapes they cannot express (rectangular q/k,
+        # oversize head_dim, unalignable T) still take the reference.
+        use_pallas = would_use_kernel(
+            q, k, mask, block_q=block_q, block_k=block_k
+        ) or (interpret and shape_ok
+              and fitted_q is not None and fitted_k is not None)
+    if not use_pallas:
         # Warn only when AUTO dispatch fell back — an explicit
         # use_pallas=False caller opted out deliberately.
         if partitioned and not explicit_opt_out:
@@ -768,22 +731,18 @@ def _dispatch(q, k, v, *, causal, mask, block_q, block_k, use_pallas,
     # path only) falls through to the clamp and _check_divisible's error.
     block_q = fitted_q if fitted_q is not None else min(block_q, q.shape[1])
     block_k = fitted_k if fitted_k is not None else min(block_k, k.shape[1])
+    mask_i32 = None if mask is None else mask.astype(jnp.int32)
+    kernel_mesh = dispatch_lib.kernel_mesh(mesh) if partitioned else None
+    if kernel_mesh is not None:
+        return _flash_sharded(
+            kernel_mesh, batch_axes, head_axes, q, k, v, mask_i32,
+            causal=causal, block_q=block_q, block_k=block_k,
+            interpret=interpret,
+        )
     # [B, T, H, D] -> [B, H, T, D] for (T, D)-tiled kernels.
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    mask_i32 = None if mask is None else mask.astype(jnp.int32)
-    if partitioned:
-        if with_lse:
-            raise NotImplementedError(
-                "partitioned dispatch covers the out-only entry point "
-                "(ring attention wraps its own full-manual shard_map)"
-            )
-        f = _flash_partitioned(
-            causal, block_q, block_k, interpret, mask is not None
-        )
-        args = (qt, kt, vt) + (() if mask is None else (mask_i32,))
-        return f(*args).transpose(0, 2, 1, 3)
     if with_lse:
         out, lse = _flash_lse(
             qt, kt, vt, mask_i32, causal, block_q, block_k, interpret
@@ -815,27 +774,48 @@ def flash_attention_with_lse(
     )
 
 
-def _fit_block(t: int, block: int) -> Optional[int]:
+def _fit_block(t: int, block: int, *, lane_aligned: bool = False
+               ) -> Optional[int]:
     """Largest multiple-of-8 block <= ``block`` that divides ``t``.
 
     T=768 with the default block_k=512 fits at 384 (not a clamp — 512
     doesn't divide 768); T=100 has no 8-aligned divisor and returns None
-    (the (8,128) sublane tile would break)."""
-    for candidate in range(min(block, t) - min(block, t) % 8, 7, -8):
+    (the (8,128) sublane tile would break).  ``lane_aligned`` is for the
+    key block under a mask: it is the LAST dim of the mask's block, so
+    it must be a multiple of 128 unless it is the whole of T."""
+    top = min(block, t)
+    if lane_aligned and top == t:
+        return t if t % 8 == 0 else None
+    step = 128 if lane_aligned else 8
+    for candidate in range(top - top % step, step - 1, -step):
         if t % candidate == 0:
             return candidate
     return None
+
+
+def _mask_ok(q, k, mask) -> bool:
+    return mask is None or (
+        mask.ndim == 2
+        and mask.shape[0] == q.shape[0]
+        and mask.shape[1] == k.shape[1]
+    )
+
+
+def _shape_eligible(q, k) -> bool:
+    return (
+        q.ndim == 4
+        and q.shape == k.shape
+        and q.shape[-1] <= 256  # head_dim beyond this overflows VMEM blocks
+    )
 
 
 def _kernel_eligible(q, k, block_q, block_k) -> bool:
     """Called with blocks already fitted to T: both must have resolved to
     8-aligned divisors of their sequence length."""
     return (
-        q.ndim == 4
-        and q.shape == k.shape
+        _shape_eligible(q, k)
         and block_q is not None
         and block_k is not None
-        and q.shape[-1] <= 256  # head_dim beyond this overflows VMEM blocks
     )
 
 
@@ -849,20 +829,14 @@ def would_use_kernel(
 ) -> bool:
     """The full ``use_pallas=None`` auto-dispatch predicate, exposed so
     callers (tests, capacity planners) never duplicate it and drift."""
-    import jax as _jax
-
     fitted_q = _fit_block(q.shape[1], block_q)
-    fitted_k = _fit_block(k.shape[1], block_k)
-    mask_ok = mask is None or (
-        mask.ndim == 2
-        and mask.shape[0] == q.shape[0]
-        and mask.shape[1] == k.shape[1]
-    )
+    fitted_k = _fit_block(k.shape[1], block_k, lane_aligned=mask is not None)
     return (
-        _jax.default_backend() == "tpu"
-        and mask_ok
+        jax.default_backend() == "tpu"
+        and _mask_ok(q, k, mask)
         and _kernel_worthwhile(q, k)
         and _kernel_eligible(q, k, fitted_q, fitted_k)
+        and not _in_partial_manual_region()
     )
 
 
@@ -878,6 +852,9 @@ def flash_attention(
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
     partitioned: bool = False,
+    mesh=None,
+    batch_axes=None,
+    head_axes=None,
 ) -> jnp.ndarray:
     """Attention over [B, T, H, D] tensors, differentiable.
 
@@ -889,14 +866,18 @@ def flash_attention(
     reference path.  ``interpret=True`` runs the kernels in the Pallas
     interpreter (CPU tests of kernel logic).
 
-    ``partitioned=True`` emits the kernels through ``custom_partitioning``
-    so the GSPMD/shardy partitioner places them itself (batch/heads
+    ``partitioned=True`` places the kernels under a mesh (batch/heads
     shardable, sequence replicated) instead of the caller wrapping a
-    shard_map.  Required inside partial-manual regions (the pipeline
-    body); valid in any auto-sharded context.
+    shard_map: over ``mesh`` (default: the framework's global mesh) in a
+    full-manual shard_map, batch and heads split over ``batch_axes`` /
+    ``head_axes`` — the mesh axes the CALLER's sharding rules assign to
+    them (None: not split).  Inside a partial-manual region (the pipeline
+    body) the compiled kernel is not offered yet: ``use_pallas=True``
+    raises, auto-dispatch takes the reference with a warning.
     """
     return _dispatch(
         q, k, v, causal=causal, mask=mask, block_q=block_q, block_k=block_k,
         use_pallas=use_pallas, interpret=interpret, with_lse=False,
-        partitioned=partitioned,
+        partitioned=partitioned, mesh=mesh, batch_axes=batch_axes,
+        head_axes=head_axes,
     )

@@ -63,10 +63,8 @@ def _reference(x, scale, bias, num_groups, eps=1e-5, relu=False,
 
 
 def _onehot(c: int, g: int) -> jnp.ndarray:
-    """[C, G] channel->group fold matrix.  Built from iota (traced ops,
-    not a baked array constant): custom_partitioning traces its impl with
-    an empty const list, so a materialized jnp constant would trip its
-    ``assert not consts``."""
+    """[C, G] channel->group fold matrix, built from iota (traced ops,
+    not a baked array constant)."""
     cg = c // g
     ch_group = jax.lax.broadcasted_iota(jnp.int32, (c, g), 0) // cg
     group = jax.lax.broadcasted_iota(jnp.int32, (c, g), 1)
@@ -106,8 +104,8 @@ def _fwd_kernel(x_ref, scale_ref, bias_ref, oh_ref, oht_ref, y_ref,
         # read+write of the whole activation on a bandwidth-bound model.
         y = jnp.maximum(y, 0.0)
     y_ref[0] = y.reshape(h, w, c).astype(y_ref.dtype)
-    mean_ref[0] = mean_g[0]
-    rstd_ref[0] = rstd_g[0]
+    mean_ref[0] = mean_g
+    rstd_ref[0] = rstd_g
 
 
 def _fwd_kernel_res(x_ref, scale_ref, bias_ref, res_ref, oh_ref, oht_ref,
@@ -124,8 +122,8 @@ def _fwd_kernel_res(x_ref, scale_ref, bias_ref, res_ref, oh_ref, oht_ref,
     if relu:
         y = jnp.maximum(y, 0.0)
     y_ref[0] = y.reshape(h, w, c).astype(y_ref.dtype)
-    mean_ref[0] = mean_g[0]
-    rstd_ref[0] = rstd_g[0]
+    mean_ref[0] = mean_g
+    rstd_ref[0] = rstd_g
 
 
 def _bwd_core(x2, dy2, mean_row, rstd_row, scale_row, oh, oht, n):
@@ -138,8 +136,8 @@ def _bwd_core(x2, dy2, mean_row, rstd_row, scale_row, oh, oht, n):
     a_c = (jnp.sum(dxh, axis=0, keepdims=True) @ oh) @ oht         # [1, C]
     b_c = (jnp.sum(dxh * xhat, axis=0, keepdims=True) @ oh) @ oht   # [1, C]
     dx = rstd_c * (dxh - (a_c + xhat * b_c) / n)
-    ds = jnp.sum(dy2 * xhat, axis=0)                # [C] per-sample partial
-    db = jnp.sum(dy2, axis=0)                       # [C]
+    ds = jnp.sum(dy2 * xhat, axis=0, keepdims=True)  # [1, C] per-sample partial
+    db = jnp.sum(dy2, axis=0, keepdims=True)         # [1, C]
     return dx, ds, db, xhat
 
 
@@ -157,12 +155,12 @@ def _bwd_kernel(x_ref, dy_ref, mean_ref, rstd_ref, scale_ref, bias_ref,
     if relu:
         # Recompute the pre-activation sign from the saved stats: the
         # relu gate zeroes the cotangent where the fused forward clamped.
-        mean_c = mean_ref[...] @ oht
-        rstd_c = rstd_ref[...] @ oht
+        mean_c = mean_ref[0] @ oht
+        rstd_c = rstd_ref[0] @ oht
         pre = (x2 - mean_c) * rstd_c * scale_ref[...] + bias_ref[...]
         dy2 = jnp.where(pre > 0.0, dy2, 0.0)
     dx, ds, db, _ = _bwd_core(
-        x2, dy2, mean_ref[...], rstd_ref[...], scale_ref[...], oh, oht, n
+        x2, dy2, mean_ref[0], rstd_ref[0], scale_ref[...], oh, oht, n
     )
     dx_ref[0] = dx.reshape(h, w, c).astype(dx_ref.dtype)
     ds_ref[0] = ds
@@ -185,8 +183,8 @@ def _bwd_kernel_res(x_ref, dy_ref, mean_ref, rstd_ref, scale_ref, bias_ref,
     n = float(hw * cg)
 
     if relu:
-        mean_c = mean_ref[...] @ oht
-        rstd_c = rstd_ref[...] @ oht
+        mean_c = mean_ref[0] @ oht
+        rstd_c = rstd_ref[0] @ oht
         pre = (
             (x2 - mean_c) * rstd_c * scale_ref[...] + bias_ref[...]
             + res_ref[0].astype(jnp.float32).reshape(hw, c)
@@ -194,7 +192,7 @@ def _bwd_kernel_res(x_ref, dy_ref, mean_ref, rstd_ref, scale_ref, bias_ref,
         dy2 = jnp.where(pre > 0.0, dy2, 0.0)
     dres_ref[0] = dy2.reshape(h, w, c).astype(dres_ref.dtype)
     dx, ds, db, _ = _bwd_core(
-        x2, dy2, mean_ref[...], rstd_ref[...], scale_ref[...], oh, oht, n
+        x2, dy2, mean_ref[0], rstd_ref[0], scale_ref[...], oh, oht, n
     )
     dx_ref[0] = dx.reshape(h, w, c).astype(dx_ref.dtype)
     ds_ref[0] = ds
@@ -206,8 +204,13 @@ def _block_specs(b, h, w, c, g):
     vec_spec = pl.BlockSpec((1, c), lambda i: (0, 0))
     oh_spec = pl.BlockSpec((c, g), lambda i: (0, 0))
     oht_spec = pl.BlockSpec((g, c), lambda i: (0, 0))
-    stat_spec = pl.BlockSpec((1, g), lambda i: (i, 0))
-    return x_spec, vec_spec, oh_spec, oht_spec, stat_spec
+    # Per-sample rows (stats [b, 1, g], dscale/dbias partials [b, 1, c])
+    # carry a unit middle axis: Mosaic wants a block's last two dims
+    # divisible by (8, 128) or equal to the array's, and a (1, g) block of
+    # [b, g] is neither, while (1, 1, g) of [b, 1, g] is the array's own.
+    stat_spec = pl.BlockSpec((1, 1, g), lambda i: (i, 0, 0))
+    partial_spec = pl.BlockSpec((1, 1, c), lambda i: (i, 0, 0))
+    return x_spec, vec_spec, oh_spec, oht_spec, stat_spec, partial_spec
 
 
 def _fwd_pallas(x, scale, bias, num_groups, eps, interpret, relu=False):
@@ -217,7 +220,8 @@ def _fwd_pallas(x, scale, bias, num_groups, eps, interpret, relu=False):
     g = min(num_groups, c)
     hw, cg = h * w, c // g
     oh = _onehot(c, g)
-    x_spec, vec_spec, oh_spec, oht_spec, stat_spec = _block_specs(b, h, w, c, g)
+    x_spec, vec_spec, oh_spec, oht_spec, stat_spec, _ = _block_specs(
+        b, h, w, c, g)
     y, mean, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps, hw=hw, cg=cg, relu=relu),
         grid=(b,),
@@ -225,8 +229,8 @@ def _fwd_pallas(x, scale, bias, num_groups, eps, interpret, relu=False):
         out_specs=[x_spec, stat_spec, stat_spec],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct((b, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, g), jnp.float32),
         ],
         interpret=interpret,
     )(x, scale.reshape(1, c), bias.reshape(1, c), oh, oh.T)
@@ -241,8 +245,8 @@ def _bwd_pallas(x, dy, mean, rstd, scale, bias, num_groups, interpret,
     g = min(num_groups, c)
     hw, cg = h * w, c // g
     oh = _onehot(c, g)
-    x_spec, vec_spec, oh_spec, oht_spec, stat_spec = _block_specs(b, h, w, c, g)
-    partial_spec = pl.BlockSpec((1, c), lambda i: (i, 0))
+    (x_spec, vec_spec, oh_spec, oht_spec, stat_spec,
+     partial_spec) = _block_specs(b, h, w, c, g)
     dx, ds, db = pl.pallas_call(
         functools.partial(_bwd_kernel, hw=hw, cg=cg, relu=relu),
         grid=(b,),
@@ -251,8 +255,8 @@ def _bwd_pallas(x, dy, mean, rstd, scale, bias, num_groups, interpret,
         out_specs=[x_spec, partial_spec, partial_spec],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct((b, c), jnp.float32),
-            jax.ShapeDtypeStruct((b, c), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
         ],
         interpret=interpret,
     )(x, dy, mean, rstd, scale.reshape(1, c), bias.reshape(1, c), oh, oh.T)
@@ -267,7 +271,8 @@ def _fwd_pallas_res(x, scale, bias, residual, num_groups, eps, interpret,
     g = min(num_groups, c)
     hw, cg = h * w, c // g
     oh = _onehot(c, g)
-    x_spec, vec_spec, oh_spec, oht_spec, stat_spec = _block_specs(b, h, w, c, g)
+    x_spec, vec_spec, oh_spec, oht_spec, stat_spec, _ = _block_specs(
+        b, h, w, c, g)
     y, mean, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel_res, eps=eps, hw=hw, cg=cg, relu=relu),
         grid=(b,),
@@ -275,8 +280,8 @@ def _fwd_pallas_res(x, scale, bias, residual, num_groups, eps, interpret,
         out_specs=[x_spec, stat_spec, stat_spec],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct((b, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, g), jnp.float32),
         ],
         interpret=interpret,
     )(x, scale.reshape(1, c), bias.reshape(1, c), residual, oh, oh.T)
@@ -291,8 +296,8 @@ def _bwd_pallas_res(x, dy, mean, rstd, scale, bias, residual, num_groups,
     g = min(num_groups, c)
     hw, cg = h * w, c // g
     oh = _onehot(c, g)
-    x_spec, vec_spec, oh_spec, oht_spec, stat_spec = _block_specs(b, h, w, c, g)
-    partial_spec = pl.BlockSpec((1, c), lambda i: (i, 0))
+    (x_spec, vec_spec, oh_spec, oht_spec, stat_spec,
+     partial_spec) = _block_specs(b, h, w, c, g)
     dx, ds, db, dres = pl.pallas_call(
         functools.partial(_bwd_kernel_res, hw=hw, cg=cg, relu=relu),
         grid=(b,),
@@ -301,8 +306,8 @@ def _bwd_pallas_res(x, dy, mean, rstd, scale, bias, residual, num_groups,
         out_specs=[x_spec, partial_spec, partial_spec, x_spec],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct((b, c), jnp.float32),
-            jax.ShapeDtypeStruct((b, c), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
             jax.ShapeDtypeStruct(residual.shape, residual.dtype),
         ],
         interpret=interpret,
@@ -329,7 +334,7 @@ def _gn_bwd(num_groups, eps, interpret, relu, residuals, dy):
     dx, ds, db = _bwd_pallas(
         x, dy, mean, rstd, scale, bias, num_groups, interpret, relu=relu
     )
-    return dx, jnp.sum(ds, axis=0), jnp.sum(db, axis=0)
+    return dx, jnp.sum(ds, axis=(0, 1)), jnp.sum(db, axis=(0, 1))
 
 
 _gn.defvjp(_gn_fwd, _gn_bwd)
@@ -365,217 +370,37 @@ def _gn_res_bwd(num_groups, eps, interpret, relu, residuals, dy):
             relu=False,
         )
         dres = dy.astype(saved_res.dtype)
-    return dx, jnp.sum(ds, axis=0), jnp.sum(db, axis=0), dres
+    return dx, jnp.sum(ds, axis=(0, 1)), jnp.sum(db, axis=(0, 1)), dres
 
 
 _gn_res.defvjp(_gn_res_fwd, _gn_res_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Partitioner-visible route (custom_partitioning), mirroring
-# ops/flash_attention.py: under a mesh an unwrapped pallas_call would be
-# replicated by GSPMD; the Shardy rule (batch shardable, everything else
-# need-replication) lets the partitioner run the kernel per batch shard.
-# Group statistics are returned rank-4 ([B, G, 1, 1]) so every result can
-# reuse x's sharding verbatim — the callbacks then work on the opaque
-# GSPMDShardings a partial-manual region hands them.
+# Mesh route: under the framework's global mesh an unwrapped pallas_call
+# cannot be partitioned, so the kernel runs per batch shard inside a
+# full-manual shard_map (ops/dispatch.py says why not custom_partitioning).
 # ---------------------------------------------------------------------------
 
 
-@functools.lru_cache(maxsize=None)
-def _cp_fwd_call(num_groups, eps, interpret, relu=False):
-    from jax.experimental.custom_partitioning import (
-        SdyShardingRule,
-        custom_partitioning,
-    )
+def _gn_batch_sharded(mesh, batch_axes, x, scale, bias, residual,
+                      num_groups, eps, interpret, relu):
+    from jax.sharding import PartitionSpec as P
 
-    def impl(x, scale, bias):
-        y, mean, rstd = _fwd_pallas(x, scale, bias, num_groups, eps,
-                                    interpret, relu=relu)
-        return y, mean[..., None, None], rstd[..., None, None]
+    def local(x, scale, bias, *res):
+        if res:
+            return _gn_res(x, scale, bias, res[0], num_groups, eps,
+                           interpret, relu)
+        return _gn(x, scale, bias, num_groups, eps, interpret, relu)
 
-    fn = custom_partitioning(impl)
-
-    # Stats come back rank-4 [B, G, 1, 1] precisely so all three results
-    # can reuse x's sharding (only b is shardable under the rule).
-    infer, part = dispatch_lib.passthrough_callbacks(impl, 3)
-
-    bhwc = ("b", "h", "w", "c")
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=part,
-        sharding_rule=SdyShardingRule(
-            operand_mappings=(bhwc, ("c",), ("c",)),
-            result_mappings=(bhwc, ("b", "g", "o1", "o2"),
-                             ("b", "g2", "o3", "o4")),
-            need_replication_factors=(
-                "h", "w", "c", "g", "o1", "o2", "g2", "o3", "o4"
-            ),
-        ),
-    )
-    return fn
-
-
-@functools.lru_cache(maxsize=None)
-def _cp_bwd_call(num_groups, interpret, relu=False):
-    from jax.experimental.custom_partitioning import (
-        SdyShardingRule,
-        custom_partitioning,
-    )
-
-    def impl(x, dy, mean4, rstd4, scale, bias):
-        dx, ds, db = _bwd_pallas(
-            x, dy, mean4[..., 0, 0], rstd4[..., 0, 0], scale, bias,
-            num_groups, interpret, relu=relu,
-        )
-        return dx, ds[:, None, None, :], db[:, None, None, :]
-
-    fn = custom_partitioning(impl)
-
-    # dx and the [B, 1, 1, C] dscale/dbias partials all reuse x's sharding.
-    infer, part = dispatch_lib.passthrough_callbacks(impl, 3)
-
-    bhwc = ("b", "h", "w", "c")
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=part,
-        sharding_rule=SdyShardingRule(
-            operand_mappings=(bhwc, bhwc, ("b", "g", "o1", "o2"),
-                              ("b", "g2", "o3", "o4"), ("c",), ("c",)),
-            result_mappings=(bhwc, ("b", "o5", "o6", "c"),
-                             ("b", "o7", "o8", "c")),
-            need_replication_factors=(
-                "h", "w", "c", "g", "o1", "o2", "g2", "o3", "o4",
-                "o5", "o6", "o7", "o8",
-            ),
-        ),
-    )
-    return fn
-
-
-@functools.lru_cache(maxsize=None)
-def _cp_fwd_call_res(num_groups, eps, interpret, relu):
-    from jax.experimental.custom_partitioning import (
-        SdyShardingRule,
-        custom_partitioning,
-    )
-
-    def impl(x, scale, bias, residual):
-        y, mean, rstd = _fwd_pallas_res(x, scale, bias, residual,
-                                        num_groups, eps, interpret, relu)
-        return y, mean[..., None, None], rstd[..., None, None]
-
-    fn = custom_partitioning(impl)
-    infer, part = dispatch_lib.passthrough_callbacks(impl, 3)
-    bhwc = ("b", "h", "w", "c")
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=part,
-        sharding_rule=SdyShardingRule(
-            operand_mappings=(bhwc, ("c",), ("c",), bhwc),
-            result_mappings=(bhwc, ("b", "g", "o1", "o2"),
-                             ("b", "g2", "o3", "o4")),
-            need_replication_factors=(
-                "h", "w", "c", "g", "o1", "o2", "g2", "o3", "o4"
-            ),
-        ),
-    )
-    return fn
-
-
-@functools.lru_cache(maxsize=None)
-def _cp_bwd_call_res(num_groups, interpret, relu):
-    from jax.experimental.custom_partitioning import (
-        SdyShardingRule,
-        custom_partitioning,
-    )
-
-    def impl(x, dy, mean4, rstd4, scale, bias, residual):
-        dx, ds, db, dres = _bwd_pallas_res(
-            x, dy, mean4[..., 0, 0], rstd4[..., 0, 0], scale, bias,
-            residual, num_groups, interpret, relu,
-        )
-        return dx, ds[:, None, None, :], db[:, None, None, :], dres
-
-    fn = custom_partitioning(impl)
-    infer, part = dispatch_lib.passthrough_callbacks(impl, 4)
-    bhwc = ("b", "h", "w", "c")
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=part,
-        sharding_rule=SdyShardingRule(
-            operand_mappings=(bhwc, bhwc, ("b", "g", "o1", "o2"),
-                              ("b", "g2", "o3", "o4"), ("c",), ("c",),
-                              bhwc),
-            result_mappings=(bhwc, ("b", "o5", "o6", "c"),
-                             ("b", "o7", "o8", "c"), bhwc),
-            need_replication_factors=(
-                "h", "w", "c", "g", "o1", "o2", "g2", "o3", "o4",
-                "o5", "o6", "o7", "o8",
-            ),
-        ),
-    )
-    return fn
-
-
-@functools.lru_cache(maxsize=None)
-def _gn_partitioned_res(num_groups, eps, interpret, relu):
-    fwd_call = _cp_fwd_call_res(num_groups, eps, interpret, relu)
-    bwd_call = _cp_bwd_call_res(num_groups, interpret, relu)
-
-    plain_bwd_call = _cp_bwd_call(num_groups, interpret, relu=False)
-
-    @jax.custom_vjp
-    def f(x, scale, bias, residual):
-        y, _, _ = fwd_call(x, scale, bias, residual)
-        return y
-
-    def f_fwd(x, scale, bias, residual):
-        y, mean4, rstd4 = fwd_call(x, scale, bias, residual)
-        saved_res = residual if relu else residual[:0]
-        return y, (x, mean4, rstd4, scale, bias, saved_res)
-
-    def f_bwd(res, dy):
-        x, mean4, rstd4, scale, bias, saved_res = res
-        if relu:
-            dx, ds4, db4, dres = bwd_call(
-                x, dy, mean4, rstd4, scale, bias, saved_res
-            )
-        else:
-            dx, ds4, db4 = plain_bwd_call(
-                x, dy, mean4, rstd4, scale, bias
-            )
-            dres = dy.astype(saved_res.dtype)
-        return (dx, jnp.sum(ds4, axis=(0, 1, 2)),
-                jnp.sum(db4, axis=(0, 1, 2)), dres)
-
-    f.defvjp(f_fwd, f_bwd)
-    return f
-
-
-@functools.lru_cache(maxsize=None)
-def _gn_partitioned(num_groups, eps, interpret, relu=False):
-    fwd_call = _cp_fwd_call(num_groups, eps, interpret, relu)
-    bwd_call = _cp_bwd_call(num_groups, interpret, relu)
-
-    @jax.custom_vjp
-    def f(x, scale, bias):
-        y, _, _ = fwd_call(x, scale, bias)
-        return y
-
-    def f_fwd(x, scale, bias):
-        y, mean4, rstd4 = fwd_call(x, scale, bias)
-        return y, (x, mean4, rstd4, scale, bias)
-
-    def f_bwd(res, dy):
-        x, mean4, rstd4, scale, bias = res
-        dx, ds4, db4 = bwd_call(x, dy, mean4, rstd4, scale, bias)
-        # Cross-batch reduction OUTSIDE the cp boundary: GSPMD turns the
-        # sharded [B, 1, 1, C] sum into the right collective itself.
-        return dx, jnp.sum(ds4, axis=(0, 1, 2)), jnp.sum(db4, axis=(0, 1, 2))
-
-    f.defvjp(f_fwd, f_bwd)
-    return f
+    res = () if residual is None else (residual,)
+    batch = P(dispatch_lib.dividing_axes(mesh, batch_axes, x.shape[0]))
+    # Axes the specs do not name see the operands replicated.  check_vma
+    # off: pallas_call results carry no varying-axes annotation.
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(batch, P(), P()) + (batch,) * len(res),
+        out_specs=batch, check_vma=False,
+    )(x, scale, bias, *res)
 
 
 def kernel_eligible(x, num_groups, has_residual: bool = False) -> bool:
@@ -607,6 +432,8 @@ def group_norm(
     partitioned: Optional[bool] = None,
     activation: Optional[str] = None,
     residual: Optional[jnp.ndarray] = None,
+    mesh=None,
+    batch_axes=None,
 ) -> jnp.ndarray:
     """GroupNorm over NHWC with affine params [C]; differentiable.
 
@@ -615,10 +442,12 @@ def group_norm(
     reference runs — identical algorithm, so dispatch never changes
     numerics beyond kernel-vs-fusion float ordering.
 
-    ``partitioned=None`` routes through custom_partitioning whenever the
-    framework's global mesh is installed (an unwrapped pallas_call would
-    be replicated by GSPMD there); ``False``/``True`` force the direct /
-    partitioner-visible path.
+    ``partitioned`` — under ``mesh`` (default: the framework's global
+    mesh) of more than one device the kernel runs per batch shard in a
+    shard_map (an unwrapped pallas_call cannot be partitioned there):
+    ``batch_axes`` names the mesh axes the CALLER's batch is split over
+    (its rules' ``"batch"`` assignment; None: every device normalizes
+    the whole batch).  ``False`` forces the direct call.
 
     ``activation="relu"`` fuses the ReLU epilogue into the kernel (the
     separate XLA relu costs one extra HBM read+write of the whole
@@ -631,8 +460,6 @@ def group_norm(
     bottleneck tail, whose separate add+relu otherwise re-reads both
     tensors from HBM.  Fully differentiable in the residual too.
     """
-    import os
-
     if activation not in (None, "relu"):
         raise ValueError(
             f"activation must be None or 'relu', got {activation!r}"
@@ -642,24 +469,21 @@ def group_norm(
         raise ValueError(
             f"residual shape {residual.shape} != x shape {x.shape}"
         )
-    if os.environ.get("CLOUD_TPU_GN_KERNEL", "") == "0":
-        # Operational kill switch (the bench flips it when the hardware
-        # gate fails, so a kernel regression degrades to the jnp path
-        # instead of sinking the measurement).  Checked before every other
-        # dispatch rule — including force-interpret — so it always wins.
-        return _reference(x, scale, bias, num_groups, eps, relu=relu,
-                          residual=residual)
     if not interpret and dispatch_lib.force_interpret():
         interpret = True
     has_res = residual is not None
-    if use_pallas is None:
-        use_pallas = (
-            jax.default_backend() == "tpu"
-            and kernel_eligible(x, num_groups)
+    eligible = kernel_eligible(x, num_groups)
+    if use_pallas and not eligible:
+        raise ValueError(
+            f"group_norm(use_pallas=True): the kernel cannot take shape "
+            f"{tuple(x.shape)} with num_groups={num_groups} "
+            "(see kernel_eligible)"
         )
-    if interpret and kernel_eligible(x, num_groups):
-        use_pallas = True
-    if not use_pallas or not kernel_eligible(x, num_groups):
+    if use_pallas is None:
+        use_pallas = eligible and (
+            interpret or jax.default_backend() == "tpu"
+        )
+    if not use_pallas:
         return _reference(x, scale, bias, num_groups, eps, relu=relu,
                           residual=residual)
     if has_res and not kernel_eligible(x, num_groups, True):
@@ -669,25 +493,19 @@ def group_norm(
         y = group_norm(
             x, scale, bias, num_groups=num_groups, eps=eps,
             use_pallas=True, interpret=interpret, partitioned=partitioned,
+            mesh=mesh, batch_axes=batch_axes,
         )
         y = y.astype(jnp.float32) + residual.astype(jnp.float32)
         if relu:
             y = jnp.maximum(y, 0.0)
         return y.astype(x.dtype)
-    if partitioned is None:
-        from cloud_tpu.parallel import mesh as mesh_lib
-
-        partitioned = mesh_lib.get_global_mesh() is not None
     scale32 = scale.astype(jnp.float32)
     bias32 = bias.astype(jnp.float32)
-    g = min(num_groups, x.shape[-1])
+    mesh = None if partitioned is False else dispatch_lib.kernel_mesh(mesh)
+    if mesh is not None:
+        return _gn_batch_sharded(mesh, batch_axes, x, scale32, bias32,
+                                 residual, num_groups, eps, interpret, relu)
     if residual is not None:
-        if partitioned:
-            return _gn_partitioned_res(g, eps, interpret, relu)(
-                x, scale32, bias32, residual
-            )
         return _gn_res(x, scale32, bias32, residual, num_groups, eps,
                        interpret, relu)
-    if partitioned:
-        return _gn_partitioned(g, eps, interpret, relu)(x, scale32, bias32)
     return _gn(x, scale32, bias32, num_groups, eps, interpret, relu)

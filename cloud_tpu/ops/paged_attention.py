@@ -34,19 +34,15 @@ Three entry points match the serving dispatch shapes:
 Dispatch follows the house playbook: ``use_pallas=None`` auto-dispatch
 takes the kernel on real TPU at ``S >= CLOUD_TPU_PAGED_MIN_LEN``
 (measure with ``scripts/decode_crossover.py`` and keep docs/KERNELS.md's
-table honest), ``CLOUD_TPU_PAGED_FORCE_INTERPRET=1`` (or the house-wide
-``CLOUD_TPU_FLASH_FORCE_INTERPRET=1``) runs the kernel code path through
-the Pallas interpreter (the CI rig; the dedicated knob exists because the
-flash interpret path is jax-0.4.37-blocked — arming it house-wide would
-drag prefill's flash_attention into its known-red ``vma`` failure while
-this kernel's interpret path is fine), and everything
-else — off-TPU, ineligible shapes, ``CLOUD_TPU_PAGED_KERNEL=0`` — takes
+table honest), ``CLOUD_TPU_FLASH_FORCE_INTERPRET=1``
+(``dispatch.force_interpret``) runs the kernel code path through the
+Pallas interpreter (the CPU rigs), and everything else — off-TPU,
+ineligible shapes, ``CLOUD_TPU_PAGED_KERNEL=0`` — takes
 :func:`_reference`, a pure-jnp block-table gather whose math mirrors
 ``_cache_attention`` term for term (same einsum order, same finite mask,
 same post-scale quant algebra), so the fallback is bit-identical to the
-copy-based XLA path given identical pool bytes.  jax 0.4.37 lacks
-``SdyShardingRule``; the ``partitioned=True`` route degrades to the
-unwrapped kernel there (one warning) instead of going red.
+copy-based XLA path given identical pool bytes.  An explicit
+``use_pallas=True`` on a shape the kernel cannot take raises.
 """
 
 from __future__ import annotations
@@ -71,21 +67,9 @@ NEG_INF = -1e30  # finite: fully-masked rows softmax to zeros, not NaN
 #: scripts/decode_crossover.py and pin the table in docs/KERNELS.md.
 MIN_SEQ_LEN_FOR_KERNEL = int(os.environ.get("CLOUD_TPU_PAGED_MIN_LEN", 1024))
 
-#: Operational kill switch (the bench flips the GroupNorm twin when a
-#: hardware gate diverges; same contract here).
+#: Operational kill switch for auto-dispatch.
 def _kernel_enabled() -> bool:
     return os.environ.get("CLOUD_TPU_PAGED_KERNEL", "1") != "0"
-
-
-def _force_interpret() -> bool:
-    """CI interpret contract: the house-wide flash knob OR the dedicated
-    paged knob.  The dedicated one lets CPU rigs arm THIS kernel's
-    interpreter while flash_attention (whose interpret path is known-red
-    on jax 0.4.37: ShapeDtypeStruct(vma=...)) keeps its jnp reference."""
-    return (
-        dispatch_lib.force_interpret()
-        or os.environ.get("CLOUD_TPU_PAGED_FORCE_INTERPRET", "") == "1"
-    )
 
 
 #: Page size used when no prefix pool rides along (pure slot paging): the
@@ -285,12 +269,9 @@ from jax.experimental import pallas as pl  # noqa: E402
 def _compiler_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary")
     )
-    if cls is None:  # pragma: no cover — very old pallas
-        return None
-    return cls(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _paged_pallas(q, cache_l, cur_len, pool_l, block_table, bt, *,
@@ -371,112 +352,13 @@ def _paged_pallas(q, cache_l, cur_len, pool_l, block_table, bt, *,
             pltpu.VMEM((h * tq, hd), jnp.float32),
         ],
     )
-    kwargs = {}
-    params = _compiler_params()
-    if params is not None:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_compiler_params(),
         interpret=interpret,
-        **kwargs,
     )(block_table, cur_len, *operands)
-
-
-# ---------------------------------------------------------------------------
-# Partitioner-visible route (custom_partitioning; heads-shardable)
-# ---------------------------------------------------------------------------
-
-_partition_fallback_warned = False
-
-
-@functools.lru_cache(maxsize=None)
-def _partitioned_call(bt, quantized, has_pool, interpret):
-    """The kernel wrapped for the partitioner: batch/heads shardable,
-    pages/positions/depth replicated — the TP(xSP) slot grid is sharded
-    over heads, and paged attention is per-head independent, so the rule
-    lets each shard run the kernel on its own head slice.  jax builds
-    without ``SdyShardingRule`` (0.4.37) fall back to the unwrapped
-    kernel with a one-time warning (the partitioner then replicates it —
-    correct, just not sharded)."""
-
-    def impl(block_table, cur_len, q, *leaves):
-        cache_l, pool_l = _unflatten(leaves, quantized, has_pool)
-        return _paged_pallas(q, cache_l, cur_len, pool_l, block_table,
-                             bt, interpret=interpret)
-
-    try:
-        from jax.experimental.custom_partitioning import (  # noqa: PLC0415
-            SdyShardingRule,
-            custom_partitioning,
-        )
-    except ImportError:
-        SdyShardingRule = None
-        custom_partitioning = None
-    if custom_partitioning is None or SdyShardingRule is None:
-        global _partition_fallback_warned
-        if not _partition_fallback_warned:
-            _partition_fallback_warned = True
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "paged attention: this jax lacks SdyShardingRule; the "
-                "partitioned route runs the unwrapped kernel (replicated "
-                "by the partitioner) instead."
-            )
-        return impl
-
-    fn = custom_partitioning(impl)
-    infer, part = dispatch_lib.passthrough_callbacks(impl, 1,
-                                                     result_like=2)
-
-    slot = ("b", "s", "h", "d")
-    pool = ("n", "p1", "h", "d")
-    slot_sc = ("b", "s", "h", "one")
-    pool_sc = ("n", "p1", "h", "one")
-    kv = (slot, slot) + ((slot_sc, slot_sc) if quantized else ())
-    pp = ()
-    if has_pool:
-        pp = (pool, pool) + ((pool_sc, pool_sc) if quantized else ())
-    fn.def_partition(
-        infer_sharding_from_operands=infer,
-        partition=part,
-        sharding_rule=SdyShardingRule(
-            operand_mappings=(("b", "p"), ("b",), ("b", "t", "h", "d"))
-            + kv + pp,
-            result_mappings=(("b", "t", "h", "d"),),
-            need_replication_factors=("p", "t", "s", "d", "n", "p1",
-                                      "one"),
-        ),
-    )
-    return fn
-
-
-def _flatten(cache_l, pool_l, quantized, has_pool):
-    leaves = [cache_l["k"], cache_l["v"]]
-    if quantized:
-        leaves += [cache_l["k_scale"], cache_l["v_scale"]]
-    if has_pool:
-        leaves += [pool_l["k"], pool_l["v"]]
-        if quantized:
-            leaves += [pool_l["k_scale"], pool_l["v_scale"]]
-    return leaves
-
-
-def _unflatten(leaves, quantized, has_pool):
-    leaves = list(leaves)
-    cache_l = {"k": leaves.pop(0), "v": leaves.pop(0)}
-    if quantized:
-        cache_l["k_scale"] = leaves.pop(0)
-        cache_l["v_scale"] = leaves.pop(0)
-    pool_l = None
-    if has_pool:
-        pool_l = {"k": leaves.pop(0), "v": leaves.pop(0)}
-        if quantized:
-            pool_l["k_scale"] = leaves.pop(0)
-            pool_l["v_scale"] = leaves.pop(0)
-    return cache_l, pool_l
 
 
 # ---------------------------------------------------------------------------
@@ -518,37 +400,60 @@ def would_use_kernel(q, cache_l, *, page_tokens: Optional[int] = None
     )
 
 
+def _heads_sharded(mesh, head_axes, bt, interpret, q, cache_l, cur_len,
+                   pool_l, block_table):
+    """The kernel per head shard of ``mesh`` (a full-manual shard_map —
+    ops/dispatch.py says why): every KV leaf, slot or pool, value or
+    scale, is rank 4 with heads third; table and lengths are whole."""
+    from jax.sharding import PartitionSpec as P
+
+    heads = P(None, None,
+              dispatch_lib.dividing_axes(mesh, head_axes, q.shape[2]), None)
+
+    def local(q, cache_l, cur_len, pool_l, block_table):
+        return _paged_pallas(q, cache_l, cur_len, pool_l, block_table, bt,
+                             interpret=interpret)
+
+    # One spec per operand: a leaf spec covers a whole dict of KV leaves
+    # (and a ``pool_l`` of None, which has none).
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(heads, heads, P(), heads, P()),
+        out_specs=heads, check_vma=False,
+    )(q, cache_l, cur_len, pool_l, block_table)
+
+
 def _paged(q, cache_l, cur_len, *, pool_l, block_table, use_pallas,
-           interpret, partitioned):
-    quantized = "k_scale" in cache_l
-    has_pool = pool_l is not None
+           interpret, partitioned, mesh=None, head_axes=None):
     bt = _fit_page(
         cache_l["k"].shape[1],
         None if pool_l is None else pool_l["k"].shape[1],
     )
-    if not interpret and _force_interpret():
+    if not interpret and dispatch_lib.force_interpret():
         interpret = True
-    eligible = _kernel_eligible(q, cache_l, bt) and _kernel_enabled()
+    eligible = _kernel_eligible(q, cache_l, bt)
+    if use_pallas and not eligible:
+        raise ValueError(
+            "paged attention (use_pallas=True): the kernel cannot take "
+            f"q{tuple(q.shape)} over slot rows "
+            f"{tuple(cache_l['k'].shape)} (needs rank-4 q and rows of one "
+            "batch, head_dim <= 256, a page of >= 8 tokens)"
+        )
     if use_pallas is None:
         use_pallas = would_use_kernel(
             q, cache_l,
             page_tokens=None if pool_l is None else pool_l["k"].shape[1],
-        ) or (interpret and eligible)
-    if use_pallas and not eligible:
-        use_pallas = False
+        ) or (interpret and eligible and _kernel_enabled())
     if use_pallas and jax.default_backend() != "tpu":
+        # CPU-test convenience: off-TPU the kernel can only be interpreted.
         interpret = True
     if not use_pallas:
         return _reference(q, cache_l, cur_len, pool_l, block_table)
-    if block_table is None:
-        block_table = jnp.full(
-            (q.shape[0], -(-cache_l["k"].shape[1] // bt)), -1, jnp.int32
-        )
-    if partitioned:
-        fn = _partitioned_call(bt, quantized, has_pool, interpret)
-        leaves = _flatten(cache_l, pool_l, quantized, has_pool)
-        return fn(block_table.astype(jnp.int32),
-                  cur_len.astype(jnp.int32), q, *leaves)
+    # Inside a manual region, or under no mesh of more than one device,
+    # the shapes are one device's already: the direct call.
+    kernel_mesh = dispatch_lib.kernel_mesh(mesh) if partitioned else None
+    if kernel_mesh is not None:
+        return _heads_sharded(kernel_mesh, head_axes, bt, interpret, q,
+                              cache_l, cur_len, pool_l, block_table)
     return _paged_pallas(q, cache_l, cur_len, pool_l, block_table, bt,
                          interpret=interpret)
 
@@ -563,6 +468,8 @@ def paged_decode_attention(
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
     partitioned: bool = False,
+    mesh=None,
+    head_axes=None,
 ) -> jnp.ndarray:
     """Single-token decode attention ([B, 1, H, hd] queries) over a
     block-table view of slot rows + pool blocks.
@@ -574,10 +481,16 @@ def paged_decode_attention(
     to a ``pool_l`` block when ``>= 0``, to the slot row when ``-1``;
     ``block_table=None`` (or ``pool_l=None``) reads slot rows only —
     the cold-insert shape.
+
+    ``partitioned=True`` under ``mesh`` (default: the framework's global
+    mesh) of more than one device runs the kernel per head shard:
+    ``head_axes`` names the mesh axes the CALLER's heads dimension is
+    split over (its rules' ``"heads"`` assignment; None: not split).
     """
     return _paged(q, cache_l, cur_len, pool_l=pool_l,
                   block_table=block_table, use_pallas=use_pallas,
-                  interpret=interpret, partitioned=partitioned)
+                  interpret=interpret, partitioned=partitioned, mesh=mesh,
+                  head_axes=head_axes)
 
 
 def paged_chunk_attention(
@@ -590,6 +503,8 @@ def paged_chunk_attention(
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
     partitioned: bool = False,
+    mesh=None,
+    head_axes=None,
 ) -> jnp.ndarray:
     """Chunk-causal paged attention — the ``prefill_chunk_program``
     shape.  Queries are CONSECUTIVE cache positions starting at
@@ -599,7 +514,8 @@ def paged_chunk_attention(
     :func:`paged_decode_attention` — one kernel serves both."""
     return _paged(q, cache_l, cur_len, pool_l=pool_l,
                   block_table=block_table, use_pallas=use_pallas,
-                  interpret=interpret, partitioned=partitioned)
+                  interpret=interpret, partitioned=partitioned, mesh=mesh,
+                  head_axes=head_axes)
 
 
 def paged_verify_attention(
@@ -612,6 +528,8 @@ def paged_verify_attention(
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
     partitioned: bool = False,
+    mesh=None,
+    head_axes=None,
 ) -> jnp.ndarray:
     """Speculative verify-window paged attention — the
     ``verify_chunk_program`` shape ([num_slots, spec_k, H, hd] queries,
@@ -621,4 +539,5 @@ def paged_verify_attention(
     crossover bench name the shape they measure."""
     return _paged(q, cache_l, cur_len, pool_l=pool_l,
                   block_table=block_table, use_pallas=use_pallas,
-                  interpret=interpret, partitioned=partitioned)
+                  interpret=interpret, partitioned=partitioned, mesh=mesh,
+                  head_axes=head_axes)

@@ -7,7 +7,6 @@ planner maps a declarative machine config to a mesh layout, and sharding
 rules translate logical tensor axes to mesh axes.
 """
 
-from cloud_tpu.utils import jax_compat as _jax_compat  # noqa: F401  (shims)
 from cloud_tpu.parallel.mesh import (
     AXIS_DP,
     AXIS_EP,

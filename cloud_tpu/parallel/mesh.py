@@ -184,22 +184,13 @@ def use_mesh(mesh: Mesh):
     """Install ``mesh`` as the global mesh for the duration of the block.
 
     Enters via ``jax.set_mesh`` (the sharding-in-types context), not the
-    legacy ``with mesh:`` block: under the legacy context the GSPMD
-    partitioner CHECK-fails on custom_partitioning calls inside a
-    partial-manual region (spmd_partitioner_util.cc "num_devices_per_group"
-    — the pipelined flash-attention path), while the modern context
-    partitions them correctly.
+    legacy ``with mesh:`` block, so that a partial-manual region (the
+    pipeline body) finds its abstract context mesh.
     """
     prev = get_global_mesh()
     set_global_mesh(mesh)
     try:
-        set_mesh = getattr(jax, "set_mesh", None) or getattr(
-            jax.sharding, "use_mesh", None
-        )
-        # Older jax (< 0.5) has neither entry point; the legacy
-        # ``with mesh:`` context is the only option there, and the
-        # custom_partitioning CHECK-failure above doesn't apply to it.
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         set_global_mesh(prev)

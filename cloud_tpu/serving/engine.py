@@ -820,7 +820,12 @@ class ServingEngine:
         from cloud_tpu.models import generation
         from cloud_tpu.parallel import mesh as mesh_lib
         from cloud_tpu.parallel.sharding import DEFAULT_RULES
+        from cloud_tpu.training import compile_cache
 
+        # Persistent executable cache, as Trainer.fit enables it: engine
+        # warm-up is most of a cold serve.  A cheap no-op when no cache
+        # directory is configured.
+        compile_cache.maybe_enable_persistent_cache()
         self.params = params
         self.config = config
         self.serve_config = serve_config or ServeConfig()
@@ -3987,6 +3992,28 @@ class ServingEngine:
                 self._prefix.hot_prefixes()
                 if self._continuous and self._prefix is not None else {}
             ),
+        }
+
+    def placement(self) -> dict:
+        """Where the engine's state lives, read from array metadata only
+        (no device access; any thread): the ids of the devices holding
+        the params and — continuous scheduler — the slot-grid KV, with the
+        KV leaves' global shapes and one device's shard of each."""
+        import jax
+
+        leaves = jax.tree_util.tree_leaves
+
+        def device_ids(arrays):
+            return sorted({d.id for x in arrays
+                           for d in x.sharding.device_set})
+
+        kv = leaves(self._grid_cache) if self._continuous else []
+        return {
+            "param_devices": device_ids(leaves(self.params)),
+            "kv_devices": device_ids(kv),
+            "kv_shapes": sorted({tuple(x.shape) for x in kv}),
+            "kv_shard_shapes": sorted(
+                {tuple(x.sharding.shard_shape(x.shape)) for x in kv}),
         }
 
     def stats(self) -> dict:

@@ -25,26 +25,20 @@ engineered quantity instead of an accident, three ways:
   slot-insert executable per prompt bucket plus the single chunk-decode
   program under the continuous scheduler, or prefill/decode executables
   per (bucket_len, batch_size) cell under the batch scheduler.
-* **Safe persistent cache** — :func:`maybe_enable_persistent_cache`
-  re-enables jax's on-disk compilation cache behind
-  ``CLOUD_TPU_COMPILE_CACHE=<dir>``, gated on a one-time child-process
-  round-trip probe (compile a trainer-shaped jitted step, drop the
-  in-memory caches, recompile from disk, execute, compare).  jaxlib
-  0.4.36/0.4.37 executable (de)serialization corrupts the glibc heap
-  for some step executables (the reason PR 1 disabled the cache
-  outright); the probe quarantines that class in a child that can die
-  harmlessly, and a version blocklist refuses the known-bad jaxlibs up
-  front unless ``CLOUD_TPU_COMPILE_CACHE_FORCE=1``.  Newer jaxlibs get
-  warm-start across processes; ``core.deploy`` forwards the env into
-  the container so deployed jobs inherit it.
+* **Persistent cache** — :func:`maybe_enable_persistent_cache` turns on
+  jax's on-disk compilation cache.  Where ``JAX_COMPILATION_CACHE_DIR``
+  is set the cache was placed from outside and lives there — no code
+  here points jax at another directory; otherwise the directory is the
+  caller's (``chip_smoke.py`` and ``bench.py`` pass the fixed
+  ``<repo>/.jax_cache``) or ``CLOUD_TPU_COMPILE_CACHE=<dir>``, which
+  ``core.deploy`` forwards into the container.  A fixed path matters:
+  the directory is part of the cache key, so one that moves never hits.
 """
 
 from __future__ import annotations
 
 import logging
 import os
-import subprocess
-import sys
 import threading
 from typing import Any, Dict, Optional, Tuple
 
@@ -54,20 +48,14 @@ from cloud_tpu.monitoring import metrics, tracing
 
 logger = logging.getLogger(__name__)
 
-#: Directory for jax's on-disk compilation cache; unset/"off" disables.
+#: jax's own variable: where it is set, the cache is there and nowhere else.
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+#: Directory for jax's on-disk compilation cache when jax's own variable
+#: is not set; unset/"off" disables.
 ENV_COMPILE_CACHE = "CLOUD_TPU_COMPILE_CACHE"
-#: Set to 1 to bypass the known-bad jaxlib blocklist (the probe still runs).
-ENV_COMPILE_CACHE_FORCE = "CLOUD_TPU_COMPILE_CACHE_FORCE"
 #: Override jax's min-compile-time-to-cache threshold (seconds; default 0 —
 #: the jobs this launcher targets are small, so cache everything).
 ENV_COMPILE_CACHE_MIN_SECS = "CLOUD_TPU_COMPILE_CACHE_MIN_SECS"
-
-#: jaxlib versions whose executable (de)serialization is known memory-unsafe
-#: (tests/conftest.py records the observed SIGSEGV / "corrupted
-#: double-linked list" aborts).  Refused without the FORCE env because the
-#: corruption strikes *in-process*, after the probe child already exited
-#: clean on a smaller executable.
-KNOWN_BAD_JAXLIB = ("0.4.36", "0.4.37")
 
 
 # --------------------------------------------------------------------------
@@ -346,143 +334,48 @@ def start_compile_ahead(jobs) -> CompileAhead:
 
 
 # --------------------------------------------------------------------------
-# Safe persistent cache
+# Persistent cache
 
 _persist_lock = threading.Lock()
 _persist_state: Dict[str, Any] = {"checked": False, "enabled": False,
-                                  "dir": None}
+                                  "dir": None, "restore": None}
 
-#: The child probe: a trainer-shaped jitted step (dict pytree, grad,
-#: donation — the executable class whose (de)serialization corrupted the
-#: heap on jaxlib 0.4.36/0.4.37) compiled once to POPULATE the on-disk
-#: cache, then recompiled from disk after dropping the in-memory caches,
-#: executed, and numerically compared.  Heap corruption anywhere in that
-#: round-trip kills the child (SIGSEGV / glibc abort), which is exactly
-#: the signal: only a clean exit + the OK marker enables the cache
-#: in-process.  Runs on CPU (JAX_PLATFORMS pinned by the parent) so the
-#: probe never contends with the training process for the accelerator —
-#: the (de)serialization path under test is host-side.
-_PROBE_SOURCE = """
-import sys
-import jax
-import jax.numpy as jnp
-
-jax.config.update("jax_compilation_cache_dir", sys.argv[1])
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-try:
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-except Exception:
-    pass
+_OFF = ("", "off", "0", "false")
 
 
-def step(state, batch):
-    def loss(w):
-        return ((batch["x"] @ w - batch["y"]) ** 2).mean()
+def maybe_enable_persistent_cache(cache_dir: Optional[str] = None) -> bool:
+    """Turn on jax's on-disk compilation cache; returns whether it is on.
 
-    g = jax.grad(loss)(state["w"])
-    return {"w": state["w"] - 0.1 * g}
-
-
-jitted = jax.jit(step, donate_argnums=0)
-batch = {"x": jnp.ones((8, 4)), "y": jnp.ones((8, 2))}
-want = jitted({"w": jnp.zeros((4, 2))}, batch)["w"]
-jax.clear_caches()  # drop in-memory caches: the next compile reads DISK
-got = jitted({"w": jnp.zeros((4, 2))}, batch)["w"]
-assert bool(jnp.allclose(got, jnp.asarray(want))), "round-trip changed numerics"
-print("CLOUD_TPU_CACHE_PROBE_OK")
-"""
-
-_PROBE_OK_MARKER = "CLOUD_TPU_CACHE_PROBE_OK"
-
-
-def _probe_marker_path(cache_dir: str) -> str:
-    import jax
-    import jaxlib
-
-    return os.path.join(
-        cache_dir,
-        f".cloud_tpu_probe_ok-jax{jax.__version__}-jaxlib{jaxlib.__version__}",
-    )
-
-
-def _run_probe_child(cache_dir: str, timeout: float) -> Tuple[int, str]:
-    """Run the round-trip probe in a child; returns (returncode, stdout).
-
-    The child inherits the environment minus accelerator claims
-    (JAX_PLATFORMS=cpu) so it cannot steal the TPU from the process that
-    is about to train.  Any crash — the failure mode under test — is a
-    nonzero returncode here, not a dead training job.
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` where that is set (a
+    cache placed from outside is never moved, whatever ``cache_dir``
+    says), else the explicit ``cache_dir``, else
+    ``CLOUD_TPU_COMPILE_CACHE``; none of them (or ``off``/``0``) means
+    disabled and this is a cheap no-op — safe to call from every
+    ``Trainer.fit`` and every ``ServingEngine``.  The decision is made
+    once per process; pass a different ``cache_dir`` to re-decide.
+    Everything is cached (``CLOUD_TPU_COMPILE_CACHE_MIN_SECS``, default
+    0): these jobs are small and first-step latency is the metric.
     """
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", _PROBE_SOURCE, cache_dir],
-            capture_output=True, text=True, timeout=timeout, env=env,
-        )
-    except subprocess.TimeoutExpired:
-        return -1, "probe timed out"
-    except OSError as exc:
-        return -1, f"probe failed to launch: {exc}"
-    out = (proc.stdout or "") + (proc.stderr or "")
-    return proc.returncode, out
-
-
-def maybe_enable_persistent_cache(
-    cache_dir: Optional[str] = None,
-    *,
-    force: Optional[bool] = None,
-    probe_timeout: float = 120.0,
-) -> bool:
-    """Enable jax's on-disk compilation cache iff it is provably safe here.
-
-    Reads ``CLOUD_TPU_COMPILE_CACHE`` (or the explicit ``cache_dir``);
-    unset / empty / ``off`` / ``0`` means disabled and this is a cheap
-    no-op — safe to call from every ``Trainer.fit``.  The decision is
-    made once per process and cached; pass a different explicit
-    ``cache_dir`` to re-decide.
-
-    Enablement requires, in order: (1) the jaxlib is not on
-    :data:`KNOWN_BAD_JAXLIB` (override with
-    ``CLOUD_TPU_COMPILE_CACHE_FORCE=1`` / ``force=True`` — the probe
-    still runs); (2) the one-time child-process round-trip probe exits
-    clean (a prior pass recorded in a per-jax-version marker file inside
-    the cache dir short-circuits the child, which is what gives a SECOND
-    process its warm start without paying the probe again).  Only then
-    is the cache turned on in-process, with the min-compile-time
-    threshold from ``CLOUD_TPU_COMPILE_CACHE_MIN_SECS`` (default 0:
-    cache everything — these jobs are small and first-step latency is
-    the metric).
-    """
-    explicit = cache_dir is not None
-    if cache_dir is None:
+    placed = os.environ.get(ENV_JAX_CACHE_DIR, "").strip()
+    if placed:
+        cache_dir = placed
+    elif cache_dir is None:
         cache_dir = os.environ.get(ENV_COMPILE_CACHE, "")
-    if not cache_dir or cache_dir.strip().lower() in ("off", "0", "false"):
+    if cache_dir.strip().lower() in _OFF:
         return False
     with _persist_lock:
-        if _persist_state["checked"] and (
-            not explicit or _persist_state["dir"] == cache_dir
-        ):
+        if _persist_state["checked"] and _persist_state["dir"] == cache_dir:
             return _persist_state["enabled"]
 
-    if force is None:
-        force = os.environ.get(ENV_COMPILE_CACHE_FORCE, "").lower() in (
-            "1", "true"
-        )
-    import jaxlib
+    import jax
 
-    if jaxlib.__version__ in KNOWN_BAD_JAXLIB and not force:
-        logger.warning(
-            "%s=%s ignored: jaxlib %s executable (de)serialization is "
-            "known memory-unsafe (set %s=1 to probe anyway)",
-            ENV_COMPILE_CACHE, cache_dir, jaxlib.__version__,
-            ENV_COMPILE_CACHE_FORCE,
+    restore = {
+        name: getattr(jax.config, name) for name in (
+            "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
         )
-        with _persist_lock:
-            _persist_state.update(checked=True, enabled=False, dir=cache_dir)
-        return False
-
+    }
     try:
         os.makedirs(cache_dir, exist_ok=True)
     except OSError as exc:
@@ -490,47 +383,22 @@ def maybe_enable_persistent_cache(
         with _persist_lock:
             _persist_state.update(checked=True, enabled=False, dir=cache_dir)
         return False
-
-    marker = _probe_marker_path(cache_dir)
-    if not os.path.exists(marker):
-        with tracing.span("compile/cache_probe"):
-            rc, out = _run_probe_child(cache_dir, probe_timeout)
-        if rc != 0 or _PROBE_OK_MARKER not in out:
-            logger.warning(
-                "persistent compile cache DISABLED: round-trip probe "
-                "failed (rc=%s): %s", rc, out.strip()[-500:],
-            )
-            metrics.counter_inc("compile/cache_probe_failed")
-            with _persist_lock:
-                _persist_state.update(
-                    checked=True, enabled=False, dir=cache_dir
-                )
-            return False
-        try:
-            with open(marker, "w", encoding="utf-8") as f:
-                f.write(out.strip()[:200] + "\n")
-        except OSError:
-            pass  # marker is an optimization; next process re-probes
-
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        # Only ever jax's own variable's value (set after jax was
+        # imported) or, with that unset, the caller's directory.
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        _drop_open_cache()
     try:
         min_secs = float(os.environ.get(ENV_COMPILE_CACHE_MIN_SECS, "0"))
     except ValueError:
         min_secs = 0.0
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_secs)
-    except Exception:  # noqa: BLE001 — knob name varies across jax versions
-        pass
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # noqa: BLE001
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     logger.info("persistent compile cache enabled at %s", cache_dir)
     metrics.counter_inc("compile/cache_enabled")
     with _persist_lock:
+        if _persist_state["restore"] is None:
+            _persist_state["restore"] = restore
         _persist_state.update(checked=True, enabled=True, dir=cache_dir)
     return True
 
@@ -541,23 +409,22 @@ def persistent_cache_enabled() -> bool:
 
 
 def _reset_persistent_state_for_tests() -> None:
-    """Forget the once-per-process decision AND restore jax's defaults."""
+    """Forget the once-per-process decision AND put jax's cache settings
+    back to what they were before this module touched them."""
     import jax
 
     with _persist_lock:
-        was_enabled = _persist_state["enabled"]
-        _persist_state.update(checked=False, enabled=False, dir=None)
-    if was_enabled:
-        jax.config.update("jax_compilation_cache_dir", None)
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
-        except Exception:  # noqa: BLE001
-            pass
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0
-            )
-        except Exception:  # noqa: BLE001
-            pass
+        restore = _persist_state["restore"]
+        _persist_state.update(checked=False, enabled=False, dir=None,
+                              restore=None)
+    for name, value in (restore or {}).items():
+        jax.config.update(name, value)
+    _drop_open_cache()
+
+
+def _drop_open_cache() -> None:
+    """jax opens its cache once, at the directory set at that moment; a
+    directory set later takes effect only after the open one is dropped."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()

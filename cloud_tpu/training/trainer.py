@@ -601,6 +601,15 @@ class Trainer:
         )
         return self.state
 
+    def lower_train_step(self, batch):
+        """The jitted train step, lowered for the current state and a host
+        ``batch`` sharded as ``fit`` shards it: what ``fit`` dispatches,
+        for inspection (``.compile().as_text()``, ``.memory_analysis()``)
+        without running it."""
+        batch = train_lib.shard_batch(batch, self.mesh, self.rules)
+        with self._mesh_context():
+            return self._train_step.lower(self.state, batch)
+
     def fit(
         self,
         train_data: Callable[[], Iterable],
@@ -687,8 +696,9 @@ class Trainer:
                     "nonfinite_guard=True (the on-device skip supplies the "
                     "signal the rollback trigger counts)"
                 )
-        # Env-gated persistent executable cache (CLOUD_TPU_COMPILE_CACHE):
-        # a once-per-process probe + enable, a cheap no-op when unset.
+        # Persistent executable cache (JAX_COMPILATION_CACHE_DIR, else
+        # CLOUD_TPU_COMPILE_CACHE): decided once per process, a cheap
+        # no-op when neither is set.
         compile_cache.maybe_enable_persistent_cache()
         if state is not None:
             self.state = state

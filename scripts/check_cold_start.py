@@ -17,13 +17,10 @@ retraces, persistent cache silently off) shows up as the warm number
 converging on the cold one.  Wired as a ``slow``-marked test in
 ``tests/unit/test_compile_cache.py`` so full runs see it.
 
-Deliberate tradeoff: the children run with CLOUD_TPU_COMPILE_CACHE_FORCE=1
-so the harness works on the blocklisted jaxlibs too — the warm child then
-exercises the executable-deserialization path the blocklist quarantines.
-That is acceptable HERE because the children are disposable (a corruption
-crash fails this check loudly instead of killing a training job) and the
-tiny probe-class executables have round-tripped cleanly on the known-bad
-jaxlibs; production enablement still goes through the blocklist + probe.
+This is a CPU rig: cold means an EMPTY cache, so unlike ``chip_smoke.py``
+and ``bench.py`` (one fixed directory, so that a later run hits) it makes a
+fresh temporary directory by design, and hands it to its children as
+``JAX_COMPILATION_CACHE_DIR`` — placed from outside, as on the chip.
 """
 
 from __future__ import annotations
@@ -118,10 +115,8 @@ def main(argv=None) -> int:
     env = dict(
         os.environ,
         JAX_PLATFORMS="cpu",
-        CLOUD_TPU_COMPILE_CACHE=cache_dir,
-        # The known-bad-jaxlib blocklist would refuse on the CI rig; the
-        # children are disposable, which is exactly what FORCE is for.
-        CLOUD_TPU_COMPILE_CACHE_FORCE="1",
+        # Empty by design (see the module docstring), never a fixed path.
+        JAX_COMPILATION_CACHE_DIR=cache_dir,
     )
     try:
         cold = _run_child(env, args.timeout)

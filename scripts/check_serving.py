@@ -44,7 +44,7 @@ one draft/verify/draft-prefill executable each (retrace guard), and
 zero leaked threads.  Phase 6 is the KERNEL churn: the shared-prefix
 workload with the paged decode-attention kernel armed
 (``decode_kernel="pallas"``, real Pallas kernel body through the
-interpreter via ``CLOUD_TPU_PAGED_FORCE_INTERPRET=1``) — per-request
+interpreter via ``CLOUD_TPU_FLASH_FORCE_INTERPRET=1``) — per-request
 parity, compile-once programs, and prefix hits attaching through the
 block table with ZERO ``copy_prefix_program`` dispatches.  Phase 7 is
 the PIPELINED churn: the same burst workload through a
@@ -657,7 +657,7 @@ def main(argv=None) -> int:
     # one-executable retrace guard, prefix hits attaching via the block
     # table with ZERO copy_prefix_program dispatches (the kernel path's
     # reason to exist), and zero leaked threads.
-    os.environ["CLOUD_TPU_PAGED_FORCE_INTERPRET"] = "1"
+    os.environ["CLOUD_TPU_FLASH_FORCE_INTERPRET"] = "1"
     kernel_serve = ServeConfig(
         max_new_tokens=MAX_NEW,
         prompt_buckets=(8, 16),
@@ -727,7 +727,7 @@ def main(argv=None) -> int:
         kernel_health = kernel_engine.health()
     finally:
         kernel_engine.close()
-        os.environ.pop("CLOUD_TPU_PAGED_FORCE_INTERPRET", None)
+        os.environ.pop("CLOUD_TPU_FLASH_FORCE_INTERPRET", None)
     # Retrace guard: same budget as the prefix phase — plus the
     # tentpole's contract, the copy program NEVER compiled (hits attach
     # through the block table instead of copying pool bytes).

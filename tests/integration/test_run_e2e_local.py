@@ -6,7 +6,7 @@ locally.  The submit half produces the artifacts under ``dry_run``; the
 container half is the real ``cloud_tpu.core.bootstrap`` CLI run as a
 subprocess with the produced mesh plan — exactly the ENTRYPOINT the
 Dockerfile encodes, minus the docker daemon.  The virtual-mesh rig lives
-in ``cloud_tpu.utils.local_rig`` (shared with scripts/measure_baselines).
+in ``cloud_tpu.utils.local_rig``.
 
 Reference analogue: core/tests/integration/run_on_script_test.py, which
 needed a real GCP project; the GCP-gated equivalents live in

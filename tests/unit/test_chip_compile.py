@@ -1,0 +1,266 @@
+"""The chip's compiler, asked without the chip (compile, never a run).
+
+libtpu compiles for a described ``v5e:2x2`` topology with no TPU attached,
+so every Pallas kernel on the two main paths (ResNet50 trainer, SMALL
+serving engine) is lowered here at its real width with ``interpret=False``.
+Interpret mode accepts block shapes Mosaic refuses; these cases are what
+stands between such a kernel and a chip call.
+
+Rules of this file (the reason it is ONE file, and why nothing below runs
+at import): only one process may load libtpu, so the topology is described
+inside a module-scoped fixture — never at import, in a ``skipif``, in a
+``parametrize`` argument or in a child process — and every sharding, mesh
+and shape built from it is built in a fixture or a test.
+"""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import cloud_tpu.ops  # noqa: F401  (binds the kernel modules)
+
+gn_mod = sys.modules["cloud_tpu.ops.group_norm"]
+fa_mod = sys.modules["cloud_tpu.ops.flash_attention"]
+pa_mod = sys.modules["cloud_tpu.ops.paged_attention"]
+
+RESNET50_STAGE_SHAPES = [
+    (256, 32, 32, 64),
+    (256, 32, 32, 256),
+    (256, 16, 16, 512),
+    (256, 8, 8, 1024),
+    (256, 4, 4, 2048),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    # A described-topology executable can be written to the persistent
+    # cache but not read back without a chip; keep these compiles out.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ---------------------------------------------------------------------------
+# GroupNorm: the five ResNet50 stage shapes of the b256 CIFAR headline
+# ---------------------------------------------------------------------------
+
+
+def _gn_args(shape, sharding):
+    x = _spec(shape, jnp.bfloat16, sharding)
+    vec = _spec((shape[-1],), jnp.float32, sharding)
+    return x, vec, vec
+
+
+@pytest.mark.parametrize("shape", RESNET50_STAGE_SHAPES, ids=str)
+def test_group_norm_fwd(one_chip, shape):
+    fn = functools.partial(
+        gn_mod.group_norm, num_groups=32, use_pallas=True,
+        partitioned=False, activation="relu",
+    )
+    _compile(fn, *_gn_args(shape, one_chip))
+
+
+@pytest.mark.parametrize("shape", RESNET50_STAGE_SHAPES, ids=str)
+def test_group_norm_grad(one_chip, shape):
+    def loss(x, scale, bias):
+        y = gn_mod.group_norm(
+            x, scale, bias, num_groups=32, use_pallas=True,
+            partitioned=False, activation="relu",
+        )
+        return jnp.sum(y.astype(jnp.float32))
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *_gn_args(shape, one_chip))
+
+
+@pytest.mark.parametrize("shape", RESNET50_STAGE_SHAPES, ids=str)
+def test_group_norm_fused_residual_grad(one_chip, shape):
+    def loss(x, scale, bias, residual):
+        y = gn_mod.group_norm(
+            x, scale, bias, num_groups=32, use_pallas=True,
+            partitioned=False, activation="relu", residual=residual,
+        )
+        return jnp.sum(y.astype(jnp.float32))
+
+    x, vec, _ = _gn_args(shape, one_chip)
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, vec, vec, x)
+
+
+def _mesh(topo, sizes):
+    """The four described chips as the framework's six-axis mesh, the way
+    ``MeshSpec(sizes).build()`` lays out attached ones."""
+    from cloud_tpu import parallel
+    from cloud_tpu.parallel import mesh as mesh_lib
+
+    return Mesh(
+        np.array(topo.devices).reshape(parallel.MeshSpec(sizes).shape()),
+        mesh_lib.CANONICAL_AXES,
+    )
+
+
+def _kernel_lines(compiled):
+    return [ln for ln in compiled.as_text().splitlines()
+            if "tpu_custom_call" in ln and "bf16[" in ln]
+
+
+def test_group_norm_mesh_route_shards_the_batch(topo):
+    """Under a dp=4 global mesh the kernel is in the per-device program
+    and takes a quarter of the batch (sharded, not replicated)."""
+    from cloud_tpu import parallel
+
+    mesh = _mesh(topo, {"dp": 4})
+    shape = (256, 16, 16, 512)
+    x = _spec(shape, jnp.bfloat16, NamedSharding(mesh, P("dp")))
+    vec = _spec((shape[-1],), jnp.float32, NamedSharding(mesh, P()))
+
+    def loss(x, scale, bias):
+        y = gn_mod.group_norm(
+            x, scale, bias, num_groups=32, use_pallas=True,
+            activation="relu", batch_axes="dp",
+        )
+        return jnp.sum(y.astype(jnp.float32))
+
+    with parallel.use_mesh(mesh):
+        compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, vec, vec)
+    calls = _kernel_lines(compiled)
+    assert calls and all("bf16[64,16,16,512]" in ln for ln in calls), calls
+    assert not any("bf16[256,16,16,512]" in ln for ln in calls), calls
+
+
+# ---------------------------------------------------------------------------
+# Flash attention: causal LM shape and the masked (prefill / BERT) shape
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape,causal,masked", [
+    ((8, 1024, 12, 64), True, False),
+    ((32, 512, 12, 64), False, True),
+    ((8, 1024, 12, 64), True, True),   # serving prefill: causal + prompt mask
+], ids=["causal-1024", "masked-512", "causal-masked-1024"])
+def test_flash_fwd_and_grad(one_chip, shape, causal, masked):
+    qkv = _spec(shape, jnp.bfloat16, one_chip)
+    mask = _spec(shape[:2], jnp.bool_, one_chip) if masked else None
+
+    def loss(q, k, v, mask=None):
+        out = fa_mod.flash_attention(
+            q, k, v, causal=causal, mask=mask, use_pallas=True
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    args = (qkv, qkv, qkv) + ((mask,) if masked else ())
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), *args)
+
+
+def test_flash_mesh_route_shards_the_heads(topo):
+    """Serving prefill on a tp=4 slice: the masked causal kernel runs on a
+    quarter of the heads per chip ([B, H/4, T, D] inside the kernel)."""
+    mesh = _mesh(topo, {"tp": 4})
+    qkv = _spec((1, 1024, 12, 64), jnp.bfloat16,
+                NamedSharding(mesh, P(None, None, "tp", None)))
+    mask = _spec((1, 1024), jnp.int32, NamedSharding(mesh, P()))
+
+    def fn(q, k, v, mask):
+        return fa_mod.flash_attention(
+            q, k, v, causal=True, mask=mask, use_pallas=True,
+            partitioned=True, mesh=mesh, head_axes="tp",
+        )
+
+    calls = _kernel_lines(_compile(fn, qkv, qkv, qkv, mask))
+    assert calls and all("bf16[1,3,1024,64]" in ln for ln in calls), calls
+
+
+# ---------------------------------------------------------------------------
+# Paged attention: 16 slots x 1152 x 12 heads x 64, with a 64 x 16 pool
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("has_pool", [False, True], ids=["slot", "pool"])
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("tq", [1, 8], ids=["decode", "chunk"])
+def test_paged(one_chip, tq, kv_dtype, has_pool):
+    slots, s, h, hd, blocks, bt = 16, 1152, 12, 64, 64, 16
+
+    def leaves(lead):
+        out = {"k": _spec((*lead, h, hd), kv_dtype, one_chip),
+               "v": _spec((*lead, h, hd), kv_dtype, one_chip)}
+        if kv_dtype == jnp.int8:
+            out["k_scale"] = _spec((*lead, h, 1), jnp.float32, one_chip)
+            out["v_scale"] = _spec((*lead, h, 1), jnp.float32, one_chip)
+        return out
+
+    q = _spec((slots, tq, h, hd), jnp.bfloat16, one_chip)
+    cur_len = _spec((slots,), jnp.int32, one_chip)
+    cache_l = leaves((slots, s))
+    pool_l = leaves((blocks, bt)) if has_pool else None
+    page = bt if has_pool else pa_mod._fit_page(s, None)
+    table = _spec((slots, -(-s // page)), jnp.int32, one_chip)
+
+    def fn(q, cache_l, cur_len, pool_l, table):
+        return pa_mod._paged_pallas(
+            q, cache_l, cur_len, pool_l, table, page, interpret=False
+        )
+
+    _compile(fn, q, cache_l, cur_len, pool_l, table)
+
+
+def test_paged_mesh_route_shards_the_heads(topo, monkeypatch):
+    """``decode_kernel="pallas"`` on a tp=4 slice: each chip's kernel reads
+    its own three heads of the slot rows."""
+    # The public entry point turns the interpreter on by itself off-TPU;
+    # this process is on the CPU and compiles for the chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = _mesh(topo, {"tp": 4})
+    slots, s, h, hd = 16, 1152, 12, 64
+    heads = NamedSharding(mesh, P(None, None, "tp", None))
+    whole = NamedSharding(mesh, P())
+    q = _spec((slots, 1, h, hd), jnp.bfloat16, heads)
+    cache_l = {"k": _spec((slots, s, h, hd), jnp.bfloat16, heads),
+               "v": _spec((slots, s, h, hd), jnp.bfloat16, heads)}
+    cur_len = _spec((slots,), jnp.int32, whole)
+    table = _spec((slots, s // 128), jnp.int32, whole)
+
+    def fn(q, cache_l, cur_len, table):
+        return pa_mod.paged_decode_attention(
+            q, cache_l, cur_len, block_table=table, use_pallas=True,
+            partitioned=True, mesh=mesh, head_axes="tp",
+        )
+
+    calls = _kernel_lines(_compile(fn, q, cache_l, cur_len, table))
+    assert calls and all("bf16[16,1152,3,64]" in ln for ln in calls), calls

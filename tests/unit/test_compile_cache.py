@@ -383,101 +383,58 @@ class TestPersistentCache:
     @pytest.fixture(autouse=True)
     def _isolated(self, monkeypatch):
         monkeypatch.delenv(compile_cache.ENV_COMPILE_CACHE, raising=False)
-        monkeypatch.delenv(
-            compile_cache.ENV_COMPILE_CACHE_FORCE, raising=False
-        )
+        monkeypatch.delenv(compile_cache.ENV_JAX_CACHE_DIR, raising=False)
         compile_cache._reset_persistent_state_for_tests()
+        before = jax.config.jax_compilation_cache_dir
         yield
         compile_cache._reset_persistent_state_for_tests()
+        assert jax.config.jax_compilation_cache_dir == before
 
     def test_unset_env_is_a_noop(self):
         assert compile_cache.maybe_enable_persistent_cache() is False
         assert not compile_cache.persistent_cache_enabled()
 
-    def test_refuses_on_failing_probe(self, tmp_path, monkeypatch):
-        """Acceptance: a failing probe child (stubbed subprocess — the
-        crash-of-the-child signal) must leave the cache OFF."""
-        calls = {"n": 0}
-
-        def failing_probe(cache_dir, timeout):
-            calls["n"] += 1
-            return 139, "Fatal Python error: Segmentation fault"
-
-        monkeypatch.setattr(
-            compile_cache, "_run_probe_child", failing_probe
-        )
-        ok = compile_cache.maybe_enable_persistent_cache(
-            str(tmp_path / "cache"), force=True
-        )
-        assert ok is False
-        assert calls["n"] == 1
-        assert not compile_cache.persistent_cache_enabled()
-        assert jax.config.jax_compilation_cache_dir is None
-        # No marker was written: the next process re-probes.
-        assert not [
-            f for f in os.listdir(tmp_path / "cache")
-            if f.startswith(".cloud_tpu_probe_ok")
-        ]
-
-    def test_clean_exit_without_marker_string_refused(self, tmp_path,
-                                                      monkeypatch):
-        monkeypatch.setattr(
-            compile_cache, "_run_probe_child",
-            lambda cache_dir, timeout: (0, "no marker here"),
-        )
-        assert compile_cache.maybe_enable_persistent_cache(
-            str(tmp_path), force=True
-        ) is False
-
-    def test_blocklisted_jaxlib_refused_without_force(self, tmp_path,
-                                                      monkeypatch):
-        import jaxlib
-
-        monkeypatch.setattr(
-            compile_cache, "KNOWN_BAD_JAXLIB", (jaxlib.__version__,)
-        )
-
-        def must_not_run(cache_dir, timeout):  # pragma: no cover
-            raise AssertionError("probe must not run for blocklisted jaxlib")
-
-        monkeypatch.setattr(compile_cache, "_run_probe_child", must_not_run)
-        assert compile_cache.maybe_enable_persistent_cache(
-            str(tmp_path), force=False
-        ) is False
-
-    def test_probe_pass_enables_and_warm_starts_second_process(
-        self, tmp_path
-    ):
-        """Acceptance: a passing probe enables the cache in-process AND a
-        second process warm-starts from the entries the first wrote —
-        compiling the same step adds no new cache entries."""
+    @pytest.mark.parametrize("how", ["argument", "CLOUD_TPU_COMPILE_CACHE"])
+    def test_jax_variable_unset_uses_the_given_dir(self, tmp_path,
+                                                   monkeypatch, how):
         cache_dir = str(tmp_path / "cache")
-        ok = compile_cache.maybe_enable_persistent_cache(
-            cache_dir, force=True  # FORCE: the rig's jaxlib is blocklisted
-        )
+        if how == "argument":
+            ok = compile_cache.maybe_enable_persistent_cache(cache_dir)
+        else:
+            monkeypatch.setenv(compile_cache.ENV_COMPILE_CACHE, cache_dir)
+            ok = compile_cache.maybe_enable_persistent_cache()
         assert ok is True
         assert compile_cache.persistent_cache_enabled()
-        markers = [
-            f for f in os.listdir(cache_dir)
-            if f.startswith(".cloud_tpu_probe_ok")
-        ]
-        assert len(markers) == 1
-        # The interesting entries are the trainer-step executables (the
-        # class the probe exercises); the child prints via numpy so it
-        # compiles nothing beyond the step itself.
-        step_entries = lambda: {  # noqa: E731
-            f for f in os.listdir(cache_dir)
-            if f.startswith("jit_step") and f.endswith("-cache")
-        }
-        before = step_entries()
-        assert before  # the probe's own step compile populated the cache
+        assert jax.config.jax_compilation_cache_dir == cache_dir
+        assert os.path.isdir(cache_dir)
 
+    @pytest.mark.parametrize("how", ["argument", "CLOUD_TPU_COMPILE_CACHE"])
+    def test_jax_variable_is_honoured_and_never_overridden(
+        self, tmp_path, monkeypatch, how
+    ):
+        """A cache placed from outside stays where it was placed."""
+        placed = str(tmp_path / "placed")
+        other = str(tmp_path / "other")
+        monkeypatch.setenv(compile_cache.ENV_JAX_CACHE_DIR, placed)
+        if how == "argument":
+            ok = compile_cache.maybe_enable_persistent_cache(other)
+        else:
+            monkeypatch.setenv(compile_cache.ENV_COMPILE_CACHE, other)
+            ok = compile_cache.maybe_enable_persistent_cache()
+        assert ok is True
+        assert jax.config.jax_compilation_cache_dir == placed
+        assert not os.path.exists(other)
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones((5,))).block_until_ready()
+        assert os.listdir(placed)
+
+    def test_enables_and_warm_starts_second_process(self, tmp_path):
+        """Acceptance: the cache enabled in-process is found again by a
+        second process — compiling the same step there adds no entry."""
+        cache_dir = str(tmp_path / "cache")
         child = (
             "import sys\n"
             "from cloud_tpu.training import compile_cache\n"
-            "ok = compile_cache.maybe_enable_persistent_cache("
-            "sys.argv[1], force=True)\n"
-            "assert ok, 'marker should enable without re-probing'\n"
+            "assert compile_cache.maybe_enable_persistent_cache(sys.argv[1])\n"
             "import jax, jax.numpy as jnp\n"
             "def step(state, batch):\n"
             "    def loss(w):\n"
@@ -491,16 +448,26 @@ class TestPersistentCache:
             "print('WARM_OK', float(np.asarray(out['w']).sum()))\n"
         )
         env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop(compile_cache.ENV_JAX_CACHE_DIR, None)
         env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-        proc = subprocess.run(
-            [sys.executable, "-c", child, cache_dir],
-            capture_output=True, text=True, timeout=120, env=env,
-        )
-        assert proc.returncode == 0, proc.stderr[-500:]
-        assert "WARM_OK" in proc.stdout
+
+        def run_child():
+            proc = subprocess.run(
+                [sys.executable, "-c", child, cache_dir],
+                capture_output=True, text=True, timeout=120, env=env,
+            )
+            assert proc.returncode == 0, proc.stderr[-500:]
+            assert "WARM_OK" in proc.stdout
+            return {
+                f for f in os.listdir(cache_dir)
+                if f.startswith("jit_step") and f.endswith("-cache")
+            }
+
+        first = run_child()
+        assert first  # the step executable was written to disk
         # Warm start: the second process's step compile was served from
         # disk — it wrote NO new step-executable cache entries.
-        assert step_entries() == before
+        assert run_child() == first
 
 
 class TestDeployForwarding:

@@ -1,5 +1,5 @@
-"""Fused GroupNorm kernel tests (interpret mode; real-TPU compile is
-covered by scripts/tpu_smoke.py and the bench hardware gate)."""
+"""Fused GroupNorm kernel tests (interpret mode; the TPU compile is
+covered by tests/unit/test_chip_compile.py and chip_smoke.py)."""
 
 import jax
 import jax.numpy as jnp
@@ -138,7 +138,7 @@ class TestPartitioned:
         def loss(x, s, b, partitioned):
             y = group_norm(
                 x, s, b, num_groups=32, use_pallas=True, interpret=True,
-                partitioned=partitioned,
+                partitioned=partitioned, batch_axes=("dp", "fsdp"),
             )
             return jnp.sum(y * y)
 
@@ -225,6 +225,7 @@ class TestFusedRelu:
                 return group_norm(
                     x, s, b, num_groups=groups, use_pallas=True,
                     interpret=True, partitioned=part, activation="relu",
+                    batch_axes="dp",
                 )
             return f
 
@@ -271,6 +272,67 @@ class TestFusedRelu:
             state, metrics = step(state, batch)
             losses.append(float(metrics["loss"]))
         assert losses[-1] < losses[0], losses
+
+
+class TestResnetUnderTheCallersRules:
+    def test_kernel_shards_follow_the_trainers_rules(self, monkeypatch):
+        """A Trainer whose rules put the batch on ``tp`` hands the same
+        rules to ``resnet.loss_fn``: every GroupNorm kernel of the step
+        ``fit`` would dispatch then runs on a tp shard of the batch —
+        not on the default table's dp/fsdp, not on the whole batch."""
+        import functools
+
+        import optax
+
+        from cloud_tpu.models import resnet
+        from cloud_tpu.parallel import sharding
+        from cloud_tpu.training import trainer
+
+        monkeypatch.setenv("CLOUD_TPU_FLASH_FORCE_INTERPRET", "1")
+        cfg = resnet.ResNetConfig(
+            stage_sizes=(1,), width=8, num_classes=4, num_groups=4,
+            dtype=jnp.float32,
+        )
+        mesh = parallel.MeshSpec({"tp": 4}).build(jax.devices()[:4])
+        rng = np.random.default_rng(0)
+        batch = {
+            "image": rng.normal(size=(8, 16, 16, 3)).astype(np.float32),
+            "label": rng.integers(0, 4, 8).astype(np.int32),
+        }
+
+        from cloud_tpu.ops import dispatch
+
+        asked = []
+        dividing_axes = dispatch.dividing_axes
+
+        def spy(mesh, axes, size):
+            asked.append(axes)
+            return dividing_axes(mesh, axes, size)
+
+        monkeypatch.setattr(dispatch, "dividing_axes", spy)
+
+        def kernel_batches(rules):
+            del asked[:]
+            t = trainer.Trainer(
+                functools.partial(resnet.loss_fn, config=cfg, rules=rules,
+                                  mesh=mesh),
+                optax.sgd(0.05), functools.partial(resnet.init, config=cfg),
+                mesh=mesh, rules=rules,
+                logical_axes=resnet.param_logical_axes(cfg),
+            )
+            t.init_state(jax.random.PRNGKey(0))
+            text = t.lower_train_step(batch).as_text()
+            # The stem's kernel sees [b, 8, 8, 8] after the stride-2 conv.
+            return {b for b in (2, 8) if f"tensor<{b}x8x8x8xf32>" in text}
+
+        on_tp = sharding.DEFAULT_RULES.extended(batch="tp")
+        assert 2 in kernel_batches(on_tp)
+        # Every norm of the model (stem, three in the block, projection).
+        assert len(asked) >= 5 and set(asked) == {"tp"}, asked
+        # The default table splits the batch over dp/fsdp, which this mesh
+        # does not have: the kernel then sees the whole batch.
+        assert 2 not in kernel_batches(sharding.DEFAULT_RULES)
+        assert set(asked) == {("dp", "fsdp")}, asked
 
 
 class TestFusedResidual:
@@ -327,7 +389,7 @@ class TestFusedResidual:
                 return group_norm(
                     x, s, b, num_groups=groups, use_pallas=True,
                     interpret=True, partitioned=part, residual=r,
-                    activation="relu",
+                    activation="relu", batch_axes="dp",
                 )
             return f
 

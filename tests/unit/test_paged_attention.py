@@ -194,7 +194,7 @@ class TestDispatch:
         assert pa._fit_page(4, None) is None   # too short to page
 
     def test_interpret_knob_routes_kernel(self, monkeypatch):
-        monkeypatch.setenv("CLOUD_TPU_PAGED_FORCE_INTERPRET", "1")
+        monkeypatch.setenv("CLOUD_TPU_FLASH_FORCE_INTERPRET", "1")
         b, s, h, hd = 1, 16, 2, 16
         cache, pool, table, rng = _make(b, s, h, hd, 8, 2, seed=5)
         q = jnp.asarray(rng.normal(size=(b, 1, h, hd)), jnp.float32)

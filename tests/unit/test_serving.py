@@ -60,6 +60,14 @@ def model():
     return config, params
 
 
+def _eos_after_first(greedy):
+    """(eos, num_generated) for an eos that retires a greedy run early but
+    not at once: the first token that differs from ``greedy[0]``."""
+    stop = next(i for i, t in enumerate(greedy) if t != greedy[0])
+    assert 0 < stop < len(greedy) - 1, greedy
+    return int(greedy[stop]), stop + 1
+
+
 def _direct(params, config, prompt, max_new_tokens,
             sample=generation.SampleConfig(temperature=0.0)):
     return generation.generate(
@@ -135,7 +143,7 @@ class TestParity:
         config, params = model
         prompt = np.asarray([7, 3, 11, 2], np.int32)
         greedy = np.asarray(_direct(params, config, prompt, 6)["tokens"])[0]
-        eos = int(greedy[1])
+        eos, n_until_eos = _eos_after_first(greedy)
         sample = generation.SampleConfig(temperature=0.0, eos_id=eos,
                                          pad_id=0)
         serve = ServeConfig(
@@ -148,7 +156,9 @@ class TestParity:
         np.testing.assert_array_equal(
             result.tokens, np.asarray(want["tokens"])[0]
         )
-        assert result.num_generated == int(want["num_generated"][0]) == 2
+        assert result.num_generated == int(
+            want["num_generated"][0]
+        ) == n_until_eos
 
     def test_sampled_decode_deterministic_per_seed(self, model):
         """Non-greedy serving: the engine owns the rng chain, so the same
@@ -948,7 +958,7 @@ class TestContinuous:
         config, params = model
         prompt = np.asarray([7, 3, 11, 2], np.int32)
         greedy = np.asarray(_direct(params, config, prompt, 6)["tokens"])[0]
-        eos = int(greedy[1])
+        eos, n_until_eos = _eos_after_first(greedy)
         sample = generation.SampleConfig(temperature=0.0, eos_id=eos,
                                          pad_id=0)
         serve = ServeConfig(
@@ -962,7 +972,9 @@ class TestContinuous:
         np.testing.assert_array_equal(
             result.tokens, np.asarray(want["tokens"])[0]
         )
-        assert result.num_generated == int(want["num_generated"][0]) == 2
+        assert result.num_generated == int(
+            want["num_generated"][0]
+        ) == n_until_eos
         assert stats["retires"] == 1
         assert stats["expired"] == 0  # eos retired it, not the budget cap
 
@@ -1131,6 +1143,7 @@ class TestShardedServing:
                 results = [f.result(timeout=120) for f in futures]
                 stats = engine.stats()
                 traces = engine.chunk_traces
+                placement = engine.placement()
             report = TraceReport(collector.events())
         for prompt, budget, result in zip(prompts, budgets, results):
             direct = _direct(params, config, prompt, budget)
@@ -1141,6 +1154,12 @@ class TestShardedServing:
         assert health["slice_chips"] == 2
         assert stats["slice_chips"] == 2
         assert traces == 1, "the mesh must not multiply chunk compiles"
+        # Params and slot KV on both chips, each KV leaf split over heads.
+        assert len(placement["param_devices"]) == 2
+        assert len(placement["kv_devices"]) == 2
+        for whole, shard in zip(placement["kv_shapes"],
+                                placement["kv_shard_shapes"]):
+            assert shard[-2] * 2 == whole[-2] == config.num_heads
         reshards = [
             e for e in collector.events() if e.get("name") == "serve/reshard"
         ]
