@@ -87,6 +87,17 @@ def maybe_start_server_from_env() -> bool:
     return True
 
 
+def trace_options():
+    """The host tracer cut to annotations (``host_tracer_level=1``, no
+    Python tracer).  JAX's default also records every futex and every
+    Python call: millions of events, and a host slowed enough that the
+    trace measures the tracer."""
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 1
+    options.python_tracer_level = 0
+    return options
+
+
 @contextlib.contextmanager
 def trace(logdir: Optional[str] = None, *, perfetto_link: bool = False):
     """Capture a trace of the enclosed block to ``logdir``.
@@ -96,24 +107,22 @@ def trace(logdir: Optional[str] = None, *, perfetto_link: bool = False):
     supported by the underlying writer, so traces can land next to the
     job's checkpoints.
     """
+    logdir = start_trace(logdir, perfetto_link=perfetto_link)
+    try:
+        yield logdir
+    finally:
+        stop_trace()
+
+
+def start_trace(logdir: Optional[str] = None, *,
+                perfetto_link: bool = False) -> str:
     from cloud_tpu.monitoring import tracing
 
     logdir = logdir or default_logdir()
-    with jax.profiler.trace(logdir, create_perfetto_link=perfetto_link):
-        # Host-side tracing spans opened inside the block mirror
-        # themselves as TraceAnnotations onto the device timeline.
-        tracing.xprof_trace_started()
-        try:
-            yield logdir
-        finally:
-            tracing.xprof_trace_stopped()
-
-
-def start_trace(logdir: Optional[str] = None) -> str:
-    from cloud_tpu.monitoring import tracing
-
-    logdir = logdir or default_logdir()
-    jax.profiler.start_trace(logdir)
+    jax.profiler.start_trace(logdir, create_perfetto_link=perfetto_link,
+                             profiler_options=trace_options())
+    # Host-side tracing spans opened while the trace is live mirror
+    # themselves as TraceAnnotations onto the device timeline.
     tracing.xprof_trace_started()
     return logdir
 

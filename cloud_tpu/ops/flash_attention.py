@@ -254,6 +254,7 @@ def _fwd_pallas(q, k, v, mask, *, causal, block_q, block_k, interpret):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
     return out, lse
 
@@ -458,6 +459,7 @@ def _bwd_pallas(q, k, v, mask, do, out, lse, *, causal, block_q, block_k,
         scratch_shapes=[_vmem((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_operands)[0]
 
     # dK/dV: grid walks key blocks in the parallel dims, query blocks in the
@@ -498,6 +500,7 @@ def _bwd_pallas(q, k, v, mask, do, out, lse, *, causal, block_q, block_k,
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_operands)
     return dq, dk, dv
 

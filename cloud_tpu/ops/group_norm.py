@@ -233,6 +233,7 @@ def _fwd_pallas(x, scale, bias, num_groups, eps, interpret, relu=False):
             jax.ShapeDtypeStruct((b, 1, g), jnp.float32),
         ],
         interpret=interpret,
+        name="group_norm_fwd",
     )(x, scale.reshape(1, c), bias.reshape(1, c), oh, oh.T)
     return y, mean, rstd
 
@@ -259,6 +260,7 @@ def _bwd_pallas(x, dy, mean, rstd, scale, bias, num_groups, interpret,
             jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
         ],
         interpret=interpret,
+        name="group_norm_bwd",
     )(x, dy, mean, rstd, scale.reshape(1, c), bias.reshape(1, c), oh, oh.T)
     return dx, ds, db
 
@@ -284,6 +286,7 @@ def _fwd_pallas_res(x, scale, bias, residual, num_groups, eps, interpret,
             jax.ShapeDtypeStruct((b, 1, g), jnp.float32),
         ],
         interpret=interpret,
+        name="group_norm_res_fwd",
     )(x, scale.reshape(1, c), bias.reshape(1, c), residual, oh, oh.T)
     return y, mean, rstd
 
@@ -311,6 +314,7 @@ def _bwd_pallas_res(x, dy, mean, rstd, scale, bias, residual, num_groups,
             jax.ShapeDtypeStruct(residual.shape, residual.dtype),
         ],
         interpret=interpret,
+        name="group_norm_res_bwd",
     )(x, dy, mean, rstd, scale.reshape(1, c), bias.reshape(1, c), residual,
       oh, oh.T)
     return dx, ds, db, dres

@@ -358,6 +358,7 @@ def _paged_pallas(q, cache_l, cur_len, pool_l, block_table, bt, *,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="paged_decode",
     )(block_table, cur_len, *operands)
 
 

@@ -97,7 +97,27 @@ with two schedulers sharing one submit/future/admission surface:
   ``tracing.record_span``), ``serve/prefill`` spans in both modes;
   ``serve/chunk`` spans (with per-dispatch ``active``/``occupancy``
   attributes) in continuous mode, ``serve/batch_form``/``serve/decode``
-  in batch mode.  ``serve/qps`` and ``serve/tokens_per_sec``
+  in batch mode.  A continuous scheduler pass closes: one numbered
+  ``serve/pass`` span per loop iteration that did work (``inserts``
+  taken off the queue with their ``prompt_tokens``/``bucket_tokens``,
+  ``active`` slots in its chunk,
+  ``kv_rows_in_use``/``kv_rows_reserved``; recorded, never mirrored
+  into a profile), and under it the leaves ``serve/launch`` (the
+  host's time to enqueue a program, ``what``), ``serve/readback``
+  (waiting for and copying a result, ``what``) and ``serve/commit``
+  (token append, stream delivery, retires; ``tokens``/``retired``),
+  each with the pass's number, as ``serve/prefill`` and
+  ``serve/chunk`` carry it.  A request closes: while a collector is
+  active every request has a trace id (the caller's or one ``submit``
+  mints) shared by its ``serve/queue_wait``, ``serve/prefill``, the
+  chunk spans' ``traces`` map and, on both schedulers, its terminal
+  ``serve/request`` (``ttft_s``, ``queue_wait_s``, ``decode_s``,
+  ``tokens``, ``prompt_len``, ``bucket``, ``slot``, ``passes``) and
+  ``serve/ttft`` (submit's stamp to the first token on the host).
+  ``stats()`` counts ``kv_row_steps_reserved`` /
+  ``kv_row_steps_in_use`` at every chunk dispatch and, with
+  ``health()``, reports ``kv_bytes_reserved`` / ``kv_bytes_in_use``.
+  ``serve/qps`` and ``serve/tokens_per_sec``
   windowed-rate gauges, ``serve/slot_occupancy`` /
   ``serve/batch_occupancy`` gauges, slot-churn counters
   (``serve/slot_inserts``, ``serve/slot_retires``,
@@ -606,11 +626,13 @@ class _Request:
     #: Cross-layer per-token hook (the fleet's stream forwarding):
     #: called as ``on_token(index, token)`` from the scheduler thread.
     on_token: Optional[object] = None
-    #: Fleet-minted ``tracing.TraceContext`` (None = untraced).  Inert
-    #: unless a collector is active: no span gains attributes from it
-    #: while tracing is off, so the disabled span set stays
-    #: byte-identical.
+    #: The request's ``tracing.TraceContext``: the caller's (the fleet
+    #: mints one per request) or, while a collector is active, one
+    #: ``submit`` minted.  None only while tracing is off.
     trace: Optional[tracing.TraceContext] = None
+    #: perf_counter when the scheduler took the request off the queue
+    #: (the end of its ``serve/queue_wait``).
+    admitted: Optional[float] = None
     #: Disaggregated prefill leg: export the prompt's cached prefix
     #: blocks host-side after prefill (``ServeResult.handoff``).
     handoff_export: bool = False
@@ -628,9 +650,8 @@ class _Request:
 
 
 def _trace_attrs(request: _Request, **attrs) -> dict:
-    """Span attributes + the request's ``trace_id`` when it carries a
-    trace context.  Untraced requests get exactly the attrs passed in,
-    so pre-tracing span payloads stay byte-identical."""
+    """Span attributes + the request's ``trace_id`` (every request has
+    one while a collector is active)."""
     if request.trace is not None:
         attrs["trace_id"] = request.trace.trace_id
     return attrs
@@ -655,6 +676,8 @@ class _Slot:
     #: Tokens already delivered to the request's stream/on_token hook
     #: (prefix of ``tokens``, capped at the request's budget).
     streamed: int = 0
+    #: Scheduler passes whose chunk decoded for this slot.
+    passes: int = 0
     #: Exported KV handoff payload (``handoff_export`` requests only):
     #: built right after the prefix save, carried to ``_retire_slot``
     #: which rides it out on the result.
@@ -848,6 +871,15 @@ class ServingEngine:
         # unobservable under greedy — one decode signature either way).
         self._rng = jax.random.PRNGKey(self.serve_config.seed)
 
+        def split_key(key):
+            new, sub = jax.random.split(key)
+            return new, sub
+
+        #: One program per key split (``_split_rng``): the eager split
+        #: is two dispatches, a millisecond of host time before every
+        #: insert and chunk with the device idle.
+        self._split_key = jax.jit(split_key)
+
         self._cond = threading.Condition()
         #: bucket_len -> FIFO of waiting _Requests (guarded by _cond).
         self._pending: Dict[int, collections.deque] = {}
@@ -865,6 +897,10 @@ class ServingEngine:
         #: close() so a finite hang never leaks past the engine's life.
         self._orphan_dispatches: List[threading.Thread] = []
         self._last_dispatch_ts: Optional[float] = None
+        #: Sequence number of the continuous scheduler's open pass (from
+        #: 1): ``serve/pass`` and every span recorded inside the pass
+        #: carry it.  0 on the batch scheduler, which has no pass.
+        self._pass_seq = 0
         #: Timeline lane (synthetic Chrome-trace pid) this engine's
         #: scheduler stamps its spans with; None = the real process pid.
         #: Set by the owning fleet replica via :meth:`set_trace_lane`.
@@ -909,9 +945,11 @@ class ServingEngine:
             "spec_proposed": 0, "spec_accepted": 0, "draft_prefills": 0,
             # Robustness counters: queue-shed deadlines, watchdog fires.
             "shed": 0, "watchdog_timeouts": 0,
-            # Requests submitted carrying a TraceContext (0 with
-            # tracing off — stable schema either way).
-            "traced": 0,
+            # KV rows reserved against in use, summed over every chunk
+            # dispatch (continuous scheduler; their quotient is the
+            # share of the rows a decode step reads that hold a live
+            # token).
+            "kv_row_steps_reserved": 0, "kv_row_steps_in_use": 0,
             # QoS brownout sheds (0 unless qos arms a brownout depth).
             "brownout_shed": 0,
             # Disaggregated-serving KV handoff counters (all 0 with
@@ -982,6 +1020,9 @@ class ServingEngine:
                 )
             #: Scheduler-thread-only slot bookkeeping (the host mirror).
             self._slot_table: List[Optional[_Slot]] = [None] * cfg.num_slots
+            #: Slots in the open pass's chunk (``serve/pass``'s
+            #: ``active``; scheduler-thread only, reset at each pass).
+            self._pass_active = 0
             self._free_slots = list(range(cfg.num_slots))[::-1]
             self._active_slots: set = set()
             self._insert_cells: Dict[int, "compile_cache.AotStep"] = {}
@@ -1042,6 +1083,20 @@ class ServingEngine:
                         self._prefix_pool = jax.device_put(
                             self._prefix_pool, device
                         )
+            #: KV accounting: the rows and bytes the slot grid and the
+            #: prefix pool reserve (constant), and the rows that held a
+            #: live token at the last chunk dispatch (plain int swap:
+            #: the scheduler writes, ``stats()``/``health()`` read).
+            leaves = jax.tree_util.tree_leaves
+            self._kv_rows_reserved = cfg.num_slots * self._max_len + (
+                cfg.prefix_cache_blocks * cfg.prefix_block_tokens
+                if self._prefix is not None else 0
+            )
+            self._kv_bytes_reserved = sum(
+                x.nbytes for x in
+                leaves(self._grid_cache) + leaves(self._prefix_pool)
+            )
+            self._kv_rows_in_use = 0
             #: Paged decode attention (``decode_kernel != "xla"``): the
             #: slot grid's attention reads KV through a per-slot block
             #: table — page p of a row resolves to a prefix-pool block
@@ -1418,16 +1473,18 @@ class ServingEngine:
         return cell
 
     def _to_host(self, what: str, *arrays):
-        """Materialize device results host-side.  On a sharded slice
-        this pull is the sampling boundary's logits/token gather — the
-        slice's only cross-chip reshard — and is spanned as
-        ``serve/reshard``; single-chip engines skip the span (their
-        timeline stays exactly the pre-slice shape)."""
-        if self._slice_chips > 1:
-            with tracing.span("serve/reshard", what=what,
-                              chips=self._slice_chips):
-                return tuple(np.asarray(a) for a in arrays)
-        return tuple(np.asarray(a) for a in arrays)
+        """Materialize device results host-side: the wait for a
+        program's result and its copy, spanned as ``serve/readback``.
+        On a sharded slice this pull is also the sampling boundary's
+        logits/token gather — the slice's only cross-chip reshard —
+        spanned as ``serve/reshard`` inside it."""
+        with tracing.span("serve/readback", what=what,
+                          **{"pass": self._pass_seq}):
+            if self._slice_chips > 1:
+                with tracing.span("serve/reshard", what=what,
+                                  chips=self._slice_chips):
+                    return tuple(np.asarray(a) for a in arrays)
+            return tuple(np.asarray(a) for a in arrays)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -1567,12 +1624,13 @@ class ServingEngine:
         the cross-layer per-token hook the fleet uses to forward a
         stream — called as ``(index, token)`` on the scheduler thread.
 
-        ``trace`` carries the fleet-minted
-        :class:`~cloud_tpu.monitoring.tracing.TraceContext` so every
-        span this request touches stamps its ``trace_id`` (and the
-        result reports it).  Inert while tracing is disabled; None (the
-        default) keeps the engine's span set byte-identical to the
-        pre-tracing behavior.
+        ``trace`` carries the caller's
+        :class:`~cloud_tpu.monitoring.tracing.TraceContext` (the fleet
+        mints one per request) so every span this request touches
+        stamps its ``trace_id`` (and the result reports it).  Without
+        one, ``submit`` mints the request's own while a collector is
+        active; with tracing off nothing is minted and nothing
+        recorded.
 
         ``handoff_export=True`` marks the request as a disaggregated
         PREFILL leg: right after its prompt blocks land in the prefix
@@ -1620,6 +1678,8 @@ class ServingEngine:
             )
         bucket_len = next(b for b in cfg.prompt_buckets if b >= n)
         submitted = time.perf_counter()
+        if trace is None:
+            trace = tracing.new_trace_context()  # None while tracing is off
         token_stream = TokenStream() if stream else None
         request = _Request(
             prompt=prompt, prompt_len=n, max_new_tokens=m,
@@ -1665,8 +1725,6 @@ class ServingEngine:
             self._cond.notify_all()
         with self._stats_lock:
             self._stats["requests"] += 1
-            if trace is not None:
-                self._stats["traced"] += 1
         metrics.counter_inc("serve/requests")
         return token_stream if token_stream is not None else request.future
 
@@ -2403,11 +2461,20 @@ class ServingEngine:
         harness's ``hang`` mode, a recovered device) unwinds without a
         leak; a truly wedged program leaves one daemon thread, which is
         the best Python can do short of killing the process.
+
+        Spanned as ``serve/launch``: the host's time to enqueue the
+        program(s) ``fn`` dispatches (where ``fn`` itself waits for its
+        result, as the batch scheduler's do, the wait is inside).
         """
         timeout = self.serve_config.dispatch_timeout_s
         self._last_dispatch_ts = time.perf_counter()
-        if timeout is None:
-            return fn()
+        with tracing.span("serve/launch", what=label,
+                          **{"pass": self._pass_seq}):
+            if timeout is None:
+                return fn()
+            return self._run_under_watchdog(label, fn, timeout)
+
+    def _run_under_watchdog(self, label: str, fn, timeout: float):
         box: dict = {}
         done = threading.Event()
 
@@ -2436,6 +2503,16 @@ class ServingEngine:
         if "error" in box:
             raise box["error"]
         return box["result"]
+
+    def _split_rng(self):
+        """Advance the engine's key and return a fresh one for the next
+        program.  The split is itself a tiny device program, so it is
+        spanned as a launch: the device's idle time round it is then
+        named in a profile."""
+        with tracing.span("serve/launch", what="rng_split",
+                          **{"pass": self._pass_seq}):
+            self._rng, key = self._split_key(self._rng)
+        return key
 
     def _pop_batch_locked(self, now: float) -> Optional[List[_Request]]:
         """The batch-formation policy (caller holds the lock).
@@ -2568,7 +2645,9 @@ class ServingEngine:
         degenerates to the old insert-then-decode loop).  A dispatch
         failure here is fatal to the grid (the cache/state pytrees may
         be half-donated), so it propagates to the crash handler, which
-        fails every queued and in-flight request."""
+        fails every queued and in-flight request.  Each iteration that
+        did work is one ``serve/pass`` span, numbered, from the pop
+        that found its work to the end of its drain."""
         while True:
             # Re-assert the timeline lane each pass: the owning replica
             # tags the engine AFTER this thread is already running (and
@@ -2582,6 +2661,9 @@ class ServingEngine:
                     if self._closed and not self._draining:
                         abort = True
                         break
+                    # The pass opens at the pop that finds its work:
+                    # never over the wait below.
+                    pass_start = time.perf_counter()
                     self._pop_inserts_locked(inserts)
                     if (inserts or self._active_slots
                             or self._prefill_tasks or self._inflight):
@@ -2596,6 +2678,8 @@ class ServingEngine:
                     "engine closed without draining in-flight requests"
                 ))
                 return
+            self._pass_seq += 1
+            self._pass_active = 0
             try:
                 for idx, (request, slot) in enumerate(inserts):
                     self._admit_request(request, slot)
@@ -2654,6 +2738,19 @@ class ServingEngine:
                     if self._active_slots and self._predict_survivors()
                     else 0):
                 self._drain_inflight()
+            if tracing.enabled():
+                # Recorded, not a context manager: a mirrored pass
+                # would cover every idle gap of a profile at least as
+                # well as its leaves and take them all.
+                tracing.record_span(
+                    "serve/pass", pass_start, time.perf_counter(),
+                    **{"pass": self._pass_seq},
+                    inserts=len(inserts), active=self._pass_active,
+                    prompt_tokens=sum(r.prompt_len for r, _ in inserts),
+                    bucket_tokens=sum(r.bucket_len for r, _ in inserts),
+                    kv_rows_in_use=self._kv_rows_in_use,
+                    kv_rows_reserved=self._kv_rows_reserved,
+                )
 
     def _pop_inserts_locked(self, inserts) -> None:
         """Claim one free slot per waiting request — oldest submit first
@@ -2786,7 +2883,7 @@ class ServingEngine:
         if hit is None and not use_chunks:
             self._insert_request(request, slot)
             return
-        now = time.perf_counter()
+        now = request.admitted = time.perf_counter()
         tracing.record_span(
             "serve/queue_wait", request.submitted, now,
             **_trace_attrs(request, bucket=request.bucket_len, slot=slot),
@@ -2906,10 +3003,8 @@ class ServingEngine:
         device twin of what ``insert_slot_program`` does inline), save
         the prompt's new prefix blocks, and activate — or retire, when
         the first token already finishes the request."""
-        import jax
-
         request, slot = task.request, task.slot
-        self._rng, fin_rng = jax.random.split(self._rng)
+        fin_rng = self._split_rng()
         cell = self._finalize_cell()
 
         def dispatch():
@@ -3157,9 +3252,7 @@ class ServingEngine:
             self._active_slots.add(slot)
 
     def _insert_request(self, request: _Request, slot: int) -> None:
-        import jax
-
-        start = time.perf_counter()
+        start = request.admitted = time.perf_counter()
         tracing.record_span(
             "serve/queue_wait", request.submitted, start,
             **_trace_attrs(request, bucket=request.bucket_len, slot=slot),
@@ -3167,7 +3260,7 @@ class ServingEngine:
         tokens = np.zeros((1, request.bucket_len), np.int32)
         tokens[0, :request.prompt_len] = request.prompt
         cell = self._insert_cell(request.bucket_len)
-        self._rng, insert_rng = jax.random.split(self._rng)
+        insert_rng = self._split_rng()
 
         def dispatch():
             faults.fault_point("serve.prefill")
@@ -3179,7 +3272,8 @@ class ServingEngine:
 
         with tracing.span(
             "serve/prefill",
-            **_trace_attrs(request, bucket=request.bucket_len, slot=slot),
+            **_trace_attrs(request, bucket=request.bucket_len, slot=slot,
+                           **{"pass": self._pass_seq}),
         ):
             self._grid_cache, self._slot_state, tok0 = self._supervised(
                 "serve/prefill", dispatch
@@ -3196,12 +3290,10 @@ class ServingEngine:
         self._activate_or_retire(slot, request, tok0)
 
     def _active_trace_map(self) -> Optional[Dict[str, str]]:
-        """slot -> trace_id for the traced requests a multi-slot dispatch
+        """slot -> trace_id for the requests a multi-slot dispatch
         serves (chunk/verify spans carry it as the ``traces`` attribute,
-        since one dispatch advances MANY requests).  None when tracing is
-        off or no active request carries a context — the attribute is
-        then omitted entirely, keeping untraced span payloads
-        byte-identical.  JSON object keys must be strings, hence
+        since one dispatch advances MANY requests).  None when tracing
+        is off.  JSON object keys must be strings, hence
         ``str(slot)``."""
         if not tracing.enabled():
             return None
@@ -3213,11 +3305,9 @@ class ServingEngine:
         return traces or None
 
     def _dispatch_chunk(self) -> None:
-        import jax
-
         cfg = self.serve_config
         num_slots, chunk = cfg.num_slots, cfg.chunk_tokens
-        self._rng, chunk_rng = jax.random.split(self._rng)
+        chunk_rng = self._split_rng()
 
         def dispatch():
             faults.fault_point("serve.chunk")
@@ -3228,6 +3318,7 @@ class ServingEngine:
 
         span_attrs = dict(
             slots=num_slots, chunk=chunk, active=len(self._active_slots),
+            **{"pass": self._pass_seq},
         )
         if self._slice_chips > 1:
             span_attrs["slice"] = (
@@ -3237,6 +3328,7 @@ class ServingEngine:
         traces = self._active_trace_map()
         if traces:
             span_attrs["traces"] = traces
+        self._note_kv_rows()
         self._note_dispatch_gap(time.perf_counter())
         with tracing.span("serve/chunk", **span_attrs) as chunk_span:
             self._grid_cache, self._slot_state, toks, valid = (
@@ -3286,18 +3378,29 @@ class ServingEngine:
         slot table and retire what finished — shared verbatim by the
         decode-chunk and verify paths (``valid`` is a per-row prefix in
         both).  Streaming requests get each committed token the moment
-        it lands here (host-side delivery; the dispatch is unchanged)."""
+        it lands here (host-side delivery; the dispatch is unchanged).
+        Spanned as ``serve/commit``: token append, stream/``on_token``
+        delivery and the retires."""
         eos = self.serve_config.sample.eos_id
-        for slot in sorted(self._active_slots):
-            entry = self._slot_table[slot]
-            for i in range(width):
-                if not valid[slot, i]:
-                    break
-                entry.tokens.append(int(toks[slot, i]))
-            self._feed_entry(entry)
-            hit_eos = eos is not None and entry.tokens[-1] == eos
-            if hit_eos or len(entry.tokens) >= entry.request.max_new_tokens:
-                self._retire_slot(slot)
+        tokens = retired = 0
+        with tracing.span("serve/commit",
+                          **{"pass": self._pass_seq}) as span:
+            for slot in sorted(self._active_slots):
+                entry = self._slot_table[slot]
+                entry.passes += 1
+                for i in range(width):
+                    if not valid[slot, i]:
+                        break
+                    entry.tokens.append(int(toks[slot, i]))
+                    tokens += 1
+                self._feed_entry(entry)
+                hit_eos = eos is not None and entry.tokens[-1] == eos
+                if (hit_eos or len(entry.tokens)
+                        >= entry.request.max_new_tokens):
+                    self._retire_slot(slot)
+                    retired += 1
+            span.set_attribute("tokens", tokens)
+            span.set_attribute("retired", retired)
 
     def _dispatch_spec_chunk(self) -> None:
         """One draft-and-verify round: the draft proposes a ``spec_k``
@@ -3309,6 +3412,7 @@ class ServingEngine:
         cfg = self.serve_config
         num_slots, k = cfg.num_slots, cfg.draft.spec_k
         active_n = len(self._active_slots)
+        self._note_kv_rows()
         self._note_dispatch_gap(time.perf_counter())
 
         def draft_dispatch():
@@ -3330,7 +3434,8 @@ class ServingEngine:
                 *self._paged_extra(),
             )
 
-        span_attrs = dict(slots=num_slots, spec_k=k, active=active_n)
+        span_attrs = dict(slots=num_slots, spec_k=k, active=active_n,
+                          **{"pass": self._pass_seq})
         if self._slice_chips > 1:
             span_attrs["slice"] = (
                 f"{self._slice_shape[0]}x{self._slice_shape[1]}"
@@ -3383,6 +3488,34 @@ class ServingEngine:
         return accepted / proposed if proposed else 0.0
 
     # -- pipelined scheduling (pipeline_depth=2) ---------------------------
+
+    def _note_kv_rows(self) -> None:
+        """KV accounting at a chunk dispatch: the rows the grid (and
+        the prefix pool) reserve against the rows that hold a live
+        token right now, added to the ``kv_row_steps_*`` counters.  A
+        decoding slot holds ``prompt_len + tokens so far`` rows, a slot
+        mid-prefill the positions prefilled; a pool block counts once
+        while any live slot references it, and the rows a paged slot
+        reads from attached pool blocks are not counted twice.  At
+        ``pipeline_depth=2`` the host's token counts trail the device
+        by the chunk in flight."""
+        rows = sum(task.next_pos for task in self._prefill_tasks)
+        for slot in self._active_slots:
+            entry = self._slot_table[slot]
+            rows += entry.request.prompt_len + len(entry.tokens)
+        if self._prefix is not None:
+            block_tokens = self.serve_config.prefix_block_tokens
+            rows += block_tokens * len({
+                id(node) for entry in self._slot_table if entry is not None
+                for node in entry.prefix_nodes
+            })
+            if self._block_table is not None:
+                rows -= block_tokens * int((self._block_table >= 0).sum())
+        self._kv_rows_in_use = rows
+        self._pass_active = len(self._active_slots)
+        with self._stats_lock:
+            self._stats["kv_row_steps_reserved"] += self._kv_rows_reserved
+            self._stats["kv_row_steps_in_use"] += rows
 
     def _note_dispatch_gap(self, start: float) -> None:
         """Record the host gap between the previous chunk dispatch and
@@ -3446,11 +3579,9 @@ class ServingEngine:
         retire/insert host work overlaps device compute.  Metrics and
         stats move to the drain with the emissions: a disposed (never
         drained) chunk is never counted."""
-        import jax
-
         cfg = self.serve_config
         num_slots, chunk = cfg.num_slots, cfg.chunk_tokens
-        self._rng, chunk_rng = jax.random.split(self._rng)
+        chunk_rng = self._split_rng()
 
         def dispatch():
             faults.fault_point("serve.chunk")
@@ -3461,6 +3592,7 @@ class ServingEngine:
 
         span_attrs = dict(
             slots=num_slots, chunk=chunk, active=len(self._active_slots),
+            **{"pass": self._pass_seq},
         )
         if self._slice_chips > 1:
             span_attrs["slice"] = (
@@ -3470,6 +3602,7 @@ class ServingEngine:
         traces = self._active_trace_map()
         if traces:
             span_attrs["traces"] = traces
+        self._note_kv_rows()
         start = time.perf_counter()
         self._note_dispatch_gap(start)
         self._grid_cache, self._slot_state, toks, valid, summary = (
@@ -3502,6 +3635,7 @@ class ServingEngine:
                 self._draft_params, self._draft_cache, self._slot_state
             )
 
+        self._note_kv_rows()
         start = time.perf_counter()
         self._note_dispatch_gap(start)
         with tracing.span("serve/draft", slots=num_slots, spec_k=k,
@@ -3517,7 +3651,8 @@ class ServingEngine:
                 *self._paged_extra(),
             )
 
-        span_attrs = dict(slots=num_slots, spec_k=k, active=active_n)
+        span_attrs = dict(slots=num_slots, spec_k=k, active=active_n,
+                          **{"pass": self._pass_seq})
         if self._slice_chips > 1:
             span_attrs["slice"] = (
                 f"{self._slice_shape[0]}x{self._slice_shape[1]}"
@@ -3703,25 +3838,37 @@ class ServingEngine:
             self._stats["generated_tokens"] += num
             if self._qos is not None:
                 self._class_completed[request.priority] += 1
-        if self._qos is not None or request.trace is not None:
-            # Per-request terminal span — with QoS armed (report.py's
-            # per-class TTFT/latency breakdown reads the priority
-            # attribute) or when the request carries a trace context
-            # (the lifecycle stitch needs a terminal under the
-            # trace_id).  A FIFO engine serving untraced requests keeps
-            # its exact pre-QoS span set.
-            attrs = {"ttft_s": round(result.ttft_seconds, 6),
-                     "tokens": num}
-            if request.priority is not None:
-                attrs["priority"] = request.priority
-            tracing.record_span(
-                "serve/request", request.submitted, done,
-                **_trace_attrs(request, **attrs),
-            )
+        self._record_request_spans(request, result, first, done,
+                                   slot=slot, passes=entry.passes)
         try:
             request.future.set_result(result)
         except InvalidStateError:  # pragma: no cover - cancelled
             pass
+
+    def _record_request_spans(self, request: _Request, result: ServeResult,
+                              first: float, done: float, *, slot: int,
+                              passes: int) -> None:
+        """The two spans that close a served request, on both
+        schedulers: ``serve/request`` (submit to the last token, with
+        where its time went) and ``serve/ttft`` (submit's own stamp to
+        the first token on the host: TTFT as the engine sees it)."""
+        if not tracing.enabled():
+            return
+        attrs = {
+            "ttft_s": round(result.ttft_seconds, 6),
+            "queue_wait_s": round(request.admitted - request.submitted, 6),
+            "decode_s": round(done - first, 6),
+            "tokens": result.num_generated,
+            "prompt_len": request.prompt_len,
+            "bucket": request.bucket_len,
+            "slot": slot, "passes": passes,
+        }
+        if request.priority is not None:
+            attrs["priority"] = request.priority
+        tracing.record_span("serve/request", request.submitted, done,
+                            **_trace_attrs(request, **attrs))
+        tracing.record_span("serve/ttft", request.submitted, first,
+                            **_trace_attrs(request))
 
     def _fail_live_slots(self, exc: BaseException) -> None:
         for slot, entry in enumerate(self._slot_table):
@@ -3737,6 +3884,7 @@ class ServingEngine:
         batch_size = next(b for b in cfg.batch_buckets if b >= n)
         form_start = time.perf_counter()
         for request in batch:
+            request.admitted = form_start
             tracing.record_span(
                 "serve/queue_wait", request.submitted, form_start,
                 **_trace_attrs(request, bucket=bucket_len),
@@ -3749,7 +3897,7 @@ class ServingEngine:
                 tokens[i, :request.prompt_len] = request.prompt
                 lens[i] = request.prompt_len
         cell = self._cell(bucket_len, batch_size)
-        self._rng, batch_rng = jax.random.split(self._rng)
+        batch_rng = self._split_rng()
 
         def prefill():
             faults.fault_point("serve.prefill")
@@ -3793,18 +3941,10 @@ class ServingEngine:
             metrics.distribution_record(
                 "serve/latency_seconds", result.latency_seconds
             )
-            if request.trace is not None:
-                # Terminal span for the lifecycle stitch (continuous
-                # engines emit it in _retire_slot); untraced batch
-                # requests keep the pre-tracing span set.
-                attrs = {"ttft_s": round(result.ttft_seconds, 6),
-                         "tokens": num,
-                         "trace_id": request.trace.trace_id}
-                if request.priority is not None:
-                    attrs["priority"] = request.priority
-                tracing.record_span(
-                    "serve/request", request.submitted, done, **attrs
-                )
+            # Batch decode hands every token over at once: one pass,
+            # no time between the first token and the last.
+            self._record_request_spans(request, result, done, done,
+                                       slot=i, passes=1)
             results.append(result)
 
         # Stats/metrics BEFORE the futures resolve: a caller waking from
@@ -3936,6 +4076,7 @@ class ServingEngine:
                 self._stats["handoff_import_blocks"]
             )
         snap.update(self._prefix_snapshot())
+        snap.update(self._kv_snapshot())
         if self._continuous:
             snap["free_slots"] = free_slots
         return snap
@@ -4066,7 +4207,24 @@ class ServingEngine:
             float(np.percentile(gaps, 99)) if gaps else 0.0
         )
         snap.update(self._prefix_snapshot())
+        snap.update(self._kv_snapshot())
         return snap
+
+    def _kv_snapshot(self) -> dict:
+        """The KV bytes ``health()`` and ``stats()`` both carry: what
+        the slot grid and the prefix pool reserve (constant), and what
+        held a live token at the last chunk dispatch (rows in use at
+        the grid's bytes a row).  Zeros on the batch scheduler, whose
+        cache lives for one batch."""
+        if not self._continuous:
+            return {"kv_bytes_reserved": 0, "kv_bytes_in_use": 0}
+        return {
+            "kv_bytes_reserved": self._kv_bytes_reserved,
+            "kv_bytes_in_use": (
+                self._kv_bytes_reserved * self._kv_rows_in_use
+                // self._kv_rows_reserved
+            ),
+        }
 
     def _dispatch_gap_window(self) -> List[float]:
         """Snapshot of the rolling dispatch-gap window (ms), empty on

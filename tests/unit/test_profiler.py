@@ -40,6 +40,31 @@ class TestTrace:
         profiler.stop_trace()
         assert _profile_files(logdir)
 
+    @pytest.mark.parametrize("entry", ["trace", "start_trace"])
+    def test_host_tracer_is_cut_to_annotations(self, entry, monkeypatch,
+                                               tmp_path):
+        """ISSUE 26: an operator's trace starts as the benchmark's does
+        (``host_tracer_level=1``, no Python tracer), not with JAX's
+        default flood of futex and Python-call events."""
+        seen = []
+        real = jax.profiler.start_trace
+
+        def start(log_dir, *args, profiler_options=None, **kwargs):
+            seen.append(profiler_options)
+            return real(log_dir, *args, profiler_options=profiler_options,
+                        **kwargs)
+
+        monkeypatch.setattr(jax.profiler, "start_trace", start)
+        if entry == "trace":
+            with profiler.trace(str(tmp_path / "t")):
+                pass
+        else:
+            profiler.start_trace(str(tmp_path / "t"))
+            profiler.stop_trace()
+        assert len(seen) == 1
+        assert seen[0].host_tracer_level == 1
+        assert seen[0].python_tracer_level == 0
+
     def test_default_logdir_env(self, monkeypatch, tmp_path):
         monkeypatch.setenv(profiler.ENV_PROFILER_LOGDIR, str(tmp_path))
         assert profiler.default_logdir() == str(tmp_path)

@@ -445,9 +445,12 @@ class TestHealth:
         assert stats["class_completed"] == zeros
         assert stats["class_shed"] == zeros
         assert stats["class_backlog"] == zeros
-        # ISSUE 16: the traced-request counter is schema in both
-        # schedulers too — zero whenever requests carry no context.
-        assert stats["traced"] == 0
+        # ISSUE 26: the KV accounting is schema in both schedulers
+        # (all zeros on the batch scheduler, whose cache lives for one
+        # batch): in use never exceeds reserved.
+        assert 0 <= stats["kv_row_steps_in_use"] <= (
+            stats["kv_row_steps_reserved"])
+        assert 0 <= stats["kv_bytes_in_use"] <= stats["kv_bytes_reserved"]
         # ISSUE 17: block-table prefix attaches are schema too — zero
         # whenever decode_kernel="xla" (hits copy, never attach).
         assert stats["prefix_attaches"] == 0
@@ -710,7 +713,6 @@ class TestObservability:
                 result = engine.submit(
                     np.asarray([1, 2, 3], np.int32), trace=ctx
                 ).result(timeout=120)
-                assert engine.stats()["traced"] == 1
         assert result.trace_id == ctx.trace_id
         events = collector.events()
         terminals = [e for e in events if e["name"] == "serve/request"]
@@ -754,11 +756,11 @@ class TestObservability:
         assert [e["args"]["trace_id"] for e in terminals] == [ctx.trace_id]
 
     def test_untraced_span_set_is_unchanged(self, model):
-        """The default-off pin: with tracing active but requests
-        submitted WITHOUT a context, the emitted span set is what it
-        was before trace propagation existed — no terminal span on the
-        FIFO path, no trace_id attribute, no slot map — so enabling the
-        collector alone never changes a timeline's shape."""
+        """ISSUE 26's contract, which replaced "the span set stays
+        byte-identical when untraced": with the collector on and no
+        context passed, ``submit`` mints the request's id and the
+        request leaves one terminal span under it; with the collector
+        off nothing is minted and nothing recorded."""
         from cloud_tpu.monitoring import tracing
 
         config, params = model
@@ -771,14 +773,22 @@ class TestObservability:
                 result = engine.submit(
                     np.asarray([1, 2, 3], np.int32)
                 ).result(timeout=120)
-                assert engine.stats()["traced"] == 0
-        assert result.trace_id is None
+        assert result.trace_id is not None
         events = collector.events()
-        assert all("serve/request" != e["name"] for e in events)
-        for event in events:
-            args = event.get("args") or {}
-            assert "trace_id" not in args, event["name"]
-            assert "traces" not in args, event["name"]
+        terminals = [e for e in events if e["name"] == "serve/request"]
+        assert [e["args"]["trace_id"] for e in terminals] == [
+            result.trace_id]
+        assert "priority" not in terminals[0]["args"]
+        waits = [e for e in events if e["name"] == "serve/queue_wait"]
+        assert [e["args"]["trace_id"] for e in waits] == [result.trace_id]
+
+        assert tracing.active() is None
+        with ServingEngine(params, config, serve) as engine:
+            result = engine.submit(
+                np.asarray([1, 2, 3], np.int32)
+            ).result(timeout=120)
+        assert result.trace_id is None
+        assert tracing.timeline_events() == []
 
 
 class TestContinuous:

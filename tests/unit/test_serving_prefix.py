@@ -637,6 +637,10 @@ class TestDemoteBurst:
             # burst paths must compose with the genuine supervision
             # contract.
             self._supervised = ServingEngine._supervised.__get__(self)
+            self._run_under_watchdog = (
+                ServingEngine._run_under_watchdog.__get__(self)
+            )
+            self._pass_seq = 0
             self._flush_demotes = (
                 ServingEngine._flush_demotes.__get__(self)
             )

@@ -1,0 +1,224 @@
+"""ISSUE 26: a scheduler pass, a request and the KV rows are accounted for.
+
+CPU only, a tiny model.  A pass closes (``serve/pass`` holds its leaves and
+its own number), a request closes (one ``serve/request`` and one
+``serve/ttft`` under the id its other spans carry), and the KV counters
+equal a hand count for a schedule that cannot vary.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cloud_tpu.models import transformer
+from cloud_tpu.monitoring import tracing
+from cloud_tpu.serving import ServeConfig, ServingEngine
+
+LEAVES = ("serve/launch", "serve/readback", "serve/commit")
+IN_A_PASS = LEAVES + ("serve/prefill", "serve/chunk")
+#: Six ragged prompts with mixed budgets over three slots: every slot is
+#: reused, and requests of different lengths decode side by side.
+PROMPTS = [[1, 2, 3], [4, 5, 6, 7, 8], [9], [10, 11, 12, 13, 14, 15, 16],
+           [17, 18], [19, 20, 21, 22]]
+BUDGETS = [5, 3, 6, 2, 4, 6]
+CHUNK = 2
+
+
+@pytest.fixture(scope="module")
+def model():
+    config = transformer.TINY.scaled(dtype=jnp.float32, num_layers=1)
+    return config, transformer.init(jax.random.PRNGKey(0), config)
+
+
+def _serve(model, prompts=PROMPTS, budgets=BUDGETS, **overrides):
+    """Serve ``prompts`` (all queued before the scheduler starts, so the
+    schedule is fixed) under a collector; (events, results, stats)."""
+    config, params = model
+    settings = dict(max_new_tokens=6, prompt_buckets=(4, 8), num_slots=3,
+                    chunk_tokens=CHUNK, warmup=False)
+    settings.update(overrides)
+    with tracing.collecting() as collector:
+        engine = ServingEngine(params, config, ServeConfig(**settings),
+                               start=False)
+        futures = [engine.submit(np.asarray(p, np.int32), max_new_tokens=m)
+                   for p, m in zip(prompts, budgets)]
+        engine.start()
+        results = [f.result(timeout=300) for f in futures]
+        engine.close()
+        stats = engine.stats()
+    return collector.events(), results, stats
+
+
+@pytest.fixture(scope="module")
+def served(model):
+    return _serve(model)
+
+
+def _named(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def _ends(event):
+    return event["ts"], event["ts"] + event["dur"]
+
+
+def test_every_leaf_lies_inside_the_pass_whose_number_it_carries(served):
+    events = served[0]
+    passes = {e["args"]["pass"]: e for e in _named(events, "serve/pass")}
+    assert sorted(passes) == list(range(1, len(passes) + 1))
+    inside = {n: 0.0 for n in passes}
+    for name in IN_A_PASS:
+        spans = _named(events, name)
+        assert spans, name
+        for span in spans:
+            lo, hi = _ends(passes[span["args"]["pass"]])
+            start, end = _ends(span)
+            assert lo <= start and end <= hi, (name, span["args"])
+            if name in ("serve/prefill", "serve/chunk"):
+                inside[span["args"]["pass"]] += span["dur"]
+    # A pass's self time: what is left once its two programs are out.
+    for number, span in passes.items():
+        assert span["dur"] - inside[number] >= 0.0, number
+    assert {e["args"]["what"] for e in _named(events, "serve/launch")} >= {
+        "serve/prefill", "serve/chunk", "rng_split"}
+    assert {e["args"]["what"] for e in _named(events, "serve/readback")} == {
+        "insert_tok0", "chunk_tokens"}
+
+
+def test_pass_attributes_add_up_to_what_was_submitted(served):
+    events, results, stats = served
+    passes = [e["args"] for e in _named(events, "serve/pass")]
+    assert sum(p["inserts"] for p in passes) == len(PROMPTS)
+    assert sum(p["prompt_tokens"] for p in passes) == sum(
+        len(p) for p in PROMPTS)
+    assert sum(p["bucket_tokens"] for p in passes) == sum(
+        r.bucket_len for r in results)
+    assert all(1 <= p["active"] <= 3 for p in passes)
+    assert {p["kv_rows_reserved"] for p in passes} == {3 * (8 + 6)}
+    assert sum(p["kv_rows_in_use"] for p in passes) == stats[
+        "kv_row_steps_in_use"]
+    commits = [e["args"] for e in _named(events, "serve/commit")]
+    # Every token but each request's first (the prefill's) is committed.
+    assert sum(c["tokens"] for c in commits) == sum(BUDGETS) - len(BUDGETS)
+    assert sum(c["retired"] for c in commits) == len(PROMPTS)
+
+
+def test_every_request_closes_under_one_id(served):
+    events, results, _ = served
+    ids = [r.trace_id for r in results]
+    assert None not in ids and len(set(ids)) == len(ids)
+    for result, budget, prompt in zip(results, BUDGETS, PROMPTS):
+        mine = [e for e in events
+                if e["args"].get("trace_id") == result.trace_id]
+        names = sorted(e["name"] for e in mine)
+        assert names == ["serve/prefill", "serve/queue_wait",
+                         "serve/request", "serve/ttft"]
+        by_name = {e["name"]: e for e in mine}
+        assert by_name["serve/ttft"]["dur"] * 1e-6 == pytest.approx(
+            result.ttft_seconds, rel=1e-6, abs=1e-9)
+        args = by_name["serve/request"]["args"]
+        assert args["tokens"] == budget and args["prompt_len"] == len(prompt)
+        assert args["bucket"] == result.bucket_len
+        assert args["passes"] == math.ceil((budget - 1) / CHUNK)
+        assert args["ttft_s"] == pytest.approx(result.ttft_seconds, abs=1e-6)
+        assert 0.0 <= args["queue_wait_s"] <= args["ttft_s"]
+        assert args["decode_s"] == pytest.approx(
+            result.latency_seconds - result.ttft_seconds, abs=1e-5)
+        assert "priority" not in args
+        # The chunks that decoded for it name it in their slot map.
+        riding = [e for e in _named(events, "serve/chunk")
+                  if e["args"]["traces"].get(str(args["slot"]))
+                  == result.trace_id]
+        assert len(riding) == args["passes"]
+
+
+def test_leaves_mirror_into_a_live_profile_and_the_pass_does_not(
+        model, monkeypatch):
+    mirrored = []
+
+    class Annotation:
+        def __init__(self, name):
+            mirrored.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tracing.xprof_trace_started()
+    try:
+        events, _, _ = _serve(model, PROMPTS[:2], BUDGETS[:2])
+    finally:
+        tracing.xprof_trace_stopped()
+    assert set(IN_A_PASS) <= set(mirrored)
+    recorded = {e["name"] for e in events}
+    for name in ("serve/pass", "serve/request", "serve/ttft",
+                 "serve/queue_wait"):
+        assert name in recorded and name not in mirrored
+
+
+def test_kv_row_steps_equal_a_hand_count(model):
+    """Two slots, chunks of 2, no eos.  A (3 prompt tokens, 5 to make)
+    decodes in chunks 1 and 2 holding 3+1 then 3+3 rows; B (5 and 3) in
+    chunk 1 holding 5+1.  Two chunks of 2 slots x (8 + 5) rows."""
+    _, _, stats = _serve(model, [[1, 2, 3], [4, 5, 6, 7, 8]], [5, 3],
+                         max_new_tokens=5, prompt_buckets=(8,), num_slots=2)
+    assert stats["chunks"] == 2
+    assert stats["kv_row_steps_in_use"] == (4 + 6) + 6
+    assert stats["kv_row_steps_reserved"] == 2 * 2 * 13
+    per_row = stats["kv_bytes_reserved"] // (2 * 13)
+    assert per_row * 2 * 13 == stats["kv_bytes_reserved"] > 0
+    # The last dispatch: A alone, 3 + 3 rows.
+    assert stats["kv_bytes_in_use"] == 6 * per_row
+
+
+def test_a_referenced_pool_block_counts_once_as_in_use(model):
+    """Prefix pool on: its blocks are reserved whole; a block counts as
+    in use while a live slot references it.  One request of 8 prompt
+    tokens saves the whole blocks of its first 7 (one block of 4) and
+    holds it while it decodes."""
+    _, _, stats = _serve(model, [list(range(1, 9))], [3],
+                         max_new_tokens=3, prompt_buckets=(8,), num_slots=1,
+                         prefix_cache_blocks=4, prefix_block_tokens=4)
+    assert stats["chunks"] == 1
+    assert stats["kv_row_steps_reserved"] == 1 * (8 + 3) + 4 * 4
+    assert stats["kv_row_steps_in_use"] == (8 + 1) + 4
+
+
+@pytest.mark.parametrize("overrides, names", [
+    (dict(pipeline_depth=2),
+     IN_A_PASS + ("serve/pass", "serve/request", "serve/ttft")),
+    (dict(scheduler="batch", batch_buckets=(1, 2, 4), flush_deadline_s=0.0),
+     ("serve/launch", "serve/readback", "serve/prefill", "serve/request",
+      "serve/ttft")),
+], ids=["pipeline_depth=2", "batch"])
+def test_the_same_names_on_the_other_paths(model, overrides, names):
+    events, results, _ = _serve(model, **overrides)
+    recorded = {e["name"] for e in events}
+    assert set(names) <= recorded
+    for name in ("serve/request", "serve/ttft"):
+        assert sorted(e["args"]["trace_id"] for e in _named(events, name)) \
+            == sorted(r.trace_id for r in results)
+    if overrides.get("scheduler") == "batch":
+        assert "serve/pass" not in recorded
+        assert all(e["args"]["passes"] == 1
+                   for e in _named(events, "serve/request"))
+
+
+def test_collector_off_counts_kv_and_records_nothing(model):
+    config, params = model
+    assert tracing.active() is None
+    serve = ServeConfig(max_new_tokens=3, prompt_buckets=(4,), num_slots=1,
+                        chunk_tokens=CHUNK, warmup=False)
+    with ServingEngine(params, config, serve) as engine:
+        result = engine.submit(np.asarray([1, 2], np.int32)).result(
+            timeout=300)
+        stats = engine.stats()
+    assert result.trace_id is None and tracing.timeline_events() == []
+    assert stats["kv_row_steps_in_use"] == 2 + 1
+    assert stats["kv_row_steps_reserved"] == 4 + 3
