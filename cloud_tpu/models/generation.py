@@ -116,8 +116,9 @@ def _init_cache(config: transformer.TransformerConfig, b: int, s: int,
     scales [L, B, S, H, 1] — the cache is re-read WHOLE every decode
     step, so at long context its bytes are the decode bandwidth; int8
     quarters them vs f32 (halves vs a bf16 cache).  The scales ride the
-    same pytree so every cache operation (scan slicing, beam repeat/
-    reorder) is a tree_map.
+    same pytree so every cache operation (the layer loop's in-place
+    scatter and indexed read, beam repeat/reorder) maps over its
+    leaves.
     """
     shape = (config.num_layers, b, s, config.num_heads, config.head_dim)
 
@@ -223,59 +224,97 @@ def _paged_attended(kind, q, cache_l, cur_len, paged):
     )
 
 
-def _decode_layer(layer_params, x, cache_l, cur_len, config, rules,
-                  write_pos=None, paged=None):
-    """One block on a single-token slice x [B, 1, D]; writes this step's
-    k/v at position cur_len[i] and attends over the whole valid prefix
-    (including the just-written position).
+def _scan_layers(params, cache, x, positions, write_cols, config, rules,
+                 mesh, *, kind, slot=None, pool=None, block_table=None,
+                 use_pallas=None):
+    """The layer stack over a KV cache that rides the scan as the CARRY.
 
-    ``write_pos`` overrides the write index per row; an out-of-range
-    entry SUPPRESSES that row's write (drop-mode scatter).  The chunk
-    scheduler uses it to keep inactive slots from stomping their frozen
-    position — a row mid-way through a chunked prefill holds real KV
-    there (see ``decode_chunk_program``).
+    The one spelling of "scan the layers with the cache carried", shared
+    by :func:`_decode_step` (``kind="decode"``),
+    :func:`prefill_chunk_program` (``"chunk"``) and
+    :func:`verify_chunk_program` (``"verify"``).  The scan runs over
+    ``(params["layers"], layer index)`` and carries ``(x, cache)``:
+    layer ``l`` scatters its new k/v (and, for an int8 cache, the
+    scales) straight into the stacked ``[L, B, S, H, hd]`` leaves at
+    ``[l, row, write_cols]``, then attends over layer ``l`` of the
+    carried leaves, read by index.  The cache is never a scan ``xs`` /
+    ``ys`` operand (XLA would slice every layer out, stack it into a
+    fresh whole-cache buffer and copy that back), so the update is in
+    place in the donated buffer.
 
-    ``paged`` (see :func:`_paged_attended`) swaps the attention read for
-    the block-table paged path; ``None`` keeps this function's trace
-    byte-identical to its pre-paged form."""
-    b = x.shape[0]
-    y = layers.rmsnorm_apply(layer_params["ln1"], x)
-    q, k_new, v_new = transformer.qkv_project(
-        layer_params["att"], y, cur_len[:, None], config
-    )
-    rows = jnp.arange(b)
-    wp = cur_len if write_pos is None else write_pos
-    cache_l = dict(cache_l)
-    if "k_scale" in cache_l:
-        k_q, k_sc = _quantize_kv(k_new[:, 0])
-        v_q, v_sc = _quantize_kv(v_new[:, 0])
-        cache_l["k"] = cache_l["k"].at[rows, wp].set(k_q, mode="drop")
-        cache_l["k_scale"] = cache_l["k_scale"].at[rows, wp].set(
-            k_sc, mode="drop"
+    ``x`` is [B, T, D] with ``positions`` [B, T] the tokens' absolute
+    (consecutive) cache positions; ``write_cols`` [B, T] is where each
+    token's k/v lands, and an out-of-range entry SUPPRESSES that write
+    (drop-mode scatter; how the slot grid keeps inactive rows from
+    stomping a frozen position).  Write comes before attend, so a
+    query sees its own key: key j of row i is valid for query t iff
+    ``j <= positions[i, t]``.  ``slot`` (a traced scalar, with B == 1)
+    makes that one row of the grid both the write row and the only row
+    attended over; ``None`` means x's rows ARE the cache's rows.
+
+    ``block_table`` routes the attention read through the paged path
+    (:func:`_paged_attended`, with ``pool`` scanned alongside as a
+    read-only ``xs`` operand); the Pallas kernels take one layer's
+    [B, S, H, hd] operand, so there the layer is still sliced out per
+    layer — never stacked back.  Returns ``(x, cache)``.
+    """
+    b, t, _ = x.shape
+    quantized = "k_scale" in cache
+    attend_len = positions[:, 0] + 1
+    chunked = kind != "decode"
+    rows = (jnp.arange(b)[:, None] if slot is None
+            else jnp.reshape(slot, (1, 1)))
+    paged = None
+    if block_table is not None:
+        paged = {"block_table": block_table, "use_pallas": use_pallas,
+                 "partitioned": mesh is not None, "mesh": mesh,
+                 "head_axes": rules.assignment("heads")}
+
+    def layer_of(leaf, l):
+        if slot is None:
+            return jax.lax.dynamic_index_in_dim(leaf, l, keepdims=False)
+        zero = jnp.int32(0)
+        return jax.lax.dynamic_slice(
+            leaf, (l, slot, zero, zero, zero), (1, 1) + leaf.shape[2:]
+        )[0]
+
+    def layer_body(carry, layer_slice):
+        x, cache = carry
+        layer_params, l = layer_slice[:2]
+        y = layers.rmsnorm_apply(layer_params["ln1"], x)
+        q, k_new, v_new = transformer.qkv_project(
+            layer_params["att"], y, positions, config
         )
-        cache_l["v"] = cache_l["v"].at[rows, wp].set(v_q, mode="drop")
-        cache_l["v_scale"] = cache_l["v_scale"].at[rows, wp].set(
-            v_sc, mode="drop"
+        updates = _kv_leaf_updates(k_new, v_new, config, quantized)
+        cache = {
+            name: leaf.at[l, rows, write_cols].set(updates[name],
+                                                   mode="drop")
+            for name, leaf in cache.items()
+        }
+        cache_l = {name: layer_of(leaf, l) for name, leaf in cache.items()}
+        if paged is None:
+            attended = _cache_attention(q, cache_l, attend_len,
+                                        chunk_causal=chunked)
+        else:
+            pool_l = layer_slice[2] if pool is not None else None
+            attended = _paged_attended(kind, q, cache_l, attend_len,
+                                       dict(paged, pool_l=pool_l))
+        att_out = layers.dense_apply(
+            layer_params["att"]["out"], attended.reshape(b, t, -1)
         )
-    else:
-        cache_l["k"] = cache_l["k"].at[rows, wp].set(
-            k_new[:, 0], mode="drop"
-        )
-        cache_l["v"] = cache_l["v"].at[rows, wp].set(
-            v_new[:, 0], mode="drop"
-        )
-    if paged is None:
-        attended = _cache_attention(q, cache_l, cur_len + 1)
-    else:
-        attended = _paged_attended("decode", q, cache_l, cur_len + 1,
-                                   paged)
-    att_out = layers.dense_apply(
-        layer_params["att"]["out"], attended.reshape(b, 1, -1)
-    )
-    x = x + att_out
-    y = layers.rmsnorm_apply(layer_params["ln2"], x)
-    x = x + _mlp(layer_params, y, config, rules)
-    return x, cache_l
+        x = x + att_out
+        y = layers.rmsnorm_apply(layer_params["ln2"], x)
+        x = x + _mlp(layer_params, y, config, rules)
+        if chunked:
+            x = shard_constraint(x, "batch", "seq", "act_embed",
+                                 rules=rules, mesh=mesh)
+        return (x, cache), None
+
+    xs = (params["layers"], jnp.arange(cache["k"].shape[0]))
+    if pool is not None:
+        xs += (pool,)
+    (x, cache), _ = jax.lax.scan(layer_body, (x, cache), xs)
+    return x, cache
 
 
 def _prefill_layer(layer_params, x, positions, prompt_mask, config, rules,
@@ -395,46 +434,33 @@ def _decode_step(params, cache, token, cur_len, config, rules, mesh,
                  write_pos=None, pool=None, block_table=None,
                  use_pallas=None):
     """One single-token decode step for every row at once: embed
-    ``token`` [B], run the scanned layer stack against the cache (each
-    row's k/v written at its ``cur_len``, or ``write_pos`` when given —
-    see :func:`_decode_layer`), return the updated cache and the
+    ``token`` [B], run the layer stack with the cache carried
+    (:func:`_scan_layers`: each row's k/v written in place at its
+    ``cur_len``, then attended over the whole valid prefix including
+    the just-written position), return the updated cache and the
     next-token logits [B, V].  The shared inner loop of
-    :func:`_decode_tokens`, :func:`beam_search`, and
-    :func:`decode_chunk_program`.
+    :func:`_decode_tokens`, :func:`beam_search`,
+    :func:`decode_chunk_program` and :func:`draft_chunk_program`.
 
-    ``block_table`` [B, n_pages] (with the optional prefix ``pool``
-    scanned alongside the cache) routes attention through the paged
-    read-in-place path; ``None`` (the default, and every non-serving
-    caller) keeps the trace byte-identical to the pre-paged program."""
+    ``write_pos`` overrides the write index per row; an out-of-range
+    entry SUPPRESSES that row's write (drop-mode scatter).  The chunk
+    scheduler uses it to keep inactive slots from stomping their frozen
+    position — a row mid-way through a chunked prefill holds real KV
+    there (see ``decode_chunk_program``).
+
+    ``block_table`` [B, n_pages] (with the optional prefix ``pool``)
+    routes attention through the paged read-in-place path."""
     x = layers.embedding_apply(
         params["embed"], token[:, None], dtype=config.dtype,
         rules=rules, mesh=mesh,
     )
     x = x * math.sqrt(config.dim)
-    paged_base = None
-    if block_table is not None:
-        paged_base = {"block_table": block_table, "use_pallas": use_pallas,
-                      "partitioned": mesh is not None, "mesh": mesh,
-                      "head_axes": rules.assignment("heads")}
-
-    def layer_body(x, layer_slice):
-        if pool is None:
-            layer_params, cache_l = layer_slice
-            paged = paged_base
-        else:
-            layer_params, cache_l, pool_l = layer_slice
-            paged = (None if paged_base is None
-                     else dict(paged_base, pool_l=pool_l))
-        x, cache_l = _decode_layer(
-            layer_params, x, cache_l, cur_len, config, rules,
-            write_pos=write_pos, paged=paged,
-        )
-        return x, cache_l
-
-    xs = (params["layers"], cache) if pool is None else (
-        params["layers"], cache, pool
+    wp = cur_len if write_pos is None else write_pos
+    x, cache = _scan_layers(
+        params, cache, x, cur_len[:, None], wp[:, None], config, rules,
+        mesh, kind="decode", pool=pool, block_table=block_table,
+        use_pallas=use_pallas,
     )
-    x, cache = jax.lax.scan(layer_body, x, xs)
     logits = _final_logits(params, x, config)[:, 0]
     # Sampling boundary reshard (see _prefill_forward): vocab-sharded
     # logits gather to replicated exactly once per decode step.
@@ -1119,8 +1145,6 @@ def prefill_chunk_program(
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
     positions = (start + jnp.arange(c))[None, :]
-    pos_idx = start + jnp.arange(c)
-    quantized = "k_scale" in cache
     table_row = None
     if block_table is not None:
         table_row = jax.lax.dynamic_slice(
@@ -1133,53 +1157,11 @@ def prefill_chunk_program(
     x = x * math.sqrt(config.dim)
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
-
-    def layer_body(x, layer_slice):
-        if pool is None:
-            layer_params, cache_l = layer_slice
-            pool_l = None
-        else:
-            layer_params, cache_l, pool_l = layer_slice
-        y = layers.rmsnorm_apply(layer_params["ln1"], x)
-        q, k_new, v_new = transformer.qkv_project(
-            layer_params["att"], y, positions, config
-        )
-        updates = _kv_leaf_updates(k_new[0], v_new[0], config, quantized)
-        cache_l = dict(cache_l)
-        for name, val in updates.items():
-            cache_l[name] = cache_l[name].at[slot, pos_idx].set(
-                val, mode="drop"
-            )
-        row = {
-            name: jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=0)
-            for name, leaf in cache_l.items()
-        }
-        if table_row is None:
-            attended = _cache_attention(
-                q, row, jnp.reshape(start + 1, (1,)), chunk_causal=True
-            )
-        else:
-            attended = _paged_attended(
-                "chunk", q, row, jnp.reshape(start + 1, (1,)),
-                {"pool_l": pool_l, "block_table": table_row,
-                 "use_pallas": use_pallas,
-                 "partitioned": mesh is not None, "mesh": mesh,
-                 "head_axes": rules.assignment("heads")},
-            )
-        att_out = layers.dense_apply(
-            layer_params["att"]["out"], attended.reshape(1, c, -1)
-        )
-        x = x + att_out
-        y = layers.rmsnorm_apply(layer_params["ln2"], x)
-        x = x + _mlp(layer_params, y, config, rules)
-        x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
-                             mesh=mesh)
-        return x, cache_l
-
-    xs = (params["layers"], cache) if pool is None else (
-        params["layers"], cache, pool
+    x, cache = _scan_layers(
+        params, cache, x, positions, positions, config, rules, mesh,
+        kind="chunk", slot=slot, pool=pool, block_table=table_row,
+        use_pallas=use_pallas,
     )
-    x, cache = jax.lax.scan(layer_body, x, xs)
     last_idx = jnp.clip(chunk_len - 1, 0, c - 1)[None, None, None]
     last_x = jnp.take_along_axis(
         x, jnp.broadcast_to(last_idx, (1, 1, x.shape[-1])), axis=1
@@ -1344,9 +1326,7 @@ def verify_chunk_program(
     active = state["active"]
     pos = state["pos"]
     s = cache["k"].shape[2]
-    rows = jnp.arange(num_slots)
     positions = pos[:, None] + jnp.arange(k)[None, :]  # [slots, k]
-    quantized = "k_scale" in cache
 
     x = layers.embedding_apply(params["embed"], window, dtype=config.dtype,
                                rules=rules, mesh=mesh)
@@ -1357,48 +1337,11 @@ def verify_chunk_program(
     # same frozen-position protection as decode_chunk_program — a slot
     # mid-chunked-prefill holds real prompt KV at pos.
     write_idx = jnp.where(active[:, None], positions, jnp.int32(s))
-
-    def layer_body(x, layer_slice):
-        if pool is None:
-            layer_params, cache_l = layer_slice
-            pool_l = None
-        else:
-            layer_params, cache_l, pool_l = layer_slice
-        y = layers.rmsnorm_apply(layer_params["ln1"], x)
-        q, k_new, v_new = transformer.qkv_project(
-            layer_params["att"], y, positions, config
-        )
-        updates = _kv_leaf_updates(k_new, v_new, config, quantized)
-        cache_l = dict(cache_l)
-        for name, val in updates.items():
-            cache_l[name] = cache_l[name].at[rows[:, None], write_idx].set(
-                val, mode="drop"
-            )
-        if block_table is None:
-            attended = _cache_attention(q, cache_l, pos + 1,
-                                        chunk_causal=True)
-        else:
-            attended = _paged_attended(
-                "verify", q, cache_l, pos + 1,
-                {"pool_l": pool_l, "block_table": block_table,
-                 "use_pallas": use_pallas,
-                 "partitioned": mesh is not None, "mesh": mesh,
-                 "head_axes": rules.assignment("heads")},
-            )
-        att_out = layers.dense_apply(
-            layer_params["att"]["out"], attended.reshape(num_slots, k, -1)
-        )
-        x = x + att_out
-        y = layers.rmsnorm_apply(layer_params["ln2"], x)
-        x = x + _mlp(layer_params, y, config, rules)
-        x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
-                             mesh=mesh)
-        return x, cache_l
-
-    xs = (params["layers"], cache) if pool is None else (
-        params["layers"], cache, pool
+    x, cache = _scan_layers(
+        params, cache, x, positions, write_idx, config, rules, mesh,
+        kind="verify", pool=pool, block_table=block_table,
+        use_pallas=use_pallas,
     )
-    x, cache = jax.lax.scan(layer_body, x, xs)
     logits = _final_logits(params, x, config)  # [slots, k, V]
     # Sampling boundary reshard (see _prefill_forward): once per forward.
     logits = shard_constraint(logits, "batch", None, None, rules=rules,

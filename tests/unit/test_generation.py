@@ -766,6 +766,68 @@ class TestSlotPrograms:
         )
         assert live[0] == np.asarray(want["tokens"])[0].tolist()
 
+    @pytest.mark.parametrize("kv_quant", [False, True],
+                             ids=["bf16", "int8kv"])
+    def test_chunk_writes_only_its_own_rows(self, kv_quant):
+        """The chunk program updates the carried cache IN PLACE, so what
+        it must not touch matters: after a chunk every byte outside
+        ``[*, active slot, pos .. pos + steps)`` is what it was — other
+        rows, inactive slots, every layer, every leaf — and a slot
+        whose write position is out of range writes nothing at all."""
+        import functools
+
+        config = transformer.TINY.scaled(dtype=jnp.bfloat16, num_layers=2)
+        params = transformer.init(jax.random.PRNGKey(0), config)
+        sample = generation.SampleConfig(temperature=0.0)
+        num_slots, max_len, steps = 4, 16, 3
+        zeros = generation.init_slot_cache(config, num_slots, max_len,
+                                           kv_quant=kv_quant)
+        keys = jax.random.split(jax.random.PRNGKey(1), len(zeros))
+        cache = {}
+        for key, (name, leaf) in zip(keys, sorted(zeros.items())):
+            if leaf.dtype == jnp.int8:
+                cache[name] = jax.random.randint(
+                    key, leaf.shape, -127, 128, jnp.int32).astype(jnp.int8)
+            else:
+                cache[name] = jax.random.uniform(
+                    key, leaf.shape, jnp.float32, 0.5, 1.5
+                ).astype(leaf.dtype)
+        # Slot 0 decodes all three steps; slot 1 runs out of budget after
+        # two; slot 2 is inactive over a frozen position that holds KV;
+        # slot 3 is active with its position past the row's end.
+        state = generation.init_slot_state(config, num_slots, sample=sample)
+        state.update(
+            pos=jnp.asarray([5, 9, 7, max_len], jnp.int32),
+            tok=jnp.asarray([11, 12, 13, 14], jnp.int32),
+            remaining=jnp.asarray([10, 2, 10, 10], jnp.int32),
+            active=jnp.asarray([True, True, False, True]),
+        )
+        chunk = jax.jit(functools.partial(
+            generation.decode_chunk_program, config=config,
+            chunk_size=steps, sample=sample,
+        ))
+        after, new_state, _, valid = chunk(params, cache, state)
+        np.testing.assert_array_equal(
+            np.asarray(valid).sum(axis=1), [3, 2, 0, 3])
+        np.testing.assert_array_equal(
+            np.asarray(new_state["pos"]), [8, 11, 7, max_len + 3])
+
+        written = np.zeros((num_slots, max_len), bool)
+        written[0, 5:8] = True
+        written[1, 9:11] = True
+        assert sorted(after) == sorted(cache)
+        for name, leaf in cache.items():
+            was = np.asarray(leaf).view(np.uint8)
+            now = np.asarray(after[name]).view(np.uint8)
+            assert now.shape == was.shape, name
+            np.testing.assert_array_equal(
+                now[:, ~written], was[:, ~written], err_msg=name)
+            # ... and every layer did write each of its own positions.
+            changed = (now != was).any(axis=(3, 4))  # [L, slots, rows]
+            np.testing.assert_array_equal(
+                changed, np.broadcast_to(written, changed.shape),
+                err_msg=name)
+
 
 class TestQuantizedKvCache:
     """kv_quant=True: int8 cache with per-(position, head) scales.  The
