@@ -35,7 +35,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from cloud_tpu.models import layers, moe as moe_lib
+from cloud_tpu.models import layers, moe as moe_lib, ssm as ssm_lib
 from cloud_tpu.models import transformer
 from cloud_tpu.parallel import mesh as mesh_lib
 from cloud_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules, shard_constraint
@@ -108,6 +108,10 @@ def sample_logits(rng, logits, sample: SampleConfig, *, seen=None,
     return jax.random.categorical(rng, logits, axis=-1)
 
 
+#: The cache leaves that hold a recurrent state, not K/V rows.
+STATE_LEAVES = ("ssm", "conv")
+
+
 def _init_cache(config: transformer.TransformerConfig, b: int, s: int,
                 rules: ShardingRules, mesh, kv_quant: bool = False):
     """KV cache pytree [L, B, S, H, hd].
@@ -119,23 +123,40 @@ def _init_cache(config: transformer.TransformerConfig, b: int, s: int,
     same pytree so every cache operation (the layer loop's in-place
     scatter and indexed read, beam repeat/reorder) maps over its
     leaves.
+
+    With ``config.ssm`` two leaves WITHOUT a position axis ride along
+    (:data:`STATE_LEAVES`): ``ssm`` [L, B, H, P, N] float32, each row's
+    recurrent state, and ``conv`` [L, B, W - 1, conv_dim], the last
+    inputs of its causal convolution.  A row's state is whole whatever
+    its context holds: nothing masks a stale one, so whoever arms a row
+    writes both leaves whole and whoever skips a row leaves both alone.
     """
-    shape = (config.num_layers, b, s, config.num_heads, config.head_dim)
+    shape = (config.num_layers, b, s, config.kv_heads, config.head_dim)
 
     def constrain(x):
         return shard_constraint(x, None, "batch", None, "heads", None,
                                 rules=rules, mesh=mesh)
 
     if not kv_quant:
-        return {"k": constrain(jnp.zeros(shape, config.dtype)),
-                "v": constrain(jnp.zeros(shape, config.dtype))}
-    scale_shape = shape[:-1] + (1,)
-    return {
-        "k": constrain(jnp.zeros(shape, jnp.int8)),
-        "k_scale": constrain(jnp.ones(scale_shape, jnp.float32)),
-        "v": constrain(jnp.zeros(shape, jnp.int8)),
-        "v_scale": constrain(jnp.ones(scale_shape, jnp.float32)),
-    }
+        cache = {"k": constrain(jnp.zeros(shape, config.dtype)),
+                 "v": constrain(jnp.zeros(shape, config.dtype))}
+    else:
+        scale_shape = shape[:-1] + (1,)
+        cache = {
+            "k": constrain(jnp.zeros(shape, jnp.int8)),
+            "k_scale": constrain(jnp.ones(scale_shape, jnp.float32)),
+            "v": constrain(jnp.zeros(shape, jnp.int8)),
+            "v_scale": constrain(jnp.ones(scale_shape, jnp.float32)),
+        }
+    if config.ssm is not None:
+        m = config.ssm
+        cache["ssm"] = jnp.zeros(
+            (config.num_layers, b, m.num_heads, m.head_dim, m.state_dim),
+            ssm_lib.STATE_DTYPE)
+        cache["conv"] = jnp.zeros(
+            (config.num_layers, b, m.conv_width - 1, m.conv_dim),
+            config.dtype)
+    return cache
 
 
 def _quantize_kv(x):
@@ -164,6 +185,14 @@ def _cache_attention(q, cache_l, cur_len, *, chunk_causal: bool = False):
     k_cache, v_cache = cache_l["k"], cache_l["v"]
     s = k_cache.shape[1]
     scale = 1.0 / math.sqrt(q.shape[-1])
+    b, t_q, h, hd = q.shape
+    group = h // k_cache.shape[2]
+    if group > 1:
+        # Grouped K/V heads: the ``group`` query heads that read one K/V
+        # head line up as extra query rows of that head (row t * group +
+        # g), so the products below never see a repeated cache.
+        q = q.reshape(b, t_q, h // group, group, hd).transpose(
+            0, 1, 3, 2, 4).reshape(b, t_q * group, h // group, hd)
 
     def fold(scores_like, kv_scale):
         # [B, S, H, 1] -> [B, H, 1, S] broadcast over the query dim.
@@ -178,7 +207,8 @@ def _cache_attention(q, cache_l, cur_len, *, chunk_causal: bool = False):
     if chunk_causal:
         # [B, Tq, S]: query t sits at cache position cur_len - 1 + t.
         valid = jnp.arange(s)[None, None, :] < (
-            cur_len[:, None, None] + jnp.arange(q.shape[1])[None, :, None]
+            cur_len[:, None, None]
+            + (jnp.arange(q.shape[1]) // group)[None, :, None]
         )
         scores = jnp.where(valid[:, None, :, :], scores, -1e30)
     else:
@@ -190,6 +220,9 @@ def _cache_attention(q, cache_l, cur_len, *, chunk_causal: bool = False):
     out = jnp.einsum(
         "bhqk,bkhd->bqhd", weights, v_cache.astype(jnp.float32)
     )
+    if group > 1:
+        out = out.reshape(b, t_q, group, h // group, hd).transpose(
+            0, 1, 3, 2, 4).reshape(b, t_q, h, hd)
     return out.astype(q.dtype)
 
 
@@ -197,7 +230,7 @@ def _mlp(layer_params, y, config, rules):
     if config.moe is not None:
         out, _ = moe_lib.moe_mlp_apply(layer_params["mlp"], y, config.moe)
         return out
-    return layers.mlp_block_apply(layer_params["mlp"], y, rules=rules)
+    return transformer.mlp_apply(layer_params["mlp"], y, config, rules)
 
 
 def _paged_attended(kind, q, cache_l, cur_len, paged):
@@ -257,8 +290,18 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
     read-only ``xs`` operand); the Pallas kernels take one layer's
     [B, S, H, hd] operand, so there the layer is still sliced out per
     layer — never stacked back.  Returns ``(x, cache)``.
+
+    With ``config.ssm`` (``kind="decode"`` only: one token a row) the
+    carried cache also holds each row's recurrent state; layer ``l``
+    reads it, advances it by the token and writes it back at ``[l]``.
+    A row whose K/V write is suppressed is FROZEN: its state and its
+    convolution's tail come back bit for bit (the drop-mode scatter has
+    no equivalent for a leaf without positions).
     """
     b, t, _ = x.shape
+    if kind != "decode" or slot is not None or block_table is not None:
+        _refuse_recurrent(
+            config, f"a {kind} pass over a slot's rows or a paged read")
     quantized = "k_scale" in cache
     attend_len = positions[:, 0] + 1
     chunked = kind != "decode"
@@ -281,17 +324,18 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
     def layer_body(carry, layer_slice):
         x, cache = carry
         layer_params, l = layer_slice[:2]
-        y = layers.rmsnorm_apply(layer_params["ln1"], x)
+        y = layers.rmsnorm_apply(layer_params["ln1"], x,
+                                 eps=config.norm_eps)
         q, k_new, v_new = transformer.qkv_project(
             layer_params["att"], y, positions, config
         )
         updates = _kv_leaf_updates(k_new, v_new, config, quantized)
-        cache = {
-            name: leaf.at[l, rows, write_cols].set(updates[name],
-                                                   mode="drop")
-            for name, leaf in cache.items()
-        }
-        cache_l = {name: layer_of(leaf, l) for name, leaf in cache.items()}
+        cache = dict(cache, **{
+            name: cache[name].at[l, rows, write_cols].set(update,
+                                                          mode="drop")
+            for name, update in updates.items()
+        })
+        cache_l = {name: layer_of(cache[name], l) for name in updates}
         if paged is None:
             attended = _cache_attention(q, cache_l, attend_len,
                                         chunk_causal=chunked)
@@ -299,11 +343,28 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
             pool_l = layer_slice[2] if pool is not None else None
             attended = _paged_attended(kind, q, cache_l, attend_len,
                                        dict(paged, pool_l=pool_l))
-        att_out = layers.dense_apply(
-            layer_params["att"]["out"], attended.reshape(b, t, -1)
-        )
-        x = x + att_out
-        y = layers.rmsnorm_apply(layer_params["ln2"], x)
+        mixed = transformer.attention_out(layer_params["att"], attended,
+                                          config)
+        if config.ssm is not None:
+            held = {name: jax.lax.dynamic_index_in_dim(
+                cache[name], l, keepdims=False) for name in STATE_LEAVES}
+            ssm_out, state, tail = ssm_lib.ssm_step(
+                layer_params["ssm"], y[:, 0], held["ssm"], held["conv"],
+                config.ssm, config.multipliers, config.norm_eps,
+            )
+            # Rows that advance (their write column is in range).
+            live = ((write_cols[:, 0] >= 0)
+                    & (write_cols[:, 0] < cache["k"].shape[2]))
+            for name, new in (("ssm", state), ("conv", tail)):
+                keep = live.reshape((b,) + (1,) * (new.ndim - 1))
+                new = jnp.where(keep, new.astype(held[name].dtype),
+                                held[name])
+                cache[name] = jax.lax.dynamic_update_index_in_dim(
+                    cache[name], new, l, axis=0)
+            mixed = mixed + ssm_out[:, None]
+        x = x + mixed
+        y = layers.rmsnorm_apply(layer_params["ln2"], x,
+                                 eps=config.norm_eps)
         x = x + _mlp(layer_params, y, config, rules)
         if chunked:
             x = shard_constraint(x, "batch", "seq", "act_embed",
@@ -317,66 +378,70 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
     return x, cache
 
 
-def _prefill_layer(layer_params, x, positions, prompt_mask, config, rules,
-                   mesh):
-    """One block on the full prompt buffer [B, T, D], returning the
-    block's k/v for the cache.  Causal attention with the padding mask
-    applied key-side (padded tail slots are later overwritten by decode
-    before they can ever be attended)."""
+def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
+                   config, rules, mesh):
+    """One block on the full prompt buffer [B, T, D], returning what the
+    block leaves in a cache, raw: its k/v and, with ``config.ssm``, each
+    row's state and convolution tail at its last real token.  Causal
+    attention with the padding mask applied key-side (padded tail slots
+    are later overwritten by decode before they can ever be attended);
+    the mixer masks the padding itself (``ssm.ssd_prefill``)."""
     from cloud_tpu import ops
 
-    b, t, _ = x.shape
-    y = layers.rmsnorm_apply(layer_params["ln1"], x)
+    y = layers.rmsnorm_apply(layer_params["ln1"], x, eps=config.norm_eps)
     q, k, v = transformer.qkv_project(layer_params["att"], y, positions,
                                       config)
     attended = ops.flash_attention(
-        q, k, v, causal=True, mask=prompt_mask,
-        partitioned=mesh is not None, mesh=mesh,
+        q, *transformer.repeat_kv(k, v, config), causal=True,
+        mask=prompt_mask, partitioned=mesh is not None, mesh=mesh,
         batch_axes=rules.assignment("batch"),
         head_axes=rules.assignment("heads"),
     )
-    att_out = layers.dense_apply(
-        layer_params["att"]["out"], attended.reshape(b, t, -1)
-    )
-    x = x + att_out
-    y = layers.rmsnorm_apply(layer_params["ln2"], x)
+    mixed = transformer.attention_out(layer_params["att"], attended, config)
+    left = {"k": k, "v": v}
+    if config.ssm is not None:
+        ssm_out, left["ssm"], left["conv"] = ssm_lib.ssd_prefill(
+            layer_params["ssm"], y, prompt_mask, prompt_lens, config.ssm,
+            config.multipliers, config.norm_eps,
+        )
+        mixed = mixed + ssm_out
+    x = x + mixed
+    y = layers.rmsnorm_apply(layer_params["ln2"], x, eps=config.norm_eps)
     x = x + _mlp(layer_params, y, config, rules)
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
-    return x, k, v
+    return x, left
 
 
 def _final_logits(params, x, config):
-    x = layers.rmsnorm_apply(params["ln_f"], x)
+    x = layers.rmsnorm_apply(params["ln_f"], x, eps=config.norm_eps)
     return transformer.lm_logits(params, x, config)
 
 
 def _prefill_forward(params, prompt_tokens, prompt_lens, config, rules,
                      mesh):
-    """The prompt forward pass alone: per-layer k/v stacks
-    [L, B, T_prompt, H, hd] (raw, pre-cast) plus the next-token logits
-    [B, V] at each row's last real prompt position.  Where those k/v
-    land is the caller's business: :func:`_prefill` writes them at the
-    origin of a fresh batch cache, :func:`insert_slot_program` into one
-    row of a persistent slot grid."""
+    """The prompt forward pass alone: what every layer leaves in a
+    cache, stacked and raw (pre-cast) — ``k`` / ``v``
+    [L, B, T_prompt, kv_heads, hd] and, with ``config.ssm``, ``ssm``
+    [L, B, H, P, N] and ``conv`` [L, B, W - 1, conv_dim] at each row's
+    last real token — plus the next-token logits [B, V] at that
+    position.  Where they land is the caller's business
+    (:func:`_write_prefill`): :func:`_prefill` writes them at the origin
+    of a fresh batch cache, :func:`insert_slot_program` into one row of
+    a persistent slot grid."""
     b, t_prompt = prompt_tokens.shape
     positions = jnp.broadcast_to(jnp.arange(t_prompt), (b, t_prompt))
     prompt_mask = (positions < prompt_lens[:, None]).astype(jnp.int32)
-    x = layers.embedding_apply(params["embed"], prompt_tokens,
-                               dtype=config.dtype, rules=rules, mesh=mesh)
-    x = x * math.sqrt(config.dim)
+    x = transformer.embed_tokens(params, prompt_tokens, config, rules, mesh)
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
 
     def prefill_body(x, layer_slice):
         layer_params, = layer_slice
-        x, k, v = _prefill_layer(layer_params, x, positions, prompt_mask,
-                                 config, rules, mesh)
-        return x, (k, v)
+        return _prefill_layer(layer_params, x, positions, prompt_mask,
+                              prompt_lens, config, rules, mesh)
 
-    x, (k_pref, v_pref) = jax.lax.scan(
-        prefill_body, x, (params["layers"],)
-    )
+    x, left = jax.lax.scan(prefill_body, x, (params["layers"],))
     last_idx = (prompt_lens - 1)[:, None, None]
     last_x = jnp.take_along_axis(
         x, jnp.broadcast_to(last_idx, (b, 1, x.shape[-1])), axis=1
@@ -388,7 +453,7 @@ def _prefill_forward(params, prompt_tokens, prompt_lens, config, rules,
     # HERE (once per forward) and nowhere else.  No-op without a mesh.
     logits0 = shard_constraint(logits0, "batch", None, rules=rules,
                                mesh=mesh)
-    return k_pref, v_pref, logits0
+    return left, logits0
 
 
 def _kv_leaf_updates(k_raw, v_raw, config, quantized: bool):
@@ -406,12 +471,21 @@ def _kv_leaf_updates(k_raw, v_raw, config, quantized: bool):
             "v": v_raw.astype(config.dtype)}
 
 
-def _write_prefill(cache, k_pref, v_pref, start, config):
-    """Write a prefill's k/v stacks into ``cache`` at the 5-D ``start``
-    index (quantizing first when the cache is int8)."""
-    updates = _kv_leaf_updates(k_pref, v_pref, config, "k_scale" in cache)
+def _write_prefill(cache, left, start, config):
+    """Write what a prefill left (:func:`_prefill_forward`) into
+    ``cache`` at the 5-D ``start`` index ``(layer, row, 0, 0, 0)``: the
+    k/v stacks (quantizing first when the cache is int8) and, where the
+    cache holds them, each row's state and convolution tail WHOLE (a
+    prefill starts at position 0, so the same index, cut to the leaf's
+    rank, addresses a state leaf's layer and row)."""
+    updates = _kv_leaf_updates(left["k"], left["v"], config,
+                               "k_scale" in cache)
+    for name in STATE_LEAVES:
+        if name in cache:
+            updates[name] = left[name].astype(cache[name].dtype)
     for name, val in updates.items():
-        cache[name] = jax.lax.dynamic_update_slice(cache[name], val, start)
+        cache[name] = jax.lax.dynamic_update_slice(
+            cache[name], val, start[:val.ndim])
     return cache
 
 
@@ -423,10 +497,10 @@ def _prefill(params, prompt_tokens, prompt_lens, config, s, rules, mesh,
     sampling and beam decoding."""
     b, _ = prompt_tokens.shape
     cache = _init_cache(config, b, s, rules, mesh, kv_quant=kv_quant)
-    k_pref, v_pref, logits0 = _prefill_forward(
+    left, logits0 = _prefill_forward(
         params, prompt_tokens, prompt_lens, config, rules, mesh
     )
-    cache = _write_prefill(cache, k_pref, v_pref, (0, 0, 0, 0, 0), config)
+    cache = _write_prefill(cache, left, (0, 0, 0, 0, 0), config)
     return cache, logits0
 
 
@@ -450,11 +524,8 @@ def _decode_step(params, cache, token, cur_len, config, rules, mesh,
 
     ``block_table`` [B, n_pages] (with the optional prefix ``pool``)
     routes attention through the paged read-in-place path."""
-    x = layers.embedding_apply(
-        params["embed"], token[:, None], dtype=config.dtype,
-        rules=rules, mesh=mesh,
-    )
-    x = x * math.sqrt(config.dim)
+    x = transformer.embed_tokens(params, token[:, None], config, rules,
+                                 mesh)
     wp = cur_len if write_pos is None else write_pos
     x, cache = _scan_layers(
         params, cache, x, cur_len[:, None], wp[:, None], config, rules,
@@ -779,19 +850,22 @@ def insert_slot_program(
     unless the request is already finished (``max_new_tokens == 1`` or
     the first token sampled eos).  Stale cache beyond the new prompt is
     harmless — attention masks positions ``>= pos`` and decode
-    overwrites each position before it can become valid.  Returns
+    overwrites each position before it can become valid.  That holds for
+    K/V rows only: a recurrent state has no positions to mask, so the
+    slot's state and convolution tail are overwritten WHOLE with the
+    prompt's (a reused slot carries nothing over).  Returns
     ``(cache, state, first_token)``.
     """
     t_prompt = prompt_tokens.shape[1]
     prompt_len = jnp.clip(jnp.asarray(prompt_len, jnp.int32), 1, t_prompt)
     lens = jnp.reshape(prompt_len, (1,))
-    k_pref, v_pref, logits0 = _prefill_forward(
+    left, logits0 = _prefill_forward(
         params, prompt_tokens, lens, config, rules, mesh
     )
     slot = jnp.asarray(slot, jnp.int32)
     zero = jnp.int32(0)
     cache = _write_prefill(
-        cache, k_pref, v_pref, (zero, slot, zero, zero, zero), config
+        cache, left, (zero, slot, zero, zero, zero), config
     )
 
     state, tok0 = _arm_slot(state, logits0, prompt_len, slot,
@@ -859,7 +933,10 @@ def decode_chunk_program(
     (drop-mode scatter at an out-of-range position): a slot mid-way
     through a chunked prefill already holds real prompt KV at its frozen
     position, so the old write-then-overwrite staleness argument no
-    longer covers inactive rows.
+    longer covers inactive rows.  The same out-of-range position FREEZES
+    an inactive slot's recurrent state (:func:`_scan_layers`): a slot
+    that finished mid-chunk, or waits empty, keeps its state bit for bit
+    until an insert overwrites it.
 
     Returns ``(cache, state, tokens, valid)`` with ``tokens``/``valid``
     shaped [num_slots, chunk_size]: ``valid[s, i]`` marks a real
@@ -967,6 +1044,7 @@ def init_prefix_pool(config, num_blocks: int, block_tokens: int, *,
     slot cache, so copies are per-leaf slicing).  Which block holds
     which token prefix is host-side bookkeeping
     (``serving.prefix_cache.PrefixCacheManager``)."""
+    _refuse_recurrent(config, "the prefix pool")
     return _init_cache(config, num_blocks, block_tokens, rules, mesh,
                        kv_quant=kv_quant)
 
@@ -1152,9 +1230,7 @@ def prefill_chunk_program(
             (1, block_table.shape[1]),
         )
 
-    x = layers.embedding_apply(params["embed"], chunk_tokens,
-                               dtype=config.dtype, rules=rules, mesh=mesh)
-    x = x * math.sqrt(config.dim)
+    x = transformer.embed_tokens(params, chunk_tokens, config, rules, mesh)
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
     x, cache = _scan_layers(
@@ -1328,9 +1404,7 @@ def verify_chunk_program(
     s = cache["k"].shape[2]
     positions = pos[:, None] + jnp.arange(k)[None, :]  # [slots, k]
 
-    x = layers.embedding_apply(params["embed"], window, dtype=config.dtype,
-                               rules=rules, mesh=mesh)
-    x = x * math.sqrt(config.dim)
+    x = transformer.embed_tokens(params, window, config, rules, mesh)
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
     # Inactive slots write NOWHERE (out-of-range -> drop-mode scatter):
@@ -1430,13 +1504,13 @@ def draft_prefill_slot_program(
     t_prompt = prompt_tokens.shape[1]
     prompt_len = jnp.clip(jnp.asarray(prompt_len, jnp.int32), 1, t_prompt)
     lens = jnp.reshape(prompt_len, (1,))
-    k_pref, v_pref, _ = _prefill_forward(
+    left, _ = _prefill_forward(
         params, prompt_tokens, lens, config, rules, mesh
     )
     slot = jnp.asarray(slot, jnp.int32)
     zero = jnp.int32(0)
     return _write_prefill(
-        cache, k_pref, v_pref, (zero, slot, zero, zero, zero), config
+        cache, left, (zero, slot, zero, zero, zero), config
     )
 
 
@@ -1458,6 +1532,26 @@ def _check_inference_supported(config, rules, mesh, what: str):
     if transformer._zigzag_active(config, mesh):
         raise ValueError(
             f"zigzag_sp is training-only; disable it for {what}"
+        )
+    if config.ssm is not None and mesh is not None:
+        shape = dict(mesh.shape)
+        if any(shape.get(axis, 1) > 1
+               for axis in (mesh_lib.AXIS_TP, mesh_lib.AXIS_SP)):
+            _refuse_recurrent(config, f"{what} under a tp/sp mesh")
+
+
+def _refuse_recurrent(config, what: str):
+    """The one error of every path that takes "a prefix's cache is its
+    K/V rows" for granted: a recurrent state is whole at every token, so
+    a copied, paged, chunked, rewound or head-sharded cache of rows does
+    not carry it (ROADMAP R4 keeps the list)."""
+    if config.ssm is not None:
+        raise NotImplementedError(
+            f"{what} is not supported for a model with a recurrent state "
+            "(TransformerConfig.ssm): its slot cache holds a state and a "
+            "convolution tail per row besides the K/V rows, and only the "
+            "plain slot path (insert at a bucket, decode chunks, "
+            "generate()) carries them"
         )
 
 
@@ -1497,6 +1591,7 @@ def beam_search(
     """
     mesh = mesh if mesh is not None else mesh_lib.get_global_mesh()
     _check_inference_supported(config, rules, mesh, "beam_search")
+    _refuse_recurrent(config, "beam_search")
     if num_beams < 1:
         raise ValueError(f"num_beams must be >= 1, got {num_beams}")
     if max_new_tokens < 1:

@@ -54,6 +54,13 @@ def remat_wrap(body, enabled: bool = True, policy: str = "full"):
     )
 
 
+def scaled(x, multiplier: float):
+    """``x`` times a fixed scalar of the configuration (a muP multiplier);
+    a multiplier of exactly 1 adds no operation, so a model that has none
+    computes, bit for bit, what it computed before they existed."""
+    return x if multiplier == 1.0 else x * multiplier
+
+
 def dense_axes(in_axis: Optional[str], out_axis: Optional[str],
                use_bias: bool = True):
     """Logical axes for a dense layer's params — the single source of truth
@@ -408,13 +415,17 @@ def attention_block_axes():
     }
 
 
-def attention_block_init(rng, dim: int, num_heads: int, head_dim: int):
+def attention_block_init(rng, dim: int, num_heads: int, head_dim: int,
+                         num_kv_heads: Optional[int] = None):
+    """``num_kv_heads`` < ``num_heads``: grouped-query attention, the k
+    and v projections that many heads wide."""
     rngs = jax.random.split(rng, 4)
+    kv_heads = num_heads if num_kv_heads is None else num_kv_heads
     params = {}
     for name, r, (i, o) in [
         ("q", rngs[0], (dim, num_heads * head_dim)),
-        ("k", rngs[1], (dim, num_heads * head_dim)),
-        ("v", rngs[2], (dim, num_heads * head_dim)),
+        ("k", rngs[1], (dim, kv_heads * head_dim)),
+        ("v", rngs[2], (dim, kv_heads * head_dim)),
     ]:
         params[name], _ = dense_init(
             r, i, o, in_axis="embed", out_axis="heads", use_bias=False
@@ -473,8 +484,14 @@ def mlp_block_init(rng, dim: int, hidden: int):
     return params, mlp_block_axes()
 
 
-def mlp_block_apply(params, x, *, rules: ShardingRules = DEFAULT_RULES):
-    """Gated (SwiGLU) MLP with tp-sharded hidden dim."""
-    h = jax.nn.silu(dense_apply(params["wi"], x)) * dense_apply(params["wg"], x)
+def mlp_block_apply(params, x, *, rules: ShardingRules = DEFAULT_RULES,
+                    gate_multiplier: float = 1.0,
+                    down_multiplier: float = 1.0):
+    """Gated (SwiGLU) MLP with tp-sharded hidden dim: ``wi`` is the gate
+    (multiplied by ``gate_multiplier`` before the silu), ``wg`` the up
+    projection, ``wo`` the down projection (its product multiplied by
+    ``down_multiplier``)."""
+    gate = scaled(dense_apply(params["wi"], x), gate_multiplier)
+    h = jax.nn.silu(gate) * dense_apply(params["wg"], x)
     h = shard_constraint(h, "batch", "seq", "mlp", rules=rules)
-    return dense_apply(params["wo"], h)
+    return scaled(dense_apply(params["wo"], h), down_multiplier)

@@ -29,10 +29,35 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from cloud_tpu.models import layers, moe as moe_lib
+from cloud_tpu.models import layers, moe as moe_lib, ssm as ssm_lib
 from cloud_tpu.parallel import mesh as mesh_lib
 from cloud_tpu.parallel import pipeline as pipeline_lib
 from cloud_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules, shard_constraint
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """Fixed scalars on the block's products (muP, as Falcon-H1's config
+    publishes them).  The default is today's arithmetic bit for bit:
+    ``embedding=None`` reads sqrt(dim), and a multiplier of 1 adds no
+    operation (``layers.scaled``)."""
+
+    embedding: Optional[float] = None  # None -> sqrt(dim)
+    attention_in: float = 1.0   # the normed input of q, k and v
+    attention_out: float = 1.0  # attention's output projection
+    key: float = 1.0            # the k projection, before RoPE
+    ssm_in: float = 1.0         # the normed input of the mixer
+    ssm_out: float = 1.0        # the mixer's output projection
+    #: The mixer's input projection, by segment: z | x | B | C | dt.
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp_gate: float = 1.0       # the gate product, before the silu
+    mlp_down: float = 1.0       # the down projection's product
+    lm_head: float = 1.0        # the logits
+
+    def __post_init__(self):
+        # A config read back from JSON (models/export.py) brings a list:
+        # keep the field hashable, as a jit-static config has to be.
+        object.__setattr__(self, "ssm", tuple(self.ssm))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,10 +66,19 @@ class TransformerConfig:
     num_layers: int = 12
     dim: int = 768
     num_heads: int = 12
+    #: K/V heads (grouped-query attention: query head h reads K/V head
+    #: ``h // (num_heads / num_kv_heads)``); None -> ``num_heads``.
+    num_kv_heads: Optional[int] = None
     head_dim: int = 64
     mlp_hidden: int = 3072
     max_seq_len: int = 2048
     moe: Optional[moe_lib.MoeConfig] = None  # None -> dense SwiGLU MLP
+    #: A Mamba-2 mixer beside attention in EVERY block: both read the
+    #: same normed input and their outputs are summed before the one
+    #: residual add (models/ssm.py).  None -> attention alone.
+    ssm: Optional[ssm_lib.SsmConfig] = None
+    multipliers: Multipliers = Multipliers()
+    norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     remat: bool = True
     #: Which remat policy when ``remat`` is on: "full" (save carry only)
@@ -81,6 +115,18 @@ class TransformerConfig:
     #: Training only — apply()/generation still produce real logits.
     fused_ce: bool = False
 
+    def __post_init__(self):
+        if self.num_heads % self.kv_heads:
+            raise ValueError(
+                f"num_kv_heads={self.num_kv_heads} must divide "
+                f"num_heads={self.num_heads}"
+            )
+
+    @property
+    def kv_heads(self) -> int:
+        return (self.num_heads if self.num_kv_heads is None
+                else self.num_kv_heads)
+
     def scaled(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
 
@@ -99,9 +145,10 @@ SMALL = TransformerConfig(
 
 
 def _layer_init(rng, config: TransformerConfig):
-    r_att, r_mlp, rn1, rn2 = jax.random.split(rng, 4)
+    r_att, r_mlp, r_ssm, _ = jax.random.split(rng, 4)
     att, att_axes = layers.attention_block_init(
-        r_att, config.dim, config.num_heads, config.head_dim
+        r_att, config.dim, config.num_heads, config.head_dim,
+        config.num_kv_heads,
     )
     ln1, ln1_axes = layers.rmsnorm_init(config.dim)
     ln2, ln2_axes = layers.rmsnorm_init(config.dim)
@@ -111,10 +158,14 @@ def _layer_init(rng, config: TransformerConfig):
         )
     else:
         mlp, mlp_axes = layers.mlp_block_init(r_mlp, config.dim, config.mlp_hidden)
-    return (
-        {"att": att, "ln1": ln1, "mlp": mlp, "ln2": ln2},
-        {"att": att_axes, "ln1": ln1_axes, "mlp": mlp_axes, "ln2": ln2_axes},
-    )
+    params = {"att": att, "ln1": ln1, "mlp": mlp, "ln2": ln2}
+    axes = {"att": att_axes, "ln1": ln1_axes, "mlp": mlp_axes,
+            "ln2": ln2_axes}
+    if config.ssm is not None:
+        params["ssm"], axes["ssm"] = ssm_lib.ssm_init(
+            r_ssm, config.dim, config.ssm
+        )
+    return params, axes
 
 
 def init(rng, config: TransformerConfig) -> Dict[str, Any]:
@@ -166,6 +217,8 @@ def _layer_init_axes(config: TransformerConfig):
         "mlp": mlp_axes,
         "ln2": {"scale": (None,)},
     }
+    if config.ssm is not None:
+        axes["ssm"] = ssm_lib.ssm_axes()
     return None, axes
 
 
@@ -175,29 +228,67 @@ def qkv_project(att_params, x, positions, config: TransformerConfig):
     must produce bit-identical projections for the KV cache to be
     equivalent to a full re-forward)."""
     b, t, _ = x.shape
-    h, hd = config.num_heads, config.head_dim
+    mult = config.multipliers
+    x = layers.scaled(x, mult.attention_in)
 
-    def proj(p):
+    def proj(p, heads):
         y = layers.dense_apply(p, x)
-        return y.reshape(b, t, h, hd)
+        return y.reshape(b, t, heads, config.head_dim)
 
     q = layers.rotary_embedding(
-        proj(att_params["q"]), positions, base=config.rope_base
+        proj(att_params["q"], config.num_heads), positions,
+        base=config.rope_base,
     )
     k = layers.rotary_embedding(
-        proj(att_params["k"]), positions, base=config.rope_base
+        layers.scaled(proj(att_params["k"], config.kv_heads), mult.key),
+        positions, base=config.rope_base,
     )
-    v = proj(att_params["v"])
+    v = proj(att_params["v"], config.kv_heads)
     return q, k, v
+
+
+def repeat_kv(k, v, config: TransformerConfig):
+    """K/V [B, T, kv_heads, hd] at the query heads' count, for an
+    attention that pairs heads one to one (query head h reads K/V head
+    ``h // group``).  The cache keeps them unrepeated."""
+    group = config.num_heads // config.kv_heads
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
+def attention_out(att_params, attended, config: TransformerConfig):
+    """Attention's output projection on ``attended`` [B, T, H, hd]."""
+    b, t = attended.shape[:2]
+    out = layers.dense_apply(att_params["out"], attended.reshape(b, t, -1))
+    return layers.scaled(out, config.multipliers.attention_out)
+
+
+def mlp_apply(mlp_params, y, config: TransformerConfig, rules):
+    """The dense SwiGLU MLP with the configuration's multipliers."""
+    mult = config.multipliers
+    return layers.mlp_block_apply(
+        mlp_params, y, rules=rules, gate_multiplier=mult.mlp_gate,
+        down_multiplier=mult.mlp_down,
+    )
+
+
+def embed_tokens(params, tokens, config: TransformerConfig, rules, mesh):
+    """Token embeddings [..., D] in the compute dtype, times the
+    embedding multiplier (sqrt(dim) unless the configuration gives
+    one)."""
+    x = layers.embedding_apply(params["embed"], tokens, dtype=config.dtype,
+                               rules=rules, mesh=mesh)
+    scale = config.multipliers.embedding
+    return x * (math.sqrt(config.dim) if scale is None else scale)
 
 
 def _attention(
     x, att_params, config: TransformerConfig, rules: ShardingRules,
     mesh, positions,
 ):
-    b, t, _ = x.shape
-    h, hd = config.num_heads, config.head_dim
     q, k, v = qkv_project(att_params, x, positions, config)
+    k, v = repeat_kv(k, v, config)
     q = shard_constraint(q, "batch", "seq", "heads", None, rules=rules, mesh=mesh)
     k = shard_constraint(k, "batch", "seq", "heads", None, rules=rules, mesh=mesh)
     v = shard_constraint(v, "batch", "seq", "heads", None, rules=rules, mesh=mesh)
@@ -207,23 +298,32 @@ def _attention(
         zigzag=config.zigzag_sp, ulysses=config.ulysses_sp,
     )
 
-    attended = attended.reshape(b, t, h * hd)
-    return layers.dense_apply(att_params["out"], attended)
+    return attention_out(att_params, attended, config)
 
 
 def _layer_compute(layer_params, x, aux, *, config, rules, mesh, positions):
     """One transformer block on (x [B, T, D], aux scalar) — the single
     source of truth shared by the scanned and pipelined layer stacks."""
-    y = layers.rmsnorm_apply(layer_params["ln1"], x)
-    x = x + _attention(y, layer_params["att"], config, rules, mesh, positions)
-    y = layers.rmsnorm_apply(layer_params["ln2"], x)
+    y = layers.rmsnorm_apply(layer_params["ln1"], x, eps=config.norm_eps)
+    mixed = _attention(y, layer_params["att"], config, rules, mesh, positions)
+    if config.ssm is not None:
+        # The whole buffer is real tokens here; the states are the
+        # generation path's business.
+        lens = jnp.full((x.shape[0],), x.shape[1], jnp.int32)
+        ssm_out, _, _ = ssm_lib.ssd_prefill(
+            layer_params["ssm"], y, jnp.ones(x.shape[:2], jnp.int32), lens,
+            config.ssm, config.multipliers, config.norm_eps,
+        )
+        mixed = mixed + ssm_out
+    x = x + mixed
+    y = layers.rmsnorm_apply(layer_params["ln2"], x, eps=config.norm_eps)
     if config.moe is not None:
         mlp_out, layer_aux = moe_lib.moe_mlp_apply(
             layer_params["mlp"], y, config.moe
         )
         aux = aux + layer_aux
     else:
-        mlp_out = layers.mlp_block_apply(layer_params["mlp"], y, rules=rules)
+        mlp_out = mlp_apply(layer_params["mlp"], y, config, rules)
     x = x + mlp_out
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules, mesh=mesh)
     return x, aux
@@ -338,9 +438,7 @@ def apply_hidden(
         sp = dict(mesh.shape)[mesh_lib.AXIS_SP]
         perm = zigzag_indices(t, sp)
         tokens = jnp.take(tokens, perm, axis=1)
-    x = layers.embedding_apply(params["embed"], tokens, dtype=config.dtype,
-                               rules=rules, mesh=mesh)
-    x = x * math.sqrt(config.dim)
+    x = embed_tokens(params, tokens, config, rules, mesh)
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules, mesh=mesh)
 
     if _is_pipelined(config, rules, mesh):
@@ -365,7 +463,7 @@ def apply_hidden(
             body, (x, jnp.zeros((), jnp.float32)), params["layers"]
         )
 
-    x = layers.rmsnorm_apply(params["ln_f"], x)
+    x = layers.rmsnorm_apply(params["ln_f"], x, eps=config.norm_eps)
     return x, aux
 
 
@@ -398,7 +496,13 @@ def head_table(params, config: TransformerConfig):
 
 
 def lm_logits(params, x, config: TransformerConfig) -> jnp.ndarray:
-    """Final vocabulary projection in f32 (tying via :func:`head_table`,
+    """Final vocabulary projection in f32, times the head's multiplier."""
+    return layers.scaled(_head_product(params, x, config),
+                         config.multipliers.lm_head)
+
+
+def _head_product(params, x, config: TransformerConfig) -> jnp.ndarray:
+    """The vocabulary projection in f32 (tying via :func:`head_table`,
     shared with the generation path and the fused-CE loss).
 
     Quantized heads take the post-scale path — ``(x @ q) * scale`` —
@@ -444,6 +548,11 @@ def loss_fn(
     tokens = batch["tokens"]
     mesh = mesh if mesh is not None else mesh_lib.get_global_mesh()
     if config.fused_ce:
+        if config.multipliers.lm_head != 1.0:
+            raise NotImplementedError(
+                "fused_ce reads the head's table directly and knows no "
+                "lm_head multiplier"
+            )
         hidden, aux = apply_hidden(params, tokens, config, rules=rules,
                                    mesh=mesh)
         # Pin the hidden states' layout before the chunked-CE scan:

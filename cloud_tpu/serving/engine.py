@@ -117,6 +117,10 @@ with two schedulers sharing one submit/future/admission surface:
   ``stats()`` counts ``kv_row_steps_reserved`` /
   ``kv_row_steps_in_use`` at every chunk dispatch and, with
   ``health()``, reports ``kv_bytes_reserved`` / ``kv_bytes_in_use``.
+  A model with a recurrent state (``TransformerConfig.ssm``) keeps one
+  state row a slot a layer beside the K/V rows: ``state_row_steps_*``
+  and ``state_bytes_*`` count them the same way (zeros otherwise), and
+  ``serve/pass`` carries ``state_rows_in_use``.
   ``serve/qps`` and ``serve/tokens_per_sec``
   windowed-rate gauges, ``serve/slot_occupancy`` /
   ``serve/batch_occupancy`` gauges, slot-churn counters
@@ -822,6 +826,35 @@ def _resolve_payload(payload):
     return payload
 
 
+def _refuse_for_recurrent_state(config, cfg: ServeConfig) -> None:
+    """Every engine feature that takes "a prefix's cache is its K/V
+    rows" for granted refuses, at construction and in words, a model
+    whose slot cache also holds a recurrent state
+    (``TransformerConfig.ssm``); the plain slot path — insert at a
+    bucket, decode chunks — and the batch scheduler serve it."""
+    if config.ssm is None:
+        return
+    asked = [name for name, on in (
+        ("prefix_cache_blocks (the prefix pool)", cfg.prefix_cache_blocks),
+        ("prefill_chunk_tokens (chunked prefill)",
+         cfg.prefill_chunk_tokens is not None),
+        ("decode_kernel != 'xla' (the paged read)",
+         cfg.decode_kernel != "xla"),
+        ("draft (draft and verify)", cfg.draft is not None),
+        ("role != 'both' (KV hand-off)", cfg.role != "both"),
+        ("mesh_shape / layout='auto' (a tp/sp serving mesh)",
+         cfg.layout == "auto" or cfg.mesh_shape not in (None, (1, 1))),
+    ) if on]
+    if asked:
+        raise NotImplementedError(
+            "ServeConfig asks for " + "; ".join(asked) + ", which a model "
+            "with a recurrent state (TransformerConfig.ssm) does not "
+            "support yet: a copied, chunked, paged, rewound, exported or "
+            "head-sharded cache of K/V rows does not carry the state and "
+            "the convolution tail each slot also holds (ROADMAP R4)"
+        )
+
+
 class ServingEngine:
     """In-process continuous-batching server over ``generation`` (module
     docstring; ``scheduler="batch"`` selects the batch-synchronous
@@ -860,6 +893,7 @@ class ServingEngine:
         #: param placement only happens for engine-owned meshes — a
         #: caller-provided mesh keeps the caller's placement).
         self._built_serving_mesh = False
+        _refuse_for_recurrent_state(config, self.serve_config)
         self._slice_shape, self._slice_chips = self._resolve_serving_mesh()
         generation.check_inference_supported(
             config, self.rules, self.mesh, "serving"
@@ -950,6 +984,9 @@ class ServingEngine:
             # share of the rows a decode step reads that hold a live
             # token).
             "kv_row_steps_reserved": 0, "kv_row_steps_in_use": 0,
+            # The same for a recurrent state's rows, one a slot a layer
+            # (0 for a model without one).
+            "state_row_steps_reserved": 0, "state_row_steps_in_use": 0,
             # QoS brownout sheds (0 unless qos arms a brownout depth).
             "brownout_shed": 0,
             # Disaggregated-serving KV handoff counters (all 0 with
@@ -1092,11 +1129,22 @@ class ServingEngine:
                 cfg.prefix_cache_blocks * cfg.prefix_block_tokens
                 if self._prefix is not None else 0
             )
+            state_leaves = [
+                self._grid_cache[name] for name in generation.STATE_LEAVES
+                if name in self._grid_cache
+            ]
+            self._state_bytes_reserved = sum(x.nbytes for x in state_leaves)
             self._kv_bytes_reserved = sum(
                 x.nbytes for x in
                 leaves(self._grid_cache) + leaves(self._prefix_pool)
-            )
+            ) - self._state_bytes_reserved
             self._kv_rows_in_use = 0
+            #: A recurrent state's rows: one a slot a layer, in use while
+            #: the slot decodes (0 for a model without a state).
+            self._state_rows_reserved = (
+                cfg.num_slots * config.num_layers if state_leaves else 0
+            )
+            self._state_rows_in_use = 0
             #: Paged decode attention (``decode_kernel != "xla"``): the
             #: slot grid's attention reads KV through a per-slot block
             #: table — page p of a row resolves to a prefix-pool block
@@ -1300,7 +1348,7 @@ class ServingEngine:
         itemsize = 1 if cfg.kv_quant else np.dtype(c.dtype).itemsize
         # Per cached position: k + v across every layer and head (+ the
         # two f32 scale columns when quantized).
-        per_pos = 2 * c.num_layers * c.num_heads * (
+        per_pos = 2 * c.num_layers * c.kv_heads * (
             c.head_dim * itemsize + (4 if cfg.kv_quant else 0)
         )
         max_len = cfg.prompt_buckets[-1] + cfg.max_new_tokens
@@ -2750,6 +2798,7 @@ class ServingEngine:
                     bucket_tokens=sum(r.bucket_len for r, _ in inserts),
                     kv_rows_in_use=self._kv_rows_in_use,
                     kv_rows_reserved=self._kv_rows_reserved,
+                    state_rows_in_use=self._state_rows_in_use,
                 )
 
     def _pop_inserts_locked(self, inserts) -> None:
@@ -3498,7 +3547,8 @@ class ServingEngine:
         while any live slot references it, and the rows a paged slot
         reads from attached pool blocks are not counted twice.  At
         ``pipeline_depth=2`` the host's token counts trail the device
-        by the chunk in flight."""
+        by the chunk in flight.  A recurrent state's rows are counted
+        beside them: one a layer for every slot in the chunk."""
         rows = sum(task.next_pos for task in self._prefill_tasks)
         for slot in self._active_slots:
             entry = self._slot_table[slot]
@@ -3513,9 +3563,17 @@ class ServingEngine:
                 rows -= block_tokens * int((self._block_table >= 0).sum())
         self._kv_rows_in_use = rows
         self._pass_active = len(self._active_slots)
+        self._state_rows_in_use = (
+            self._pass_active * self.config.num_layers
+            if self._state_rows_reserved else 0
+        )
         with self._stats_lock:
             self._stats["kv_row_steps_reserved"] += self._kv_rows_reserved
             self._stats["kv_row_steps_in_use"] += rows
+            self._stats["state_row_steps_reserved"] += (
+                self._state_rows_reserved
+            )
+            self._stats["state_row_steps_in_use"] += self._state_rows_in_use
 
     def _note_dispatch_gap(self, start: float) -> None:
         """Record the host gap between the previous chunk dispatch and
@@ -4214,15 +4272,22 @@ class ServingEngine:
         """The KV bytes ``health()`` and ``stats()`` both carry: what
         the slot grid and the prefix pool reserve (constant), and what
         held a live token at the last chunk dispatch (rows in use at
-        the grid's bytes a row).  Zeros on the batch scheduler, whose
-        cache lives for one batch."""
+        the grid's bytes a row), and the same for a recurrent state's
+        rows.  Zeros on the batch scheduler, whose cache lives for one
+        batch."""
         if not self._continuous:
-            return {"kv_bytes_reserved": 0, "kv_bytes_in_use": 0}
+            return {"kv_bytes_reserved": 0, "kv_bytes_in_use": 0,
+                    "state_bytes_reserved": 0, "state_bytes_in_use": 0}
         return {
             "kv_bytes_reserved": self._kv_bytes_reserved,
             "kv_bytes_in_use": (
                 self._kv_bytes_reserved * self._kv_rows_in_use
                 // self._kv_rows_reserved
+            ),
+            "state_bytes_reserved": self._state_bytes_reserved,
+            "state_bytes_in_use": (
+                self._state_bytes_reserved * self._state_rows_in_use
+                // max(self._state_rows_reserved, 1)
             ),
         }
 

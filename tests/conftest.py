@@ -9,6 +9,8 @@ cloud_fit/tests/unit/remote_test.py:76-82).
 import os
 import sys
 
+import pytest
+
 # Force-override: tests always run on the virtual CPU platform, whatever
 # the session's JAX_PLATFORMS says.  jax snapshots JAX_PLATFORMS into its
 # config at import time and pytest plugins may import jax before this
@@ -66,3 +68,19 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _interpret_knob_stays_in_its_module():
+    """``CLOUD_TPU_FLASH_FORCE_INTERPRET`` set straight into ``os.environ``
+    by a test module (``benchmarks.run.rehearse_on_cpu``, a module-scoped
+    fixture) ends with that module: a worker runs many files, and a test
+    of auto-dispatch "without the knob" in the next one would find it on.
+    """
+    knob = "CLOUD_TPU_FLASH_FORCE_INTERPRET"
+    before = os.environ.get(knob)
+    yield
+    if before is None:
+        os.environ.pop(knob, None)
+    else:
+        os.environ[knob] = before

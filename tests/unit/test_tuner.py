@@ -9,6 +9,7 @@ integration rig (tuner_integration_test.py:283-296).
 
 import multiprocessing
 import os
+import time
 
 import numpy as np
 import pytest
@@ -195,8 +196,21 @@ def _worker(args):
                          hyperparameters=_hp(), max_trials=12)
     tuner = Tuner(lambda h: FakeTrainer(h.get("lr")), oracle,
                   tuner_id=f"tuner{worker_id}")
+    _wait_for_all_workers(directory + "-ready", worker_id)
     tuner.search(epochs=1)
     return len(oracle.trials)
+
+
+def _wait_for_all_workers(ready_dir, worker_id, workers=4, timeout=60.0):
+    """Start searching together: on a loaded machine the first two
+    processes of the pool would else finish the whole budget before the
+    last two are up, and "every worker took part" measures the load."""
+    os.makedirs(ready_dir, exist_ok=True)
+    open(os.path.join(ready_dir, str(worker_id)), "w").close()
+    deadline = time.monotonic() + timeout
+    while (len(os.listdir(ready_dir)) < workers
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
 
 
 def _hp():
