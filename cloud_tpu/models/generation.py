@@ -235,9 +235,10 @@ def _mlp(layer_params, y, config, rules):
 
 def _paged_attended(kind, q, cache_l, cur_len, paged):
     """Route one attention through ``ops.paged_attention`` (the
-    block-table read-in-place path).  ``paged`` carries the per-layer
-    pool slice, the block table, and the dispatch knobs; KV writes stay
-    in the slot row (suffix positions never overlap pool-backed pages —
+    read-in-place path).  ``paged`` holds its keywords: the layer to
+    read when ``cache_l`` holds the stacked leaves, the per-layer pool
+    slice, the block table, and the dispatch knobs; KV writes stay in
+    the slot row (suffix positions never overlap pool-backed pages —
     prefix hits are block-aligned), so only the READ side changes."""
     from cloud_tpu import ops
 
@@ -246,15 +247,7 @@ def _paged_attended(kind, q, cache_l, cur_len, paged):
         "chunk": ops.paged_chunk_attention,
         "verify": ops.paged_verify_attention,
     }[kind]
-    return fn(
-        q, cache_l, cur_len,
-        pool_l=paged.get("pool_l"),
-        block_table=paged["block_table"],
-        use_pallas=paged.get("use_pallas"),
-        partitioned=paged.get("partitioned", False),
-        mesh=paged.get("mesh"),
-        head_axes=paged.get("head_axes"),
-    )
+    return fn(q, cache_l, cur_len, **paged)
 
 
 def _scan_layers(params, cache, x, positions, write_cols, config, rules,
@@ -285,11 +278,20 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
     makes that one row of the grid both the write row and the only row
     attended over; ``None`` means x's rows ARE the cache's rows.
 
-    ``block_table`` routes the attention read through the paged path
-    (:func:`_paged_attended`, with ``pool`` scanned alongside as a
-    read-only ``xs`` operand); the Pallas kernels take one layer's
-    [B, S, H, hd] operand, so there the layer is still sliced out per
-    layer — never stacked back.  Returns ``(x, cache)``.
+    The decode read (``kind="decode"``, ``slot=None``, K/V not int8)
+    goes through the paged path (:func:`_paged_attended`) whenever its
+    kernel would run: on a TPU, for every eligible shape, with nothing
+    to switch it on.
+    It takes the carried leaves WHOLE with the layer index, fetches only
+    the pages that hold a row's tokens, and a row that does not advance
+    (its write suppressed) is given length 0: nothing of it is read,
+    and its output lanes, zeros, are masked by whoever suppressed the
+    write.  Off the chip the read is :func:`_cache_attention` over layer
+    ``l`` read by index.  ``block_table`` routes every kind's read
+    through the paged path, with ``pool`` scanned alongside as a
+    read-only ``xs`` operand; for the chunk and verify kinds the layer
+    (or the slot's row of it) is still sliced out per layer — never
+    stacked back.  Returns ``(x, cache)``.
 
     With ``config.ssm`` (``kind="decode"`` only: one token a row) the
     carried cache also holds each row's recurrent state; layer ``l``
@@ -298,20 +300,26 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
     convolution's tail come back bit for bit (the drop-mode scatter has
     no equivalent for a leaf without positions).
     """
+    from cloud_tpu.ops import paged_attention
+
     b, t, _ = x.shape
     if kind != "decode" or slot is not None or block_table is not None:
         _refuse_recurrent(
-            config, f"a {kind} pass over a slot's rows or a paged read")
+            config, f"a {kind} pass over a slot's rows or a prefix pool")
     quantized = "k_scale" in cache
     attend_len = positions[:, 0] + 1
     chunked = kind != "decode"
     rows = (jnp.arange(b)[:, None] if slot is None
             else jnp.reshape(slot, (1, 1)))
-    paged = None
-    if block_table is not None:
-        paged = {"block_table": block_table, "use_pallas": use_pallas,
-                 "partitioned": mesh is not None, "mesh": mesh,
-                 "head_axes": rules.assignment("heads")}
+    # (An int8 cache keeps the per-layer operand: its scale leaves are
+    # re-laid-out whole for the kernel, so the less of them the better.)
+    in_place = kind == "decode" and slot is None and not quantized
+    paged = {"block_table": block_table, "use_pallas": use_pallas,
+             "partitioned": mesh is not None, "mesh": mesh,
+             "head_axes": rules.assignment("heads"),
+             "batch_axes": rules.assignment("batch")}
+    # Rows that advance (their write column is in range).
+    live = (write_cols[:, 0] >= 0) & (write_cols[:, 0] < cache["k"].shape[2])
 
     def layer_of(leaf, l):
         if slot is None:
@@ -335,14 +343,22 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
                                                           mode="drop")
             for name, update in updates.items()
         })
-        cache_l = {name: layer_of(cache[name], l) for name in updates}
-        if paged is None:
-            attended = _cache_attention(q, cache_l, attend_len,
-                                        chunk_causal=chunked)
+        kv = {name: cache[name] for name in updates}
+        pool_l = layer_slice[2] if pool is not None else None
+        take_kernel = (paged_attention.would_use_kernel(q, kv)
+                       if use_pallas is None else use_pallas)
+        if in_place and (block_table is not None or take_kernel):
+            attended = _paged_attended(
+                kind, q, kv, jnp.where(live, attend_len, 0),
+                dict(paged, layer=l, pool_l=pool_l))
         else:
-            pool_l = layer_slice[2] if pool is not None else None
-            attended = _paged_attended(kind, q, cache_l, attend_len,
-                                       dict(paged, pool_l=pool_l))
+            cache_l = {name: layer_of(leaf, l) for name, leaf in kv.items()}
+            attended = (
+                _cache_attention(q, cache_l, attend_len,
+                                 chunk_causal=chunked)
+                if block_table is None else
+                _paged_attended(kind, q, cache_l, attend_len,
+                                dict(paged, pool_l=pool_l)))
         mixed = transformer.attention_out(layer_params["att"], attended,
                                           config)
         if config.ssm is not None:
@@ -352,9 +368,6 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
                 layer_params["ssm"], y[:, 0], held["ssm"], held["conv"],
                 config.ssm, config.multipliers, config.norm_eps,
             )
-            # Rows that advance (their write column is in range).
-            live = ((write_cols[:, 0] >= 0)
-                    & (write_cols[:, 0] < cache["k"].shape[2]))
             for name, new in (("ssm", state), ("conv", tail)):
                 keep = live.reshape((b,) + (1,) * (new.ndim - 1))
                 new = jnp.where(keep, new.astype(held[name].dtype),

@@ -115,7 +115,10 @@ with two schedulers sharing one submit/future/admission surface:
   ``tokens``, ``prompt_len``, ``bucket``, ``slot``, ``passes``) and
   ``serve/ttft`` (submit's stamp to the first token on the host).
   ``stats()`` counts ``kv_row_steps_reserved`` /
-  ``kv_row_steps_in_use`` at every chunk dispatch and, with
+  ``kv_row_steps_in_use`` / ``kv_row_steps_read`` (the rows a decode
+  step fetches: every row of the grid, or, where the decode read goes
+  through the paged kernel, the live slots' rows rounded up to its
+  page) at every chunk dispatch and, with
   ``health()``, reports ``kv_bytes_reserved`` / ``kv_bytes_in_use``.
   A model with a recurrent state (``TransformerConfig.ssm``) keeps one
   state row a slot a layer beside the K/V rows: ``state_row_steps_*``
@@ -343,19 +346,20 @@ class ServeConfig:
     #: per-class health/stats keys read zero.  Host-side policy only:
     #: the compiled programs are untouched either way.
     qos: Optional[QosConfig] = None
-    #: Decode-attention path for the continuous slot grid.  ``"xla"``
-    #: (default) keeps today's programs byte-identical — plain
-    #: ``_cache_attention`` over the padded slot rows, prefix hits
-    #: copied into the row before decode.  ``"pallas"`` routes the
-    #: chunk/prefill-chunk/verify programs through
-    #: ``ops.paged_attention`` (block-table read-in-place: prefix hits
-    #: ATTACH pool blocks to the slot's block table instead of
-    #: dispatching ``copy_prefix_program``, and dead pages past each
-    #: row's length are skipped) with the Pallas kernel forced on;
-    #: ``"auto"`` takes the same paged route but lets the op's measured
-    #: crossover pick kernel vs its jnp reference per shape
-    #: (docs/KERNELS.md).  Greedy outputs are token-identical on every
-    #: setting.  Continuous-scheduler only.
+    #: How a prefix hit reaches the continuous slot grid's attention.
+    #: ``"xla"`` (default): hits are COPIED into the slot's row before
+    #: decode (``copy_prefix_program``) and every program reads slot rows
+    #: only.  ``"pallas"`` routes the chunk/prefill-chunk/verify
+    #: programs through ``ops.paged_attention`` with a per-slot block
+    #: table: prefix hits ATTACH pool blocks to the table instead of
+    #: dispatching the copy, with the Pallas kernel forced on;
+    #: ``"auto"`` takes the same route but lets the op's dispatch pick
+    #: kernel vs its jnp reference per shape (docs/KERNELS.md).  The
+    #: decode step's skip of rows that hold nothing does NOT depend on
+    #: this: on a TPU the decode read goes through the paged kernel
+    #: under every setting (``generation._scan_layers``).  Greedy
+    #: outputs are token-identical on every setting.  Continuous-
+    #: scheduler only.
     decode_kernel: str = "xla"
     #: Disaggregated-serving role this engine plays in a fleet:
     #: ``"prefill"`` (serves the prefill leg of split requests),
@@ -982,8 +986,10 @@ class ServingEngine:
             # KV rows reserved against in use, summed over every chunk
             # dispatch (continuous scheduler; their quotient is the
             # share of the rows a decode step reads that hold a live
-            # token).
+            # token), and the rows a decode step fetches: the grid's,
+            # or the live slots' pages where the paged kernel reads.
             "kv_row_steps_reserved": 0, "kv_row_steps_in_use": 0,
+            "kv_row_steps_read": 0,
             # The same for a recurrent state's rows, one a slot a layer
             # (0 for a model without one).
             "state_row_steps_reserved": 0, "state_row_steps_in_use": 0,
@@ -1145,12 +1151,12 @@ class ServingEngine:
                 cfg.num_slots * config.num_layers if state_leaves else 0
             )
             self._state_rows_in_use = 0
-            #: Paged decode attention (``decode_kernel != "xla"``): the
+            #: Block-table attention (``decode_kernel != "xla"``): the
             #: slot grid's attention reads KV through a per-slot block
             #: table — page p of a row resolves to a prefix-pool block
             #: (entry >= 0) or the slot row itself (-1) — so a prefix
             #: hit ATTACHES pool blocks instead of dispatching the copy
-            #: program, and pages past each row's length are skipped.
+            #: program.
             #: Page size is ``prefix_block_tokens`` (hits are whole
             #: blocks, so attached pages align by construction).
             self._paged = cfg.decode_kernel != "xla"
@@ -1165,6 +1171,7 @@ class ServingEngine:
                 self._block_table = np.full(
                     (cfg.num_slots, n_pages), -1, np.int32
                 )
+            self._decode_read_page = self._decode_page()
             #: Python-trace counters: the retrace guard for "one chunk
             #: compile serves the whole run" (tests/helpers/retrace_guard
             #: idiom — the wrapped body executes only while tracing).
@@ -3538,6 +3545,30 @@ class ServingEngine:
 
     # -- pipelined scheduling (pipeline_depth=2) ---------------------------
 
+    def _decode_page(self) -> Optional[int]:
+        """The page the decode read fetches K/V rows by where it goes
+        through the paged kernel — by default wherever the kernel would
+        run (``generation._scan_layers``' rule: on a TPU, an eligible
+        shape), with ``decode_kernel != "xla"`` by the kernel's own
+        dispatch at the pool's block size — or None where it reads every
+        row of the grid.  What ``kv_row_steps_read`` counts by."""
+        import jax
+
+        from cloud_tpu.ops import paged_attention
+
+        cfg, config = self.serve_config, self.config
+        q = jax.ShapeDtypeStruct(
+            (cfg.num_slots, 1, config.num_heads, config.head_dim),
+            config.dtype)
+        kv = {"k": self._grid_cache["k"]}
+        if self._paged:
+            return paged_attention.kernel_page(
+                q, kv, page_tokens=cfg.prefix_block_tokens,
+                use_pallas=self._paged_use_pallas)
+        if paged_attention.would_use_kernel(q, kv):
+            return paged_attention.kernel_page(q, kv, use_pallas=True)
+        return None
+
     def _note_kv_rows(self) -> None:
         """KV accounting at a chunk dispatch: the rows the grid (and
         the prefix pool) reserve against the rows that hold a live
@@ -3547,12 +3578,23 @@ class ServingEngine:
         while any live slot references it, and the rows a paged slot
         reads from attached pool blocks are not counted twice.  At
         ``pipeline_depth=2`` the host's token counts trail the device
-        by the chunk in flight.  A recurrent state's rows are counted
-        beside them: one a layer for every slot in the chunk."""
+        by the chunk in flight.  ``kv_row_steps_read`` adds the rows
+        the chunk's decode steps each fetch: every row of the grid, or,
+        through the paged kernel, each decoding slot's rows rounded up
+        to the kernel's page (a slot that does not decode, empty or
+        mid-prefill, costs grid steps and no rows; a verify window reads
+        a page more at most, not counted).  A recurrent state's rows
+        are counted beside them: one a layer for every slot in the
+        chunk."""
         rows = sum(task.next_pos for task in self._prefill_tasks)
+        page = self._decode_read_page
+        read = 0 if page else self.serve_config.num_slots * self._max_len
         for slot in self._active_slots:
             entry = self._slot_table[slot]
-            rows += entry.request.prompt_len + len(entry.tokens)
+            held = entry.request.prompt_len + len(entry.tokens)
+            rows += held
+            if page:
+                read += min(-(-held // page) * page, self._max_len)
         if self._prefix is not None:
             block_tokens = self.serve_config.prefix_block_tokens
             rows += block_tokens * len({
@@ -3570,6 +3612,7 @@ class ServingEngine:
         with self._stats_lock:
             self._stats["kv_row_steps_reserved"] += self._kv_rows_reserved
             self._stats["kv_row_steps_in_use"] += rows
+            self._stats["kv_row_steps_read"] += read
             self._stats["state_row_steps_reserved"] += (
                 self._state_rows_reserved
             )

@@ -242,29 +242,33 @@ def test_paged(one_chip, tq, kv_dtype, has_pool):
 
 
 def test_paged_mesh_route_shards_the_heads(topo, monkeypatch):
-    """``decode_kernel="pallas"`` on a tp=4 slice: each chip's kernel reads
-    its own three heads of the slot rows."""
+    """The decode read on a tp=4 slice: each chip's kernel reads its own
+    three heads of the stacked slot rows, in place (the whole
+    ``[L, B, S, H/4, hd]`` shard is the operand, the layer an index)."""
     # The public entry point turns the interpreter on by itself off-TPU;
     # this process is on the CPU and compiles for the chip.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = _mesh(topo, {"tp": 4})
-    slots, s, h, hd = 16, 1152, 12, 64
-    heads = NamedSharding(mesh, P(None, None, "tp", None))
+    layers, slots, s, h, hd = 4, 16, 1152, 12, 64
     whole = NamedSharding(mesh, P())
-    q = _spec((slots, 1, h, hd), jnp.bfloat16, heads)
-    cache_l = {"k": _spec((slots, s, h, hd), jnp.bfloat16, heads),
-               "v": _spec((slots, s, h, hd), jnp.bfloat16, heads)}
+    q = _spec((slots, 1, h, hd), jnp.bfloat16,
+              NamedSharding(mesh, P(None, None, "tp", None)))
+    heads = NamedSharding(mesh, P(None, None, None, "tp", None))
+    cache = {"k": _spec((layers, slots, s, h, hd), jnp.bfloat16, heads),
+             "v": _spec((layers, slots, s, h, hd), jnp.bfloat16, heads)}
     cur_len = _spec((slots,), jnp.int32, whole)
     table = _spec((slots, s // 128), jnp.int32, whole)
+    layer = _spec((), jnp.int32, whole)
 
-    def fn(q, cache_l, cur_len, table):
+    def fn(q, cache, cur_len, table, layer):
         return pa_mod.paged_decode_attention(
-            q, cache_l, cur_len, block_table=table, use_pallas=True,
-            partitioned=True, mesh=mesh, head_axes="tp",
+            q, cache, cur_len, layer=layer, block_table=table,
+            use_pallas=True, partitioned=True, mesh=mesh, head_axes="tp",
         )
 
-    calls = _kernel_lines(_compile(fn, q, cache_l, cur_len, table))
-    assert calls and all("bf16[16,1152,3,64]" in ln for ln in calls), calls
+    calls = _kernel_lines(_compile(fn, q, cache, cur_len, table, layer))
+    assert calls and all("bf16[4,16,1152,3,64]" in ln for ln in calls), calls
+    assert not any("bf16[4,16,1152,12,64]" in ln for ln in calls), calls
 
 
 # ---------------------------------------------------------------------------
@@ -328,7 +332,11 @@ def test_slot_cache_is_updated_in_place(one_chip, program, kv_quant):
         args = (params, cache, state, _spec((slots, 4), jnp.int32, one_chip))
 
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
-    kv = "{}[{}]".format("s8" if kv_quant else "bf16",
+    _assert_cache_in_place(compiled, cache)
+
+
+def _assert_cache_in_place(compiled, cache):
+    kv = "{}[{}]".format("s8" if "k_scale" in cache else "bf16",
                          ",".join(map(str, cache["k"].shape)))
     moved = [
         line.strip()[:200] for line in compiled.as_text().splitlines()
@@ -342,3 +350,64 @@ def test_slot_cache_is_updated_in_place(one_chip, program, kv_quant):
         for leaf in cache.values())
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < cache_bytes / 2, (temp, cache_bytes)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("kv_heads", [None, 4], ids=["mha", "grouped"])
+def test_decode_chunk_reads_the_carried_cache_in_place(
+        one_chip, monkeypatch, kv_heads, kv_quant):
+    """On a TPU the decode read is the paged kernel, with nothing to
+    switch it on: the compiled chunk program holds ONE ``paged_decode``
+    call (the layer loop's) whose K/V operands are the whole stacked
+    ``[L, B, S, Hkv, hd]`` leaves, nothing in it produces a
+    ``[B, S, Hkv, hd]`` layer of K or V (no slice, no copy before the
+    call), and the cache is still the loop's carry, updated in place.
+    An int8 cache keeps the XLA read (its scale leaves would reach the
+    kernel re-laid-out whole): no kernel in the program."""
+    from cloud_tpu.models import generation, transformer
+
+    # Auto-dispatch asks the default backend; this process is on the CPU
+    # and compiles for the chip.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, rows = 4, 2048
+    config = transformer.TransformerConfig(
+        vocab_size=32000, num_layers=4, dim=2048, num_heads=16,
+        num_kv_heads=kv_heads, head_dim=128, mlp_hidden=5632,
+        max_seq_len=rows, remat=False,
+    )
+    sample = generation.SampleConfig(temperature=0.0)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda x: _spec(x.shape, dtype or x.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: transformer.init(jax.random.PRNGKey(0), config)),
+        jnp.bfloat16)
+    cache = on_chip(jax.eval_shape(lambda: generation.init_slot_cache(
+        config, slots, rows, kv_quant=kv_quant)))
+    state = on_chip(jax.eval_shape(lambda: generation.init_slot_state(
+        config, slots, sample=sample)))
+
+    def fn(params, cache, state):
+        return generation.decode_chunk_program(
+            params, cache, state, config, chunk_size=8, sample=sample)
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, state).compile()
+    lines = compiled.as_text().splitlines()
+    calls = [ln for ln in lines
+             if re.match(r"\s*%paged_decode(\.\d+)? = ", ln)]
+    _assert_cache_in_place(compiled, cache)
+    if kv_quant:
+        assert not calls and "tpu_custom_call" not in compiled.as_text()
+        return
+    assert len(calls) == 1, calls
+    dtype = "s8" if kv_quant else "bf16"
+    stacked = "{}[{}]".format(dtype, ",".join(map(str, cache["k"].shape)))
+    constraints = calls[0].split("operand_layout_constraints={")[1]
+    assert constraints.split("}}")[0].count(stacked) == 2, calls[0][:2000]
+    layer = "{}[{}]".format(dtype, ",".join(map(str, cache["k"].shape[1:])))
+    sliced = [ln.strip()[:200] for ln in lines
+              if re.match(r"\s*(ROOT )?%[\w.\-]+ = " + re.escape(layer), ln)]
+    assert not sliced, sliced

@@ -208,3 +208,142 @@ class TestDispatch:
             q, cache, jnp.asarray([s], jnp.int32), pool, table
         )
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: the decode read over the carried cache, in place
+# ---------------------------------------------------------------------------
+
+
+def _stacked_cache(layers, b, s, kv_heads, hd, *, kv, seed):
+    rng = np.random.default_rng(seed)
+    shape = (layers, b, s, kv_heads, hd)
+    if kv == "int8":
+        cache = {
+            "k": jnp.asarray(rng.integers(-127, 127, size=shape), jnp.int8),
+            "v": jnp.asarray(rng.integers(-127, 127, size=shape), jnp.int8),
+            "k_scale": jnp.asarray(
+                rng.uniform(0.01, 0.1, size=shape[:-1] + (1,)), jnp.float32),
+            "v_scale": jnp.asarray(
+                rng.uniform(0.01, 0.1, size=shape[:-1] + (1,)), jnp.float32),
+        }
+    else:
+        cache = {"k": jnp.asarray(rng.normal(size=shape), jnp.bfloat16),
+                 "v": jnp.asarray(rng.normal(size=shape), jnp.bfloat16)}
+    return cache, rng
+
+
+#: Row lengths over pages of 8 in rows of 40: mid-page, at a page's
+#: edge, one token, a whole row — and 0, a slot that does not decode.
+LENGTHS = {
+    "mid-page": [13, 3, 21, 39],
+    "page-edge": [8, 16, 40, 24],
+    "dead-first": [0, 0, 11, 40],
+    "dead-between": [17, 0, 0, 1],
+    "dead-last": [9, 32, 0, 0],
+}
+
+
+@pytest.mark.parametrize("lengths", list(LENGTHS))
+@pytest.mark.parametrize("group", [1, 5])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_stacked_leaves_read_in_place(kv, group, lengths):
+    """The kernel over the STACKED leaves with a traced layer index
+    against ``_cache_attention`` on the sliced layer.  A row of length 0
+    comes back finite (zeros) and its neighbours read as if it were not
+    there."""
+    layers, b, s, kv_heads, hd, bt = 3, 4, 40, 2, 32, 8
+    cache, rng = _stacked_cache(layers, b, s, kv_heads, hd, kv=kv, seed=7)
+    qdtype = jnp.bfloat16 if kv == "bf16" else jnp.float32
+    q = jnp.asarray(rng.normal(size=(b, 1, kv_heads * group, hd)), qdtype)
+    lens = jnp.asarray(LENGTHS[lengths], jnp.int32)
+
+    @jax.jit
+    def read(q, cache, lens, layer):
+        return pa._paged_pallas(q, cache, lens, None, None, bt, layer=layer,
+                                interpret=True)
+
+    live = np.asarray(lens) > 0
+    for layer in (0, 2):
+        got = np.asarray(read(q, cache, lens, jnp.int32(layer)), np.float32)
+        cache_l = {name: leaf[layer] for name, leaf in cache.items()}
+        want = np.asarray(
+            _cache_attention(q, cache_l, jnp.maximum(lens, 1)), np.float32)
+        # bf16 outputs: one rounding of values up to a few units.
+        tol = 2e-2 if kv == "bf16" else 2e-5
+        np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
+        assert np.isfinite(got).all()
+        assert not got[~live].any()
+
+
+def test_one_token_body_keeps_float32_weights():
+    """float32 queries over a bf16 cache: scores, softmax and the
+    weighted sum stay float32 (the weights go through the MXU as three
+    bf16 parts that sum back to them), so the read equals
+    ``_cache_attention`` far inside a bf16 rounding."""
+    layers, b, s, kv_heads, hd, bt = 2, 3, 44, 2, 32, 16
+    cache, rng = _stacked_cache(layers, b, s, kv_heads, hd, kv="bf16", seed=9)
+    q = jnp.asarray(rng.normal(size=(b, 1, kv_heads, hd)), jnp.float32)
+    lens = jnp.asarray([5, 44, 17], jnp.int32)  # 44: into the partial page
+    got = pa._paged_pallas(q, cache, lens, None, None, bt,
+                           layer=jnp.int32(1), interpret=True)
+    want = _cache_attention(
+        q, {name: leaf[1] for name, leaf in cache.items()}, lens)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("lens, rows, first, last", [
+    ([0, 130, 0, 0, 300, 0], [1, 1, 1, 1, 4, 4], [0, 0, 1, 1, 0, 2],
+     [0, 1, 1, 1, 2, 2]),
+    ([0, 0, 0], [0, 0, 0], [0, 0, 0], [0, 0, 0]),
+    ([128, 129, 640], [0, 1, 2], [0, 0, 0], [0, 1, 4]),
+], ids=["dead-rows-pin", "all-dead", "all-live"])
+def test_fetch_plan_pins_dead_rows_to_the_resident_block(lens, rows, first,
+                                                         last):
+    """Every grid step of a row of length 0 names the block the step
+    before left resident, so it is no fetch: the last live page of the
+    nearest live row above, else page 0 of the nearest below."""
+    got = pa._fetch_plan(jnp.asarray(lens, jnp.int32), 1, 128, 5)
+    assert [list(map(int, x)) for x in got] == [rows, first, last]
+
+
+@pytest.mark.parametrize("kv_heads", [None, 2], ids=["mha", "grouped"])
+def test_decode_chunk_program_reads_in_place(kv_heads):
+    """The call site: ``decode_chunk_program`` with the kernel forced on
+    (interpreted here; on a TPU it is the default) emits the tokens of
+    the ``_cache_attention`` program, with an inactive slot in the grid
+    and a slot that finishes mid-chunk."""
+    from cloud_tpu.models import generation, transformer
+
+    config = transformer.TINY.scaled(dtype=jnp.float32, num_layers=2,
+                                     num_kv_heads=kv_heads)
+    params = transformer.init(jax.random.PRNGKey(0), config)
+    slots, rows = 3, 24
+    sample = generation.SampleConfig(temperature=0.0)
+
+    def chunks(use_pallas):
+        cache = generation.init_slot_cache(config, slots, rows)
+        state = generation.init_slot_state(config, slots, sample=sample)
+        for slot, (prompt, budget) in enumerate(
+                [([3, 1, 4, 1, 5], 9), ([9, 2, 6], 3)]):
+            tokens = jnp.asarray([prompt + [0] * (8 - len(prompt))])
+            cache, state, _ = generation.insert_slot_program(
+                params, cache, state, tokens, len(prompt), slot, budget,
+                config, sample=sample)
+        out = []
+        for _ in range(2):
+            cache, state, toks, valid = generation.decode_chunk_program(
+                params, cache, state, config, chunk_size=4, sample=sample,
+                use_pallas=use_pallas)
+            out.append((np.asarray(toks), np.asarray(valid)))
+        return out, cache
+
+    before = pa.KERNEL_TRACE_COUNT
+    got, got_cache = chunks(True)
+    assert pa.KERNEL_TRACE_COUNT > before
+    want, want_cache = chunks(None)
+    for (toks, valid), (want_toks, want_valid) in zip(got, want):
+        np.testing.assert_array_equal(valid, want_valid)
+        np.testing.assert_array_equal(toks[valid], want_toks[want_valid])
+    assert not got[0][1][2].any()  # the third slot never decodes
+    np.testing.assert_allclose(got_cache["k"], want_cache["k"], atol=1e-5)

@@ -177,6 +177,35 @@ def test_kv_row_steps_equal_a_hand_count(model):
     assert stats["kv_bytes_in_use"] == 6 * per_row
 
 
+@pytest.mark.parametrize("prompts, overrides, chunks, read", [
+    # The read is _cache_attention's (off the chip): every row of the
+    # grid, at each of the two chunks.
+    ([[1, 2, 3], [4, 5, 6, 7, 8]], {}, 2, 2 * 2 * 13),
+    # The paged kernel, pages of 4.  Chunk 1: A holds 3+1 rows, a page's
+    # edge (4), B 5+1, mid-page (8).  Chunk 2: A 3+3 (8), B has retired:
+    # a dead slot costs no rows.
+    ([[1, 2, 3], [4, 5, 6, 7, 8]],
+     dict(decode_kernel="pallas", prefix_block_tokens=4), 2, 4 + 8 + 8),
+    # B's 8 prompt tokens prefill in two chunks of 4.  Chunk 1: A alone,
+    # 3+1 (4).  Chunk 2: A 3+3 (8) while B sits mid-prefill with 4 rows
+    # filled: in use, read by no decode step.  Chunk 3: B 8+1 (12).
+    ([[1, 2, 3], list(range(4, 12))],
+     dict(decode_kernel="pallas", prefix_block_tokens=4,
+          prefill_chunk_tokens=4, prefix_cache_blocks=4), 3, 4 + 8 + 12),
+], ids=["full-rows", "paged", "mid-prefill"])
+def test_kv_row_steps_read_counts_what_a_decode_step_fetches(
+        model, prompts, overrides, chunks, read):
+    """``kv_row_steps_read``: the grid's rows where the decode read takes
+    whole rows, the decoding slots' rows rounded up to the kernel's page
+    where it goes through the paged kernel.  A (3 prompt tokens, 5 to
+    make) and B (3 to make), two slots of 8 + 5 rows, chunks of 2."""
+    _, _, stats = _serve(model, prompts, [5, 3], max_new_tokens=5,
+                         prompt_buckets=(8,), num_slots=2, **overrides)
+    assert stats["chunks"] == chunks
+    assert stats["kv_row_steps_read"] == read
+    assert read <= stats["kv_row_steps_reserved"]
+
+
 def test_a_referenced_pool_block_counts_once_as_in_use(model):
     """Prefix pool on: its blocks are reserved whole; a block counts as
     in use while a live slot references it.  One request of 8 prompt
