@@ -72,8 +72,8 @@ def default_jax_pin() -> Optional[str]:
     editable/source checkout shadowing an installed wheel must not be
     pinned to the stale dist-info).  Otherwise read the distribution
     metadata rather than importing: a cold ``import jax`` costs ~1.5-2 s,
-    which would triple run()'s submit-artifacts latency (the north-star
-    half BASELINE.md tracks) just to learn a version string.
+    which would triple run()'s submit-artifacts latency (half of
+    BASELINE.json's north star) just to learn a version string.
     """
     import sys
 

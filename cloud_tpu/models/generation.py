@@ -720,69 +720,6 @@ def generate(
     }
 
 
-def prefill_program(
-    params,
-    prompt_tokens: jnp.ndarray,
-    prompt_lens: jnp.ndarray,
-    config: transformer.TransformerConfig,
-    *,
-    max_new_tokens: int,
-    rules: ShardingRules = DEFAULT_RULES,
-    mesh=None,
-    kv_quant: bool = False,
-):
-    """Batched serving entry, half 1: prompt prefill as its own program.
-
-    Jit-friendly (no host-side validation — the serving engine runs
-    :func:`check_inference_supported` once at startup): accepts a
-    pre-padded prompt bucket [B, bucket_len] with per-row true lengths,
-    returns ``(cache, logits0)`` sized for ``bucket_len +
-    max_new_tokens`` decode positions.  Feed both to
-    :func:`decode_program`; the split lets ``cloud_tpu.serving`` compile,
-    dispatch, and span prefill and decode independently (their cost
-    scales differently: prefill with prompt length, decode with
-    max_new_tokens x batch).
-    """
-    t_prompt = prompt_tokens.shape[1]
-    prompt_lens = jnp.clip(prompt_lens.astype(jnp.int32), 1, t_prompt)
-    return _prefill(params, prompt_tokens, prompt_lens, config,
-                    t_prompt + max_new_tokens, rules, mesh,
-                    kv_quant=kv_quant)
-
-
-def decode_program(
-    params,
-    cache,
-    logits0: jnp.ndarray,
-    prompt_lens: jnp.ndarray,
-    config: transformer.TransformerConfig,
-    *,
-    max_new_tokens: int,
-    sample: SampleConfig = SampleConfig(temperature=0.0),
-    rng: Optional[jax.Array] = None,
-    rules: ShardingRules = DEFAULT_RULES,
-    mesh=None,
-) -> Dict[str, Any]:
-    """Batched serving entry, half 2: scan-decode from a prefilled cache.
-
-    ``max_new_tokens`` must match the value the cache was prefilled for
-    (the cache's trailing positions are the decode slots).  Returns
-    ``tokens`` [B, max_new_tokens] and the per-row generated lengths
-    ``num_generated`` — what the serving engine demultiplexes back onto
-    individual requests.  ``rng`` is always accepted (ignored under
-    greedy) so one compiled signature serves every sampling config.
-    """
-    t_prompt = cache["k"].shape[2] - max_new_tokens
-    prompt_lens = jnp.clip(prompt_lens.astype(jnp.int32), 1, t_prompt)
-    rng = jax.random.PRNGKey(0) if rng is None else rng
-    tokens, num_generated = _decode_tokens(
-        params, cache, logits0, prompt_lens, config,
-        max_new_tokens=max_new_tokens, sample=sample, rng=rng,
-        rules=rules, mesh=mesh,
-    )
-    return {"tokens": tokens, "num_generated": num_generated}
-
-
 # --------------------------------------------------------------------------
 # Continuous batching: slot-grid programs (the ``cloud_tpu.serving``
 # iteration-level scheduler).  The unit of work is no longer a batch of
@@ -1530,7 +1467,7 @@ def draft_prefill_slot_program(
 def check_inference_supported(config, rules, mesh, what: str = "inference"):
     """Public guard for callers that bypass :func:`generate`'s own checks
     (the serving engine validates once at startup, then dispatches the
-    jit-friendly :func:`prefill_program`/:func:`decode_program` pair)."""
+    jit-friendly slot-grid programs)."""
     _check_inference_supported(config, rules, mesh, what)
 
 

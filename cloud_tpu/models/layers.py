@@ -19,8 +19,8 @@ from cloud_tpu.parallel.sharding import ShardingRules, DEFAULT_RULES, shard_cons
 
 
 #: Named rematerialization policies for the layer-stack scans.  Memory /
-#: recompute trade-offs on TPU (BASELINE.md "BERT MFU ceiling" — remat
-#: policy on the scan is an ablation axis):
+#: recompute trade-offs on TPU (the remat policy on the scan is an
+#: ablation axis):
 #:
 #: - "full": ``jax.checkpoint`` saving only the carry — minimum live
 #:   activations (one layer's worth), backward re-runs the whole layer

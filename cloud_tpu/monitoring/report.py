@@ -3,8 +3,8 @@
 ``python -m cloud_tpu.monitoring.report /path/to/timeline.json`` prints a
 per-span-name table (count, total, mean, p50, max, % of wall) from a
 Chrome trace-event file written by ``tracing.dump_timeline``.  The same
-summarization is importable as :class:`TraceReport` for programmatic use
-(bench.py ships the equivalent aggregates in its BENCH json).
+summarization is importable as :class:`TraceReport` for programmatic
+use.
 
 When the timeline contains serving spans (``serve/*`` — the
 ``cloud_tpu.serving`` engine), a dedicated breakdown follows the main
@@ -41,8 +41,8 @@ tracing was active — the fleet mints a :class:`tracing.TraceContext`
 per request and every layer stamps it) additionally get per-request
 stitching: a **traced requests** line, a **TTFT decomposition** table
 attributing fleet TTFT to queue / route / swap-in / prefill /
-first-decode shares at p50/p99 (the distributional gate bench.py and
-check_fleet.py compare instead of raw percentiles), and a ``--trace
+first-decode shares at p50/p99 (the distributional gate
+check_fleet.py compares instead of raw percentiles), and a ``--trace
 <id>`` drill-down that prints one request's whole lifecycle — every
 span under its trace id across fleet and replicas, failovers included
 — in start order.
@@ -128,12 +128,10 @@ class TraceReport:
         return rows
 
     #: The serving phases, in request order (the ``cloud_tpu.serving``
-    #: engine's span names — batch-mode batch_form/decode, continuous-
-    #: mode chunk); anything else under ``serve/`` rides along.
+    #: engine's span names); anything else under ``serve/`` rides along.
     _SERVE_ORDER = (
-        "serve/queue_wait", "serve/batch_form", "serve/prefill",
-        "serve/decode", "serve/chunk", "serve/draft", "serve/verify",
-        "serve/host_bubble", "serve/dispatch_gap",
+        "serve/queue_wait", "serve/prefill", "serve/chunk", "serve/draft",
+        "serve/verify", "serve/host_bubble", "serve/dispatch_gap",
     )
 
     def continuous_summary(self) -> Optional[Dict[str, float]]:
@@ -141,7 +139,7 @@ class TraceReport:
         (the continuous-batching scheduler stamps ``active``, ``slots``,
         ``tokens`` and ``occupancy`` on every chunk) into one line of
         grid health: how full the decode grid ran.  None when the
-        timeline has no chunk spans (batch-mode or non-serving trace).
+        timeline has no chunk spans (a non-serving trace).
         """
         chunks = [
             e.get("args") or {} for e in self.events
@@ -220,7 +218,7 @@ class TraceReport:
         admission path paid to promote demoted blocks (count, total,
         and max — the worst single admission stall attributable to the
         tier).  None when the timeline has none of these spans (prefix
-        caching and chunked prefill off, batch mode, or a non-serving
+        caching and chunked prefill off, or a non-serving
         trace).
         """
         lookups = 0
@@ -281,7 +279,7 @@ class TraceReport:
         ``serve/draft_prefill`` spans and ``verify_seconds`` the verify
         spans — the draft/verify wall-clock split the spec_k knob is
         tuned against.  None when the timeline has no speculative spans
-        (draft off, batch mode, or a non-serving trace).
+        (draft off, or a non-serving trace).
         """
         verify_durs: List[float] = []
         draft_durs: List[float] = []

@@ -42,10 +42,10 @@ DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 
 #: Auto-dispatch (``use_pallas=None``) takes the kernel only at T >= this.
-#: Measured on TPU v5e (scripts/attn_crossover.py, value+grad, steady
-#: state): XLA's fused attention is ~1.15-1.25x faster at T in [256, 512]
-#: (the whole O(T^2) score tensor still fits cache-friendly tiles there),
-#: while the kernel wins 1.38x at 1024, 1.45x at 2048, 1.61x at 4096 — and
+#: Set from a round-3 reading on a TPU v5e (value+grad, steady state; not
+#: re-measured on today's chip, ROADMAP S8): XLA's fused attention was
+#: faster at T in [256, 512] (the whole O(T^2) score tensor still fits
+#: cache-friendly tiles there), the kernel from 1024 up — and it
 #: is O(T) in memory where XLA materializes the [B,H,T,T] scores.  Callers
 #: that need the kernel below the threshold (masked long-tail, tests) pass
 #: ``use_pallas=True`` explicitly.

@@ -5,7 +5,7 @@ Why: CloudLM's stock loss path computes ``logits = x @ W`` ([B, T, V]
 f32) and then ``log_softmax`` — under ``value_and_grad`` XLA keeps both
 as residuals, ~2 * B*T*V*4 bytes.  At B8 x T2048 x V32000 that is
 ~4 GiB of HBM for ONE layer of the program, and the softmax+gather
-epilogue is pure HBM traffic (BASELINE.md's BERT ablation measured the
+epilogue is pure HBM traffic (a round-3 BERT ablation on a v5e read the
 vocab term at 1.4 ms/step at only V=30k classification scale).
 
 This op computes per-token ``nll = logsumexp_V(x @ W) - (x @ W)[target]``

@@ -1,7 +1,7 @@
 """Fused GroupNorm Pallas kernels (NHWC, per-sample grid).
 
 Why a kernel: at CIFAR scale the ResNet50 step is VPU/HBM-bound and
-GroupNorm is its largest non-conv cost (BASELINE.md "ResNet ceiling").
+GroupNorm is its largest non-conv cost (a round-3 reading on a v5e).
 XLA's lowering reads the activation twice (reduce, then normalize); the
 kernel computes group statistics and writes the normalized+affine output
 in ONE pass over VMEM-resident data — one HBM read + one write per

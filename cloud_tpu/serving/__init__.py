@@ -7,10 +7,7 @@ counterpart — a request queue + scheduler that drives
 ``models.generation``'s slot-grid programs (insert + chunk decode) at
 steady-state occupancy, retiring and refilling decode slots between
 chunks, while individual callers see a simple future-per-request API.
-The PR 4 batch-synchronous scheduler survives as
-``ServeConfig(scheduler="batch")``, the baseline the continuous path is
-measured against.  See ``docs/serving.md`` and
-:mod:`cloud_tpu.serving.engine`.
+See ``docs/serving.md`` and :mod:`cloud_tpu.serving.engine`.
 """
 
 from cloud_tpu.serving.engine import (

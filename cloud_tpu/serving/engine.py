@@ -5,9 +5,9 @@ program, but traffic arrives one request at a time; serving economics on
 TPU hinge on the gap between those two facts (batched decode occupancy
 amortizes the weight reads every decode step re-pays — arxiv 2605.25645,
 arxiv 2309.08918).  :class:`ServingEngine` closes the gap in-process,
-with two schedulers sharing one submit/future/admission surface:
+with one scheduler behind a submit/future/admission surface:
 
-* **Continuous batching** (``scheduler="continuous"``, the default) —
+* **Continuous batching** —
   iteration-level scheduling over a persistent decode grid: a static
   ``(num_slots, max_len)`` KV cache plus per-slot ``{position,
   remaining, active}`` state lives on the device for the engine's whole
@@ -22,7 +22,7 @@ with two schedulers sharing one submit/future/admission surface:
   a long neighbor's decode: occupancy is a steady-state quantity
   instead of the batch-synchronous sawtooth (Orca-style iteration
   scheduling — arxiv 2605.25645).
-* **Prefix caching** (``prefix_cache_blocks > 0``, continuous mode) —
+* **Prefix caching** (``prefix_cache_blocks > 0``) —
   requests sharing a prompt prefix (system prompts, few-shot headers)
   share its KV bytes: a radix/token-trie manager
   (``serving.prefix_cache``) keys a device pool of KV blocks by
@@ -38,7 +38,7 @@ with two schedulers sharing one submit/future/admission surface:
   match-vs-acquire revalidation extended so a swap-in that loses the
   race falls back to a cold prefill — docs/serving.md "Tiered prefix
   cache".
-* **Chunked prefill** (``prefill_chunk_tokens``, continuous mode) —
+* **Chunked prefill** (``prefill_chunk_tokens``) —
   prompt prefill splits into bounded chunks
   (``generation.prefill_chunk_program``) the scheduler interleaves
   with decode chunks, one prefill chunk per pass: a long arrival
@@ -58,8 +58,8 @@ with two schedulers sharing one submit/future/admission surface:
   error).  Unset / ``(1, 1)`` keeps the single-chip path
   byte-identical, and greedy outputs on any slice are token-identical
   to single-chip ``generate()`` — docs/serving.md "Sharded serving".
-* **Speculative decoding** (``draft=DraftConfig(...)``, continuous
-  mode) — draft-and-verify on the slot grid: a small draft model
+* **Speculative decoding** (``draft=DraftConfig(...)``) —
+  draft-and-verify on the slot grid: a small draft model
   proposes a ``spec_k``-token window per active slot
   (``generation.draft_chunk_program`` over the draft's own slot cache),
   and the target model scores every window position in ONE chunked
@@ -72,19 +72,11 @@ with two schedulers sharing one submit/future/admission surface:
   ``draft=None`` (default) is byte-identical to the non-speculative
   path; ``spec_k=1`` is a pure-overhead test knob.  ``health()`` and
   ``stats()`` report a rolling/cumulative acceptance rate.
-* **Dynamic batching** (``scheduler="batch"``, the PR 4 path) — the
-  scheduler groups waiting requests by prompt-length bucket, pads each
-  group to a static ``(bucket_len, batch_size)`` grid point, and
-  dispatches prefill + scan-decode as two compiled programs
-  (``generation.prefill_program`` / ``generation.decode_program``).  A
-  batch forms on a full max-batch or a ``flush_deadline_s`` timeout.
-  Kept as the baseline the continuous scheduler is measured against
-  (tests assert continuous slot occupancy beats it on churn workloads).
-* **AOT warmup** — either grid is enumerable, so ``warmup=True``
-  pre-compiles it through ``training.compile_cache`` (the trainer's AOT
-  registry + background worker) at engine start: continuous warms one
-  insert program per prompt bucket plus the single chunk program;
-  batch warms prefill/decode per ``(bucket_len, batch_size)`` cell.
+* **AOT warmup** — the programs are enumerable, so ``warmup=True``
+  pre-compiles them through ``training.compile_cache`` (the trainer's
+  AOT registry + background worker) at engine start: one insert program
+  per prompt bucket plus the single chunk program (and, where they are
+  on, the prefix, chunked-prefill and draft/verify programs).
 * **Admission control** — the waiting set is bounded by ``max_queue``;
   ``admission="block"`` makes ``submit`` wait for space,
   ``admission="reject"`` raises :class:`QueueFullError` (typed, so a
@@ -94,10 +86,9 @@ with two schedulers sharing one submit/future/admission surface:
   scheduler/warmup thread survives (same thread-hygiene contract as
   ``training.pipeline_io``).
 * **Observability** — ``serve/queue_wait`` (recorded cross-thread via
-  ``tracing.record_span``), ``serve/prefill`` spans in both modes;
+  ``tracing.record_span``), ``serve/prefill`` spans and
   ``serve/chunk`` spans (with per-dispatch ``active``/``occupancy``
-  attributes) in continuous mode, ``serve/batch_form``/``serve/decode``
-  in batch mode.  A continuous scheduler pass closes: one numbered
+  attributes).  A scheduler pass closes: one numbered
   ``serve/pass`` span per loop iteration that did work (``inserts``
   taken off the queue with their ``prompt_tokens``/``bucket_tokens``,
   ``active`` slots in its chunk,
@@ -110,7 +101,7 @@ with two schedulers sharing one submit/future/admission surface:
   ``serve/chunk`` carry it.  A request closes: while a collector is
   active every request has a trace id (the caller's or one ``submit``
   mints) shared by its ``serve/queue_wait``, ``serve/prefill``, the
-  chunk spans' ``traces`` map and, on both schedulers, its terminal
+  chunk spans' ``traces`` map and its terminal
   ``serve/request`` (``ttft_s``, ``queue_wait_s``, ``decode_s``,
   ``tokens``, ``prompt_len``, ``bucket``, ``slot``, ``passes``) and
   ``serve/ttft`` (submit's stamp to the first token on the host).
@@ -125,8 +116,8 @@ with two schedulers sharing one submit/future/admission surface:
   and ``state_bytes_*`` count them the same way (zeros otherwise), and
   ``serve/pass`` carries ``state_rows_in_use``.
   ``serve/qps`` and ``serve/tokens_per_sec``
-  windowed-rate gauges, ``serve/slot_occupancy`` /
-  ``serve/batch_occupancy`` gauges, slot-churn counters
+  windowed-rate gauges, the ``serve/slot_occupancy`` gauge,
+  slot-churn counters
   (``serve/slot_inserts``, ``serve/slot_retires``,
   ``serve/slot_expired``, ``serve/chunks``) and a
   ``serve/latency_seconds`` distribution.  ``python -m
@@ -134,7 +125,7 @@ with two schedulers sharing one submit/future/admission surface:
   breakdown, with a continuous-batching section when chunk spans are
   present.
 
-Greedy parity is the correctness contract in both modes: for any mix of
+Greedy parity is the correctness contract: for any mix of
 prompt lengths, arrival times, and per-request decode budgets, a
 request's tokens are identical to a direct per-request
 ``generation.generate`` call (slot/bucket padding is masked out of
@@ -238,39 +229,27 @@ class ServeConfig:
     """Engine knobs (all static — they define the compiled-program grid).
 
     ``prompt_buckets`` are the padded prompt lengths the engine compiles
-    for (a request lands in the smallest bucket that fits it).  Under
-    the default continuous scheduler the compiled grid is one insert
-    program per prompt bucket plus ONE chunk program over the
+    for (a request lands in the smallest bucket that fits it).  The
+    compiled grid is one insert program per prompt bucket plus ONE
+    chunk program over the
     ``(num_slots, prompt_buckets[-1] + max_new_tokens)`` slot cache;
     ``chunk_tokens`` is the scheduling quantum (admission/retirement
-    granularity vs dispatch overhead — docs/serving.md).  Under
-    ``scheduler="batch"``, ``batch_buckets`` are the batch sizes (a
-    formed group pads up to the smallest batch bucket that fits, so
-    occupancy is explicit: 3 requests in a bucket-4 dispatch is 75%),
-    the grid is the cross product x {prefill, decode}, and
-    ``flush_deadline_s`` bounds how long a request may wait for
-    co-batching once it is first in line.  ``max_queue``/``admission``
-    are the backpressure contract in both modes (module docstring).
+    granularity vs dispatch overhead — docs/serving.md).
+    ``max_queue``/``admission`` are the backpressure contract (module
+    docstring).
     """
 
     max_new_tokens: int = 32
     prompt_buckets: Tuple[int, ...] = (32, 128, 512)
-    batch_buckets: Tuple[int, ...] = (1, 2, 4, 8)
-    flush_deadline_s: float = 0.01
     max_queue: int = 256
     admission: str = "block"
-    #: ``"continuous"`` (default) — slot-based in-flight decode over a
-    #: persistent grid; ``"batch"`` — the PR 4 batch-synchronous path.
-    scheduler: str = "continuous"
-    #: Decode-slot count for the continuous grid (None: the largest
-    #: batch bucket, so both schedulers size their device footprint the
-    #: same way).
-    num_slots: Optional[int] = None
-    #: Tokens decoded per chunk dispatch in continuous mode.  Small
+    #: Decode-slot count of the grid.
+    num_slots: int = 8
+    #: Tokens decoded per chunk dispatch.  Small
     #: chunks admit/retire at finer granularity (lower latency under
     #: churn); large chunks amortize host dispatch overhead.
     chunk_tokens: int = 8
-    #: Shared-prefix KV cache (continuous mode): pool size in blocks.
+    #: Shared-prefix KV cache: pool size in blocks.
     #: 0 (default) disables — the compatibility default.  When set, the
     #: scheduler looks up each arriving prompt's longest cached prefix,
     #: copies its KV into the slot row (``generation.
@@ -290,13 +269,13 @@ class ServeConfig:
     #: the ``prefix_dram_*`` health/stats keys read zero).  Requires
     #: ``prefix_cache_blocks > 0``.
     prefix_dram_blocks: int = 0
-    #: Chunked prefill (continuous mode): split prompt prefill into
+    #: Chunked prefill: split prompt prefill into
     #: dispatches of this many tokens, interleaved with decode chunks,
     #: so a long arrival stalls in-flight decode by at most ONE chunk
     #: instead of one full prefill.  None (default) keeps the one-shot
     #: insert prefill — the compatibility default.
     prefill_chunk_tokens: Optional[int] = None
-    #: Draft-and-verify speculative decoding (continuous mode): arm with
+    #: Draft-and-verify speculative decoding: arm with
     #: ``DraftConfig(config=..., params=..., spec_k=...)``.  ``None``
     #: (default) keeps the one-dispatch-per-token decode path
     #: byte-identical.  Greedy-only (module docstring).
@@ -305,13 +284,13 @@ class ServeConfig:
     #: the compiled decode program).  Default greedy.
     sample: "SampleConfig" = None  # type: ignore[assignment]
     kv_quant: bool = False
-    #: Pre-compile the whole (bucket_len, batch_size) grid at start on a
-    #: background worker (``training.compile_cache``).
+    #: Pre-compile the slot grid's programs at start on a background
+    #: worker (``training.compile_cache``).
     warmup: bool = False
     #: Seed for the engine-owned sampling rng chain (non-greedy configs).
     seed: int = 0
-    #: Watchdog bound on any single device dispatch (prefill, chunk,
-    #: decode).  ``None`` (default) trusts the device; when set, a
+    #: Watchdog bound on any single device dispatch (prefill, chunk).
+    #: ``None`` (default) trusts the device; when set, a
     #: dispatch exceeding it fails its requests with
     #: :class:`DispatchTimeoutError` and marks the engine unhealthy
     #: (``health()``) instead of wedging the scheduler forever.  Costs
@@ -338,7 +317,7 @@ class ServeConfig:
     #: speed; a budget picks the NARROWEST tp that fits, leaving chips
     #: for more replicas.
     hbm_bytes_per_chip: Optional[int] = None
-    #: Multi-tenant QoS (continuous mode): ``serving.qos.QosConfig``
+    #: Multi-tenant QoS: ``serving.qos.QosConfig``
     #: arms priority classes (slot admission by SLO slack + weighted
     #: fairness debt instead of arrival order) and class-aware brownout
     #: shedding.  ``None`` (default) keeps the FIFO path byte-identical
@@ -346,7 +325,7 @@ class ServeConfig:
     #: per-class health/stats keys read zero.  Host-side policy only:
     #: the compiled programs are untouched either way.
     qos: Optional[QosConfig] = None
-    #: How a prefix hit reaches the continuous slot grid's attention.
+    #: How a prefix hit reaches the slot grid's attention.
     #: ``"xla"`` (default): hits are COPIED into the slot's row before
     #: decode (``copy_prefix_program``) and every program reads slot rows
     #: only.  ``"pallas"`` routes the chunk/prefill-chunk/verify
@@ -358,8 +337,7 @@ class ServeConfig:
     #: decode step's skip of rows that hold nothing does NOT depend on
     #: this: on a TPU the decode read goes through the paged kernel
     #: under every setting (``generation._scan_layers``).  Greedy
-    #: outputs are token-identical on every setting.  Continuous-
-    #: scheduler only.
+    #: outputs are token-identical on every setting.
     decode_kernel: str = "xla"
     #: Disaggregated-serving role this engine plays in a fleet:
     #: ``"prefill"`` (serves the prefill leg of split requests),
@@ -368,8 +346,8 @@ class ServeConfig:
     #: today; the ``role``/handoff health keys read ``"both"``/zero).
     #: Routing policy lives in the fleet; the engine only reports the
     #: role and accepts the handoff submit kwargs, which themselves
-    #: need the continuous scheduler plus a prefix pool (the handoff IS
-    #: cross-replica prefix-cache seeding — docs/fleet.md).  A fleet
+    #: need a prefix pool (the handoff IS cross-replica prefix-cache
+    #: seeding — docs/fleet.md).  A fleet
     #: replica may override per-replica via :meth:`ServingEngine.
     #: set_role`, so one factory serves mixed-role fleets.
     role: str = "both"
@@ -404,27 +382,19 @@ class ServeConfig:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {self.max_new_tokens}"
             )
-        for name in ("prompt_buckets", "batch_buckets"):
-            buckets = tuple(getattr(self, name))
-            object.__setattr__(self, name, buckets)
-            if not buckets or any(b < 1 for b in buckets):
-                raise ValueError(f"{name} must be non-empty and positive")
-            if list(buckets) != sorted(set(buckets)):
-                raise ValueError(
-                    f"{name} must be strictly increasing, got {buckets}"
-                )
+        buckets = tuple(self.prompt_buckets)
+        object.__setattr__(self, "prompt_buckets", buckets)
+        if not buckets or any(b < 1 for b in buckets):
+            raise ValueError("prompt_buckets must be non-empty and positive")
+        if list(buckets) != sorted(set(buckets)):
+            raise ValueError(
+                f"prompt_buckets must be strictly increasing, got {buckets}"
+            )
         if self.admission not in ("block", "reject"):
             raise ValueError(
                 f"admission must be 'block' or 'reject', "
                 f"got {self.admission!r}"
             )
-        if self.scheduler not in ("continuous", "batch"):
-            raise ValueError(
-                f"scheduler must be 'continuous' or 'batch', "
-                f"got {self.scheduler!r}"
-            )
-        if self.num_slots is None:
-            object.__setattr__(self, "num_slots", self.batch_buckets[-1])
         if self.num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {self.num_slots}")
         if self.chunk_tokens < 1:
@@ -458,21 +428,7 @@ class ServeConfig:
                 f"prefill_chunk_tokens must be >= 1 or None, got "
                 f"{self.prefill_chunk_tokens}"
             )
-        if self.scheduler == "batch" and (
-            self.prefix_cache_blocks or self.prefill_chunk_tokens is not None
-        ):
-            raise ValueError(
-                "prefix_cache_blocks / prefill_chunk_tokens need the "
-                "continuous scheduler (slot-grid prefill); the batch "
-                "path has no per-slot cache rows to reuse"
-            )
         if self.draft is not None:
-            if self.scheduler != "continuous":
-                raise ValueError(
-                    "draft= (speculative decoding) needs the continuous "
-                    "scheduler — the verify program is a slot-grid "
-                    "dispatch"
-                )
             if self.sample.temperature != 0.0:
                 raise ValueError(
                     "draft= (speculative decoding) requires greedy "
@@ -489,26 +445,16 @@ class ServeConfig:
                 )
         if self.max_queue < 1:
             raise ValueError(f"max_queue must be >= 1, got {self.max_queue}")
-        if self.flush_deadline_s < 0:
-            raise ValueError("flush_deadline_s must be >= 0")
         if self.dispatch_timeout_s is not None and self.dispatch_timeout_s <= 0:
             raise ValueError(
                 f"dispatch_timeout_s must be > 0 or None, "
                 f"got {self.dispatch_timeout_s}"
             )
-        if self.qos is not None:
-            if not isinstance(self.qos, QosConfig):
-                raise ValueError(
-                    f"qos must be a serving.qos.QosConfig, got "
-                    f"{type(self.qos).__name__}"
-                )
-            if self.scheduler != "continuous":
-                raise ValueError(
-                    "qos= (priority scheduling) needs the continuous "
-                    "scheduler — slot admission is where the class "
-                    "order is enforced; the batch path forms batches "
-                    "by bucket, not by request"
-                )
+        if self.qos is not None and not isinstance(self.qos, QosConfig):
+            raise ValueError(
+                f"qos must be a serving.qos.QosConfig, got "
+                f"{type(self.qos).__name__}"
+            )
         if self.decode_kernel not in ("auto", "pallas", "xla"):
             raise ValueError(
                 f"decode_kernel must be 'auto', 'pallas', or 'xla', "
@@ -519,12 +465,10 @@ class ServeConfig:
                 f"role must be 'prefill', 'decode', or 'both', "
                 f"got {self.role!r}"
             )
-        if self.role != "both" and (
-            self.scheduler != "continuous" or not self.prefix_cache_blocks
-        ):
+        if self.role != "both" and not self.prefix_cache_blocks:
             raise ValueError(
-                "role= (disaggregated serving) needs the continuous "
-                "scheduler and prefix_cache_blocks > 0 — the KV handoff "
+                "role= (disaggregated serving) needs "
+                "prefix_cache_blocks > 0 — the KV handoff "
                 "exports/imports prefix-pool blocks"
             )
         if (self.prefix_summary_ttl_s is not None
@@ -533,24 +477,10 @@ class ServeConfig:
                 f"prefix_summary_ttl_s must be > 0 or None, got "
                 f"{self.prefix_summary_ttl_s}"
             )
-        if self.decode_kernel != "xla" and self.scheduler != "continuous":
-            raise ValueError(
-                "decode_kernel= (paged decode attention) needs the "
-                "continuous scheduler — the block table pages slot rows "
-                "of the persistent grid; the batch path re-prefills a "
-                "fresh cache per batch"
-            )
         if self.pipeline_depth not in (1, 2):
             raise ValueError(
                 f"pipeline_depth must be 1 or 2, got "
                 f"{self.pipeline_depth!r}"
-            )
-        if self.pipeline_depth > 1 and self.scheduler != "continuous":
-            raise ValueError(
-                "pipeline_depth=2 (pipelined scheduling) needs the "
-                "continuous scheduler — the in-flight ring overlaps "
-                "chunk dispatches on the persistent slot grid; the "
-                "batch path has no standing state to dispatch against"
             )
         if self.layout not in ("explicit", "auto"):
             raise ValueError(
@@ -579,10 +509,9 @@ class ServeResult:
     ``tokens`` is the request's generated row, length =
     its ``max_new_tokens`` (eos included where sampled, pad after it) —
     byte-identical to ``generation.generate``'s row for the same prompt.
-    ``num_generated`` counts real tokens (eos included).  The batch
-    fields record how the request was served (occupancy debugging);
-    under the continuous scheduler ``batch_size`` is the grid's
-    ``num_slots``.
+    ``num_generated`` counts real tokens (eos included).
+    ``bucket_len`` is the prompt bucket it was inserted at and
+    ``batch_size`` the grid's ``num_slots``.
     """
 
     tokens: np.ndarray
@@ -590,11 +519,9 @@ class ServeResult:
     bucket_len: int
     batch_size: int
     latency_seconds: float
-    #: Submit -> first token known.  Under the continuous scheduler the
-    #: first token is sampled when the prefill lands, so this isolates
-    #: queueing + prefill (what prefix caching and chunked prefill move)
-    #: from decode.  The batch scheduler only materializes tokens when
-    #: the whole batch decode returns, so there it equals latency.
+    #: Submit -> first token known.  The first token is sampled when
+    #: the prefill lands, so this isolates queueing + prefill (what
+    #: prefix caching and chunked prefill move) from decode.
     ttft_seconds: float = 0.0
     #: Fleet-wide trace id when the request carried a ``TraceContext``
     #: (``tracing.new_trace_context``); None otherwise — the key that
@@ -749,52 +676,6 @@ class _InflightChunk:
     dispatch_end: float
 
 
-class _Cell:
-    """The compiled-program pair for one (bucket_len, batch_size) point.
-
-    ``AotStep`` wrappers (training.compile_cache): a warmed cell
-    dispatches the pre-compiled executable; an un-warmed (or mismatched)
-    one falls back to the jitted function, which compiles on first use —
-    warmup makes the engine fast, never wrong.
-    """
-
-    def __init__(self, engine: "ServingEngine", bucket_len: int,
-                 batch_size: int):
-        import functools
-
-        import jax
-
-        from cloud_tpu.models import generation
-        from cloud_tpu.training import compile_cache
-
-        cfg = engine.serve_config
-        self.bucket_len = bucket_len
-        self.batch_size = batch_size
-        prefill_fn = jax.jit(functools.partial(
-            generation.prefill_program,
-            config=engine.config, max_new_tokens=cfg.max_new_tokens,
-            rules=engine.rules, mesh=engine.mesh, kv_quant=cfg.kv_quant,
-        ))
-
-        # Positional-arg wrapper: AotStep (and AOT-compiled executables)
-        # dispatch positionally, but decode_program's rng is keyword-only.
-        def decode_positional(params, cache, logits0, prompt_lens, rng):
-            return generation.decode_program(
-                params, cache, logits0, prompt_lens, engine.config,
-                max_new_tokens=cfg.max_new_tokens, sample=cfg.sample,
-                rng=rng, rules=engine.rules, mesh=engine.mesh,
-            )
-
-        decode_fn = jax.jit(decode_positional)
-        tag = f"L{bucket_len}_B{batch_size}"
-        self.prefill = compile_cache.AotStep(
-            prefill_fn, label=f"serve/prefill_{tag}"
-        )
-        self.decode = compile_cache.AotStep(
-            decode_fn, label=f"serve/decode_{tag}"
-        )
-
-
 class _DeferredPayload:
     """A demoted block's host bytes, not yet downloaded.
 
@@ -835,7 +716,7 @@ def _refuse_for_recurrent_state(config, cfg: ServeConfig) -> None:
     rows" for granted refuses, at construction and in words, a model
     whose slot cache also holds a recurrent state
     (``TransformerConfig.ssm``); the plain slot path — insert at a
-    bucket, decode chunks — and the batch scheduler serve it."""
+    bucket, decode chunks — serves it."""
     if config.ssm is None:
         return
     asked = [name for name, on in (
@@ -861,8 +742,7 @@ def _refuse_for_recurrent_state(config, cfg: ServeConfig) -> None:
 
 class ServingEngine:
     """In-process continuous-batching server over ``generation`` (module
-    docstring; ``scheduler="batch"`` selects the batch-synchronous
-    path).  Construct, ``submit()`` concurrently from any thread,
+    docstring).  Construct, ``submit()`` concurrently from any thread,
     ``close()`` when done (or use as a context manager)."""
 
     def __init__(
@@ -905,7 +785,7 @@ class ServingEngine:
         if self._built_serving_mesh:
             self._shard_params()
         metrics.gauge_set("serve/slice_chips", self._slice_chips)
-        # Engine-owned rng chain: split per batch (carried but
+        # Engine-owned rng chain: split per dispatch (carried but
         # unobservable under greedy — one decode signature either way).
         self._rng = jax.random.PRNGKey(self.serve_config.seed)
 
@@ -925,7 +805,6 @@ class ServingEngine:
         self._closed = False
         self._draining = True
         self._thread: Optional[threading.Thread] = None
-        self._cells: Dict[Tuple[int, int], _Cell] = {}
         self._warmup_plan = None
         #: Why the engine is unhealthy (watchdog fire, scheduler crash);
         #: None while healthy.  Written by the scheduler, read by
@@ -935,9 +814,9 @@ class ServingEngine:
         #: close() so a finite hang never leaks past the engine's life.
         self._orphan_dispatches: List[threading.Thread] = []
         self._last_dispatch_ts: Optional[float] = None
-        #: Sequence number of the continuous scheduler's open pass (from
-        #: 1): ``serve/pass`` and every span recorded inside the pass
-        #: carry it.  0 on the batch scheduler, which has no pass.
+        #: Sequence number of the scheduler's open pass (from 1):
+        #: ``serve/pass`` and every span recorded inside the pass carry
+        #: it.
         self._pass_seq = 0
         #: Timeline lane (synthetic Chrome-trace pid) this engine's
         #: scheduler stamps its spans with; None = the real process pid.
@@ -954,20 +833,16 @@ class ServingEngine:
         #: the colocated default).  Plain str swap — the owning fleet
         #: replica may restamp it via :meth:`set_role`.
         self._role = self.serve_config.role
-        #: Rows of the batch currently on the device (batch scheduler;
-        #: the continuous path reads its slot table instead).  Plain int
-        #: swap — written by the scheduler, read by ``health()``.
-        self._inflight_rows = 0
 
         self._stats_lock = threading.Lock()
         self._stats = {
             "requests": 0, "completed": 0, "failed": 0, "rejected": 0,
             "batches": 0, "slots": 0, "real_rows": 0,
             "generated_tokens": 0,
-            # Token-level decode accounting, comparable across the two
-            # schedulers: useful emissions vs dispatched emission slots.
+            # Token-level decode accounting: useful emissions vs
+            # dispatched emission slots.
             "decode_slot_steps": 0, "useful_decode_tokens": 0,
-            # Continuous-mode churn counters.
+            # Slot churn counters.
             "inserts": 0, "retires": 0, "expired": 0, "chunks": 0,
             # Prefix-cache / chunked-prefill counters (0 when disabled).
             "prefill_chunks": 0, "prefix_hits": 0, "prefix_misses": 0,
@@ -984,9 +859,9 @@ class ServingEngine:
             # Robustness counters: queue-shed deadlines, watchdog fires.
             "shed": 0, "watchdog_timeouts": 0,
             # KV rows reserved against in use, summed over every chunk
-            # dispatch (continuous scheduler; their quotient is the
-            # share of the rows a decode step reads that hold a live
-            # token), and the rows a decode step fetches: the grid's,
+            # dispatch (their quotient is the share of the rows a
+            # decode step reads that hold a live token), and the rows
+            # a decode step fetches: the grid's,
             # or the live slots' pages where the paged kernel reads.
             "kv_row_steps_reserved": 0, "kv_row_steps_in_use": 0,
             "kv_row_steps_read": 0,
@@ -1020,219 +895,213 @@ class ServingEngine:
             "serve/tokens_per_sec", window=256
         )
 
-        self._continuous = self.serve_config.scheduler == "continuous"
-        #: Speculative decoding armed (continuous branch may flip it).
-        self._spec = False
-        #: Paged decode attention armed (continuous branch may flip it);
-        #: ``_block_table`` is its host-side [num_slots, n_pages] mirror
-        #: (None on the XLA path and under the batch scheduler).
-        self._paged = False
-        self._block_table = None
-        if self._continuous:
-            cfg = self.serve_config
-            #: Slot cache rows must fit the largest bucket's prompt plus
-            #: the engine-wide decode budget.
-            self._max_len = cfg.prompt_buckets[-1] + cfg.max_new_tokens
+        cfg = self.serve_config
+        #: Slot cache rows must fit the largest bucket's prompt plus
+        #: the engine-wide decode budget.
+        self._max_len = cfg.prompt_buckets[-1] + cfg.max_new_tokens
 
-            def make_grid():
-                return generation.init_slot_cache(
-                    config, cfg.num_slots, self._max_len, rules=self.rules,
+        def make_grid():
+            return generation.init_slot_cache(
+                config, cfg.num_slots, self._max_len, rules=self.rules,
+                mesh=self.mesh, kv_quant=cfg.kv_quant,
+            )
+
+        # Under a serving slice the grid is born head-sharded:
+        # building it INSIDE jit binds init_slot_cache's logical-
+        # axis constraints to the mesh, so every leaf lands
+        # [L, slots, S, H/tp, hd] per chip.  Single-chip keeps the
+        # eager allocation — byte-identical to the pre-slice path.
+        self._grid_cache = (
+            jax.jit(make_grid)() if self._slice_chips > 1
+            else make_grid()
+        )
+        self._slot_state = generation.init_slot_state(
+            config, cfg.num_slots, sample=cfg.sample
+        )
+        if self._slice_chips > 1:
+            # Per-slot scalars are tiny: replicate them across the
+            # slice so every chip samples from the same state.
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._slot_state = jax.device_put(
+                self._slot_state,
+                NamedSharding(self.mesh, PartitionSpec()),
+            )
+        #: Scheduler-thread-only slot bookkeeping (the host mirror).
+        self._slot_table: List[Optional[_Slot]] = [None] * cfg.num_slots
+        #: Slots in the open pass's chunk (``serve/pass``'s
+        #: ``active``; scheduler-thread only, reset at each pass).
+        self._pass_active = 0
+        self._free_slots = list(range(cfg.num_slots))[::-1]
+        self._active_slots: set = set()
+        self._insert_cells: Dict[int, "compile_cache.AotStep"] = {}
+        #: Requests mid-prefill (chunked prefill / prefix hits):
+        #: FIFO, advanced one chunk dispatch per scheduler pass.
+        self._prefill_tasks: collections.deque = collections.deque()
+        self._chunk_prefill_cells: Dict[int, "compile_cache.AotStep"] = {}
+        self._finalize_step = None
+        self._copy_cells: Dict[int, "compile_cache.AotStep"] = {}
+        self._save_cells: Dict[int, "compile_cache.AotStep"] = {}
+        #: The shared-prefix block pool + its host-side radix
+        #: bookkeeping (None unless prefix_cache_blocks > 0).
+        self._prefix = None
+        self._prefix_pool = None
+        if cfg.prefix_cache_blocks:
+            from cloud_tpu.serving.prefix_cache import PrefixCacheManager
+
+            self._prefix = PrefixCacheManager(
+                cfg.prefix_cache_blocks, cfg.prefix_block_tokens,
+                dram_blocks=cfg.prefix_dram_blocks,
+                demote_fn=(
+                    self._demote_block if cfg.prefix_dram_blocks
+                    else None
+                ),
+                summary_ttl_s=cfg.prefix_summary_ttl_s,
+            )
+
+            def make_pool():
+                return generation.init_prefix_pool(
+                    config, cfg.prefix_cache_blocks,
+                    cfg.prefix_block_tokens, rules=self.rules,
                     mesh=self.mesh, kv_quant=cfg.kv_quant,
                 )
 
-            # Under a serving slice the grid is born head-sharded:
-            # building it INSIDE jit binds init_slot_cache's logical-
-            # axis constraints to the mesh, so every leaf lands
-            # [L, slots, S, H/tp, hd] per chip.  Single-chip keeps the
-            # eager allocation — byte-identical to the pre-slice path.
-            self._grid_cache = (
-                jax.jit(make_grid)() if self._slice_chips > 1
-                else make_grid()
+            # The block pool shards by head exactly like the slot
+            # grid (same pytree structure), so pool<->slot copies
+            # stay chip-local — no resharding on the hit path.
+            self._prefix_pool = (
+                jax.jit(make_pool)() if self._slice_chips > 1
+                else make_pool()
             )
-            self._slot_state = generation.init_slot_state(
-                config, cfg.num_slots, sample=cfg.sample
-            )
-            if self._slice_chips > 1:
-                # Per-slot scalars are tiny: replicate them across the
-                # slice so every chip samples from the same state.
-                from jax.sharding import NamedSharding, PartitionSpec
-
+        # Engine device-state lives WITH the params: the init
+        # programs above land on the process default device, so on
+        # multi-device hosts (a fleet pinning one replica's params
+        # per device) the grid, slot state, and pool must be
+        # re-committed to the params' device or the first dispatch
+        # raises on mixed committed placements.
+        if self.mesh is None:
+            device = self._params_device()
+            if device is not None:
+                self._grid_cache = jax.device_put(
+                    self._grid_cache, device
+                )
                 self._slot_state = jax.device_put(
-                    self._slot_state,
-                    NamedSharding(self.mesh, PartitionSpec()),
+                    self._slot_state, device
                 )
-            #: Scheduler-thread-only slot bookkeeping (the host mirror).
-            self._slot_table: List[Optional[_Slot]] = [None] * cfg.num_slots
-            #: Slots in the open pass's chunk (``serve/pass``'s
-            #: ``active``; scheduler-thread only, reset at each pass).
-            self._pass_active = 0
-            self._free_slots = list(range(cfg.num_slots))[::-1]
-            self._active_slots: set = set()
-            self._insert_cells: Dict[int, "compile_cache.AotStep"] = {}
-            #: Requests mid-prefill (chunked prefill / prefix hits):
-            #: FIFO, advanced one chunk dispatch per scheduler pass.
-            self._prefill_tasks: collections.deque = collections.deque()
-            self._chunk_prefill_cells: Dict[int, "compile_cache.AotStep"] = {}
-            self._finalize_step = None
-            self._copy_cells: Dict[int, "compile_cache.AotStep"] = {}
-            self._save_cells: Dict[int, "compile_cache.AotStep"] = {}
-            #: The shared-prefix block pool + its host-side radix
-            #: bookkeeping (None unless prefix_cache_blocks > 0).
-            self._prefix = None
-            self._prefix_pool = None
-            if cfg.prefix_cache_blocks:
-                from cloud_tpu.serving.prefix_cache import PrefixCacheManager
-
-                self._prefix = PrefixCacheManager(
-                    cfg.prefix_cache_blocks, cfg.prefix_block_tokens,
-                    dram_blocks=cfg.prefix_dram_blocks,
-                    demote_fn=(
-                        self._demote_block if cfg.prefix_dram_blocks
-                        else None
-                    ),
-                    summary_ttl_s=cfg.prefix_summary_ttl_s,
-                )
-
-                def make_pool():
-                    return generation.init_prefix_pool(
-                        config, cfg.prefix_cache_blocks,
-                        cfg.prefix_block_tokens, rules=self.rules,
-                        mesh=self.mesh, kv_quant=cfg.kv_quant,
+                if self._prefix_pool is not None:
+                    self._prefix_pool = jax.device_put(
+                        self._prefix_pool, device
                     )
-
-                # The block pool shards by head exactly like the slot
-                # grid (same pytree structure), so pool<->slot copies
-                # stay chip-local — no resharding on the hit path.
-                self._prefix_pool = (
-                    jax.jit(make_pool)() if self._slice_chips > 1
-                    else make_pool()
-                )
-            # Engine device-state lives WITH the params: the init
-            # programs above land on the process default device, so on
-            # multi-device hosts (a fleet pinning one replica's params
-            # per device) the grid, slot state, and pool must be
-            # re-committed to the params' device or the first dispatch
-            # raises on mixed committed placements.
-            if self.mesh is None:
-                device = self._params_device()
-                if device is not None:
-                    self._grid_cache = jax.device_put(
-                        self._grid_cache, device
-                    )
-                    self._slot_state = jax.device_put(
-                        self._slot_state, device
-                    )
-                    if self._prefix_pool is not None:
-                        self._prefix_pool = jax.device_put(
-                            self._prefix_pool, device
-                        )
-            #: KV accounting: the rows and bytes the slot grid and the
-            #: prefix pool reserve (constant), and the rows that held a
-            #: live token at the last chunk dispatch (plain int swap:
-            #: the scheduler writes, ``stats()``/``health()`` read).
-            leaves = jax.tree_util.tree_leaves
-            self._kv_rows_reserved = cfg.num_slots * self._max_len + (
-                cfg.prefix_cache_blocks * cfg.prefix_block_tokens
-                if self._prefix is not None else 0
+        #: KV accounting: the rows and bytes the slot grid and the
+        #: prefix pool reserve (constant), and the rows that held a
+        #: live token at the last chunk dispatch (plain int swap:
+        #: the scheduler writes, ``stats()``/``health()`` read).
+        leaves = jax.tree_util.tree_leaves
+        self._kv_rows_reserved = cfg.num_slots * self._max_len + (
+            cfg.prefix_cache_blocks * cfg.prefix_block_tokens
+            if self._prefix is not None else 0
+        )
+        state_leaves = [
+            self._grid_cache[name] for name in generation.STATE_LEAVES
+            if name in self._grid_cache
+        ]
+        self._state_bytes_reserved = sum(x.nbytes for x in state_leaves)
+        self._kv_bytes_reserved = sum(
+            x.nbytes for x in
+            leaves(self._grid_cache) + leaves(self._prefix_pool)
+        ) - self._state_bytes_reserved
+        self._kv_rows_in_use = 0
+        #: A recurrent state's rows: one a slot a layer, in use while
+        #: the slot decodes (0 for a model without a state).
+        self._state_rows_reserved = (
+            cfg.num_slots * config.num_layers if state_leaves else 0
+        )
+        self._state_rows_in_use = 0
+        #: Block-table attention (``decode_kernel != "xla"``): the
+        #: slot grid's attention reads KV through a per-slot block
+        #: table — page p of a row resolves to a prefix-pool block
+        #: (entry >= 0) or the slot row itself (-1) — so a prefix
+        #: hit ATTACHES pool blocks instead of dispatching the copy
+        #: program.
+        #: Page size is ``prefix_block_tokens`` (hits are whole
+        #: blocks, so attached pages align by construction).
+        #: ``_block_table`` is the table's host-side
+        #: [num_slots, n_pages] mirror (None on the XLA path).
+        self._paged = cfg.decode_kernel != "xla"
+        self._block_table = None
+        #: "pallas" forces the kernel; "auto" defers to the op's
+        #: measured-crossover dispatch (kernel on eligible TPU
+        #: shapes, jnp paged reference elsewhere).
+        self._paged_use_pallas = (
+            True if cfg.decode_kernel == "pallas" else None
+        )
+        if self._paged:
+            n_pages = -(-self._max_len // cfg.prefix_block_tokens)
+            self._block_table = np.full(
+                (cfg.num_slots, n_pages), -1, np.int32
             )
-            state_leaves = [
-                self._grid_cache[name] for name in generation.STATE_LEAVES
-                if name in self._grid_cache
-            ]
-            self._state_bytes_reserved = sum(x.nbytes for x in state_leaves)
-            self._kv_bytes_reserved = sum(
-                x.nbytes for x in
-                leaves(self._grid_cache) + leaves(self._prefix_pool)
-            ) - self._state_bytes_reserved
-            self._kv_rows_in_use = 0
-            #: A recurrent state's rows: one a slot a layer, in use while
-            #: the slot decodes (0 for a model without a state).
-            self._state_rows_reserved = (
-                cfg.num_slots * config.num_layers if state_leaves else 0
-            )
-            self._state_rows_in_use = 0
-            #: Block-table attention (``decode_kernel != "xla"``): the
-            #: slot grid's attention reads KV through a per-slot block
-            #: table — page p of a row resolves to a prefix-pool block
-            #: (entry >= 0) or the slot row itself (-1) — so a prefix
-            #: hit ATTACHES pool blocks instead of dispatching the copy
-            #: program.
-            #: Page size is ``prefix_block_tokens`` (hits are whole
-            #: blocks, so attached pages align by construction).
-            self._paged = cfg.decode_kernel != "xla"
-            #: "pallas" forces the kernel; "auto" defers to the op's
-            #: measured-crossover dispatch (kernel on eligible TPU
-            #: shapes, jnp paged reference elsewhere).
-            self._paged_use_pallas = (
-                True if cfg.decode_kernel == "pallas" else None
-            )
-            if self._paged:
-                n_pages = -(-self._max_len // cfg.prefix_block_tokens)
-                self._block_table = np.full(
-                    (cfg.num_slots, n_pages), -1, np.int32
-                )
-            self._decode_read_page = self._decode_page()
-            #: Python-trace counters: the retrace guard for "one chunk
-            #: compile serves the whole run" (tests/helpers/retrace_guard
-            #: idiom — the wrapped body executes only while tracing).
-            self._chunk_traces = 0
-            self._insert_traces = 0
-            self._prefill_chunk_traces = 0
-            self._finalize_traces = 0
-            self._copy_traces = 0
-            self._save_traces = 0
-            self._download_traces = 0
-            self._swapin_traces = 0
-            #: The DRAM-tier block movers (built on demand; one compile
-            #: each — block index and payload shapes are static).
-            self._download_step = None
-            self._swapin_step = None
-            self._upload_traces = 0
-            self._upload_step = None
-            self._export_traces = 0
-            self._export_step = None
-            self._draft_traces = 0
-            self._verify_traces = 0
-            self._draft_prefill_traces = 0
-            # Donating the grid through each dispatch keeps the cache
-            # update in place; CPU ignores donation with a warning, so
-            # only ask for it where the backend honors it.
-            self._donate = jax.default_backend() != "cpu"
-            #: Effective pipelining depth: the config's, unless the
-            #: CLOUD_TPU_PIPELINE=0 kill switch forces the synchronous
-            #: loop (same env idiom as CLOUD_TPU_TRACE).  Resolved once
-            #: at build — flipping the env mid-run does nothing.
-            self._pipe_depth = cfg.pipeline_depth
-            if os.environ.get("CLOUD_TPU_PIPELINE", "1") == "0":
-                self._pipe_depth = 1
-            #: Dispatched-but-undrained chunks, oldest first
-            #: (scheduler-thread only).  Empty at every pass boundary
-            #: at depth 1 — the synchronous loop never grows it, so
-            #: the default path stays byte-identical.
-            self._inflight: collections.deque = collections.deque()
-            #: Rolling dispatch→dispatch host gaps (ms) — the bubble
-            #: the pipeline exists to hide.  Tracked at every depth
-            #: (host-side bookkeeping only; no spans at depth 1) so
-            #: bench probes can compare p50/p99 across arms.
-            self._dispatch_gaps: collections.deque = collections.deque(
-                maxlen=512
-            )
-            self._last_chunk_dispatch_end: Optional[float] = None
-            self._chunk_step = self._make_chunk_step()
-            #: Speculative decoding (None unless ServeConfig.draft):
-            #: the draft model's own slot cache + its program cells and
-            #: a rolling per-dispatch (accepted, proposed) window for
-            #: health()'s acceptance rate.
-            self._spec = cfg.draft is not None
-            self._draft_cache = None
-            self._draft_step = None
-            self._verify_step = None
-            self._draft_prefill_cells: Dict[int, "compile_cache.AotStep"] = {}
-            self._accept_window: collections.deque = collections.deque(
-                maxlen=64
-            )
-            if self._spec:
-                self._init_draft()
+        self._decode_read_page = self._decode_page()
+        #: Python-trace counters: the retrace guard for "one chunk
+        #: compile serves the whole run" (tests/helpers/retrace_guard
+        #: idiom — the wrapped body executes only while tracing).
+        self._chunk_traces = 0
+        self._insert_traces = 0
+        self._prefill_chunk_traces = 0
+        self._finalize_traces = 0
+        self._copy_traces = 0
+        self._save_traces = 0
+        self._download_traces = 0
+        self._swapin_traces = 0
+        #: The DRAM-tier block movers (built on demand; one compile
+        #: each — block index and payload shapes are static).
+        self._download_step = None
+        self._swapin_step = None
+        self._upload_traces = 0
+        self._upload_step = None
+        self._export_traces = 0
+        self._export_step = None
+        self._draft_traces = 0
+        self._verify_traces = 0
+        self._draft_prefill_traces = 0
+        # Donating the grid through each dispatch keeps the cache
+        # update in place; CPU ignores donation with a warning, so
+        # only ask for it where the backend honors it.
+        self._donate = jax.default_backend() != "cpu"
+        #: Effective pipelining depth: the config's, unless the
+        #: CLOUD_TPU_PIPELINE=0 kill switch forces the synchronous
+        #: loop (same env idiom as CLOUD_TPU_TRACE).  Resolved once
+        #: at build — flipping the env mid-run does nothing.
+        self._pipe_depth = cfg.pipeline_depth
+        if os.environ.get("CLOUD_TPU_PIPELINE", "1") == "0":
+            self._pipe_depth = 1
+        #: Dispatched-but-undrained chunks, oldest first
+        #: (scheduler-thread only).  Empty at every pass boundary
+        #: at depth 1 — the synchronous loop never grows it, so
+        #: the default path stays byte-identical.
+        self._inflight: collections.deque = collections.deque()
+        #: Rolling dispatch→dispatch host gaps (ms) — the bubble
+        #: the pipeline exists to hide.  Tracked at every depth
+        #: (host-side bookkeeping only; no spans at depth 1) so
+        #: bench probes can compare p50/p99 across arms.
+        self._dispatch_gaps: collections.deque = collections.deque(
+            maxlen=512
+        )
+        self._last_chunk_dispatch_end: Optional[float] = None
+        self._chunk_step = self._make_chunk_step()
+        #: Speculative decoding (None unless ServeConfig.draft):
+        #: the draft model's own slot cache + its program cells and
+        #: a rolling per-dispatch (accepted, proposed) window for
+        #: health()'s acceptance rate.
+        self._spec = cfg.draft is not None
+        self._draft_cache = None
+        self._draft_step = None
+        self._verify_step = None
+        self._draft_prefill_cells: Dict[int, "compile_cache.AotStep"] = {}
+        self._accept_window: collections.deque = collections.deque(
+            maxlen=64
+        )
+        if self._spec:
+            self._init_draft()
 
         if self.serve_config.warmup:
             self._start_warmup()
@@ -1345,9 +1214,8 @@ class ServingEngine:
     def _kv_bytes_estimate(self, model_config=None,
                            include_prefix: bool = True) -> int:
         """Total KV bytes the engine will allocate (slot grid + prefix
-        pool for the continuous scheduler, the largest batch cell
-        otherwise) — the planner's auto-layout input, an estimate, not
-        an allocator.  ``model_config`` sizes a different model's cache
+        pool) — the planner's auto-layout input, an estimate, not an
+        allocator.  ``model_config`` sizes a different model's cache
         over the same grid (the speculative draft, which gets no
         prefix pool — ``include_prefix=False``)."""
         cfg = self.serve_config
@@ -1359,14 +1227,9 @@ class ServingEngine:
             c.head_dim * itemsize + (4 if cfg.kv_quant else 0)
         )
         max_len = cfg.prompt_buckets[-1] + cfg.max_new_tokens
-        if cfg.scheduler == "continuous":
-            positions = cfg.num_slots * max_len
-            if include_prefix:
-                positions += (
-                    cfg.prefix_cache_blocks * cfg.prefix_block_tokens
-                )
-        else:
-            positions = cfg.batch_buckets[-1] * max_len
+        positions = cfg.num_slots * max_len
+        if include_prefix:
+            positions += cfg.prefix_cache_blocks * cfg.prefix_block_tokens
         return per_pos * positions
 
     def _shard_params(self) -> None:
@@ -1556,19 +1419,17 @@ class ServingEngine:
         """Adopt a disaggregated-serving role (``"prefill"``,
         ``"decode"``, or ``"both"``): advertised through ``health()``/
         ``stats()`` so the fleet router can steer legs, and validated
-        against the same scheduler requirements as the ctor knob.
+        against the same requirement as the ctor knob.
         Duck-typed like :meth:`set_trace_lane` — the fleet replica
         calls it via ``hasattr``.  Thread-safe (str swap)."""
         if role not in ("prefill", "decode", "both"):
             raise ValueError(
                 f"role must be 'prefill', 'decode' or 'both', got {role!r}"
             )
-        if role != "both" and (
-                not self._continuous
-                or not self.serve_config.prefix_cache_blocks):
+        if role != "both" and not self.serve_config.prefix_cache_blocks:
             raise ValueError(
-                "role= (disaggregated serving) needs the continuous "
-                "scheduler and prefix_cache_blocks > 0 — the KV handoff "
+                "role= (disaggregated serving) needs "
+                "prefix_cache_blocks > 0 — the KV handoff "
                 "exports/imports prefix-pool blocks"
             )
         self._role = role
@@ -1673,10 +1534,9 @@ class ServingEngine:
         the lowest class first; without it the tag is validated and
         recorded but never reorders anything (FIFO — byte-identical).
         ``stream=True`` returns a :class:`~cloud_tpu.serving.qos.
-        TokenStream` fed per emitted token from the chunk-commit path
-        (the batch scheduler delivers at completion); iterating yields
-        the exact tokens the final result row carries.  ``on_token`` is
-        the cross-layer per-token hook the fleet uses to forward a
+        TokenStream` fed per emitted token from the chunk-commit path;
+        iterating yields the exact tokens the final result row carries.
+        ``on_token`` is the cross-layer per-token hook the fleet uses to forward a
         stream — called as ``(index, token)`` on the scheduler thread.
 
         ``trace`` carries the caller's
@@ -1696,19 +1556,16 @@ class ServingEngine:
         admission, so the request's normal prefix lookup hits them
         (ATTACH when paged, copy program otherwise) and decode runs
         token-identical to a colocated ``generate()``.  Both require
-        the continuous scheduler with a prefix cache; both default off
-        — the engine stays byte-identical without them.
+        a prefix cache; both default off — the engine stays
+        byte-identical without them.
         """
         cfg = self.serve_config
         if deadline_s is not None and deadline_s <= 0:
             raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
-        if (handoff_export or handoff is not None) and (
-                not self._continuous
-                or getattr(self, "_prefix", None) is None):
+        if (handoff_export or handoff is not None) and self._prefix is None:
             raise ValueError(
-                "handoff_export/handoff need the continuous scheduler "
-                "and prefix_cache_blocks > 0 — the KV handoff moves "
-                "prefix-pool blocks"
+                "handoff_export/handoff need prefix_cache_blocks > 0 — "
+                "the KV handoff moves prefix-pool blocks"
             )
         if self._qos is not None:
             priority = self._qos.resolve_priority(priority)
@@ -2211,9 +2068,9 @@ class ServingEngine:
         metrics.counter_inc("serve/prefix_swapin_blocks", len(plan))
 
     def _start_warmup(self) -> None:
-        """Queue AOT compiles for the whole grid on the compile-ahead
-        worker (one background thread, in grid order — smallest programs
-        first so early traffic warms soonest)."""
+        """Queue AOT compiles for every program the slot grid will
+        dispatch on the compile-ahead worker (one background thread,
+        smallest programs first so early traffic warms soonest)."""
         import jax
 
         from cloud_tpu.training import compile_cache
@@ -2222,170 +2079,138 @@ class ServingEngine:
         params_avals = compile_cache.abstract_state(self.params)
         context = compile_cache.context_key(mesh=self.mesh, rules=self.rules)
         rng_aval = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
-        if self._continuous:
-            cache_avals = compile_cache.abstract_state(self._grid_cache)
-            state_avals = compile_cache.abstract_state(self._slot_state)
-            scalar = jax.ShapeDtypeStruct((), np.int32)
-            use_chunks = cfg.prefill_chunk_tokens is not None
-            # Paged cells take the (pool,) table as extra operands —
-            # warm with matching avals so the AOT executable is the one
-            # traffic dispatches.
-            paged_avals: tuple = ()
-            if self._paged:
-                table_aval = jax.ShapeDtypeStruct(
-                    self._block_table.shape, np.int32
+        cache_avals = compile_cache.abstract_state(self._grid_cache)
+        state_avals = compile_cache.abstract_state(self._slot_state)
+        scalar = jax.ShapeDtypeStruct((), np.int32)
+        use_chunks = cfg.prefill_chunk_tokens is not None
+        # Paged cells take the (pool,) table as extra operands —
+        # warm with matching avals so the AOT executable is the one
+        # traffic dispatches.
+        paged_avals: tuple = ()
+        if self._paged:
+            table_aval = jax.ShapeDtypeStruct(
+                self._block_table.shape, np.int32
+            )
+            if self._prefix_pool is not None:
+                paged_avals = (
+                    compile_cache.abstract_state(self._prefix_pool),
+                    table_aval,
                 )
-                if self._prefix_pool is not None:
-                    paged_avals = (
-                        compile_cache.abstract_state(self._prefix_pool),
-                        table_aval,
-                    )
-                else:
-                    paged_avals = (table_aval,)
-            jobs = []
-            if not use_chunks:
-                # One-shot inserts serve cold prefills (and with
-                # chunking on they are never dispatched — skip them).
-                for bucket_len in cfg.prompt_buckets:
-                    cell = self._insert_cell(bucket_len)
-                    tok_aval = jax.ShapeDtypeStruct(
-                        (1, bucket_len), np.int32
-                    )
-                    jobs.append((cell, (
-                        params_avals, cache_avals, state_avals, tok_aval,
-                        scalar, scalar, scalar, rng_aval,
-                    ), context))
-            # Chunked-prefill widths: THE chunk width when chunking is
-            # on; the per-bucket suffix widths when only the prefix
-            # cache drives partial prefills.
-            if use_chunks:
-                widths = (cfg.prefill_chunk_tokens,)
-            elif self._prefix is not None:
-                widths = cfg.prompt_buckets
             else:
-                widths = ()
-            for width in widths:
-                cell = self._chunk_prefill_cell(width)
-                tok_aval = jax.ShapeDtypeStruct((1, width), np.int32)
-                jobs.append((cell, (
-                    params_avals, cache_avals, tok_aval, scalar, scalar,
-                    scalar, *paged_avals,
-                ), context))
-            if widths:
-                logits_aval = jax.ShapeDtypeStruct(
-                    (1, self.config.vocab_size), np.float32
-                )
-                jobs.append((self._finalize_cell(), (
-                    state_avals, logits_aval, scalar, scalar, scalar,
-                    rng_aval,
-                ), context))
-            if self._prefix is not None:
-                pool_avals = compile_cache.abstract_state(self._prefix_pool)
-                for bucket_len in cfg.prompt_buckets:
-                    n_blocks = bucket_len // cfg.prefix_block_tokens
-                    if n_blocks < 1:
-                        continue
-                    ids_aval = jax.ShapeDtypeStruct((n_blocks,), np.int32)
-                    if not self._paged:
-                        # The paged path NEVER dispatches the copy
-                        # program (hits attach); warming it would both
-                        # waste a compile and advance _copy_traces,
-                        # breaking the zero-copy assertion.
-                        jobs.append((self._copy_cell(bucket_len), (
-                            cache_avals, pool_avals, ids_aval, scalar,
-                        ), context))
-                    jobs.append((self._save_cell(bucket_len), (
-                        pool_avals, cache_avals, scalar, ids_aval,
-                    ), context))
-                if cfg.prefix_dram_blocks:
-                    # The tier's block movers: one executable each.
-                    payload_avals = {
-                        name: jax.ShapeDtypeStruct(
-                            (leaf.shape[0],) + leaf.shape[2:], leaf.dtype
-                        )
-                        for name, leaf in self._prefix_pool.items()
-                    }
-                    jobs.append((self._download_cell(), (
-                        pool_avals, scalar,
-                    ), context))
-                    jobs.append((self._swapin_cell(), (
-                        pool_avals, payload_avals, scalar,
-                    ), context))
-            if self._spec:
-                # Speculation replaces the decode chunk wholesale: warm
-                # the draft-prefill/draft/verify trio instead (the
-                # never-dispatched chunk program is skipped, like the
-                # insert programs under chunked prefill).
-                draft_params_avals = compile_cache.abstract_state(
-                    self._draft_params
-                )
-                draft_cache_avals = compile_cache.abstract_state(
-                    self._draft_cache
-                )
-                for bucket_len in cfg.prompt_buckets:
-                    tok_aval = jax.ShapeDtypeStruct(
-                        (1, bucket_len), np.int32
-                    )
-                    jobs.append((self._draft_prefill_cell(bucket_len), (
-                        draft_params_avals, draft_cache_avals, tok_aval,
-                        scalar, scalar,
-                    ), context))
-                jobs.append((self._draft_step, (
-                    draft_params_avals, draft_cache_avals, state_avals,
-                ), context))
-                window_aval = jax.ShapeDtypeStruct(
-                    (cfg.num_slots, cfg.draft.spec_k), np.int32
-                )
-                jobs.append((self._verify_step, (
-                    params_avals, cache_avals, state_avals, window_aval,
-                    *paged_avals,
-                ), context))
-            else:
-                jobs.append((self._chunk_step, (
-                    params_avals, cache_avals, state_avals, rng_aval,
-                    *paged_avals,
-                ), context))
-            self._warmup_plan = compile_cache.start_compile_ahead(jobs)
-            return
+                paged_avals = (table_aval,)
         jobs = []
-        for bucket_len in cfg.prompt_buckets:
-            for batch_size in cfg.batch_buckets:
-                cell = self._cell(bucket_len, batch_size)
+        if not use_chunks:
+            # One-shot inserts serve cold prefills (and with
+            # chunking on they are never dispatched — skip them).
+            for bucket_len in cfg.prompt_buckets:
+                cell = self._insert_cell(bucket_len)
                 tok_aval = jax.ShapeDtypeStruct(
-                    (batch_size, bucket_len), np.int32
+                    (1, bucket_len), np.int32
                 )
-                lens_aval = jax.ShapeDtypeStruct((batch_size,), np.int32)
-                prefill_args = (params_avals, tok_aval, lens_aval)
-                jobs.append((cell.prefill, prefill_args, context))
-
-                def decode_args(cell=cell, prefill_args=prefill_args):
-                    # Resolved on the worker right before the decode
-                    # compile: the cache/logits avals come from an
-                    # eval_shape of the prefill program (pure tracing).
-                    cache_aval, logits_aval = jax.eval_shape(
-                        cell.prefill.jitted, *prefill_args
+                jobs.append((cell, (
+                    params_avals, cache_avals, state_avals, tok_aval,
+                    scalar, scalar, scalar, rng_aval,
+                ), context))
+        # Chunked-prefill widths: THE chunk width when chunking is
+        # on; the per-bucket suffix widths when only the prefix
+        # cache drives partial prefills.
+        if use_chunks:
+            widths = (cfg.prefill_chunk_tokens,)
+        elif self._prefix is not None:
+            widths = cfg.prompt_buckets
+        else:
+            widths = ()
+        for width in widths:
+            cell = self._chunk_prefill_cell(width)
+            tok_aval = jax.ShapeDtypeStruct((1, width), np.int32)
+            jobs.append((cell, (
+                params_avals, cache_avals, tok_aval, scalar, scalar,
+                scalar, *paged_avals,
+            ), context))
+        if widths:
+            logits_aval = jax.ShapeDtypeStruct(
+                (1, self.config.vocab_size), np.float32
+            )
+            jobs.append((self._finalize_cell(), (
+                state_avals, logits_aval, scalar, scalar, scalar,
+                rng_aval,
+            ), context))
+        if self._prefix is not None:
+            pool_avals = compile_cache.abstract_state(self._prefix_pool)
+            for bucket_len in cfg.prompt_buckets:
+                n_blocks = bucket_len // cfg.prefix_block_tokens
+                if n_blocks < 1:
+                    continue
+                ids_aval = jax.ShapeDtypeStruct((n_blocks,), np.int32)
+                if not self._paged:
+                    # The paged path NEVER dispatches the copy
+                    # program (hits attach); warming it would both
+                    # waste a compile and advance _copy_traces,
+                    # breaking the zero-copy assertion.
+                    jobs.append((self._copy_cell(bucket_len), (
+                        cache_avals, pool_avals, ids_aval, scalar,
+                    ), context))
+                jobs.append((self._save_cell(bucket_len), (
+                    pool_avals, cache_avals, scalar, ids_aval,
+                ), context))
+            if cfg.prefix_dram_blocks:
+                # The tier's block movers: one executable each.
+                payload_avals = {
+                    name: jax.ShapeDtypeStruct(
+                        (leaf.shape[0],) + leaf.shape[2:], leaf.dtype
                     )
-                    return (params_avals, cache_aval, logits_aval,
-                            prefill_args[2], rng_aval)
-
-                jobs.append((cell.decode, decode_args, context))
+                    for name, leaf in self._prefix_pool.items()
+                }
+                jobs.append((self._download_cell(), (
+                    pool_avals, scalar,
+                ), context))
+                jobs.append((self._swapin_cell(), (
+                    pool_avals, payload_avals, scalar,
+                ), context))
+        if self._spec:
+            # Speculation replaces the decode chunk wholesale: warm
+            # the draft-prefill/draft/verify trio instead (the
+            # never-dispatched chunk program is skipped, like the
+            # insert programs under chunked prefill).
+            draft_params_avals = compile_cache.abstract_state(
+                self._draft_params
+            )
+            draft_cache_avals = compile_cache.abstract_state(
+                self._draft_cache
+            )
+            for bucket_len in cfg.prompt_buckets:
+                tok_aval = jax.ShapeDtypeStruct(
+                    (1, bucket_len), np.int32
+                )
+                jobs.append((self._draft_prefill_cell(bucket_len), (
+                    draft_params_avals, draft_cache_avals, tok_aval,
+                    scalar, scalar,
+                ), context))
+            jobs.append((self._draft_step, (
+                draft_params_avals, draft_cache_avals, state_avals,
+            ), context))
+            window_aval = jax.ShapeDtypeStruct(
+                (cfg.num_slots, cfg.draft.spec_k), np.int32
+            )
+            jobs.append((self._verify_step, (
+                params_avals, cache_avals, state_avals, window_aval,
+                *paged_avals,
+            ), context))
+        else:
+            jobs.append((self._chunk_step, (
+                params_avals, cache_avals, state_avals, rng_aval,
+                *paged_avals,
+            ), context))
         self._warmup_plan = compile_cache.start_compile_ahead(jobs)
 
     def wait_ready(self, timeout: Optional[float] = None) -> None:
-        """Block until the warmup grid has finished compiling (no-op
+        """Block until the warmup plan has finished compiling (no-op
         without ``warmup=True``; compile failures were logged and those
         cells fall back to jit — see ``compile_cache.CompileAhead``)."""
         if self._warmup_plan is not None:
             self._warmup_plan.wait(timeout=timeout)
 
     # -- scheduler ---------------------------------------------------------
-
-    def _cell(self, bucket_len: int, batch_size: int) -> _Cell:
-        key = (bucket_len, batch_size)
-        cell = self._cells.get(key)
-        if cell is None:
-            cell = self._cells[key] = _Cell(self, bucket_len, batch_size)
-        return cell
 
     def _fail_pending_locked(self, exc: BaseException) -> None:
         failed = 0
@@ -2406,8 +2231,8 @@ class ServingEngine:
         """Drop queued requests whose deadline passed (caller holds the
         lock).  Runs at every scheduling decision, so a request is shed
         at the first opportunity AFTER expiry — before it can claim a
-        slot or a batch row — with a typed failure the caller can
-        distinguish from a crash.  Returns the shed count."""
+        slot — with a typed failure the caller can distinguish from a
+        crash.  Returns the shed count."""
         shed = 0
         shed_classes: List[str] = []
         for queue_ in self._pending.values():
@@ -2462,8 +2287,8 @@ class ServingEngine:
             return 0
         waiting_at_trigger = self._waiting
         excess = waiting_at_trigger - self._qos.brownout_queue_depth
-        # ONE shed-order definition for both schedulers (qos_lib owns
-        # the policy; this method owns the engine's queue mechanics).
+        # qos_lib owns the shed order; this method owns the engine's
+        # queue mechanics.
         victims = qos_lib.brownout_victims(
             (r for queue_ in self._pending.values() for r in queue_),
             excess, self._qos,
@@ -2519,7 +2344,7 @@ class ServingEngine:
 
         Spanned as ``serve/launch``: the host's time to enqueue the
         program(s) ``fn`` dispatches (where ``fn`` itself waits for its
-        result, as the batch scheduler's do, the wait is inside).
+        result, the wait is inside).
         """
         timeout = self.serve_config.dispatch_timeout_s
         self._last_dispatch_ts = time.perf_counter()
@@ -2569,68 +2394,9 @@ class ServingEngine:
             self._rng, key = self._split_key(self._rng)
         return key
 
-    def _pop_batch_locked(self, now: float) -> Optional[List[_Request]]:
-        """The batch-formation policy (caller holds the lock).
-
-        Priority: (1) the bucket whose HEAD request has waited past
-        ``flush_deadline_s``, oldest head first — the deadline is a real
-        bound, never preempted by other buckets' saturation (under
-        sustained traffic the saturated bucket's own head is expired
-        too, so oldest-first degenerates to FIFO across buckets and a
-        minority bucket cannot starve); (2) any bucket with a full
-        max-batch — no deadline pressure, so take the occupancy win;
-        (3) when draining a closed engine, anything left.  Whichever
-        bucket wins, up to a full max-batch is taken from it.
-        """
-        self._shed_expired_locked(now)
-        max_batch = self.serve_config.batch_buckets[-1]
-        chosen = None
-        for queue_ in self._pending.values():
-            if not queue_:
-                continue
-            head = queue_[0]
-            if now - head.submitted >= self.serve_config.flush_deadline_s:
-                if chosen is None or head.submitted < chosen[0].submitted:
-                    chosen = queue_
-        if chosen is None:
-            for queue_ in self._pending.values():
-                if len(queue_) >= max_batch:
-                    chosen = queue_
-                    break
-        if chosen is None and self._closed and self._draining:
-            chosen = next(
-                (q for q in self._pending.values() if q), None
-            )
-        if chosen is None:
-            return None
-        batch = []
-        while chosen and len(batch) < max_batch:
-            batch.append(chosen.popleft())
-        return batch
-
-    def _earliest_deadline_locked(self) -> Optional[float]:
-        """Next instant the batch scheduler must wake: the earliest
-        flush deadline OR the earliest request ``deadline_s`` expiry —
-        a lone request must be shed when ITS deadline passes, not when
-        the (possibly much later) flush deadline happens to wake the
-        loop."""
-        flush = self.serve_config.flush_deadline_s
-        deadlines = []
-        for queue_ in self._pending.values():
-            if not queue_:
-                continue
-            deadlines.append(queue_[0].submitted + flush)
-            deadlines.extend(
-                r.deadline for r in queue_ if r.deadline is not None
-            )
-        return min(deadlines) if deadlines else None
-
     def _scheduler_loop(self) -> None:
         try:
-            if self._continuous:
-                self._continuous_loop()
-            else:
-                self._batch_loop()
+            self._continuous_loop()
         except BaseException as exc:  # noqa: BLE001 — scheduler must not
             # die silently: fail everything still queued and in flight,
             # and refuse new work.
@@ -2641,51 +2407,8 @@ class ServingEngine:
                 self._closed = True
                 self._fail_pending_locked(exc)
                 self._cond.notify_all()
-            if self._continuous:
-                self._dispose_inflight()
-                self._fail_live_slots(exc)
-
-    def _batch_loop(self) -> None:
-        while True:
-            if self._trace_lane is not None:
-                tracing.set_thread_lane(self._trace_lane)
-            with self._cond:
-                while True:
-                    now = time.perf_counter()
-                    batch = self._pop_batch_locked(now)
-                    if batch is not None:
-                        self._waiting -= len(batch)
-                        self._cond.notify_all()  # admission space freed
-                        break
-                    if self._closed:
-                        return
-                    deadline = self._earliest_deadline_locked()
-                    timeout = (
-                        None if deadline is None
-                        else max(deadline - now, 1e-4)
-                    )
-                    self._cond.wait(timeout)
-            self._inflight_rows = len(batch)
-            try:
-                self._dispatch(batch)
-            except BaseException as exc:  # noqa: BLE001 — per-batch
-                logger.exception("serving dispatch failed")
-                metrics.counter_inc("serve/batch_errors")
-                with self._stats_lock:
-                    self._stats["failed"] += len(batch)
-                for request in batch:
-                    try:
-                        request.future.set_exception(exc)
-                    except InvalidStateError:  # pragma: no cover
-                        pass
-                if isinstance(exc, DispatchTimeoutError):
-                    # A wedged device program is not a per-batch blip:
-                    # the next dispatch would hang the same way.  Take
-                    # the engine down (crash handler fails the queue and
-                    # leaves health() unhealthy).
-                    raise
-            finally:
-                self._inflight_rows = 0
+            self._dispose_inflight()
+            self._fail_live_slots(exc)
 
     # -- continuous scheduler ----------------------------------------------
 
@@ -3410,7 +3133,7 @@ class ServingEngine:
         path pays one attribute check).  Capped at the request's budget
         so the streamed view is exactly the final result row's prefix;
         the future's done-callback closes the stream and back-fills
-        anything this path never saw (batch scheduler, crash paths)."""
+        anything this path never saw (crash paths)."""
         request = entry.request
         if request.stream is None and request.on_token is None:
             return
@@ -3949,10 +3672,10 @@ class ServingEngine:
     def _record_request_spans(self, request: _Request, result: ServeResult,
                               first: float, done: float, *, slot: int,
                               passes: int) -> None:
-        """The two spans that close a served request, on both
-        schedulers: ``serve/request`` (submit to the last token, with
-        where its time went) and ``serve/ttft`` (submit's own stamp to
-        the first token on the host: TTFT as the engine sees it)."""
+        """The two spans that close a served request: ``serve/request``
+        (submit to the last token, with where its time went) and
+        ``serve/ttft`` (submit's own stamp to the first token on the
+        host: TTFT as the engine sees it)."""
         if not tracing.enabled():
             return
         attrs = {
@@ -3976,105 +3699,6 @@ class ServingEngine:
             if entry is not None:
                 self._retire_slot(slot, exc=exc)
 
-    def _dispatch(self, batch: List[_Request]) -> None:
-        import jax
-
-        cfg = self.serve_config
-        bucket_len = batch[0].bucket_len
-        n = len(batch)
-        batch_size = next(b for b in cfg.batch_buckets if b >= n)
-        form_start = time.perf_counter()
-        for request in batch:
-            request.admitted = form_start
-            tracing.record_span(
-                "serve/queue_wait", request.submitted, form_start,
-                **_trace_attrs(request, bucket=bucket_len),
-            )
-        with tracing.span("serve/batch_form", bucket=bucket_len,
-                          rows=n, batch=batch_size):
-            tokens = np.zeros((batch_size, bucket_len), np.int32)
-            lens = np.ones((batch_size,), np.int32)
-            for i, request in enumerate(batch):
-                tokens[i, :request.prompt_len] = request.prompt
-                lens[i] = request.prompt_len
-        cell = self._cell(bucket_len, batch_size)
-        batch_rng = self._split_rng()
-
-        def prefill():
-            faults.fault_point("serve.prefill")
-            cache, logits0 = cell.prefill(self.params, tokens, lens)
-            jax.block_until_ready(logits0)
-            return cache, logits0
-
-        with tracing.span("serve/prefill", bucket=bucket_len,
-                          batch=batch_size):
-            cache, logits0 = self._supervised("serve/prefill", prefill)
-
-        def decode():
-            faults.fault_point("serve.decode")
-            out = cell.decode(self.params, cache, logits0, lens, batch_rng)
-            return self._to_host(
-                "batch_tokens", out["tokens"], out["num_generated"]
-            )
-
-        with tracing.span("serve/decode", bucket=bucket_len,
-                          batch=batch_size):
-            out_tokens, out_nums = self._supervised("serve/decode", decode)
-        done = time.perf_counter()
-
-        results = []
-        generated = 0
-        for i, request in enumerate(batch):
-            m = request.max_new_tokens
-            num = int(min(out_nums[i], m))
-            generated += num
-            result = ServeResult(
-                tokens=out_tokens[i, :m].copy(),
-                num_generated=num,
-                bucket_len=bucket_len,
-                batch_size=batch_size,
-                latency_seconds=done - request.submitted,
-                # Batch decode materializes tokens all at once: first
-                # token and last arrive together.
-                ttft_seconds=done - request.submitted,
-                trace_id=request.trace_id,
-            )
-            metrics.distribution_record(
-                "serve/latency_seconds", result.latency_seconds
-            )
-            # Batch decode hands every token over at once: one pass,
-            # no time between the first token and the last.
-            self._record_request_spans(request, result, done, done,
-                                       slot=i, passes=1)
-            results.append(result)
-
-        # Stats/metrics BEFORE the futures resolve: a caller waking from
-        # ``future.result()`` must see this batch already counted.
-        metrics.counter_inc("serve/batches")
-        metrics.counter_inc("serve/generated_tokens", generated)
-        metrics.gauge_set("serve/batch_occupancy", n / batch_size)
-        self._qps.add(done, n)
-        self._tokens_rate.add(done, generated)
-        with self._stats_lock:
-            self._stats["batches"] += 1
-            self._stats["slots"] += batch_size
-            self._stats["real_rows"] += n
-            self._stats["completed"] += n
-            self._stats["generated_tokens"] += generated
-            # Token-level occupancy, comparable with the continuous
-            # scheduler: every dispatched row owes max_new_tokens
-            # emission slots whether or not a real request (or a short
-            # one) occupies it.
-            self._stats["decode_slot_steps"] += (
-                batch_size * cfg.max_new_tokens
-            )
-            self._stats["useful_decode_tokens"] += generated
-        for request, result in zip(batch, results):
-            try:
-                request.future.set_result(result)
-            except InvalidStateError:  # pragma: no cover - cancelled
-                pass
-
     # -- introspection -----------------------------------------------------
 
     def health(self) -> dict:
@@ -4088,11 +3712,10 @@ class ServingEngine:
         ``reason`` — why ``healthy`` is False, else None.  Plus the
         load signal a fleet router reads per routing decision —
         ``queue_depth`` (waiting requests; same value as the legacy
-        ``waiting`` key), ``active_slots`` (OCCUPIED slots / batch rows
-        on the device right now — decoding or mid-prefill, both
-        schedulers), ``num_slots`` (the engine's slot capacity, so
-        occupancy is ``active/num``) — the
-        continuous grid's ``free_slots``, orphaned dispatch count, and
+        ``waiting`` key), ``active_slots`` (OCCUPIED slots on the device
+        right now — decoding or mid-prefill), ``num_slots`` (the
+        engine's slot capacity, so occupancy is ``active/num``) — the
+        grid's ``free_slots``, orphaned dispatch count, and
         seconds since the last device dispatch (None before the first)
         for staleness alerting.
         """
@@ -4100,9 +3723,7 @@ class ServingEngine:
             waiting = self._waiting
             closed = self._closed
             thread = self._thread
-            free_slots = (
-                len(self._free_slots) if self._continuous else None
-            )
+            free_slots = len(self._free_slots)
             class_backlog = self._class_backlog_locked()
         live = thread is not None and thread.is_alive()
         reason = self._unhealthy_reason
@@ -4119,10 +3740,7 @@ class ServingEngine:
             # by a mid-prefill task (chunked prefill can hold it for
             # many passes) is load a router must see — it left the
             # queue-depth count the moment it was popped.
-            "active_slots": (
-                self.serve_config.num_slots - free_slots
-                if self._continuous else self._inflight_rows
-            ),
+            "active_slots": self.serve_config.num_slots - free_slots,
             "num_slots": self.serve_config.num_slots,
             # The slice this replica spans: (tp, sp) and total chips.
             # (1, 1)/1 on the single-chip path — stable schema, so a
@@ -4149,22 +3767,18 @@ class ServingEngine:
             # schema, so the fleet's per-class backlog aggregation and
             # the autoscaler's class signal read without probing.
             "class_backlog": class_backlog,
-            # The armed decode-attention path ("xla" default; stable
-            # schema — the batch scheduler only ever reports "xla").
+            # The armed decode-attention path ("xla" default).
             "decode_kernel": self.serve_config.decode_kernel,
             # Disaggregated serving (stable schema — "both" and zeros
             # with roles off): the role the fleet router steers legs
             # by, plus the KV handoff counters.
             "role": self._role,
-            # Pipelined scheduling (stable schema — depth 1 / 0.0 on
-            # the batch scheduler and before the first two chunks):
+            # Pipelined scheduling (0.0 before the first two chunks):
             # the effective depth and the rolling mean host gap
             # between consecutive chunk dispatches, the bubble depth 2
             # exists to hide — a supervisor alert on it regressing is
             # the cheapest "pipelining stopped helping" signal.
-            "pipeline_depth": (
-                self._pipe_depth if self._continuous else 1
-            ),
+            "pipeline_depth": self._pipe_depth,
             "dispatch_gap_ms": self._dispatch_gap_mean(),
         }
         with self._stats_lock:
@@ -4178,8 +3792,7 @@ class ServingEngine:
             )
         snap.update(self._prefix_snapshot())
         snap.update(self._kv_snapshot())
-        if self._continuous:
-            snap["free_slots"] = free_slots
+        snap["free_slots"] = free_slots
         return snap
 
     def _class_backlog_locked(self) -> Dict[str, int]:
@@ -4201,10 +3814,7 @@ class ServingEngine:
         ``prefix_dram_blocks`` unset), and ``cached_prefixes`` is the
         router-facing hot-prefix summary ({} when off) the cost-model
         router scores candidates by."""
-        prefix = (
-            self._prefix.stats()
-            if self._continuous and self._prefix is not None else None
-        )
+        prefix = self._prefix.stats() if self._prefix is not None else None
         return {
             "prefix_cache_blocks": (
                 prefix["blocks_in_use"] if prefix else 0
@@ -4232,15 +3842,15 @@ class ServingEngine:
             ),
             "cached_prefixes": (
                 self._prefix.hot_prefixes()
-                if self._continuous and self._prefix is not None else {}
+                if self._prefix is not None else {}
             ),
         }
 
     def placement(self) -> dict:
         """Where the engine's state lives, read from array metadata only
         (no device access; any thread): the ids of the devices holding
-        the params and — continuous scheduler — the slot-grid KV, with the
-        KV leaves' global shapes and one device's shard of each."""
+        the params and the slot-grid KV, with the KV leaves' global
+        shapes and one device's shard of each."""
         import jax
 
         leaves = jax.tree_util.tree_leaves
@@ -4249,7 +3859,7 @@ class ServingEngine:
             return sorted({d.id for x in arrays
                            for d in x.sharding.device_set})
 
-        kv = leaves(self._grid_cache) if self._continuous else []
+        kv = leaves(self._grid_cache)
         return {
             "param_devices": device_ids(leaves(self.params)),
             "kv_devices": device_ids(kv),
@@ -4259,15 +3869,14 @@ class ServingEngine:
         }
 
     def stats(self) -> dict:
-        """Counters snapshot plus the two occupancy quotients.
+        """Counters snapshot plus the occupancy quotient.
 
-        ``mean_batch_occupancy`` — real rows / dispatched rows (the PR 4
-        batch-formation number; 0.0 under the continuous scheduler).
         ``mean_slot_occupancy`` — useful emitted tokens / dispatched
-        token slots, comparable ACROSS schedulers: it charges a batch
-        row for the full engine decode length and a continuous chunk
-        for every slot lane, so it is the number iteration-level
-        scheduling is judged by.
+        token slots: it charges a chunk for every slot lane, so it is
+        the number iteration-level scheduling is judged by.
+        ``batches``, ``slots``, ``real_rows`` and
+        ``mean_batch_occupancy`` are always zero: the slot grid forms no
+        batches, and the keys stay for the readers of the schema.
         """
         with self._stats_lock:
             snap = dict(self._stats)
@@ -4278,9 +3887,7 @@ class ServingEngine:
         snap["role"] = self._role
         with self._cond:
             snap["class_backlog"] = self._class_backlog_locked()
-        snap["mean_batch_occupancy"] = (
-            snap["real_rows"] / snap["slots"] if snap["slots"] else 0.0
-        )
+        snap["mean_batch_occupancy"] = 0.0
         snap["mean_slot_occupancy"] = (
             snap["useful_decode_tokens"] / snap["decode_slot_steps"]
             if snap["decode_slot_steps"] else 0.0
@@ -4293,13 +3900,9 @@ class ServingEngine:
             snap["spec_accepted"] / snap["spec_proposed"]
             if snap["spec_proposed"] else 0.0
         )
-        # Pipelined scheduling (stable schema — depth 1 / 0.0 on the
-        # batch scheduler): dispatch-gap percentiles over the rolling
-        # window, the per-arm numbers the serving_pipeline bench probe
-        # reports.
-        snap["pipeline_depth"] = (
-            self._pipe_depth if self._continuous else 1
-        )
+        # Pipelined scheduling: dispatch-gap percentiles over the
+        # rolling window.
+        snap["pipeline_depth"] = self._pipe_depth
         gaps = self._dispatch_gap_window()
         snap["dispatch_gap_ms_p50"] = (
             float(np.percentile(gaps, 50)) if gaps else 0.0
@@ -4316,11 +3919,7 @@ class ServingEngine:
         the slot grid and the prefix pool reserve (constant), and what
         held a live token at the last chunk dispatch (rows in use at
         the grid's bytes a row), and the same for a recurrent state's
-        rows.  Zeros on the batch scheduler, whose cache lives for one
-        batch."""
-        if not self._continuous:
-            return {"kv_bytes_reserved": 0, "kv_bytes_in_use": 0,
-                    "state_bytes_reserved": 0, "state_bytes_in_use": 0}
+        rows."""
         return {
             "kv_bytes_reserved": self._kv_bytes_reserved,
             "kv_bytes_in_use": (
@@ -4335,10 +3934,8 @@ class ServingEngine:
         }
 
     def _dispatch_gap_window(self) -> List[float]:
-        """Snapshot of the rolling dispatch-gap window (ms), empty on
-        the batch scheduler and before the first two chunk dispatches."""
-        if not self._continuous:
-            return []
+        """Snapshot of the rolling dispatch-gap window (ms), empty
+        before the first two chunk dispatches."""
         with self._stats_lock:
             return list(self._dispatch_gaps)
 
@@ -4350,13 +3947,13 @@ class ServingEngine:
 
     @property
     def chunk_traces(self) -> int:
-        """Python-trace count of the chunk program (continuous mode): 1
-        after any amount of traffic == one compile served the run."""
-        return self._chunk_traces if self._continuous else 0
+        """Python-trace count of the chunk program: 1 after any amount
+        of traffic == one compile served the run."""
+        return self._chunk_traces
 
     @property
     def verify_traces(self) -> int:
         """Python-trace count of the speculative verify program: 1
         after any amount of traffic == one compile served the run (0
         with ``draft=None``)."""
-        return self._verify_traces if self._continuous else 0
+        return self._verify_traces

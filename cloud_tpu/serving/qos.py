@@ -439,9 +439,8 @@ class TokenStream:
 
     def _complete_from_future(self, fut: Future) -> None:
         """Done-callback for the request's future: back-fill any tokens
-        the incremental path did not deliver (the batch scheduler
-        materializes them all at once), then close the stream with the
-        same result/exception."""
+        the incremental path did not deliver, then close the stream
+        with the same result/exception."""
         try:
             exc = fut.exception()
         except BaseException as cancelled:  # noqa: BLE001 - cancelled

@@ -23,13 +23,12 @@ engineered quantity instead of an accident, three ways:
   not Trainer-specific: ``cloud_tpu.serving`` warms its whole inference
   grid through the same registry + worker at engine start — one
   slot-insert executable per prompt bucket plus the single chunk-decode
-  program under the continuous scheduler, or prefill/decode executables
-  per (bucket_len, batch_size) cell under the batch scheduler.
+  program.
 * **Persistent cache** — :func:`maybe_enable_persistent_cache` turns on
   jax's on-disk compilation cache.  Where ``JAX_COMPILATION_CACHE_DIR``
   is set the cache was placed from outside and lives there — no code
   here points jax at another directory; otherwise the directory is the
-  caller's (``chip_smoke.py`` and ``bench.py`` pass the fixed
+  caller's (``chip_smoke.py`` passes the fixed
   ``<repo>/.jax_cache``) or ``CLOUD_TPU_COMPILE_CACHE=<dir>``, which
   ``core.deploy`` forwards into the container.  A fixed path matters:
   the directory is part of the cache key, so one that moves never hits.

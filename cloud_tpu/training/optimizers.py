@@ -1,7 +1,7 @@
 """Memory-efficient optimizer state: bf16-at-rest moments, f32 compute.
 
-Why this exists (BASELINE.md "BERT MFU ceiling"): the measured adamw cost
-at BERT-base b32xs128 is ~3.1 ms/step and is HBM-BOUND — ~110 M params x
+Why this exists (a round-3 ablation on a v5e, not re-measured since): the
+adamw cost at BERT-base b32xs128 read ~3.1 ms/step and is HBM-BOUND — ~110 M params x
 4 f32 buffers read+written (params, grads, mu, nu) ~ 3.5 GB of traffic
 per step on a chip whose step is otherwise MXU work.  Storing the moments
 in bfloat16 halves their share of that traffic; the UPDATE math still
@@ -130,7 +130,7 @@ def cast_state(
 
 def optimizer_state_bytes(opt_state) -> int:
     """Total bytes of all array leaves in an optimizer state (accounting
-    helper for A/Bs and BASELINE.md entries)."""
+    helper for A/Bs)."""
     return sum(
         leaf.size * leaf.dtype.itemsize
         for leaf in jax.tree_util.tree_leaves(opt_state)

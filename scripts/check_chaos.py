@@ -208,7 +208,7 @@ def check_hung_dispatch() -> dict:
     config = transformer.TINY.scaled(dtype=jnp.float32, num_layers=2)
     params = transformer.init(jax.random.PRNGKey(0), config)
     serve = ServeConfig(
-        max_new_tokens=6, prompt_buckets=(8,), batch_buckets=(1, 2),
+        max_new_tokens=6, prompt_buckets=(8,), num_slots=2,
         chunk_tokens=2, dispatch_timeout_s=1.0, warmup=True,
     )
     prompt = np.asarray([5, 9, 17, 2], np.int32)

@@ -18,7 +18,7 @@ converging on the cold one.  Wired as a ``slow``-marked test in
 ``tests/unit/test_compile_cache.py`` so full runs see it.
 
 This is a CPU rig: cold means an EMPTY cache, so unlike ``chip_smoke.py``
-and ``bench.py`` (one fixed directory, so that a later run hits) it makes a
+(one fixed directory, so that a later run hits) it makes a
 fresh temporary directory by design, and hands it to its children as
 ``JAX_COMPILATION_CACHE_DIR`` — placed from outside, as on the chip.
 """

@@ -158,7 +158,7 @@ def check_churn_with_replica_kill(timeout: float) -> dict:
 
     config, params = _model()
     serve = ServeConfig(
-        max_new_tokens=6, prompt_buckets=(8, 16), batch_buckets=(1, 2),
+        max_new_tokens=6, prompt_buckets=(8, 16),
         num_slots=2, chunk_tokens=2, dispatch_timeout_s=1.0, warmup=True,
     )
 
@@ -254,7 +254,7 @@ def check_autoscale(timeout: float) -> dict:
     # typed, so the backlog stays at the FLEET — where a scaled-up
     # replica can actually absorb it via failover.
     serve = ServeConfig(
-        max_new_tokens=8, prompt_buckets=(8,), batch_buckets=(1,),
+        max_new_tokens=8, prompt_buckets=(8,),
         num_slots=1, chunk_tokens=2, warmup=True,
         admission="reject", max_queue=2,
     )
@@ -386,7 +386,7 @@ def _run_mixed_tenant_arm(params, config, *, qos_on: bool,
     # under the FIFO flood wait for the TTFT gate to be deterministic.
     serve = ServeConfig(
         max_new_tokens=batch_budget, prompt_buckets=(8,),
-        batch_buckets=(1, 2), num_slots=2, chunk_tokens=2,
+        num_slots=2, chunk_tokens=2,
         dispatch_timeout_s=0.3, warmup=True, qos=engine_qos,
     )
 
@@ -620,7 +620,7 @@ def _run_flash_crowd_arm(params, config, *, cost_model: bool,
     # long suffix prefill per alternation) while a replica serving one
     # tenant hits for ~the whole prompt in ONE suffix chunk.
     serve = ServeConfig(
-        max_new_tokens=4, prompt_buckets=(256,), batch_buckets=(1, 2),
+        max_new_tokens=4, prompt_buckets=(256,),
         num_slots=1, chunk_tokens=2,
         prefix_cache_blocks=36, prefix_block_tokens=8,
         prefix_dram_blocks=12,
@@ -967,7 +967,7 @@ def _run_disagg_arm(params, config, *, roles, timeout: float) -> dict:
     ]
     serve = ServeConfig(
         max_new_tokens=budget, prompt_buckets=(4096,),
-        batch_buckets=(1, 2), num_slots=2, chunk_tokens=2,
+        num_slots=2, chunk_tokens=2,
         # Two pinned 507-block imports (one per slot) plus an incoming
         # admission's worth of headroom.
         prefix_cache_blocks=1536, prefix_block_tokens=8,

@@ -2,11 +2,10 @@
 
 Spins up ``cloud_tpu.serving.ServingEngine`` in-process (TINY model,
 AOT-warmed), fires concurrent mixed-length requests from worker
-threads, and asserts the three contracts the engine makes — for BOTH
-schedulers:
+threads, and asserts the three contracts the engine makes:
 
-1. **Liveness** — every future resolves (no request stranded by the
-   batcher, the flush deadline, slot churn, or shutdown).
+1. **Liveness** — every future resolves (no request stranded by slot
+   churn or shutdown).
 2. **Parity** — each request's tokens are identical (token-for-token,
    greedy) to a direct unbatched ``generation.generate`` call for that
    prompt alone: batching, bucket padding, and slot scheduling must be
@@ -14,25 +13,22 @@ schedulers:
 3. **Thread hygiene** — after ``close()``, no scheduler / compile-ahead
    worker threads survive.
 
-Phase 1 runs the PR 4 batch-synchronous path.  Phase 2 is the churn
-workload on the continuous scheduler: staggered arrivals from jittered
+Phase 1 is the churn workload: staggered arrivals from jittered
 worker threads, mixed prompt lengths AND per-request ``max_new_tokens``
 — maximum slot churn (insert-into-freed-slot, mid-chunk expiry, eos-free
 retire all exercised) — with the same parity oracle plus the
-one-chunk-compile retrace guard.  Phase 3 is the shared-prefix churn:
+one-chunk-compile retrace guard.  Phase 2 is the shared-prefix churn:
 many requests over a few long system prompts with the prefix KV cache
 AND chunked prefill on — parity through partial hits and chunked
 suffixes, hit rate > 0, prefix programs compiling once per bucket (not
 per request), and ``prefix_hit_tokens_per_sec`` beating the cold churn
-phase's tokens/sec.  Both occupancies are REPORTED for
-trend-watching; the continuous-beats-batch assertion lives in
-tests/unit/test_serving.py, where the two schedulers run the identical
-workload (the two phases here deliberately differ).  Phase 4 is the
-SHARDED churn: the same staggered mixed-budget workload through a
+phase's tokens/sec.  The churn phase's slot occupancy is REPORTED for
+trend-watching.  Phase 3 is the SHARDED churn: the same staggered
+mixed-budget workload through a
 ``mesh_shape=(2, 1)`` engine on a 2-device CPU mesh — params and the
 slot KV cache sharded over the slice — with per-request parity against
 single-chip ``generate()``, the one-executable-per-bucket retrace guard
-despite the mesh, and the same zero-thread-leak contract.  Phase 5 is
+despite the mesh, and the same zero-thread-leak contract.  Phase 4 is
 the SPECULATIVE churn: draft-and-verify decoding under churn — a
 shared-weights draft (deterministic full-window acceptance, so the
 dispatch-count contract is provable: target verify dispatches strictly
@@ -41,12 +37,12 @@ deadline-shed request landing while verifies are in flight, plus a
 genuinely smaller (1-layer, fresh-init) draft segment whose acceptance
 is whatever it is — parity vs per-request ``generate()`` either way,
 one draft/verify/draft-prefill executable each (retrace guard), and
-zero leaked threads.  Phase 6 is the KERNEL churn: the shared-prefix
+zero leaked threads.  Phase 5 is the KERNEL churn: the shared-prefix
 workload with the paged decode-attention kernel armed
 (``decode_kernel="pallas"``, real Pallas kernel body through the
 interpreter via ``CLOUD_TPU_FLASH_FORCE_INTERPRET=1``) — per-request
 parity, compile-once programs, and prefix hits attaching through the
-block table with ZERO ``copy_prefix_program`` dispatches.  Phase 7 is
+block table with ZERO ``copy_prefix_program`` dispatches.  Phase 6 is
 the PIPELINED churn: the same burst workload through a
 ``pipeline_depth=1`` and a ``pipeline_depth=2`` engine — token-for-token
 parity between the arms AND against ``generate()``, the depth-2 arm
@@ -56,7 +52,7 @@ executable), depth 2 never lowering mean slot occupancy, the
 
 Prints one JSON line per phase plus a final summary::
 
-    {"phase": "summary", "ok": true, "requests": ..., "batches": ...,
+    {"phase": "summary", "ok": true, "requests": ...,
      "continuous_occupancy": ..., "leaked_threads": [], ...}
 
 Wired as a ``slow``-marked test in tests/unit/test_serving.py (the same
@@ -74,8 +70,8 @@ import time
 
 # CPU by default: this is a correctness/hygiene harness, not a perf one.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-# Two virtual devices BEFORE jax initializes: phase 4 runs the sharded
-# (TP=2 slice) engine; phases 1-3 ignore the second device (mesh=None
+# Two virtual devices BEFORE jax initializes: phase 3 runs the sharded
+# (TP=2 slice) engine; phases 1-2 ignore the second device (mesh=None
 # dispatches on the default device as before).
 _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
@@ -122,77 +118,13 @@ def main(argv=None) -> int:
 
     config = transformer.TINY.scaled(dtype=jnp.float32, num_layers=2)
     params = transformer.init(jax.random.PRNGKey(0), config)
-    serve = ServeConfig(
-        max_new_tokens=MAX_NEW,
-        prompt_buckets=(8, 16),
-        batch_buckets=(1, 2, 4),
-        flush_deadline_s=0.02,
-        warmup=True,
-        scheduler="batch",  # phase 1: the PR 4 baseline path
-    )
-    rng = np.random.default_rng(0)
-    prompts = [
-        rng.integers(1, 255, int(rng.integers(2, 17))).astype(np.int32)
-        for _ in range(args.requests)
-    ]
-
     start = time.perf_counter()
-    futures = [None] * len(prompts)
-    engine = ServingEngine(params, config, serve, mesh=None)
-    try:
-        engine.wait_ready()
-        print(json.dumps({
-            "phase": "warmup", "ok": engine._warmup_plan.error is None,
-            "seconds": round(time.perf_counter() - start, 3),
-        }), flush=True)
 
-        # Concurrent submitters: requests arrive interleaved, from many
-        # threads, the way traffic would — not pre-sorted by bucket.
-        def submitter(i):
-            futures[i] = engine.submit(prompts[i])
-
-        workers = [
-            threading.Thread(target=submitter, args=(i,))
-            for i in range(len(prompts))
-        ]
-        for w in workers:
-            w.start()
-        for w in workers:
-            w.join()
-
-        results = [f.result(timeout=args.timeout) for f in futures]
-        print(json.dumps({
-            "phase": "resolve", "ok": True, "requests": len(results),
-        }), flush=True)
-
-        mismatches = 0
-        for prompt, result in zip(prompts, results):
-            direct = generation.generate(
-                params, jnp.asarray(prompt[None, :]),
-                jnp.asarray([len(prompt)], np.int32), config,
-                max_new_tokens=MAX_NEW,
-                sample=generation.SampleConfig(temperature=0.0),
-            )
-            want = np.asarray(direct["tokens"])[0]
-            if not np.array_equal(result.tokens, want) or (
-                result.num_generated != int(direct["num_generated"][0])
-            ):
-                mismatches += 1
-        print(json.dumps({
-            "phase": "parity", "ok": mismatches == 0,
-            "mismatches": mismatches,
-        }), flush=True)
-        stats = engine.stats()
-    finally:
-        engine.close()
-
-    leaked = _engine_threads()
-
-    # -- phase 2: churn workload on the continuous scheduler --------------
+    # -- phase 1: churn workload ------------------------------------------
     churn_serve = ServeConfig(
         max_new_tokens=MAX_NEW,
         prompt_buckets=(8, 16),
-        batch_buckets=(1, 2, 4),
+        num_slots=4,
         chunk_tokens=2,
         warmup=True,
     )
@@ -266,7 +198,7 @@ def main(argv=None) -> int:
     }), flush=True)
     leaked_churn = _engine_threads()
 
-    # -- phase 3: shared-prefix churn (prefix cache + chunked prefill) ----
+    # -- phase 2: shared-prefix churn (prefix cache + chunked prefill) ----
     # Many requests over a few long system prompts: parity must hold
     # through partial hits and chunked suffix prefills, the hit rate
     # must be real, the prefix programs must compile once per bucket
@@ -276,7 +208,7 @@ def main(argv=None) -> int:
     prefix_serve = ServeConfig(
         max_new_tokens=MAX_NEW,
         prompt_buckets=(8, 16),
-        batch_buckets=(1, 2, 4),
+        num_slots=4,
         chunk_tokens=2,
         prefix_cache_blocks=16,
         prefix_block_tokens=4,
@@ -374,7 +306,7 @@ def main(argv=None) -> int:
     }), flush=True)
     leaked_prefix = _engine_threads()
 
-    # -- phase 4: sharded churn (one replica = one TP=2 slice) ------------
+    # -- phase 3: sharded churn (one replica = one TP=2 slice) ------------
     # The phase-2 churn workload through a sharded engine: params +
     # slot KV cache sharded over a 2-device mesh, parity per request
     # against single-chip generate(), one executable per program per
@@ -387,7 +319,7 @@ def main(argv=None) -> int:
     tp_serve = ServeConfig(
         max_new_tokens=MAX_NEW,
         prompt_buckets=(8, 16),
-        batch_buckets=(1, 2, 4),
+        num_slots=4,
         chunk_tokens=2,
         mesh_shape=(2, 1),
         warmup=True,
@@ -465,7 +397,7 @@ def main(argv=None) -> int:
     }), flush=True)
     leaked_tp = _engine_threads()
 
-    # -- phase 5: speculative churn (draft-and-verify decoding) -----------
+    # -- phase 4: speculative churn (draft-and-verify decoding) -----------
     # Segment A: a SHARED-WEIGHTS draft (acceptance is deterministic —
     # every window position matches) under churn with an eos mid-window
     # and a deadline request shed while verifies are in flight.  The
@@ -501,7 +433,7 @@ def main(argv=None) -> int:
     spec_serve = ServeConfig(
         max_new_tokens=MAX_NEW,
         prompt_buckets=(8, 16),
-        batch_buckets=(1, 2, 4),
+        num_slots=4,
         sample=spec_sample,
         draft=DraftConfig(config=config, params=params, spec_k=3),
         warmup=True,
@@ -599,7 +531,7 @@ def main(argv=None) -> int:
     small_serve = ServeConfig(
         max_new_tokens=MAX_NEW,
         prompt_buckets=(8, 16),
-        batch_buckets=(1, 2, 4),
+        num_slots=4,
         draft=DraftConfig(
             config=small_draft_cfg, params=small_draft_params, spec_k=3
         ),
@@ -647,7 +579,7 @@ def main(argv=None) -> int:
     }), flush=True)
     leaked_spec = _engine_threads()
 
-    # -- phase 6: kernel churn (paged decode attention, interpret mode) ---
+    # -- phase 5: kernel churn (paged decode attention, interpret mode) ---
     # The shared-prefix churn workload with the paged decode kernel
     # ARMED (decode_kernel="pallas"): on this CPU rig the dedicated
     # interpret knob runs the real Pallas kernel body through the
@@ -661,7 +593,7 @@ def main(argv=None) -> int:
     kernel_serve = ServeConfig(
         max_new_tokens=MAX_NEW,
         prompt_buckets=(8, 16),
-        batch_buckets=(1, 2, 4),
+        num_slots=4,
         chunk_tokens=2,
         prefix_cache_blocks=16,
         prefix_block_tokens=4,
@@ -757,7 +689,7 @@ def main(argv=None) -> int:
     }), flush=True)
     leaked_kernel = _engine_threads()
 
-    # -- phase 7: pipelined churn (pipeline_depth=2 vs 1) -----------------
+    # -- phase 6: pipelined churn (pipeline_depth=2 vs 1) -----------------
     # The same burst workload through both depths.  Burst submission
     # (no jitter) keeps the two arms' admission schedules comparable,
     # so the occupancy gate below measures the pipeline, not arrival
@@ -787,7 +719,7 @@ def main(argv=None) -> int:
         pipe_serve = ServeConfig(
             max_new_tokens=MAX_NEW,
             prompt_buckets=(8, 16),
-            batch_buckets=(1, 2, 4),
+            num_slots=4,
             chunk_tokens=2,
             warmup=True,
             pipeline_depth=depth,
@@ -855,14 +787,13 @@ def main(argv=None) -> int:
     leaked_pipe = _engine_threads()
 
     ok = (
-        mismatches == 0 and churn_mismatches == 0
+        churn_mismatches == 0
         and prefix_mismatches == 0 and tp_mismatches == 0
         and spec_mismatches == 0 and small_mismatches == 0
         and kernel_mismatches == 0 and pipe_mismatches == 0
-        and not leaked and not leaked_churn and not leaked_prefix
+        and not leaked_churn and not leaked_prefix
         and not leaked_tp and not leaked_spec and not leaked_kernel
         and not leaked_pipe
-        and stats["completed"] == len(prompts)
         and churn_stats["completed"] == len(churn_prompts)
         and prefix_stats["completed"] == len(prefix_prompts)
         and tp_stats["completed"] == len(tp_prompts)
@@ -909,21 +840,19 @@ def main(argv=None) -> int:
         # The spec phase's deadline request is shed BY DESIGN: count
         # servable requests so requests == completed stays the summary
         # invariant (the shed itself is gated via spec_shed_ok).
-        "requests": (stats["requests"] + churn_stats["requests"]
+        "requests": (churn_stats["requests"]
                      + prefix_stats["requests"] + tp_stats["requests"]
                      + spec_stats["requests"] - spec_stats["shed"]
                      + small_stats["requests"]
                      + kernel_stats["requests"]
                      + pipe1_stats["requests"] + pipe2_stats["requests"]),
-        "completed": (stats["completed"] + churn_stats["completed"]
+        "completed": (churn_stats["completed"]
                       + prefix_stats["completed"]
                       + tp_stats["completed"] + spec_stats["completed"]
                       + small_stats["completed"]
                       + kernel_stats["completed"]
                       + pipe1_stats["completed"]
                       + pipe2_stats["completed"]),
-        "batches": stats["batches"],
-        "mean_batch_occupancy": round(stats["mean_batch_occupancy"], 3),
         "continuous_occupancy": round(
             churn_stats["mean_slot_occupancy"], 3
         ),
@@ -938,7 +867,7 @@ def main(argv=None) -> int:
         "pipeline_gap_p50_ms": round(
             pipe2_stats["dispatch_gap_ms_p50"], 3
         ),
-        "leaked_threads": (leaked + leaked_churn + leaked_prefix
+        "leaked_threads": (leaked_churn + leaked_prefix
                            + leaked_tp + leaked_spec + leaked_kernel
                            + leaked_pipe),
         "wall_seconds": round(time.perf_counter() - start, 3),

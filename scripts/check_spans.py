@@ -5,8 +5,8 @@ the COMPLETE span-name contract.  This script makes that claim
 enforceable without running anything:
 
 * **code side** — every ``span("...")`` / ``record_span("...")`` /
-  ``@traced(name="...")`` string literal in ``cloud_tpu/**/*.py`` and
-  ``bench.py`` (including local wrappers like collectives' ``_span``;
+  ``@traced(name="...")`` string literal in ``cloud_tpu/**/*.py``
+  (including local wrappers like collectives' ``_span``;
   f-string placeholders normalize ``{site}`` -> ``<site>`` to match the
   docs' parameterized rows);
 * **doc side** — every backticked ``layer/name`` token inside the
@@ -65,7 +65,7 @@ _PLACEHOLDER_RE = re.compile(r"\{(\w+)\}")
 
 
 def _python_files() -> List[str]:
-    files = [os.path.join(REPO, "bench.py")]
+    files = []
     for root, _dirs, names in os.walk(os.path.join(REPO, "cloud_tpu")):
         files.extend(
             os.path.join(root, n) for n in names if n.endswith(".py")
@@ -133,7 +133,7 @@ def main(argv=None) -> int:
     for name in sorted(ghost):
         failures.append(
             f"documented span {name!r} is recorded nowhere in "
-            "cloud_tpu/ or bench.py — remove the table row or the "
+            "cloud_tpu/ — remove the table row or the "
             "allowlist entry it needs"
         )
     for name in sorted((GAUGE_TOKENS | VARIABLE_SPANS) & documented):

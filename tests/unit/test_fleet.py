@@ -975,7 +975,7 @@ class TestRealEngineFleet:
         config, params = model
         serve = ServeConfig(
             max_new_tokens=5, prompt_buckets=(8, 16),
-            batch_buckets=(1, 2, 4), chunk_tokens=2,
+            num_slots=4, chunk_tokens=2,
         )
 
         def factory():

@@ -527,7 +527,7 @@ def _direct(params, config, prompt, budget):
 
 def _serve(**overrides):
     base = dict(
-        max_new_tokens=8, prompt_buckets=(8, 32), batch_buckets=(1, 2),
+        max_new_tokens=8, prompt_buckets=(8, 32), num_slots=2,
         chunk_tokens=4, prefix_cache_blocks=16,
         prefix_block_tokens=BLOCK_TOKENS,
     )
@@ -685,7 +685,7 @@ class TestEngineHandoff:
     def test_submit_and_role_validation(self, model):
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=1,
         )  # no prefix cache
         engine = ServingEngine(params, config, serve, start=False)
         try:

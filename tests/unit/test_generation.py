@@ -1019,7 +1019,7 @@ class TestSpeculativePrograms:
         level — test_serving.py TestSpeculative's shared-draft test
         asserts full-window acceptance + dispatches < tokens, its
         mismatching-draft test the parity/floor — and e2e under churn
-        by scripts/check_serving.py phase 5 every CI run; the program-
+        by scripts/check_serving.py phase 4 every CI run; the program-
         level degenerate cases below (all-rejected window, budget/eos
         truncation) remain fast."""
         config, params = self._model()

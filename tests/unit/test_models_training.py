@@ -1188,8 +1188,8 @@ class TestArrayDataset:
 
 
 class TestLowPrecisionOptimizerState:
-    """bf16-at-rest optimizer moments (the BERT adamw HBM attack,
-    BASELINE.md 'BERT MFU ceiling'): state dtypes, traffic accounting,
+    """bf16-at-rest optimizer moments (the BERT adamw HBM attack):
+    state dtypes, traffic accounting,
     and trajectory closeness to the f32 baseline."""
 
     def _problem(self):
@@ -1400,8 +1400,8 @@ class TestUlyssesAttention:
 class TestRematPolicies:
     """remat_wrap is a pure scheduling change: loss AND gradients must be
     identical across none/full/dots on every model that exposes the knob
-    (BASELINE.md 'BERT MFU ceiling' names the scan remat policy as an
-    ablation axis — the ablation is only meaningful if numerics hold)."""
+    (the scan remat policy is an ablation axis — the ablation is only
+    meaningful if numerics hold)."""
 
     def test_transformer_policies_identical(self):
         cfg0 = transformer.TINY.scaled(dtype=jnp.float32, num_layers=2)

@@ -560,7 +560,7 @@ class TestWindowedRate:
 
 def test_check_spans_script():
     """The span-name contract is enforceable: every span recorded in
-    cloud_tpu/ + bench.py appears in docs/observability.md's
+    cloud_tpu/ appears in docs/observability.md's
     instrumentation table and vice versa (ISSUE 16 satellite).  Pure
     static grep — runs in milliseconds, so it rides tier 1 un-marked."""
     import subprocess

@@ -144,9 +144,7 @@ class TestTypedConstruction:
         )
         assert custom.shed_order() == ["b", "a"]
 
-    def test_serve_config_qos_needs_continuous(self):
-        with pytest.raises(ValueError, match="continuous"):
-            ServeConfig(scheduler="batch", qos=QosConfig())
+    def test_serve_config_qos_type_is_checked(self):
         with pytest.raises(ValueError, match="QosConfig"):
             ServeConfig(qos="interactive")
 
@@ -371,7 +369,7 @@ class TestEngineQos:
         and every request still matches its direct generate() run."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=4, prompt_buckets=(8,),
             num_slots=1, chunk_tokens=2, qos=QosConfig(),
         )
         rng = np.random.default_rng(0)
@@ -410,7 +408,7 @@ class TestEngineQos:
         BrownoutShedError — the interactive requests all serve."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=2, prompt_buckets=(8,),
             num_slots=1, chunk_tokens=1,
             qos=QosConfig(brownout_queue_depth=2),
         )
@@ -455,7 +453,7 @@ class TestEngineQos:
         result() is the same ServeResult."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=6, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=6, prompt_buckets=(8,),
             num_slots=2, chunk_tokens=2,
         )
         prompt = np.asarray([3, 1, 4, 1, 5], np.int32)
@@ -471,23 +469,6 @@ class TestEngineQos:
         np.testing.assert_array_equal(plain.tokens, want)
         assert result.num_generated == n
 
-    def test_streaming_identity_batch_scheduler(self, model):
-        """The batch scheduler materializes tokens at completion; the
-        stream contract still holds (delivery at the end, same row)."""
-        config, params = model
-        serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1,),
-            flush_deadline_s=0.0, scheduler="batch",
-        )
-        prompt = np.asarray([2, 7, 1], np.int32)
-        with ServingEngine(params, config, serve) as engine:
-            stream = engine.submit(prompt, stream=True)
-            streamed = list(stream)
-            result = stream.result(timeout=120)
-        want, _ = _direct_tokens(params, config, prompt, 4)
-        assert streamed == list(result.tokens[:result.num_generated])
-        np.testing.assert_array_equal(result.tokens, want)
-
     def test_stream_failure_closes_typed(self, model):
         """A request that never dispatches (close without drain) fails
         its stream with the same typed error as its future."""
@@ -495,7 +476,7 @@ class TestEngineQos:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=1,
         )
         engine = ServingEngine(params, config, serve, start=False)
         stream = engine.submit(np.asarray([1, 2], np.int32), stream=True)
@@ -510,7 +491,7 @@ class TestEngineQos:
         schema stay byte-identical FIFO."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=1,
         )
         engine = ServingEngine(params, config, serve, start=False)
         with pytest.raises(ValueError, match="class name"):
@@ -538,7 +519,7 @@ class TestEngineQos:
         request of a valid deployment)."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=1,
         )
         custom = QosConfig(
             classes={"gold": PriorityClass(weight=4.0, slo_s=0.5),
@@ -565,7 +546,7 @@ class TestEngineQos:
     def test_qos_health_reports_class_backlog(self, model):
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=1,
             qos=QosConfig(),
         )
         engine = ServingEngine(params, config, serve, start=False)

@@ -534,7 +534,7 @@ class TestServingDeadlines:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=5, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=5, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2,
         )
         rng = np.random.default_rng(3)
@@ -566,7 +566,7 @@ class TestServingDeadlines:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=1,
             chunk_tokens=2,
         )
         prompt = np.asarray([5, 9, 17], np.int32)
@@ -579,66 +579,17 @@ class TestServingDeadlines:
             result.tokens, np.asarray(want["tokens"])[0]
         )
 
-    def test_batch_lone_request_shed_at_its_deadline(self, model):
-        """The scheduler's wait must wake at the REQUEST deadline, not
-        the (much later) flush deadline: a lone doomed request is shed
-        promptly even with flush_deadline_s=5."""
-        from cloud_tpu.serving import (
-            DeadlineExceededError, ServeConfig, ServingEngine,
-        )
-
-        config, params = model
-        serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(4,),
-            flush_deadline_s=5.0, scheduler="batch",
-        )
-        with ServingEngine(params, config, serve, mesh=None) as engine:
-            start = time.perf_counter()
-            doomed = engine.submit(np.asarray([5, 9], np.int32),
-                                   deadline_s=0.2)
-            with pytest.raises(DeadlineExceededError):
-                doomed.result(timeout=120)
-            assert time.perf_counter() - start < 3.0  # not the 5s flush
-
     def test_bad_deadline_rejected(self, model):
         from cloud_tpu.serving import ServeConfig, ServingEngine
 
         config, params = model
         serve = ServeConfig(max_new_tokens=4, prompt_buckets=(8,),
-                            batch_buckets=(1,))
+                            num_slots=1)
         engine = ServingEngine(params, config, serve, mesh=None,
                                start=False)
         with pytest.raises(ValueError, match="deadline_s"):
             engine.submit(np.asarray([1, 2], np.int32), deadline_s=0)
         engine.close()
-
-    def test_batch_scheduler_sheds_too(self, model):
-        from cloud_tpu.serving import (
-            DeadlineExceededError, ServeConfig, ServingEngine,
-        )
-
-        config, params = model
-        serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
-            flush_deadline_s=0.0, scheduler="batch",
-        )
-        prompt = np.asarray([5, 9], np.int32)
-        engine = ServingEngine(params, config, serve, mesh=None,
-                               start=False)
-        doomed = engine.submit(prompt, deadline_s=0.005)
-        kept = engine.submit(prompt)
-        time.sleep(0.05)
-        engine.start()
-        with pytest.raises(DeadlineExceededError):
-            doomed.result(timeout=120)
-        result = kept.result(timeout=120)
-        engine.close()
-        want = _direct(params, config, prompt, 4)
-        np.testing.assert_array_equal(
-            result.tokens, np.asarray(want["tokens"])[0]
-        )
-        assert engine.stats()["shed"] == 1
-
 
 class TestDispatchWatchdog:
     def test_hung_chunk_fails_slots_and_marks_unhealthy(self, model):
@@ -651,7 +602,7 @@ class TestDispatchWatchdog:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=6, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=6, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, dispatch_timeout_s=1.0, warmup=True,
         )
         prompt = np.asarray([5, 9, 17, 2], np.int32)
@@ -690,7 +641,7 @@ class TestDispatchWatchdog:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=1,
             chunk_tokens=2, dispatch_timeout_s=1.0, warmup=True,
         )
         prompt = np.asarray([4, 7, 1], np.int32)

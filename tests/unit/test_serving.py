@@ -6,11 +6,10 @@ per-request decode budgets — engine outputs are token-for-token
 identical (greedy) to per-request ``generation.generate`` calls.
 Bucket padding, batch padding rows, co-batching with strangers, slot
 reuse over stale cache, and mid-chunk expiry must never leak into a
-request's tokens.  Around that: the continuous scheduler's slot
+request's tokens.  Around that: the scheduler's slot
 lifecycle (insert-into-freed-slot, per-slot ``max_new_tokens`` expiry,
-drain of a partially full grid, one-chunk-compile retrace guard, and
-the occupancy win over the batch-synchronous baseline), batch-mode
-formation (full-batch and deadline-flush paths), admission control
+drain of a partially full grid, one-chunk-compile retrace guard),
+admission control
 (block/reject + typed errors), graceful drain on shutdown, AOT warmup
 through the compile-cache registry, and the same thread-hygiene
 guarantee as test_pipeline_engine — a closed engine owns zero live
@@ -84,16 +83,11 @@ class TestParity:
         buckets, batched by the engine, each identical to its own
         unbatched greedy run.
 
-        Slow tier (the PR 8 wall-clock move): the same contract —
-        concurrent mixed-length batch-path parity — is what
-        scripts/check_serving.py phase 1 asserts end to end, and the
-        tier-1 suite sits against its 870 s budget since the sharded
-        serving tests landed."""
+        Slow tier (the PR 8 wall-clock move): the churn parity tests of
+        ``TestContinuous`` hold the same contract per commit."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=5, prompt_buckets=(8, 16),
-            batch_buckets=(1, 2, 4), flush_deadline_s=0.02,
-            scheduler="batch",
+            max_new_tokens=5, prompt_buckets=(8, 16), num_slots=4,
         )
         rng = np.random.default_rng(0)
         prompts = [
@@ -102,7 +96,7 @@ class TestParity:
         ]
         engine = ServingEngine(params, config, serve, start=False)
         futures = [engine.submit(p) for p in prompts]
-        engine.start()  # all queued up front: batches form deterministically
+        engine.start()  # all queued up front: the grid fills at once
         results = [f.result(timeout=120) for f in futures]
         engine.close()
 
@@ -114,17 +108,16 @@ class TestParity:
             assert result.num_generated == int(want["num_generated"][0])
         stats = engine.stats()
         assert stats["completed"] == len(prompts)
-        # Batching actually happened (6 requests in < 6 dispatches).
-        assert stats["batches"] < len(prompts)
-        assert 0 < stats["mean_batch_occupancy"] <= 1.0
+        # Batching actually happened: the chunks carried more than one
+        # request's tokens at a time.
+        assert stats["mean_slot_occupancy"] > 1.0 / serve.num_slots
 
     def test_per_request_max_new_tokens_trims(self, model):
         """A request below the engine-wide decode length gets exactly a
         shorter direct run's tokens (greedy is prefix-consistent)."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=6, prompt_buckets=(8,), batch_buckets=(1,),
-            flush_deadline_s=0.0,
+            max_new_tokens=6, prompt_buckets=(8,), num_slots=1,
         )
         prompt = np.asarray([5, 9, 17, 2], np.int32)
         with ServingEngine(params, config, serve) as engine:
@@ -147,8 +140,8 @@ class TestParity:
         sample = generation.SampleConfig(temperature=0.0, eos_id=eos,
                                          pad_id=0)
         serve = ServeConfig(
-            max_new_tokens=6, prompt_buckets=(8,), batch_buckets=(1, 2),
-            flush_deadline_s=0.0, sample=sample,
+            max_new_tokens=6, prompt_buckets=(8,), num_slots=2,
+            sample=sample,
         )
         with ServingEngine(params, config, serve) as engine:
             result = engine.submit(prompt).result(timeout=120)
@@ -162,7 +155,7 @@ class TestParity:
 
     def test_sampled_decode_deterministic_per_seed(self, model):
         """Non-greedy serving: the engine owns the rng chain, so the same
-        seed + the same deterministic batch formation reproduces."""
+        seed + the same deterministic admission order reproduces."""
         config, params = model
         rng = np.random.default_rng(1)
         prompts = [
@@ -171,13 +164,13 @@ class TestParity:
 
         def run():
             serve = ServeConfig(
-                max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(4,),
-                flush_deadline_s=5.0, seed=7,
+                max_new_tokens=4, prompt_buckets=(8,), num_slots=4,
+                seed=7,
                 sample=generation.SampleConfig(temperature=0.9, top_k=20),
             )
             engine = ServingEngine(params, config, serve, start=False)
             futures = [engine.submit(p) for p in prompts]
-            engine.start()  # 4 queued = one full batch: one rng split
+            engine.start()  # 4 queued = the whole grid, in FIFO order
             results = [f.result(timeout=120) for f in futures]
             engine.close()
             return results
@@ -187,68 +180,12 @@ class TestParity:
             np.testing.assert_array_equal(a.tokens, b.tokens)
 
 
-class TestBatchFormation:
-    def test_lone_request_flushes_at_deadline(self, model):
-        """A single request must not wait for an unfillable batch: the
-        deadline flush dispatches it alone (occupancy 1/4)."""
-        config, params = model
-        serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(4,),
-            flush_deadline_s=0.01, scheduler="batch",
-        )
-        with ServingEngine(params, config, serve) as engine:
-            result = engine.submit(
-                np.asarray([1, 2, 3], np.int32)
-            ).result(timeout=120)
-            assert result.batch_size == 4
-            assert engine.stats()["mean_batch_occupancy"] == 0.25
-
-    def test_expired_head_outranks_full_batch(self, model):
-        """flush_deadline_s is a real bound: an expired head in a
-        minority bucket is served BEFORE another bucket's full batch —
-        sustained traffic in one bucket must not starve the other
-        (deterministic check of the formation policy itself)."""
-        config, params = model
-        serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8, 16), batch_buckets=(2,),
-            flush_deadline_s=0.0, scheduler="batch",
-        )
-        engine = ServingEngine(params, config, serve, start=False)
-        minority = engine.submit(np.asarray(range(1, 10), np.int32))  # len 9
-        for _ in range(2):  # a FULL majority-bucket batch, submitted later
-            engine.submit(np.asarray([1, 2, 3], np.int32))
-        batch = engine._pop_batch_locked(time.perf_counter())
-        # Everything is expired (deadline 0); the oldest head wins even
-        # though its bucket cannot fill, and the full bucket waits.
-        assert [r.future for r in batch] == [minority]
-        engine.close(drain=False)
-
-    def test_full_batch_dispatches_before_deadline(self, model):
-        """A full max-batch goes immediately — the (long) flush deadline
-        must not throttle saturated traffic."""
-        config, params = model
-        serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(2,),
-            flush_deadline_s=30.0, scheduler="batch",
-        )
-        prompts = [np.asarray([1, 2], np.int32),
-                   np.asarray([3, 4, 5], np.int32)]
-        with ServingEngine(params, config, serve, start=False) as engine:
-            futures = [engine.submit(p) for p in prompts]
-            engine.start()
-            start = time.perf_counter()
-            for f in futures:
-                f.result(timeout=120)
-            assert time.perf_counter() - start < 30.0
-            assert engine.stats()["batches"] == 1
-
-
 class TestAdmission:
     def test_reject_policy_raises_typed_error(self, model):
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(8,),
-            max_queue=2, admission="reject", flush_deadline_s=30.0,
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=8,
+            max_queue=2, admission="reject",
         )
         engine = ServingEngine(params, config, serve, start=False)
         prompt = np.asarray([1, 2], np.int32)
@@ -264,7 +201,7 @@ class TestAdmission:
     def test_submit_validation(self, model):
         config, params = model
         serve = ServeConfig(max_new_tokens=2, prompt_buckets=(8,),
-                            batch_buckets=(1,))
+                            num_slots=1)
         engine = ServingEngine(params, config, serve, start=False)
         with pytest.raises(ValueError, match="1-D"):
             engine.submit(np.zeros((2, 2), np.int32))
@@ -284,10 +221,37 @@ class TestAdmission:
         with pytest.raises(ValueError, match="max_new_tokens"):
             ServeConfig(max_new_tokens=0)
 
+    @pytest.mark.parametrize("name,value", [
+        ("scheduler", "batch"),
+        ("batch_buckets", (1, 2)),
+        ("flush_deadline_s", 0.01),
+    ], ids=["scheduler", "batch_buckets", "flush_deadline_s"])
+    def test_retired_options_are_refused(self, name, value):
+        """The batch scheduler went with its three options: asking for
+        one is a TypeError at construction, not a silently inert knob."""
+        with pytest.raises(TypeError, match=name):
+            ServeConfig(**{name: value})
+
+    def test_num_slots_default(self, model):
+        config, params = model
+        assert ServeConfig().num_slots == 8
+        engine = ServingEngine(
+            params, config,
+            ServeConfig(max_new_tokens=2, prompt_buckets=(8,)),
+            start=False,
+        )
+        try:
+            assert engine.health()["num_slots"] == 8
+            assert engine.health()["free_slots"] == 8
+            assert all(
+                shape[1] == 8 for shape in engine.placement()["kv_shapes"])
+        finally:
+            engine.close()
+
     def test_submit_after_close_raises(self, model):
         config, params = model
         serve = ServeConfig(max_new_tokens=2, prompt_buckets=(8,),
-                            batch_buckets=(1,))
+                            num_slots=1)
         engine = ServingEngine(params, config, serve, start=False)
         engine.close()
         with pytest.raises(EngineClosedError):
@@ -300,8 +264,7 @@ class TestShutdown:
         full) are served — not dropped — by a draining close."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(8,),
-            flush_deadline_s=30.0,
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=8,
         )
         engine = ServingEngine(params, config, serve)
         futures = [
@@ -319,8 +282,8 @@ class TestShutdown:
         config, params = model
         assert not _engine_threads()
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1,),
-            flush_deadline_s=0.0, warmup=True,
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=1,
+            warmup=True,
         )
         with ServingEngine(params, config, serve) as engine:
             assert any(
@@ -333,58 +296,17 @@ class TestShutdown:
     def test_close_is_idempotent(self, model):
         config, params = model
         serve = ServeConfig(max_new_tokens=2, prompt_buckets=(8,),
-                            batch_buckets=(1,))
+                            num_slots=1)
         engine = ServingEngine(params, config, serve)
         engine.close()
         engine.close()
-
-
-class TestWarmup:
-    @pytest.mark.slow
-    def test_warmup_precompiles_the_grid(self, model):
-        """warmup=True lands every (bucket, batch) cell's prefill AND
-        decode executable in the AOT registry before any traffic; the
-        dispatch path then uses the compiled programs (AotStep attached),
-        and results still match the unbatched oracle.
-
-        Slow tier (tier-1 wall-clock at its 870s budget, the PR 8/10
-        precedent): the batch-path AOT warmup runs e2e in
-        scripts/check_serving.py phase 1 (warmup=True + wait_ready +
-        parity) on every CI pass, and the continuous warmup test below
-        keeps the registry/compiled-cell contract pinned fast per
-        commit."""
-        from cloud_tpu.training import compile_cache
-
-        config, params = model
-        before = compile_cache.registry_size()
-        serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1, 2),
-            flush_deadline_s=0.0, warmup=True, scheduler="batch",
-        )
-        engine = ServingEngine(params, config, serve)
-        engine.wait_ready()
-        assert engine._warmup_plan.error is None
-        # 1 bucket x 2 batch sizes x {prefill, decode} = 4 new entries.
-        assert compile_cache.registry_size() >= before + 4
-        for key in ((8, 1), (8, 2)):
-            assert engine._cells[key].prefill.compiled is not None
-            assert engine._cells[key].decode.compiled is not None
-
-        prompt = np.asarray([9, 4, 1], np.int32)
-        result = engine.submit(prompt).result(timeout=120)
-        engine.close()
-        want = _direct(params, config, prompt, 3)
-        np.testing.assert_array_equal(
-            result.tokens, np.asarray(want["tokens"])[0]
-        )
 
 
 class TestHealth:
     """The health() load-signal contract (ISSUE 8): the fleet router
     reads ``queue_depth``/``active_slots``/``num_slots`` off every
-    routing decision, so the keys are pinned here — for BOTH schedulers
-    — alongside the pre-existing readiness keys, which must stay
-    stable."""
+    routing decision, so the keys are pinned here alongside the
+    pre-existing readiness keys, which must stay stable."""
 
     #: Keys the PR 6 consumers (check_chaos, external supervisors)
     #: already depend on.
@@ -401,8 +323,8 @@ class TestHealth:
         assert health["active_slots"] >= 0
         assert health["num_slots"] == serve.num_slots
         # ISSUE 10: the prefix-cache load signal is part of the schema
-        # in BOTH schedulers (zeros when the cache is off), so the
-        # fleet router reads one stable shape.
+        # (zeros when the cache is off), so the fleet router reads one
+        # stable shape.
         for key in ("prefix_cache_blocks", "prefix_hit_tokens",
                     "evictions"):
             assert health[key] == 0, key
@@ -419,17 +341,17 @@ class TestHealth:
         # zeros whenever draft=None.
         assert health["spec_acceptance_rate"] == 0.0
         assert health["spec_k"] == 0
-        # ISSUE 14: the QoS per-class backlog is schema in BOTH
-        # schedulers — all-zeros whenever qos=None (the FIFO path
-        # never classes its queue, even when requests carry tags).
+        # ISSUE 14: the QoS per-class backlog is schema — all-zeros
+        # whenever qos=None (the FIFO path never classes its queue,
+        # even when requests carry tags).
         assert health["class_backlog"] == {
             "interactive": 0, "standard": 0, "batch": 0,
         }
-        # ISSUE 17: the decode-kernel selection is schema in BOTH
-        # schedulers — the default is (and must stay) the XLA path.
+        # ISSUE 17: the decode-kernel selection is schema — the default
+        # is (and must stay) the XLA path.
         assert health["decode_kernel"] == "xla"
-        # ISSUE 19: the disaggregated-serving keys are schema in BOTH
-        # schedulers — role "both" and zero handoff counters whenever
+        # ISSUE 19: the disaggregated-serving keys are schema —
+        # role "both" and zero handoff counters whenever
         # no role is assigned and no handoff submits arrive (pinned
         # byte-identical to the colocated engine).
         assert health["role"] == "both"
@@ -438,16 +360,15 @@ class TestHealth:
             assert health[key] == 0, key
 
     def _assert_qos_stats_zero(self, stats):
-        """ISSUE 14: the QoS stats keys are schema in both schedulers —
-        zeros whenever qos=None."""
+        """ISSUE 14: the QoS stats keys are schema — zeros whenever
+        qos=None."""
         assert stats["brownout_shed"] == 0
         zeros = {"interactive": 0, "standard": 0, "batch": 0}
         assert stats["class_completed"] == zeros
         assert stats["class_shed"] == zeros
         assert stats["class_backlog"] == zeros
-        # ISSUE 26: the KV accounting is schema in both schedulers
-        # (all zeros on the batch scheduler, whose cache lives for one
-        # batch): in use never exceeds reserved.
+        # ISSUE 26: the KV accounting is schema: in use never exceeds
+        # reserved.
         assert 0 <= stats["kv_row_steps_in_use"] <= (
             stats["kv_row_steps_reserved"])
         assert 0 <= stats["kv_bytes_in_use"] <= stats["kv_bytes_reserved"]
@@ -464,7 +385,7 @@ class TestHealth:
     def test_continuous_health_carries_load_signal(self, model):
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=1,
         )
         with ServingEngine(params, config, serve) as engine:
@@ -476,27 +397,6 @@ class TestHealth:
             engine.submit(np.asarray([1, 2], np.int32)).result(timeout=120)
             self._assert_load_signal(engine.health(), serve)
             self._assert_qos_stats_zero(engine.stats())
-
-    def test_batch_health_carries_load_signal(self, model):
-        config, params = model
-        serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(2,),
-            flush_deadline_s=30.0, scheduler="batch",
-        )
-        engine = ServingEngine(params, config, serve, start=False)
-        try:
-            # Two queued requests, scheduler not running: the queue
-            # depth is deterministic.
-            engine.submit(np.asarray([1, 2], np.int32))
-            engine.submit(np.asarray([3], np.int32))
-            health = engine.health()
-            self._assert_load_signal(health, serve)
-            assert health["queue_depth"] == 2
-            assert health["active_slots"] == 0  # nothing dispatched yet
-            assert "free_slots" not in health  # continuous-only key
-            self._assert_qos_stats_zero(engine.stats())
-        finally:
-            engine.close(drain=False)
 
 
 class TestDecodeKernel:
@@ -537,7 +437,7 @@ class TestDecodeKernel:
 
         before = paged_attention.KERNEL_TRACE_COUNT
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, decode_kernel="pallas",
         )
         prompts = [np.asarray([5, 3, 1], np.int32),
@@ -550,7 +450,7 @@ class TestDecodeKernel:
     def test_pallas_kv_quant_parity(self, model):
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, kv_quant=True, decode_kernel="pallas",
         )
         prompts = [np.asarray([5, 3, 1], np.int32),
@@ -578,7 +478,7 @@ class TestDecodeKernel:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=2,
             draft=DraftConfig(config=config, params=params, spec_k=2),
             decode_kernel="pallas",
         )
@@ -595,7 +495,7 @@ class TestDecodeKernel:
         from cloud_tpu.monitoring import tracing
 
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(16,), batch_buckets=(1, 2),
+            max_new_tokens=3, prompt_buckets=(16,), num_slots=2,
             chunk_tokens=2, prefix_cache_blocks=8, prefix_block_tokens=4,
             prefill_chunk_tokens=4, warmup=False,
             decode_kernel="pallas",
@@ -634,7 +534,7 @@ class TestDecodeKernel:
         from cloud_tpu.monitoring import tracing
 
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(16,), batch_buckets=(1, 2),
+            max_new_tokens=3, prompt_buckets=(16,), num_slots=2,
             chunk_tokens=2, prefix_cache_blocks=8, prefix_block_tokens=4,
             warmup=False,
         )
@@ -667,8 +567,6 @@ class TestDecodeKernel:
     def test_decode_kernel_validation(self):
         with pytest.raises(ValueError, match="decode_kernel"):
             ServeConfig(decode_kernel="bogus")
-        with pytest.raises(ValueError, match="decode_kernel"):
-            ServeConfig(scheduler="batch", decode_kernel="pallas")
 
 
 class TestObservability:
@@ -677,8 +575,7 @@ class TestObservability:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1, 2),
-            flush_deadline_s=0.0, scheduler="batch",
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=2,
         )
         with tracing.collecting() as collector:
             with ServingEngine(params, config, serve) as engine:
@@ -686,13 +583,12 @@ class TestObservability:
                     np.asarray([1, 2, 3], np.int32)
                 ).result(timeout=120)
         agg = collector.aggregates()
-        for name in ("serve/queue_wait", "serve/batch_form",
-                     "serve/prefill", "serve/decode"):
+        for name in ("serve/queue_wait", "serve/prefill", "serve/chunk"):
             assert agg.get(name, {}).get("count", 0) >= 1, name
         snap = metrics.snapshot()
         assert snap["counters"].get("serve/requests", 0) >= 1
-        assert snap["counters"].get("serve/batches", 0) >= 1
-        assert "serve/batch_occupancy" in snap["gauges"]
+        assert snap["counters"].get("serve/chunks", 0) >= 1
+        assert "serve/slot_occupancy" in snap["gauges"]
         assert "serve/latency_seconds" in snap["distributions"]
 
     def test_traced_request_emits_terminal_span_on_fifo(self, model):
@@ -704,8 +600,7 @@ class TestObservability:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1, 2),
-            flush_deadline_s=0.0, scheduler="batch",
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=2,
         )
         with tracing.collecting() as collector:
             with ServingEngine(params, config, serve) as engine:
@@ -736,7 +631,7 @@ class TestObservability:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=1,
         )
         with tracing.collecting() as collector:
@@ -765,8 +660,7 @@ class TestObservability:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(8,), batch_buckets=(1, 2),
-            flush_deadline_s=0.0, scheduler="batch",
+            max_new_tokens=2, prompt_buckets=(8,), num_slots=2,
         )
         with tracing.collecting() as collector:
             with ServingEngine(params, config, serve) as engine:
@@ -793,8 +687,8 @@ class TestObservability:
 
 class TestContinuous:
     """The ISSUE 6 tentpole: slot-based in-flight decode.  Parity under
-    churn, slot lifecycle, drain, the one-chunk-compile retrace guard,
-    and the occupancy win over the batch-synchronous path."""
+    churn, slot lifecycle, drain and the one-chunk-compile retrace
+    guard."""
 
     #: A churn workload: 10 ragged prompts across two buckets with mixed
     #: per-request decode budgets — enough traffic that every slot of a
@@ -825,25 +719,20 @@ class TestContinuous:
         return prompts, results, engine
 
     @pytest.mark.slow
-    def test_churn_parity_and_occupancy_beats_batch(self, model):
+    def test_churn_parity_with_generate(self, model):
         """The acceptance criterion: staggered arrivals, mixed prompt
-        AND output lengths — continuous outputs token-identical to
-        per-request generate(), and mean decode-slot occupancy beats the
-        SAME workload through the PR 4 batch-synchronous scheduler.
+        AND output lengths — outputs token-identical to per-request
+        generate().
 
-        Slow tier: runs the full churn workload through BOTH schedulers
-        on a real model (~20s on the CPU rig); scripts/check_serving.py's
-        churn phase asserts the same parity+occupancy contract e2e, and
-        the fast continuous-scheduler tests below keep the slot
-        lifecycle pinned per-commit."""
+        Slow tier: scripts/check_serving.py's churn phase asserts the
+        same parity contract e2e, and the fast tests below keep the
+        slot lifecycle pinned per-commit."""
         config, params = model
-        continuous = ServeConfig(
+        serve = ServeConfig(
             max_new_tokens=5, prompt_buckets=(8, 16),
-            batch_buckets=(1, 2, 4), chunk_tokens=2,
+            num_slots=4, chunk_tokens=2,
         )
-        prompts, results, engine = self._run_churn(
-            params, config, continuous
-        )
+        prompts, results, engine = self._run_churn(params, config, serve)
         for prompt, budget, result in zip(prompts, self.CHURN_BUDGETS,
                                           results):
             want = _direct(params, config, prompt, budget)
@@ -855,26 +744,6 @@ class TestContinuous:
         assert stats["completed"] == len(prompts)
         assert stats["chunks"] > 0
         assert 0 < stats["mean_slot_occupancy"] <= 1.0
-
-        batch = ServeConfig(
-            max_new_tokens=5, prompt_buckets=(8, 16),
-            batch_buckets=(1, 2, 4), flush_deadline_s=0.02,
-            scheduler="batch",
-        )
-        _, batch_results, batch_engine = self._run_churn(
-            params, config, batch
-        )
-        for result, batch_result in zip(results, batch_results):
-            np.testing.assert_array_equal(
-                result.tokens, batch_result.tokens
-            )
-        batch_stats = batch_engine.stats()
-        assert batch_stats["decode_slot_steps"] > 0
-        # The tentpole's reason to exist: iteration-level scheduling
-        # wastes fewer dispatched token slots on this workload.
-        assert (
-            stats["mean_slot_occupancy"] > batch_stats["mean_slot_occupancy"]
-        ), (stats, batch_stats)
 
     @pytest.mark.slow
     def test_one_chunk_compile_serves_the_whole_run(self, model):
@@ -891,7 +760,7 @@ class TestContinuous:
         config, params = model
         serve = ServeConfig(
             max_new_tokens=5, prompt_buckets=(8, 16),
-            batch_buckets=(1, 2, 4), chunk_tokens=2,
+            num_slots=4, chunk_tokens=2,
         )
         _, _, engine = self._run_churn(params, config, serve)
         assert engine.stats()["inserts"] == len(self.CHURN_LENS)
@@ -911,7 +780,7 @@ class TestContinuous:
         config, params = model
         serve = ServeConfig(
             max_new_tokens=4, prompt_buckets=(8, 16),
-            batch_buckets=(1, 2), num_slots=2, chunk_tokens=2,
+            num_slots=2, chunk_tokens=2,
         )
         rng = np.random.default_rng(3)
         # Long prompts first (fill the cache rows deep), short after
@@ -936,7 +805,7 @@ class TestContinuous:
         its neighbor decodes on unaffected."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=6, prompt_buckets=(8,), batch_buckets=(2,),
+            max_new_tokens=6, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=4,
         )
         short = np.asarray([5, 9, 17, 2], np.int32)
@@ -972,7 +841,7 @@ class TestContinuous:
         sample = generation.SampleConfig(temperature=0.0, eos_id=eos,
                                          pad_id=0)
         serve = ServeConfig(
-            max_new_tokens=6, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=6, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=3, sample=sample,
         )
         with ServingEngine(params, config, serve) as engine:
@@ -993,7 +862,7 @@ class TestContinuous:
         request to completion before the scheduler exits."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=8, prompt_buckets=(8,), batch_buckets=(4,),
+            max_new_tokens=8, prompt_buckets=(8,), num_slots=4,
             chunk_tokens=2,
         )
         engine = ServingEngine(params, config, serve)
@@ -1013,7 +882,7 @@ class TestContinuous:
         instead of serving the grid to completion."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=32, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=32, prompt_buckets=(8,), num_slots=1,
             chunk_tokens=1,
         )
         engine = ServingEngine(params, config, serve)
@@ -1038,7 +907,7 @@ class TestContinuous:
         config, params = model
         before = compile_cache.registry_size()
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8, 16), batch_buckets=(1, 2),
+            max_new_tokens=3, prompt_buckets=(8, 16), num_slots=2,
             chunk_tokens=2, warmup=True,
         )
         engine = ServingEngine(params, config, serve)
@@ -1059,12 +928,67 @@ class TestContinuous:
         )
         assert engine.chunk_traces == 1
 
+    def test_warmup_plan_is_the_slot_programs(self, model):
+        """The warm-up plan of a plain slot engine is one insert program
+        a prompt bucket plus THE chunk program, and nothing else: no
+        (bucket, batch) cell exists to warm."""
+        config, params = model
+        serve = ServeConfig(
+            max_new_tokens=2, prompt_buckets=(8, 16), num_slots=2,
+            chunk_tokens=2, warmup=True,
+        )
+        engine = ServingEngine(params, config, serve, start=False)
+        try:
+            engine.wait_ready()
+            assert engine._warmup_plan.error is None
+            assert sorted(engine._warmup_plan.steps) == [
+                "serve/decode_chunk", "serve/insert_L16", "serve/insert_L8",
+            ]
+            assert all(
+                step.compiled is not None
+                for step in engine._warmup_plan.steps.values())
+        finally:
+            engine.close()
+
+    def test_lone_waiting_request_is_shed_at_its_own_deadline(self, model):
+        """With the only slot held by a long request, a queued request
+        is shed within a pass of ITS deadline — typed, never served —
+        not when the slot frees."""
+        from cloud_tpu.serving import DeadlineExceededError
+
+        config, params = model
+        serve = ServeConfig(
+            max_new_tokens=2048, prompt_buckets=(8,), num_slots=1,
+            chunk_tokens=1,
+        )
+        prompt = np.asarray([1, 2, 3], np.int32)
+        engine = ServingEngine(params, config, serve)
+        try:
+            # Warm both programs so the holder's passes are short.
+            engine.submit(prompt, max_new_tokens=2).result(timeout=120)
+            holder = engine.submit(prompt)
+            while engine.health()["active_slots"] < 1:
+                time.sleep(0.001)
+            queued_at = time.perf_counter()
+            queued = engine.submit(prompt, deadline_s=0.2)
+            with pytest.raises(DeadlineExceededError):
+                queued.result(timeout=120)
+            shed_after = time.perf_counter() - queued_at
+            # Shed while the holder still decodes: its slot never freed.
+            assert not holder.done()
+            assert 0.2 <= shed_after < 0.2 + 1.0
+        finally:
+            engine.close(drain=False)
+        stats = engine.stats()
+        assert stats["shed"] == 1
+        assert stats["completed"] == 1  # the warm-up; the shed one never ran
+
     def test_continuous_spans_and_metrics(self, model):
         from cloud_tpu.monitoring import metrics, tracing
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2,
         )
         with tracing.collecting() as collector:
@@ -1096,7 +1020,7 @@ class TestContinuous:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2,
         )
         with tracing.collecting() as collector:
@@ -1134,7 +1058,7 @@ class TestShardedServing:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, mesh_shape=(2, 1),
         )
         rng = np.random.default_rng(11)
@@ -1185,7 +1109,7 @@ class TestShardedServing:
     def test_tp2_kv_quant_parity(self, model):
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=1,
             chunk_tokens=2, kv_quant=True, mesh_shape=(2, 1),
         )
         prompt = np.asarray([7, 3, 9, 11, 2], np.int32)
@@ -1293,7 +1217,7 @@ class TestShardedServing:
         token-identically."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=1,
             chunk_tokens=2, layout="auto",
         )
         prompt = np.asarray([5, 4, 3, 2], np.int32)
@@ -1350,7 +1274,7 @@ class TestSpeculative:
 
         config, params, _ = spec_model
         serve = ServeConfig(
-            max_new_tokens=7, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=7, prompt_buckets=(8,), num_slots=2,
             draft=DraftConfig(config=config, params=params, spec_k=3),
         )
         rng = np.random.default_rng(12)
@@ -1422,7 +1346,7 @@ class TestSpeculative:
         rng = np.random.default_rng(13)
         prompts = [rng.integers(1, 255, 4).astype(np.int32)]
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=2,
             draft=DraftConfig(
                 config=config, params=draft_params, spec_k=3
             ),
@@ -1439,7 +1363,7 @@ class TestSpeculative:
         assert stats["spec_emitted"] >= stats["spec_chunks"]
 
         k1 = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=1,
             draft=DraftConfig(
                 config=config, params=draft_params, spec_k=1
             ),
@@ -1461,7 +1385,7 @@ class TestSpeculative:
         config, params, _ = spec_model
         prompt = np.asarray([7, 3, 9, 11, 2], np.int32)
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=1,
             kv_quant=True,
             draft=DraftConfig(config=config, params=params, spec_k=2),
         )
@@ -1484,7 +1408,7 @@ class TestSpeculative:
         rng = np.random.default_rng(14)
         prompt = rng.integers(1, 255, 7).astype(np.int32)
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=2,
             prefix_cache_blocks=8, prefix_block_tokens=2,
             prefill_chunk_tokens=4,
             draft=DraftConfig(config=config, params=params, spec_k=2),
@@ -1511,7 +1435,7 @@ class TestSpeculative:
         prompts = [rng.integers(1, 255, n).astype(np.int32)
                    for n in (3, 6)]
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=2,
             mesh_shape=(2, 1),
             draft=DraftConfig(
                 config=config, params=draft_params, spec_k=3
@@ -1550,7 +1474,7 @@ class TestSpeculative:
         dparams = transformer.init(jax.random.PRNGKey(9), dcfg)
         prompt = np.asarray([5, 9, 17, 2], np.int32)
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(8,), batch_buckets=(1,),
+            max_new_tokens=3, prompt_buckets=(8,), num_slots=1,
             mesh_shape=(2, 1),
             draft=DraftConfig(config=dcfg, params=dparams, spec_k=2),
         )
@@ -1571,8 +1495,6 @@ class TestSpeculative:
         with pytest.raises(ValueError, match="params"):
             DraftConfig(config=config)  # forgotten weights fail HERE
         draft = DraftConfig(config=config, params=draft_params)
-        with pytest.raises(ValueError, match="continuous"):
-            ServeConfig(scheduler="batch", draft=draft)
         with pytest.raises(ValueError, match="greedy"):
             ServeConfig(
                 draft=draft,

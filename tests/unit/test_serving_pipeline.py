@@ -92,7 +92,7 @@ def _both_depths(params, config, prompts, budgets, stagger=(), **cfg_kw):
     """The module's core harness: the same workload through a depth-1
     and a depth-2 engine; returns both (results, engine) pairs."""
     base = dict(
-        max_new_tokens=6, prompt_buckets=(8, 16), batch_buckets=(1, 2, 4),
+        max_new_tokens=6, prompt_buckets=(8, 16), num_slots=4,
         chunk_tokens=2, warmup=False,
     )
     base.update(cfg_kw)
@@ -112,10 +112,7 @@ class TestValidation:
             ServeConfig(pipeline_depth=3)
         with pytest.raises(ValueError, match="pipeline_depth"):
             ServeConfig(pipeline_depth=0)
-
-    def test_depth2_needs_continuous(self):
-        with pytest.raises(ValueError, match="pipeline_depth"):
-            ServeConfig(scheduler="batch", pipeline_depth=2)
+        assert ServeConfig(pipeline_depth=2).pipeline_depth == 2
 
 
 class TestParity:
@@ -193,7 +190,7 @@ class TestParity:
         def run(depth):
             serve = ServeConfig(
                 max_new_tokens=256, prompt_buckets=(16,),
-                batch_buckets=(1, 2, 4), chunk_tokens=2, warmup=False,
+                num_slots=4, chunk_tokens=2, warmup=False,
                 prefix_cache_blocks=8, prefix_block_tokens=4,
                 prefill_chunk_tokens=4, pipeline_depth=depth,
             )
@@ -297,7 +294,7 @@ class TestLifecycle:
         config, params = model
         monkeypatch.setenv("CLOUD_TPU_PIPELINE", "0")
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, warmup=False, pipeline_depth=2,
         )
         with ServingEngine(params, config, serve) as engine:
@@ -323,7 +320,7 @@ class TestLifecycle:
         for depth in (1, 2):
             serve = ServeConfig(
                 max_new_tokens=6, prompt_buckets=(8,),
-                batch_buckets=(1, 2), chunk_tokens=2, warmup=False,
+                num_slots=2, chunk_tokens=2, warmup=False,
                 pipeline_depth=depth,
             )
             with tracing.collecting() as collector:
@@ -341,7 +338,7 @@ class TestLifecycle:
         tokens, and no engine thread survives."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=8, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=8, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, warmup=False, pipeline_depth=2,
         )
         prompts = _churn_prompts(lens=(3, 5, 7), seed=6)
@@ -363,7 +360,7 @@ class TestLifecycle:
         is gone — the extended thread-hygiene contract."""
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=64, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=64, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, warmup=False, pipeline_depth=2,
         )
         engine = ServingEngine(params, config, serve)
@@ -389,7 +386,7 @@ class TestLifecycle:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=32, prompt_buckets=(8,), batch_buckets=(1, 2),
+            max_new_tokens=32, prompt_buckets=(8,), num_slots=2,
             chunk_tokens=2, warmup=False, pipeline_depth=2,
         )
         engine = ServingEngine(params, config, serve)

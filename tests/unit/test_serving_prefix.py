@@ -798,25 +798,19 @@ class TestServeConfigKnobs:
             ServeConfig(prefix_cache_blocks=4, prefix_block_tokens=0)
         with pytest.raises(ValueError, match="prefill_chunk_tokens"):
             ServeConfig(prefill_chunk_tokens=0)
-        with pytest.raises(ValueError, match="continuous"):
-            ServeConfig(scheduler="batch", prefix_cache_blocks=4)
-        with pytest.raises(ValueError, match="continuous"):
-            ServeConfig(scheduler="batch", prefill_chunk_tokens=8)
         # ISSUE 15: the DRAM tier needs a non-negative bound AND an HBM
         # pool to demote from.
         with pytest.raises(ValueError, match="prefix_dram_blocks"):
             ServeConfig(prefix_cache_blocks=4, prefix_dram_blocks=-1)
         with pytest.raises(ValueError, match="prefix_dram_blocks"):
             ServeConfig(prefix_dram_blocks=8)
-        # ISSUE 19: a disaggregated role needs the continuous scheduler
-        # AND a prefix pool (the KV handoff is prefix-block traffic),
-        # and the summary TTL must be a positive window or None.
+        # ISSUE 19: a disaggregated role needs a prefix pool (the KV
+        # handoff is prefix-block traffic), and the summary TTL must be
+        # a positive window or None.
         with pytest.raises(ValueError, match="role"):
             ServeConfig(role="router")
         with pytest.raises(ValueError, match="prefix_cache_blocks"):
             ServeConfig(role="prefill")
-        with pytest.raises(ValueError, match="continuous"):
-            ServeConfig(scheduler="batch", role="decode")
         with pytest.raises(ValueError, match="prefix_summary_ttl_s"):
             ServeConfig(prefix_summary_ttl_s=0.0)
         assert ServeConfig(
@@ -887,7 +881,7 @@ class TestPrefixEngine:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(16,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(16,),
             num_slots=2, chunk_tokens=2,
             prefix_cache_blocks=8, prefix_block_tokens=4,
         )
@@ -939,14 +933,14 @@ class TestPrefixEngine:
 
         Slow tier (wall-clock, sharded-serving round): hit parity is
         re-pinned fast by TestShardedPrefix and end to end by
-        check_serving.py phase 3; the match-vs-acquire eviction
+        check_serving.py phase 2; the match-vs-acquire eviction
         semantics stay pinned fast at manager level in
         TestPrefixCacheManager."""
         from cloud_tpu.serving import ServeConfig, ServingEngine
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=3, prompt_buckets=(16,), batch_buckets=(1, 2),
+            max_new_tokens=3, prompt_buckets=(16,),
             num_slots=2, chunk_tokens=2,
             prefix_cache_blocks=8, prefix_block_tokens=4,
         )
@@ -1003,7 +997,7 @@ class TestPrefixEngine:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(16,), batch_buckets=(1,),
+            max_new_tokens=4, prompt_buckets=(16,),
             num_slots=1, chunk_tokens=2,
             prefix_cache_blocks=3, prefix_block_tokens=4,
         )
@@ -1041,7 +1035,7 @@ class TestChunkedPrefill:
         config, params = model
         serve = ServeConfig(
             max_new_tokens=12, prompt_buckets=(4, 16),
-            batch_buckets=(1, 2), num_slots=2, chunk_tokens=1,
+            num_slots=2, chunk_tokens=1,
             prefill_chunk_tokens=4,
         )
         rng = np.random.default_rng(8)
@@ -1104,7 +1098,7 @@ class TestChunkedPrefill:
         config, params = model
         serve = ServeConfig(
             max_new_tokens=5, prompt_buckets=(8, 16),
-            batch_buckets=(1, 2, 4), num_slots=4, chunk_tokens=2,
+            num_slots=4, chunk_tokens=2,
             prefix_cache_blocks=8, prefix_block_tokens=4,
             prefill_chunk_tokens=4,
         )
@@ -1187,7 +1181,7 @@ class TestPrefixTierEngine:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=2, prompt_buckets=(16,), batch_buckets=(1,),
+            max_new_tokens=2, prompt_buckets=(16,),
             num_slots=1, chunk_tokens=2,
             prefix_cache_blocks=4, prefix_block_tokens=4,
         )
@@ -1219,7 +1213,7 @@ class TestPrefixTierEngine:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(16,), batch_buckets=(1,),
+            max_new_tokens=4, prompt_buckets=(16,),
             num_slots=1, chunk_tokens=2,
             prefix_cache_blocks=3, prefix_block_tokens=4,
             prefix_dram_blocks=8,
@@ -1276,7 +1270,7 @@ class TestPrefixTierEngine:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(16,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(16,),
             num_slots=2, chunk_tokens=2,
             prefix_cache_blocks=3, prefix_block_tokens=4,
             prefix_dram_blocks=3,  # small enough to dram-evict too
@@ -1326,7 +1320,7 @@ class TestShardedPrefix:
 
         config, params = model
         serve = ServeConfig(
-            max_new_tokens=4, prompt_buckets=(16,), batch_buckets=(1, 2),
+            max_new_tokens=4, prompt_buckets=(16,),
             num_slots=2, chunk_tokens=2,
             prefix_cache_blocks=8, prefix_block_tokens=4,
             prefill_chunk_tokens=4,

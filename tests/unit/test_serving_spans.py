@@ -219,24 +219,14 @@ def test_a_referenced_pool_block_counts_once_as_in_use(model):
     assert stats["kv_row_steps_in_use"] == (8 + 1) + 4
 
 
-@pytest.mark.parametrize("overrides, names", [
-    (dict(pipeline_depth=2),
-     IN_A_PASS + ("serve/pass", "serve/request", "serve/ttft")),
-    (dict(scheduler="batch", batch_buckets=(1, 2, 4), flush_deadline_s=0.0),
-     ("serve/launch", "serve/readback", "serve/prefill", "serve/request",
-      "serve/ttft")),
-], ids=["pipeline_depth=2", "batch"])
-def test_the_same_names_on_the_other_paths(model, overrides, names):
-    events, results, _ = _serve(model, **overrides)
+def test_the_same_names_at_pipeline_depth_2(model):
+    events, results, _ = _serve(model, pipeline_depth=2)
     recorded = {e["name"] for e in events}
-    assert set(names) <= recorded
+    assert set(IN_A_PASS + ("serve/pass", "serve/request", "serve/ttft")) \
+        <= recorded
     for name in ("serve/request", "serve/ttft"):
         assert sorted(e["args"]["trace_id"] for e in _named(events, name)) \
             == sorted(r.trace_id for r in results)
-    if overrides.get("scheduler") == "batch":
-        assert "serve/pass" not in recorded
-        assert all(e["args"]["passes"] == 1
-                   for e in _named(events, "serve/request"))
 
 
 def test_collector_off_counts_kv_and_records_nothing(model):

@@ -280,11 +280,9 @@ class TestReport:
             # phases are ordinary context-manager spans.
             tracing.record_span("serve/queue_wait", now - 0.05, now)
             tracing.record_span("serve/queue_wait", now - 0.01, now)
-            with tracing.span("serve/batch_form"):
-                pass
             with tracing.span("serve/prefill"):
                 time.sleep(0.004)
-            with tracing.span("serve/decode"):
+            with tracing.span("serve/chunk"):
                 time.sleep(0.008)
             return tracing.dump_timeline(str(tmp_path / "serve.json"))
 
@@ -294,8 +292,7 @@ class TestReport:
         rows = report.serving_rows()
         # Request order, not cost order; the training span is excluded.
         assert [r["name"] for r in rows] == [
-            "serve/queue_wait", "serve/batch_form", "serve/prefill",
-            "serve/decode",
+            "serve/queue_wait", "serve/prefill", "serve/chunk",
         ]
         assert rows[0]["count"] == 2  # both queue waits aggregated
         assert abs(sum(r["pct_serve"] for r in rows) - 100.0) < 1e-6
