@@ -298,9 +298,15 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
     reads it, advances it by the token and writes it back at ``[l]``.
     A row whose K/V write is suppressed is FROZEN: its state and its
     convolution's tail come back bit for bit (the drop-mode scatter has
-    no equivalent for a leaf without positions).
+    no equivalent for a leaf without positions).  Wherever its kernel
+    would run (``ops.ssm_state.takes_kernel``: a TPU, a float32 state in
+    whole tiles) the ``ssm`` leaf is taken WHOLE with the layer index and
+    the live rows, like K and V, and advanced in place: a frozen row's
+    state is neither fetched nor written.  Elsewhere, and for the
+    convolution's tail everywhere, the layer is read by index, advanced,
+    and a frozen row rewritten with its own bytes.
     """
-    from cloud_tpu.ops import paged_attention
+    from cloud_tpu.ops import paged_attention, ssm_state
 
     b, t, _ = x.shape
     if kind != "decode" or slot is not None or block_table is not None:
@@ -320,6 +326,8 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
              "batch_axes": rules.assignment("batch")}
     # Rows that advance (their write column is in range).
     live = (write_cols[:, 0] >= 0) & (write_cols[:, 0] < cache["k"].shape[2])
+    state_in_place = config.ssm is not None and ssm_state.takes_kernel(
+        cache["ssm"], config.ssm.num_groups, use_pallas)
 
     def layer_of(leaf, l):
         if slot is None:
@@ -362,13 +370,21 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
         mixed = transformer.attention_out(layer_params["att"], attended,
                                           config)
         if config.ssm is not None:
-            held = {name: jax.lax.dynamic_index_in_dim(
-                cache[name], l, keepdims=False) for name in STATE_LEAVES}
-            ssm_out, state, tail = ssm_lib.ssm_step(
-                layer_params["ssm"], y[:, 0], held["ssm"], held["conv"],
-                config.ssm, config.multipliers, config.norm_eps,
-            )
-            for name, new in (("ssm", state), ("conv", tail)):
+            mixer = (layer_params["ssm"], y[:, 0])
+            sizes = (config.ssm, config.multipliers, config.norm_eps)
+            held = {"conv": jax.lax.dynamic_index_in_dim(
+                cache["conv"], l, keepdims=False)}
+            if state_in_place:
+                ssm_out, cache["ssm"], tail = ssm_lib.ssm_step_in_place(
+                    *mixer, cache["ssm"], l, live, held["conv"], *sizes)
+                fresh = {"conv": tail}
+            else:
+                held["ssm"] = jax.lax.dynamic_index_in_dim(
+                    cache["ssm"], l, keepdims=False)
+                ssm_out, state, tail = ssm_lib.ssm_step(
+                    *mixer, held["ssm"], held["conv"], *sizes)
+                fresh = {"ssm": state, "conv": tail}
+            for name, new in fresh.items():
                 keep = live.reshape((b,) + (1,) * (new.ndim - 1))
                 new = jnp.where(keep, new.astype(held[name].dtype),
                                 held[name])

@@ -17,7 +17,9 @@ Two entry points share the projections: :func:`ssd_prefill` runs a whole
 prompt in the chunked (SSD) form — quadratic inside a chunk, a scan
 over chunk states between them — and returns the state and the
 convolution's tail AT EACH ROW'S LAST REAL TOKEN; :func:`ssm_step`
-advances one token from a carried state.  Both are plain ``jnp``.
+advances one token from a carried state.  Both are plain ``jnp``;
+:func:`ssm_step_in_place` is the step with the state's own update handed
+to ``ops.ssm_state``'s kernel, over the stacked leaf of a slot cache.
 
 What the recurrence keeps is float32 (:data:`STATE_DTYPE`): the state is
 multiplied by a decay and added to at every token, so a narrower type
@@ -243,22 +245,51 @@ def ssd_prefill(params, u, mask, prompt_lens, cfg: SsmConfig, mult, eps):
     return out, state, tail
 
 
+def _step_inputs(params, u, conv, cfg: SsmConfig, mult):
+    """What one token brings to the recurrence: the gate ``z`` [B, d_ssm],
+    x [B, H, P], the groups' B and C [B, G, N], the step dt [B, H] and
+    A [H] (float32), and the convolution's new tail."""
+    z, xbc, dt = _project(params, u, cfg, mult)
+    window = jnp.concatenate([conv, xbc[:, None].astype(conv.dtype)], axis=1)
+    conv_out = jnp.sum(
+        params["conv"]["kernel"] * window.astype(jnp.float32), axis=1)
+    return (z,) + _after_conv(params, conv_out, dt, cfg) + (window[:, 1:],)
+
+
+def _step_output(params, y, x, z, cfg: SsmConfig, mult, eps, dtype):
+    """``y = h' C`` [B, H, P] -> the mixer's output [B, D]."""
+    y = y + params["D"][:, None] * x
+    return _gate_norm_out(params, y.reshape(y.shape[0], cfg.d_ssm), z, cfg,
+                          mult, eps, dtype)
+
+
 def ssm_step(params, u, state, conv, cfg: SsmConfig, mult, eps):
     """One token: ``u`` [B, D], ``state`` [B, H, P, N] float32, ``conv``
     [B, W - 1, conv_dim] the last W - 1 convolution inputs.  Returns the
     mixer's output [B, D], the new state (float32) and the new tail.
     The state is read once and written once, elementwise."""
-    z, xbc, dt = _project(params, u, cfg, mult)
-    window = jnp.concatenate([conv, xbc[:, None].astype(conv.dtype)], axis=1)
-    conv_out = jnp.sum(
-        params["conv"]["kernel"] * window.astype(jnp.float32), axis=1)
-    x, b_mat, c_mat, dt, a = _after_conv(params, conv_out, dt, cfg)
+    z, x, b_mat, c_mat, dt, a, tail = _step_inputs(params, u, conv, cfg, mult)
     b_mat, c_mat = _to_heads(b_mat, cfg), _to_heads(c_mat, cfg)  # [B, H, N]
     keep = jnp.exp(dt * a)                                        # [B, H]
     state = (keep[..., None, None] * state.astype(jnp.float32)
              + (dt[..., None] * x)[..., None] * b_mat[:, :, None, :])
     y = jnp.sum(state * c_mat[:, :, None, :], axis=-1)            # [B, H, P]
-    y = y + params["D"][:, None] * x
-    out = _gate_norm_out(params, y.reshape(y.shape[0], cfg.d_ssm), z, cfg,
-                         mult, eps, u.dtype)
-    return out, state, window[:, 1:]
+    return _step_output(params, y, x, z, cfg, mult, eps, u.dtype), state, tail
+
+
+def ssm_step_in_place(params, u, states, layer, live, conv, cfg: SsmConfig,
+                      mult, eps):
+    """:func:`ssm_step` over the stacked leaf ``states`` [L, B, H, P, N]
+    taken whole: layer ``layer`` of it advances in place, through
+    ``ops.ssm_state``'s kernel, for the rows where ``live`` [B] is true,
+    and no other byte of it moves.  Returns the mixer's output [B, D]
+    (for a row that did not advance, of a zero ``h' C``: whoever froze
+    the row masks it), the leaf and the new tail.  The projections, the
+    convolution and the gate are :func:`ssm_step`'s own."""
+    from cloud_tpu.ops import ssm_state
+
+    z, x, b_mat, c_mat, dt, a, tail = _step_inputs(params, u, conv, cfg, mult)
+    states, y = ssm_state.state_step(
+        states, layer, live, jnp.exp(dt * a), dt[..., None] * x, b_mat,
+        c_mat)
+    return _step_output(params, y, x, z, cfg, mult, eps, u.dtype), states, tail

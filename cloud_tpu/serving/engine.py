@@ -113,8 +113,11 @@ with one scheduler behind a submit/future/admission surface:
   ``health()``, reports ``kv_bytes_reserved`` / ``kv_bytes_in_use``.
   A model with a recurrent state (``TransformerConfig.ssm``) keeps one
   state row a slot a layer beside the K/V rows: ``state_row_steps_*``
-  and ``state_bytes_*`` count them the same way (zeros otherwise), and
-  ``serve/pass`` carries ``state_rows_in_use``.
+  and ``state_bytes_*`` count them the same way (zeros otherwise),
+  ``state_row_steps_read`` the rows a decode step fetches (every
+  reserved row, or the decoding slots' alone where ``ops.ssm_state``'s
+  kernel advances them), and ``serve/pass`` carries
+  ``state_rows_in_use``.
   ``serve/qps`` and ``serve/tokens_per_sec``
   windowed-rate gauges, the ``serve/slot_occupancy`` gauge,
   slot-churn counters
@@ -758,6 +761,7 @@ class ServingEngine:
         import jax
 
         from cloud_tpu.models import generation
+        from cloud_tpu.ops import ssm_state
         from cloud_tpu.parallel import mesh as mesh_lib
         from cloud_tpu.parallel.sharding import DEFAULT_RULES
         from cloud_tpu.training import compile_cache
@@ -866,8 +870,10 @@ class ServingEngine:
             "kv_row_steps_reserved": 0, "kv_row_steps_in_use": 0,
             "kv_row_steps_read": 0,
             # The same for a recurrent state's rows, one a slot a layer
-            # (0 for a model without one).
+            # (0 for a model without one); read: every reserved row, or
+            # the decoding slots' where the state kernel advances them.
             "state_row_steps_reserved": 0, "state_row_steps_in_use": 0,
+            "state_row_steps_read": 0,
             # QoS brownout sheds (0 unless qos arms a brownout depth).
             "brownout_shed": 0,
             # Disaggregated-serving KV handoff counters (all 0 with
@@ -1017,6 +1023,13 @@ class ServingEngine:
             cfg.num_slots * config.num_layers if state_leaves else 0
         )
         self._state_rows_in_use = 0
+        #: Whether a decode step advances the state through
+        #: ``ops.ssm_state``'s kernel, which fetches the decoding
+        #: slots' rows alone (``generation._scan_layers``' own rule),
+        #: or reads and rewrites every reserved row.
+        self._state_read_in_place = bool(state_leaves) and (
+            ssm_state.takes_kernel(self._grid_cache["ssm"],
+                                   config.ssm.num_groups))
         #: Block-table attention (``decode_kernel != "xla"``): the
         #: slot grid's attention reads KV through a per-slot block
         #: table — page p of a row resolves to a prefix-pool block
@@ -3308,7 +3321,9 @@ class ServingEngine:
         mid-prefill, costs grid steps and no rows; a verify window reads
         a page more at most, not counted).  A recurrent state's rows
         are counted beside them: one a layer for every slot in the
-        chunk."""
+        chunk, and as ``state_row_steps_read`` the rows a decode step
+        fetches: those, where the state kernel advances them in place,
+        else every reserved row."""
         rows = sum(task.next_pos for task in self._prefill_tasks)
         page = self._decode_read_page
         read = 0 if page else self.serve_config.num_slots * self._max_len
@@ -3340,6 +3355,10 @@ class ServingEngine:
                 self._state_rows_reserved
             )
             self._stats["state_row_steps_in_use"] += self._state_rows_in_use
+            self._stats["state_row_steps_read"] += (
+                self._state_rows_in_use if self._state_read_in_place
+                else self._state_rows_reserved
+            )
 
     def _note_dispatch_gap(self, start: float) -> None:
         """Record the host gap between the previous chunk dispatch and

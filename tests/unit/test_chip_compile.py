@@ -72,6 +72,12 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _tree_spec(sharding, tree, dtype=None):
+    """A tree of shapes on ``sharding``, recast to ``dtype`` if given."""
+    return jax.tree_util.tree_map(
+        lambda x: _spec(x.shape, dtype or x.dtype, sharding), tree)
+
+
 def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -302,10 +308,7 @@ def test_slot_cache_is_updated_in_place(one_chip, program, kv_quant):
     )
     sample = generation.SampleConfig(temperature=0.0)
 
-    def on_chip(tree, dtype=None):
-        return jax.tree_util.tree_map(
-            lambda x: _spec(x.shape, dtype or x.dtype, one_chip), tree)
-
+    on_chip = functools.partial(_tree_spec, one_chip)
     params = on_chip(jax.eval_shape(
         lambda: transformer.init(jax.random.PRNGKey(0), config)),
         jnp.bfloat16)
@@ -377,10 +380,7 @@ def test_decode_chunk_reads_the_carried_cache_in_place(
     )
     sample = generation.SampleConfig(temperature=0.0)
 
-    def on_chip(tree, dtype=None):
-        return jax.tree_util.tree_map(
-            lambda x: _spec(x.shape, dtype or x.dtype, one_chip), tree)
-
+    on_chip = functools.partial(_tree_spec, one_chip)
     params = on_chip(jax.eval_shape(
         lambda: transformer.init(jax.random.PRNGKey(0), config)),
         jnp.bfloat16)
@@ -411,3 +411,85 @@ def test_decode_chunk_reads_the_carried_cache_in_place(
     sliced = [ln.strip()[:200] for ln in lines
               if re.match(r"\s*(ROOT )?%[\w.\-]+ = " + re.escape(layer), ln)]
     assert not sliced, sliced
+
+
+# ---------------------------------------------------------------------------
+# The hybrid decode chunk at falcon-h1-34b-stage.chat-open's shapes: the
+# recurrent state is advanced in place, by one kernel call a layer
+# ---------------------------------------------------------------------------
+
+
+def test_hybrid_decode_chunk_advances_the_state_leaf_in_place(
+        one_chip, monkeypatch):
+    """The cell's chunk program (32 slots x 640 rows, 6 blocks at the
+    published widths) holds ONE ``ssm_state_step`` call (the layer
+    loop's) whose operand and result is the whole carried
+    ``f32[6,32,32,128,256]`` leaf, aliased; nothing else in the program
+    produces that leaf (no copy, no dynamic-update-slice, no fresh
+    buffer) or a layer of it, and nothing else reads it; temp is no
+    larger than the parent's 0.80 GB (the four re-laid-out projection
+    kernels: ``benchmarks/rehearse_compile_hybrid.py`` at PR 30)."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.adapters import serve_hybrid
+    from benchmarks.harness import manifest
+    from benchmarks.references import falcon_h1
+    from cloud_tpu.models import generation
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = manifest.Cell("falcon-h1-34b-stage.chat-open", root=root)
+    sizes, engine = cell.config, cell.traffic["engine"]
+    config = serve_hybrid.model_config(sizes, cell.traffic)
+    sample = generation.SampleConfig(temperature=0.0)
+    rows = engine["prompt_buckets"][-1] + engine["max_new_tokens"]
+
+    on_chip = functools.partial(_tree_spec, one_chip)
+    params = on_chip(falcon_h1.params_shape(sizes))
+    cache = on_chip(jax.eval_shape(lambda: generation.init_slot_cache(
+        config, engine["num_slots"], rows)))
+    state = on_chip(jax.eval_shape(lambda: generation.init_slot_state(
+        config, engine["num_slots"], sample=sample)))
+
+    def fn(params, cache, state, rng):
+        return generation.decode_chunk_program(
+            params, cache, state, config, chunk_size=engine["chunk_tokens"],
+            sample=sample, rng=rng)
+
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, cache, state, _spec((2,), jnp.uint32, one_chip)).compile()
+    assert cache["ssm"].shape == (6, 32, 32, 128, 256)
+    leaf = "f32[6,32,32,128,256]"
+    lines = compiled.as_text().splitlines()
+    made = {}  # instruction name -> opcode, of everything typed as the leaf
+    for line in lines:
+        match = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\(?)" + re.escape(leaf)
+                         + r"\S* .*?([\w\-]+)\(", line)
+        if match:
+            made[match.group(1)] = (match.group(3), bool(match.group(2)))
+    calls = [name for name in made
+             if re.match(r"%ssm_state_step(\.\d+)?$", name)]
+    assert len(calls) == 1, made
+    call_line = next(ln for ln in lines
+                     if re.match(r"\s*" + re.escape(calls[0]) + " = ", ln))
+    assert "output_to_operand_aliasing={{0}: (8, {})}" in call_line
+    # The leaf only ever comes out of a parameter, a loop's tuple or the
+    # call: never out of a copy, an update, a fusion or a fresh buffer.
+    passed_on = {"parameter", "get-tuple-element", "while", "tuple"}
+    moved = {name: op for name, (op, _) in made.items()
+             if op not in passed_on and name not in calls}
+    assert not moved, moved
+    # ...and only the call (and the loops' plumbing) takes it as operand.
+    holders = {name for name, (_, in_tuple) in made.items() if not in_tuple}
+    readers = []
+    for line in lines:
+        match = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = .*? ([\w\-]+)\((.*)",
+                         line)
+        if not match or match.group(2) in passed_on or \
+                match.group(1) in calls:
+            continue
+        if holders & set(re.findall(r"%[\w.\-]+", match.group(3))):
+            readers.append(line.strip()[:200])
+    assert not readers, readers
+    assert not [ln for ln in lines if "f32[32,32,128,256]" in ln]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 0.81e9

@@ -25,6 +25,7 @@ if ROOT not in sys.path:
 from benchmarks.adapters import serve_hybrid  # noqa: E402
 from benchmarks.references import falcon_h1  # noqa: E402
 from cloud_tpu.models import generation, layers, ssm, transformer  # noqa: E402
+from cloud_tpu.ops import ssm_state  # noqa: E402
 from cloud_tpu.serving import DraftConfig, ServeConfig, ServingEngine  # noqa: E402
 
 SEED = 2 ** 31 + 28
@@ -44,6 +45,11 @@ BUCKET, CHUNK, SLOTS, NEW = 16, 4, 3, 12
 MIX = {"engine": {"prompt_buckets": [BUCKET], "max_new_tokens": NEW}}
 CONFIG = serve_hybrid.model_config(SIZES, MIX).scaled(dtype=jnp.float32)
 GREEDY = generation.SampleConfig(temperature=0.0)
+#: The same model with a state that tiles as the state-step kernel needs
+#: (``ops.ssm_state``: N a multiple of 128), so that the programs below
+#: can run once more with the kernel, interpreted, in the layer loop.
+TILED_SIZES = {**SIZES, "mamba_d_state": 128}
+TILED = serve_hybrid.model_config(TILED_SIZES, MIX).scaled(dtype=jnp.float32)
 
 #: Program against reference, in units of the row's logit standard
 #: deviation.  Both compute in float32; they differ in the order of
@@ -62,19 +68,43 @@ def params():
     return falcon_h1.make_params(SEED, SIZES, dtype=jnp.float32)
 
 
-def reference_logits(params, tokens):
+@dataclasses.dataclass
+class Model:
+    """One model under test: the reference's sizes, the program's
+    configuration, the weights."""
+    sizes: dict
+    config: transformer.TransformerConfig
+    params: dict
+
+
+@pytest.fixture(params=["jnp", "kernel"])
+def model(request, params, monkeypatch):
+    """The tiny model with the state advanced by the ``jnp`` step, and
+    its tiled twin with the state-step kernel in the layer loop (the
+    interpreter armed, as the chip would arm the kernel)."""
+    if request.param == "jnp":
+        yield Model(SIZES, CONFIG, params)
+        return
+    monkeypatch.setenv("CLOUD_TPU_FLASH_FORCE_INTERPRET", "1")
+    traced = ssm_state.KERNEL_TRACE_COUNT
+    yield Model(TILED_SIZES, TILED, falcon_h1.make_params(
+        SEED, TILED_SIZES, dtype=jnp.float32))
+    assert ssm_state.KERNEL_TRACE_COUNT > traced
+
+
+def reference_logits(params, tokens, sizes=SIZES):
     """The reference's full forward pass over one sequence: [T, V]."""
-    key = falcon_h1._sizes_key(SIZES)
-    k_embed, k_layers, _, _ = falcon_h1._keys(SEED, SIZES)
+    key = falcon_h1._sizes_key(sizes)
+    k_embed, k_layers, _, _ = falcon_h1._keys(SEED, sizes)
     with jax.default_matmul_precision("highest"):
         xs = falcon_h1._embed(k_embed, jnp.asarray(tokens, jnp.int32)[None],
                               key, jnp.float32)
         for k in k_layers:
             xs = falcon_h1._apply_layer(k, xs, key, "f32", jnp.float32)
         y = falcon_h1._rmsnorm(xs[0], params["ln_f"]["scale"],
-                               SIZES["rms_norm_eps"])
+                               sizes["rms_norm_eps"])
         return np.asarray(falcon_h1.matmul(y, params["head"]["kernel"], "f32")
-                          * SIZES["lm_head_multiplier"])
+                          * sizes["lm_head_multiplier"])
 
 
 def gap(logits, reference):
@@ -112,18 +142,18 @@ def _chunk(params, cache, state, config=CONFIG):
         params, cache, state, config, chunk_size=CHUNK, sample=GREEDY)
 
 
-def _served_logits(params, prompt, slot, steps, cache=None):
+def _served_logits(params, prompt, slot, steps, cache=None, config=CONFIG):
     """Prefill ``prompt`` at a bucket longer than it into ``slot`` of a
     grid whose other slots stand idle, then ``steps`` single-token steps
     through the slot cache, feeding each step its own greedy token: the
     logits the slot programs sample from ([1 + steps, V]), the tokens,
     and the cache."""
-    cache = _grid()[0] if cache is None else cache
+    cache = _grid(config)[0] if cache is None else cache
     left, logits0 = generation._prefill_forward(
-        params, _padded(prompt), jnp.array([len(prompt)]), CONFIG,
+        params, _padded(prompt), jnp.array([len(prompt)]), config,
         transformer.DEFAULT_RULES, None)
     cache = generation._write_prefill(cache, left, (0, slot, 0, 0, 0),
-                                      CONFIG)
+                                      config)
     rows, tokens = [logits0[0]], [int(jnp.argmax(logits0[0]))]
     idle = BUCKET + NEW  # out of range: the other slots write nowhere
     for i in range(steps):
@@ -132,7 +162,7 @@ def _served_logits(params, prompt, slot, steps, cache=None):
         write = jnp.full((SLOTS,), idle, jnp.int32).at[slot].set(
             len(prompt) + i)
         cache, logits = generation._decode_step(
-            params, cache, tok, pos, CONFIG, transformer.DEFAULT_RULES, None,
+            params, cache, tok, pos, config, transformer.DEFAULT_RULES, None,
             write_pos=write)
         rows.append(logits[slot])
         tokens.append(int(jnp.argmax(logits[slot])))
@@ -143,17 +173,21 @@ def _served_logits(params, prompt, slot, steps, cache=None):
 
 
 def test_prefill_then_two_chunks_of_decode_match_the_full_forward_pass(
-        params):
+        model):
+    params, config = model.params, model.config
     prompt = _prompt(11)
-    served, tokens, _ = _served_logits(params, prompt, 1, 2 * CHUNK)
-    full = reference_logits(params, np.concatenate([prompt, tokens[:-1]]))
+    served, tokens, _ = _served_logits(params, prompt, 1, 2 * CHUNK,
+                                       config=config)
+    full = reference_logits(params, np.concatenate([prompt, tokens[:-1]]),
+                            model.sizes)
     assert gap(served, full[len(prompt) - 1:]) < LOGIT_TOLERANCE
     # The slot programs emit exactly the tokens those logits put first.
-    cache, state = _grid()
-    cache, state, tok0 = _insert(params, cache, state, prompt, 1, NEW)
+    cache, state = _grid(config)
+    cache, state, tok0 = _insert(params, cache, state, prompt, 1, NEW,
+                                 config)
     emitted = [int(tok0)]
     for _ in range(2):
-        cache, state, toks, valid = _chunk(params, cache, state)
+        cache, state, toks, valid = _chunk(params, cache, state, config)
         assert bool(valid[1].all()) and not bool(valid[0].any())
         emitted += [int(t) for t in toks[1]]
     assert emitted == tokens
@@ -221,22 +255,23 @@ def test_chunked_scan_matches_the_recurrence_off_the_chunk_grid(params):
 
 
 def test_frozen_slot_keeps_its_state_and_a_reused_slot_carries_nothing(
-        params):
+        model):
+    params, config = model.params, model.config
     first, second, short, late = (_prompt(n, seed=n) for n in (10, 16, 5, 7))
-    cache, state = _grid()
-    cache, state, _ = _insert(params, cache, state, first, 0, NEW)
+    cache, state = _grid(config)
+    cache, state, _ = _insert(params, cache, state, first, 0, NEW, config)
     # The third slot's request ends inside this chunk (2 of 4 steps).
-    cache, state, _ = _insert(params, cache, state, short, 2, 3)
-    cache, state, toks_a, valid = _chunk(params, cache, state)
+    cache, state, _ = _insert(params, cache, state, short, 2, 3, config)
+    cache, state, toks_a, valid = _chunk(params, cache, state, config)
     assert valid[2].tolist() == [True, True, False, False]
     assert not bool(state["active"][2])
     # A chunk later the second slot is inserted, beside the running first.
-    cache, state, _ = _insert(params, cache, state, second, 1, NEW)
+    cache, state, _ = _insert(params, cache, state, second, 1, NEW, config)
     frozen = {name: np.asarray(cache[name][:, 2]) for name in
               generation.STATE_LEAVES}
     running = {name: np.asarray(cache[name][:, 0]) for name in
                generation.STATE_LEAVES}
-    cache, state, toks_b, valid = _chunk(params, cache, state)
+    cache, state, toks_b, valid = _chunk(params, cache, state, config)
     assert bool(valid[0].all()) and bool(valid[1].all())
     assert not bool(valid[2].any())
     for name, before in frozen.items():
@@ -246,26 +281,138 @@ def test_frozen_slot_keeps_its_state_and_a_reused_slot_carries_nothing(
         assert not np.array_equal(running[name],
                                   np.asarray(cache[name][:, 0]))
     # Each live slot decodes as it would alone.
-    alone = _served_logits(params, first, 0, 2 * CHUNK)[1]
+    alone = _served_logits(params, first, 0, 2 * CHUNK, config=config)[1]
     assert [int(t) for t in toks_a[0]] + [int(t) for t in toks_b[0]] == \
         alone[1:]
     # The retired slot is reused: the newcomer's state and tokens are
     # those of a grid that never held anything.
-    cache, state, tok0 = _insert(params, cache, state, late, 2, NEW)
-    fresh_cache, fresh_state = _grid()
+    cache, state, tok0 = _insert(params, cache, state, late, 2, NEW, config)
+    fresh_cache, fresh_state = _grid(config)
     fresh_cache, fresh_state, fresh_tok0 = _insert(
-        params, fresh_cache, fresh_state, late, 2, NEW)
+        params, fresh_cache, fresh_state, late, 2, NEW, config)
     assert int(tok0) == int(fresh_tok0)
     for name in generation.STATE_LEAVES:
         np.testing.assert_array_equal(np.asarray(cache[name][:, 2]),
                                       np.asarray(fresh_cache[name][:, 2]))
-    cache, state, toks, _ = _chunk(params, cache, state)
+    cache, state, toks, _ = _chunk(params, cache, state, config)
     fresh_cache, fresh_state, fresh_toks, _ = _chunk(
-        params, fresh_cache, fresh_state)
+        params, fresh_cache, fresh_state, config)
     assert toks[2].tolist() == fresh_toks[2].tolist()
     for name in generation.STATE_LEAVES:
         np.testing.assert_array_equal(np.asarray(cache[name][:, 2]),
                                       np.asarray(fresh_cache[name][:, 2]))
+
+
+# -- (c') the state-step kernel against the jnp step ----------------------
+
+#: Widths that keep Falcon-H1's state tile (P = 128, N = 256) and a block
+#: of 8 heads, small everywhere else.
+WIDE_SIZES = {**SIZES, "mamba_n_heads": 8, "mamba_d_head": 128,
+              "mamba_d_ssm": 1024, "mamba_d_state": 256}
+WIDE = serve_hybrid.model_config(WIDE_SIZES, MIX).scaled(dtype=jnp.float32)
+LIVE_MASKS = {"mixed": [False, True, False, True, True], "all": [True] * 5,
+              "none": [False] * 5,
+              "first-and-last-frozen": [False, False, True, True, False]}
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return falcon_h1.make_params(SEED, WIDE_SIZES, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("mask", LIVE_MASKS.values(), ids=LIVE_MASKS)
+def test_state_kernel_matches_the_jnp_step(wide_params, mask):
+    """One mixer step over a stacked leaf, the kernel (interpreted)
+    against ``ssm_step``: the mixer's output and the new state of a row
+    that advances to float32 rounding; every other byte of the leaf, a
+    frozen row's and the other layer's, as it was."""
+    cfg, mult, eps = WIDE.ssm, WIDE.multipliers, WIDE.norm_eps
+    layer = jax.tree_util.tree_map(lambda x: x[1],
+                                   wide_params["layers"]["ssm"])
+    live = np.array(mask)
+    keys = jax.random.split(jax.random.PRNGKey(31), 3)
+    u = jax.random.normal(keys[0], (len(mask), WIDE.dim))
+    states = jax.random.normal(
+        keys[1], (2, len(mask), cfg.num_heads, cfg.head_dim, cfg.state_dim))
+    conv = jax.random.normal(
+        keys[2], (len(mask), cfg.conv_width - 1, cfg.conv_dim))
+    want_out, want_state, want_tail = ssm.ssm_step(
+        layer, u, states[1], conv, cfg, mult, eps)
+    traced = ssm_state.KERNEL_TRACE_COUNT
+    out, new, tail = ssm.ssm_step_in_place(
+        layer, u, states, 1, jnp.asarray(live), conv, cfg, mult, eps)
+    assert ssm_state.KERNEL_TRACE_COUNT == traced + 1
+    np.testing.assert_allclose(out[live], want_out[live], rtol=2e-5,
+                               atol=2e-6)
+    np.testing.assert_allclose(new[1][live], want_state[live], rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_array_equal(tail, want_tail)
+    np.testing.assert_array_equal(new[1][~live], states[1][~live])
+    np.testing.assert_array_equal(new[0], states[0])
+
+
+@pytest.mark.parametrize("mask", list(LIVE_MASKS.values())[:3],
+                         ids=list(LIVE_MASKS)[:3])
+def test_decode_step_with_the_kernel_freezes_state_and_tail_bit_for_bit(
+        wide_params, monkeypatch, mask):
+    """A decode step of five slots through ``_scan_layers``, the state
+    kernel in its layer loop against the ``jnp`` step: logits and state
+    of a slot that advances agree to float32 rounding, and a slot whose
+    write is suppressed keeps its state AND its convolution's tail bit
+    for bit, in every layer."""
+    slots, rows = len(mask), BUCKET + NEW
+    live = np.array(mask)
+    cache = generation.init_slot_cache(WIDE, slots, rows)
+    keys = jax.random.split(jax.random.PRNGKey(32), len(cache))
+    cache = {name: jax.random.normal(key, leaf.shape, leaf.dtype)
+             for key, (name, leaf) in zip(keys, sorted(cache.items()))}
+    tok = jnp.arange(1, slots + 1, dtype=jnp.int32)
+    pos = jnp.full((slots,), 6, jnp.int32)
+    write = jnp.where(jnp.asarray(live), pos, rows)  # out of range: frozen
+
+    def step():
+        return generation._decode_step(
+            wide_params, dict(cache), tok, pos, WIDE,
+            transformer.DEFAULT_RULES, None, write_pos=write)
+
+    want_cache, want_logits = step()
+    monkeypatch.setenv("CLOUD_TPU_FLASH_FORCE_INTERPRET", "1")
+    traced = ssm_state.KERNEL_TRACE_COUNT
+    got_cache, logits = step()
+    assert ssm_state.KERNEL_TRACE_COUNT == traced + 1  # one call a loop
+    np.testing.assert_allclose(logits[live], want_logits[live], rtol=1e-4,
+                               atol=1e-5)
+    np.testing.assert_allclose(got_cache["ssm"], want_cache["ssm"],
+                               rtol=1e-6, atol=1e-6)
+    for name in generation.STATE_LEAVES:
+        np.testing.assert_array_equal(got_cache[name][:, ~live],
+                                      cache[name][:, ~live])
+        if live.any():
+            assert not np.array_equal(got_cache[name][:, live],
+                                      cache[name][:, live])
+    np.testing.assert_allclose(got_cache["conv"], want_cache["conv"],
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_state_kernel_is_taken_by_what_the_code_can_see(monkeypatch):
+    """``takes_kernel``: off a TPU only with the interpreter armed; never
+    for a state that is not float32 or does not tile; ``use_pallas=True``
+    raises where the kernel cannot run."""
+    tiled = jax.ShapeDtypeStruct((2, 3, 8, 16, 128), jnp.float32)
+    untiled = jax.ShapeDtypeStruct((2, 3, 4, 16, 16), jnp.float32)
+    narrow = jax.ShapeDtypeStruct(tiled.shape, jnp.bfloat16)
+    assert not ssm_state.takes_kernel(tiled, 2)
+    assert ssm_state.takes_kernel(tiled, 2, use_pallas=True)
+    monkeypatch.setenv("CLOUD_TPU_FLASH_FORCE_INTERPRET", "1")
+    assert ssm_state.takes_kernel(tiled, 2)
+    assert not ssm_state.takes_kernel(tiled, 2, use_pallas=False)
+    for leaf in (untiled, narrow):
+        assert not ssm_state.takes_kernel(leaf, 2)
+        with pytest.raises(ValueError, match="float32"):
+            ssm_state.takes_kernel(leaf, 2, use_pallas=True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("CLOUD_TPU_FLASH_FORCE_INTERPRET")
+    assert ssm_state.takes_kernel(tiled, 2)
 
 
 # -- (d) a state in bfloat16 is caught ------------------------------------

@@ -206,6 +206,42 @@ def test_kv_row_steps_read_counts_what_a_decode_step_fetches(
     assert read <= stats["kv_row_steps_reserved"]
 
 
+@pytest.mark.parametrize("kernel, read", [
+    # The jnp step reads and rewrites every reserved row: 2 chunks x
+    # 2 slots x 2 layers.
+    (False, 2 * 2 * 2),
+    # The state kernel fetches the decoding slots' rows alone: A and B in
+    # chunk 1, A in chunk 2 (B has retired), a row a layer.
+    (True, (2 + 1) * 2),
+], ids=["jnp-step", "state-kernel"])
+def test_state_row_steps_read_counts_what_a_decode_step_fetches(
+        monkeypatch, kernel, read):
+    """``state_row_steps_read`` on the "full-rows" schedule above, for a
+    model with a recurrent state that tiles as ``ops.ssm_state``'s kernel
+    needs: every reserved row where the step is jnp's, one a layer for
+    each decoding slot where it is the kernel's (here interpreted; on a
+    TPU nothing has to switch it on)."""
+    from cloud_tpu.models import ssm
+
+    if kernel:
+        monkeypatch.setenv("CLOUD_TPU_FLASH_FORCE_INTERPRET", "1")
+    config = transformer.TINY.scaled(
+        dtype=jnp.float32, num_layers=2, ssm=ssm.SsmConfig(
+            num_heads=2, head_dim=8, state_dim=128, num_groups=1,
+            chunk_size=4))
+    hybrid = config, transformer.init(jax.random.PRNGKey(0), config)
+    _, results, stats = _serve(hybrid, [[1, 2, 3], [4, 5, 6, 7, 8]], [5, 3],
+                               max_new_tokens=5, prompt_buckets=(8,),
+                               num_slots=2)
+    assert [len(r.tokens) for r in results] == [5, 3]
+    assert stats["chunks"] == 2
+    assert stats["state_row_steps_reserved"] == 2 * 2 * 2
+    assert stats["state_row_steps_in_use"] == (2 + 1) * 2
+    assert stats["state_row_steps_read"] == read
+    # The K/V read is _cache_attention's either way, off the chip.
+    assert stats["kv_row_steps_read"] == stats["kv_row_steps_reserved"]
+
+
 def test_a_referenced_pool_block_counts_once_as_in_use(model):
     """Prefix pool on: its blocks are reserved whole; a block counts as
     in use while a live slot references it.  One request of 8 prompt
