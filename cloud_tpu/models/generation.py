@@ -518,19 +518,96 @@ def _write_prefill(cache, left, start, config):
     return cache
 
 
+#: Rows of a prefill's row tile: the grain in which the prompt's length,
+#: not the buffer's, bounds a prefill's work.  A tile of ``r`` rows costs
+#: ``2 r P`` operations against ``2 P`` bytes of a layer's weights, so
+#: below ``r`` = 197e12 / 819e9 = 240 rows (a v5e's peaks) a tile is
+#: bound by reading the weights and skipping rows buys nothing; 256 sits
+#: on that edge and 512 is compute-bound by two to one.  Derived from
+#: nothing a user sets, so it is no option.
+PREFILL_TILE_ROWS = 512
+
+
+def prefill_widths(t_prompt: int, rules: ShardingRules = DEFAULT_RULES,
+                   mesh=None):
+    """The widths, ascending, at which a prompt buffer of ``t_prompt``
+    rows can run its forward pass: the whole tiles above half the
+    buffer (whoever buckets prompts sends a shorter one to the buffer
+    below, so smaller widths would only be copies of the stack that
+    nothing reaches).  A buffer that is not whole tiles, or holds fewer
+    than two, has one width: its own.  So has every buffer under a mesh
+    that shards ``seq``: a width that is not the buffer's fights that
+    sharding."""
+    tile = PREFILL_TILE_ROWS
+    if t_prompt % tile or t_prompt < 2 * tile or _shards_seq(rules, mesh):
+        return (t_prompt,)
+    return tuple(range((t_prompt // 2 // tile + 1) * tile, t_prompt + 1,
+                       tile))
+
+
+def prefill_rows_computed(t_prompt: int, prompt_len: int,
+                          rules: ShardingRules = DEFAULT_RULES,
+                          mesh=None) -> int:
+    """The rows of a ``t_prompt`` buffer that a prefill computes for a
+    longest prompt of ``prompt_len`` tokens: the rule
+    :func:`_prefill_into` applies, for whoever counts its work."""
+    return next(w for w in prefill_widths(t_prompt, rules, mesh)
+                if w >= min(prompt_len, t_prompt))
+
+
+def _shards_seq(rules, mesh) -> bool:
+    """Whether ``rules`` split the ``seq`` axis over more than one
+    device of ``mesh``."""
+    if mesh is None:
+        return False
+    axes = rules.assignment("seq")
+    axes = (axes,) if isinstance(axes, str) else tuple(axes or ())
+    return any(dict(mesh.shape).get(axis, 1) > 1 for axis in axes)
+
+
+def _prefill_into(params, cache, prompt_tokens, prompt_lens, start, config,
+                  rules, mesh):
+    """The prompt forward pass (:func:`_prefill_forward`) written into
+    ``cache`` at ``start`` (:func:`_write_prefill`), doing the prompt's
+    work and not the buffer's: it runs at the smallest of
+    :func:`prefill_widths` that holds the longest prompt, chosen on the
+    device from the traced lengths (``lax.switch`` over static widths,
+    so one executable still serves a buffer).  Rows at and past that
+    width do no embedding-to-logits work — no projection, no MLP, no
+    attention as query or key — and nothing is written for them: the
+    cache keeps what it held there, stale and harmless.  Rows before it
+    get the whole buffer's values (a causal stack never looks ahead).
+
+    A buffer with one width (every buffer under a mesh that shards
+    ``seq`` among them) traces to the whole-buffer program and nothing
+    else.  Returns ``(cache, logits0)``."""
+    widths = prefill_widths(prompt_tokens.shape[1], rules, mesh)
+
+    def at(width):
+        def forward(cache):
+            left, logits0 = _prefill_forward(
+                params, prompt_tokens[:, :width], prompt_lens, config,
+                rules, mesh)
+            return _write_prefill(cache, left, start, config), logits0
+        return forward
+
+    if len(widths) == 1:
+        return at(widths[0])(cache)
+    longest = jnp.max(prompt_lens)
+    index = sum((longest > w).astype(jnp.int32) for w in widths[:-1])
+    return jax.lax.switch(index, [at(w) for w in widths], cache)
+
+
 def _prefill(params, prompt_tokens, prompt_lens, config, s, rules, mesh,
              kv_quant: bool = False):
-    """One full forward over the prompt buffer: returns the KV cache
-    (size ``s``, positions [0, prompt_len) filled) and the next-token
-    logits [B, V] at each row's last real prompt position — shared by
-    sampling and beam decoding."""
+    """One forward over the prompt buffer (:func:`_prefill_into`):
+    returns the KV cache (size ``s``, positions [0, prompt_len) filled)
+    and the next-token logits [B, V] at each row's last real prompt
+    position — shared by sampling and beam decoding."""
     b, _ = prompt_tokens.shape
     cache = _init_cache(config, b, s, rules, mesh, kv_quant=kv_quant)
-    left, logits0 = _prefill_forward(
-        params, prompt_tokens, prompt_lens, config, rules, mesh
-    )
-    cache = _write_prefill(cache, left, (0, 0, 0, 0, 0), config)
-    return cache, logits0
+    return _prefill_into(params, cache, prompt_tokens, prompt_lens,
+                         (0, 0, 0, 0, 0), config, rules, mesh)
 
 
 def _decode_step(params, cache, token, cur_len, config, rules, mesh,
@@ -810,28 +887,31 @@ def insert_slot_program(
     program per prompt bucket, not per batch size); ``prompt_len`` /
     ``slot`` / ``max_new_tokens`` are traced int32 scalars, so one
     executable serves every slot and every per-request decode budget.
-    Writes the prompt's k/v into the slot's cache row, samples the first
-    token from the prefill logits (exactly :func:`generate`'s ``tok0``),
-    and arms the slot state: ``remaining = max_new_tokens - 1``, active
-    unless the request is already finished (``max_new_tokens == 1`` or
-    the first token sampled eos).  Stale cache beyond the new prompt is
-    harmless — attention masks positions ``>= pos`` and decode
-    overwrites each position before it can become valid.  That holds for
-    K/V rows only: a recurrent state has no positions to mask, so the
-    slot's state and convolution tail are overwritten WHOLE with the
-    prompt's (a reused slot carries nothing over).  Returns
+    The bucket decides the executable and the buffer, the prompt's
+    length the work: the forward pass runs at the smallest of the
+    buffer's widths that holds the prompt (:func:`_prefill_into`), all
+    of them inside that one executable.  Writes the prompt's k/v into
+    the slot's cache row, samples the first token from the prefill
+    logits (exactly :func:`generate`'s ``tok0``), and arms the slot
+    state: ``remaining = max_new_tokens - 1``, active unless the request
+    is already finished (``max_new_tokens == 1`` or the first token
+    sampled eos).  Stale cache beyond the new prompt — the rows of the
+    width that ran hold the padding's k/v, the rows past it what the
+    slot held before — is harmless: attention masks positions ``>= pos``
+    and decode overwrites each position before it can become valid.
+    That holds for K/V rows only: a recurrent state has no positions to
+    mask, so the slot's state and convolution tail are overwritten WHOLE
+    with the prompt's (a reused slot carries nothing over).  Returns
     ``(cache, state, first_token)``.
     """
     t_prompt = prompt_tokens.shape[1]
     prompt_len = jnp.clip(jnp.asarray(prompt_len, jnp.int32), 1, t_prompt)
     lens = jnp.reshape(prompt_len, (1,))
-    left, logits0 = _prefill_forward(
-        params, prompt_tokens, lens, config, rules, mesh
-    )
     slot = jnp.asarray(slot, jnp.int32)
     zero = jnp.int32(0)
-    cache = _write_prefill(
-        cache, left, (zero, slot, zero, zero, zero), config
+    cache, logits0 = _prefill_into(
+        params, cache, prompt_tokens, lens, (zero, slot, zero, zero, zero),
+        config, rules, mesh
     )
 
     state, tok0 = _arm_slot(state, logits0, prompt_len, slot,
@@ -1470,14 +1550,13 @@ def draft_prefill_slot_program(
     t_prompt = prompt_tokens.shape[1]
     prompt_len = jnp.clip(jnp.asarray(prompt_len, jnp.int32), 1, t_prompt)
     lens = jnp.reshape(prompt_len, (1,))
-    left, _ = _prefill_forward(
-        params, prompt_tokens, lens, config, rules, mesh
-    )
     slot = jnp.asarray(slot, jnp.int32)
     zero = jnp.int32(0)
-    return _write_prefill(
-        cache, left, (zero, slot, zero, zero, zero), config
+    cache, _ = _prefill_into(
+        params, cache, prompt_tokens, lens, (zero, slot, zero, zero, zero),
+        config, rules, mesh
     )
+    return cache
 
 
 def check_inference_supported(config, rules, mesh, what: str = "inference"):

@@ -90,8 +90,9 @@ with one scheduler behind a submit/future/admission surface:
   ``serve/chunk`` spans (with per-dispatch ``active``/``occupancy``
   attributes).  A scheduler pass closes: one numbered
   ``serve/pass`` span per loop iteration that did work (``inserts``
-  taken off the queue with their ``prompt_tokens``/``bucket_tokens``,
-  ``active`` slots in its chunk,
+  taken off the queue with their ``prompt_tokens``/``bucket_tokens``
+  and the ``computed_tokens`` of their inserts, ``active`` slots in its
+  chunk,
   ``kv_rows_in_use``/``kv_rows_reserved``; recorded, never mirrored
   into a profile), and under it the leaves ``serve/launch`` (the
   host's time to enqueue a program, ``what``), ``serve/readback``
@@ -117,7 +118,10 @@ with one scheduler behind a submit/future/admission surface:
   ``state_row_steps_read`` the rows a decode step fetches (every
   reserved row, or the decoding slots' alone where ``ops.ssm_state``'s
   kernel advances them), and ``serve/pass`` carries
-  ``state_rows_in_use``.
+  ``state_rows_in_use``.  ``insert_rows_bucket`` /
+  ``insert_rows_computed`` sum, at every insert dispatch, the prompt
+  buffer's rows and the rows the insert program computes for the
+  prompt in it (``generation.prefill_rows_computed``).
   ``serve/qps`` and ``serve/tokens_per_sec``
   windowed-rate gauges, the ``serve/slot_occupancy`` gauge,
   slot-churn counters
@@ -869,6 +873,10 @@ class ServingEngine:
             # or the live slots' pages where the paged kernel reads.
             "kv_row_steps_reserved": 0, "kv_row_steps_in_use": 0,
             "kv_row_steps_read": 0,
+            # Rows of the prompt buffers dispatched to the insert
+            # program against the rows it computed for them (the
+            # smallest of the buffer's widths that holds the prompt).
+            "insert_rows_bucket": 0, "insert_rows_computed": 0,
             # The same for a recurrent state's rows, one a slot a layer
             # (0 for a model without one); read: every reserved row, or
             # the decoding slots' where the state kernel advances them.
@@ -2539,6 +2547,8 @@ class ServingEngine:
                     inserts=len(inserts), active=self._pass_active,
                     prompt_tokens=sum(r.prompt_len for r, _ in inserts),
                     bucket_tokens=sum(r.bucket_len for r, _ in inserts),
+                    computed_tokens=sum(
+                        self._insert_rows(r) for r, _ in inserts),
                     kv_rows_in_use=self._kv_rows_in_use,
                     kv_rows_reserved=self._kv_rows_reserved,
                     state_rows_in_use=self._state_rows_in_use,
@@ -3043,8 +3053,20 @@ class ServingEngine:
                 self._dispatch_draft_prefill(request, slot)
             self._active_slots.add(slot)
 
+    def _insert_rows(self, request: _Request) -> int:
+        """The rows the insert program computes for ``request``: its
+        own rule, asked of it."""
+        from cloud_tpu.models import generation
+
+        return generation.prefill_rows_computed(
+            request.bucket_len, request.prompt_len, self.rules, self.mesh)
+
     def _insert_request(self, request: _Request, slot: int) -> None:
         start = request.admitted = time.perf_counter()
+        computed = self._insert_rows(request)
+        with self._stats_lock:
+            self._stats["insert_rows_bucket"] += request.bucket_len
+            self._stats["insert_rows_computed"] += computed
         tracing.record_span(
             "serve/queue_wait", request.submitted, start,
             **_trace_attrs(request, bucket=request.bucket_len, slot=slot),

@@ -338,7 +338,57 @@ def test_slot_cache_is_updated_in_place(one_chip, program, kv_quant):
     _assert_cache_in_place(compiled, cache)
 
 
-def _assert_cache_in_place(compiled, cache):
+def test_insert_at_two_widths_is_one_program_that_copies_no_cache(
+        one_chip, monkeypatch):
+    """The insert program of a 2048 buffer: ONE executable that holds the
+    layer stack at both of the buffer's widths (a ``conditional`` over
+    two branches, the flash kernel once in each, at 1536 and at 2048
+    rows), each branch writing the donated slot cache in place: no
+    ``copy`` or ``AllocateBuffer`` of a whole K or V cache on either
+    side of the switch, temp under half the cache's bytes.  A 1024
+    buffer has one width and no ``conditional``.  The same 4-layer model
+    as :func:`test_slot_cache_is_updated_in_place`."""
+    from cloud_tpu.models import generation, transformer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, rows = 4, 2048 + 32
+    config = transformer.TransformerConfig(
+        vocab_size=32000, num_layers=4, dim=2048, num_heads=16,
+        head_dim=128, mlp_hidden=5632, max_seq_len=rows, remat=False,
+    )
+    sample = generation.SampleConfig(temperature=0.0)
+    on_chip = functools.partial(_tree_spec, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: transformer.init(jax.random.PRNGKey(0), config)),
+        jnp.bfloat16)
+    cache = on_chip(jax.eval_shape(
+        lambda: generation.init_slot_cache(config, slots, rows)))
+    state = on_chip(jax.eval_shape(lambda: generation.init_slot_state(
+        config, slots, sample=sample)))
+    scalar = _spec((), jnp.int32, one_chip)
+
+    def insert(params, cache, state, tokens, prompt_len, slot, new):
+        return generation.insert_slot_program(
+            params, cache, state, tokens, prompt_len, slot, new, config,
+            sample=sample)
+
+    def compiled_at(bucket):
+        tokens = _spec((1, bucket), jnp.int32, one_chip)
+        return jax.jit(insert, donate_argnums=(1, 2)).lower(
+            params, cache, state, tokens, scalar, scalar, scalar).compile()
+
+    two = compiled_at(2048)
+    text = two.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 1
+    flash = re.findall(r"%flash_fwd[\w.]* = \(bf16\[1,16,(\d+),128\]", text)
+    assert sorted(map(int, flash)) == [1536, 2048], flash
+    _assert_cache_in_place(two, cache, in_place=("dynamic-update-slice",))
+    one = compiled_at(1024).as_text()
+    assert " conditional(" not in one
+    assert len(re.findall(r"%flash_fwd[\w.]* = ", one)) == 1
+
+
+def _assert_cache_in_place(compiled, cache, in_place=()):
     kv = "{}[{}]".format("s8" if "k_scale" in cache else "bf16",
                          ",".join(map(str, cache["k"].shape)))
     moved = [
@@ -346,6 +396,7 @@ def _assert_cache_in_place(compiled, cache):
         if re.match(r"\s*(ROOT )?%[\w.\-]+ = " + re.escape(kv) + r"\S* "
                     r"(copy|dynamic-update-slice|custom-call)\(", line)
         and ("custom-call(" not in line or "AllocateBuffer" in line)
+        and not any(f" {op}(" in line for op in in_place)
     ]
     assert not moved, moved
     cache_bytes = sum(

@@ -193,6 +193,40 @@ def test_prefill_then_two_chunks_of_decode_match_the_full_forward_pass(
     assert emitted == tokens
 
 
+@pytest.mark.parametrize("length", [5, 12, 13, 16])
+def test_insert_at_the_prompts_width_leaves_the_whole_buffers_state(
+        params, monkeypatch, length):
+    """A 16-row buffer in tiles of 4 runs a prompt of up to 12 tokens at
+    12 rows (``generation.prefill_widths``): the slot's state, its
+    convolution tail, its K/V rows before the prompt's end and the first
+    token's logits are what the forward over all 16 rows leaves (the
+    mixer masks the padding; a narrower buffer only holds less of it)."""
+    monkeypatch.setattr(generation, "PREFILL_TILE_ROWS", 4)
+    assert generation.prefill_widths(BUCKET) == (12, 16)
+    prompt = _prompt(length, seed=5)
+    cache, state = _grid()
+    got, _, tok0 = _insert(params, dict(cache), dict(state), prompt, 1, NEW)
+    left, logits0 = generation._prefill_forward(
+        params, _padded(prompt), jnp.array([length]), CONFIG,
+        transformer.DEFAULT_RULES, None)
+    want = generation._write_prefill(dict(cache), left, (0, 1, 0, 0, 0),
+                                     CONFIG)
+    _, got_logits = generation._prefill_into(
+        params, dict(cache), _padded(prompt), jnp.array([length]),
+        (0, 1, 0, 0, 0), CONFIG, transformer.DEFAULT_RULES, None)
+    assert gap(got_logits, logits0) < LOGIT_TOLERANCE
+    assert int(tok0) == int(jnp.argmax(logits0[0]))
+    for name in generation.STATE_LEAVES:
+        np.testing.assert_allclose(np.asarray(got[name]),
+                                   np.asarray(want[name]),
+                                   rtol=1e-5, atol=1e-6)
+        assert np.asarray(got[name][:, 1]).any()
+    for name in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(got[name][:, 1, :length]),
+                                   np.asarray(want[name][:, 1, :length]),
+                                   rtol=1e-5, atol=1e-6)
+
+
 def test_generate_is_pinned_to_apply_and_to_the_reference(params):
     prompt = _prompt(9, seed=3)
     out = generation.generate(params, _padded(prompt),

@@ -106,6 +106,31 @@ def test_pass_attributes_add_up_to_what_was_submitted(served):
     assert sum(c["retired"] for c in commits) == len(PROMPTS)
 
 
+def test_insert_rows_count_the_bucket_against_the_width_that_ran(
+        model, monkeypatch):
+    """In tiles of 2 a bucket of 8 has the widths 6 and 8 and a bucket of
+    4 one width (``generation.prefill_widths``): prompts of 3, 5, 1, 7,
+    2 and 4 tokens are dispatched in 4 + 8 + 4 + 8 + 4 + 4 rows of
+    buffer and computed in 4 + 6 + 4 + 8 + 4 + 4, and the passes carry
+    the same rows as ``computed_tokens``.  The tokens served are those
+    of the default tile (one width a bucket)."""
+    from cloud_tpu.models import generation
+
+    _, want, whole = _serve(model)
+    assert whole["insert_rows_bucket"] == whole["insert_rows_computed"] == 32
+    monkeypatch.setattr(generation, "PREFILL_TILE_ROWS", 2)
+    events, results, stats = _serve(model)
+    assert [r.bucket_len for r in results] == [4, 8, 4, 8, 4, 4]
+    assert stats["insert_rows_bucket"] == 32
+    assert stats["insert_rows_computed"] == 30
+    passes = [e["args"] for e in _named(events, "serve/pass")]
+    assert sum(p["computed_tokens"] for p in passes) == 30
+    assert all(p["prompt_tokens"] <= p["computed_tokens"]
+               <= p["bucket_tokens"] for p in passes)
+    for got, ref in zip(results, want):
+        np.testing.assert_array_equal(got.tokens, ref.tokens)
+
+
 def test_every_request_closes_under_one_id(served):
     events, results, _ = served
     ids = [r.trace_id for r in results]
