@@ -29,13 +29,15 @@ rejected up front.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from cloud_tpu.models import layers, moe as moe_lib, ssm as ssm_lib
+from cloud_tpu.models import layers, mla as mla_lib
+from cloud_tpu.models import moe as moe_lib, ssm as ssm_lib
 from cloud_tpu.models import transformer
 from cloud_tpu.parallel import mesh as mesh_lib
 from cloud_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules, shard_constraint
@@ -111,6 +113,17 @@ def sample_logits(rng, logits, sample: SampleConfig, *, seen=None,
 #: The cache leaves that hold a recurrent state, not K/V rows.
 STATE_LEAVES = ("ssm", "conv")
 
+#: The cache leaf of a latent-attention model (``TransformerConfig.latent``):
+#: one row a token a layer, ``[c | k_pe | 0]`` [L, B, S, row_width], key
+#: and value of every head at once (models/mla.py).
+LATENT_LEAF = "latent"
+
+
+def _rows_leaf(cache):
+    """The leaf whose axes 0-2 are (layer, row, position): K, or the
+    latent rows."""
+    return cache["k"] if "k" in cache else cache[LATENT_LEAF]
+
 
 def _init_cache(config: transformer.TransformerConfig, b: int, s: int,
                 rules: ShardingRules, mesh, kv_quant: bool = False):
@@ -131,6 +144,12 @@ def _init_cache(config: transformer.TransformerConfig, b: int, s: int,
     its context holds: nothing masks a stale one, so whoever arms a row
     writes both leaves whole and whoever skips a row leaves both alone.
     """
+    if config.latent is not None:
+        if kv_quant:
+            _refuse_unless_kv_rows(config, "an int8 cache (kv_quant)")
+        return {LATENT_LEAF: jnp.zeros(
+            (config.num_layers, b, s, config.latent.row_width),
+            config.dtype)}
     shape = (config.num_layers, b, s, config.kv_heads, config.head_dim)
 
     def constrain(x):
@@ -226,11 +245,54 @@ def _cache_attention(q, cache_l, cur_len, *, chunk_causal: bool = False):
     return out.astype(q.dtype)
 
 
-def _mlp(layer_params, y, config, rules):
+def _mlp(layer_params, y, config, rules, live=None, held=None, layer=None):
+    """The block's MLP on ``y`` [B, T, D] and, from a dropless expert
+    layer, what it counted of its routing (else None); ``live`` [B, T]
+    keeps padding and idle rows off the experts.  ``held``: the routed
+    experts' matrices of EVERY layer of the stack (:func:`_split_experts`),
+    of which this is layer ``layer``."""
+    if moe_lib.counts_routing(config.moe):
+        mlp = layer_params["mlp"]
+        return moe_lib.dropless_mlp_apply(
+            mlp if held is None else dict(mlp, **held), y, config.moe,
+            live=live, layer=None if held is None else layer)
     if config.moe is not None:
         out, _ = moe_lib.moe_mlp_apply(layer_params["mlp"], y, config.moe)
-        return out
-    return transformer.mlp_apply(layer_params["mlp"], y, config, rules)
+        return out, None
+    return transformer.mlp_apply(layer_params["mlp"], y, config, rules), None
+
+
+def _stacks(params, config):
+    """The layer stacks in order, each ``(stacked params, its
+    configuration, its first layer's index)``: the leading dense layers,
+    where the model has them, then the scanned ``params["layers"]``."""
+    dense = config.leading_dense_layers
+    if not dense:
+        return [(params["layers"], config, 0)]
+    return [(params["dense_layers"], config.dense_stack, 0),
+            (params["layers"], config, dense)]
+
+
+def _split_experts(stack_params, config):
+    """``stack_params`` as ``(what a scan over the layers slices, the
+    routed experts' stacked matrices)``: a dropless expert layer's three
+    stacks stay OUT of the scan's operands and go to the grouped products
+    whole, with the layer's index — sliced out a layer, each would be
+    copied (352 MB) before every call.  ``(stack_params, None)`` for any
+    other stack."""
+    if not moe_lib.counts_routing(config.moe):
+        return stack_params, None
+    mlp = stack_params["mlp"]
+    held = {name: mlp[name] for name in moe_lib.EXPERT_LEAVES}
+    rest = {name: leaf for name, leaf in mlp.items() if name not in held}
+    return dict(stack_params, mlp=rest), held
+
+
+def _sum_routing(counted):
+    """One routing vector for a program from what its expert stacks
+    counted a layer (``counted``: [layers, ...] arrays, or None)."""
+    return sum(c.reshape(-1, c.shape[-1]).sum(0) for c in counted
+               if c is not None)
 
 
 def _paged_attended(kind, q, cache_l, cur_len, paged):
@@ -252,7 +314,7 @@ def _paged_attended(kind, q, cache_l, cur_len, paged):
 
 def _scan_layers(params, cache, x, positions, write_cols, config, rules,
                  mesh, *, kind, slot=None, pool=None, block_table=None,
-                 use_pallas=None):
+                 use_pallas=None, with_routing: bool = False):
     """The layer stack over a KV cache that rides the scan as the CARRY.
 
     The one spelling of "scan the layers with the cache carried", shared
@@ -305,12 +367,25 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
     state is neither fetched nor written.  Elsewhere, and for the
     convolution's tail everywhere, the layer is read by index, advanced,
     and a frozen row rewritten with its own bytes.
+
+    With ``config.latent`` (``kind="decode"`` only) the cache is the
+    latent leaf: layer ``l`` scatters each row's ``[c | k_pe]`` at
+    ``[l, row, write_cols]`` and attends in the ABSORBED form
+    (``models/mla.py``) through ``ops.latent_attention``, which takes the
+    carried leaf whole with the layer index and reads a live row's pages
+    once for all heads (its kernel on a TPU, its jnp route elsewhere).
+
+    A model with leading dense layers runs them first, then the scan over
+    the expert layers (:func:`_stacks`); cache layer ``l`` is model layer
+    ``l``.  ``with_routing`` appends what the dropless expert layers
+    counted (``moe.ROUTING_HEAD``), summed over layers, idle rows left
+    out: ``(x, cache, routing)``.
     """
-    from cloud_tpu.ops import paged_attention, ssm_state
+    from cloud_tpu.ops import latent_attention, paged_attention, ssm_state
 
     b, t, _ = x.shape
     if kind != "decode" or slot is not None or block_table is not None:
-        _refuse_recurrent(
+        _refuse_unless_kv_rows(
             config, f"a {kind} pass over a slot's rows or a prefix pool")
     quantized = "k_scale" in cache
     attend_len = positions[:, 0] + 1
@@ -325,7 +400,8 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
              "head_axes": rules.assignment("heads"),
              "batch_axes": rules.assignment("batch")}
     # Rows that advance (their write column is in range).
-    live = (write_cols[:, 0] >= 0) & (write_cols[:, 0] < cache["k"].shape[2])
+    live = (write_cols[:, 0] >= 0) & (
+        write_cols[:, 0] < _rows_leaf(cache).shape[2])
     state_in_place = config.ssm is not None and ssm_state.takes_kernel(
         cache["ssm"], config.ssm.num_groups, use_pallas)
 
@@ -337,11 +413,21 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
             leaf, (l, slot, zero, zero, zero), (1, 1) + leaf.shape[2:]
         )[0]
 
-    def layer_body(carry, layer_slice):
-        x, cache = carry
-        layer_params, l = layer_slice[:2]
-        y = layers.rmsnorm_apply(layer_params["ln1"], x,
-                                 eps=config.norm_eps)
+    def latent_attend(att, y, cache, l):
+        q_nope, q_pe, c, k_pe = mla_lib.project(att, y, positions, config)
+        cache = dict(cache, **{LATENT_LEAF: cache[LATENT_LEAF].at[
+            l, rows, write_cols].set(
+                mla_lib.cache_rows(c, k_pe, config.latent, config.dtype),
+                mode="drop")})
+        o_lat = latent_attention.latent_decode_attention(
+            mla_lib.absorbed_queries(att, q_nope[:, 0], q_pe[:, 0], config),
+            cache[LATENT_LEAF], jnp.where(live, attend_len, 0),
+            value_dim=config.latent.kv_rank,
+            scale=mla_lib.softmax_scale(config.latent), layer=l)
+        attended = mla_lib.absorbed_values(att, o_lat, config)
+        return mla_lib.attention_out(att, attended[:, None], config), cache
+
+    def kv_attend(layer_params, layer_slice, y, cache, l):
         q, k_new, v_new = transformer.qkv_project(
             layer_params["att"], y, positions, config
         )
@@ -367,8 +453,18 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
                 if block_table is None else
                 _paged_attended(kind, q, cache_l, attend_len,
                                 dict(paged, pool_l=pool_l)))
-        mixed = transformer.attention_out(layer_params["att"], attended,
-                                          config)
+        return transformer.attention_out(layer_params["att"], attended,
+                                         config), cache
+
+    def layer_body(stack_config, held, first, carry, layer_slice):
+        x, cache = carry
+        layer_params, l = layer_slice[:2]
+        y = layers.rmsnorm_apply(layer_params["ln1"], x,
+                                 eps=config.norm_eps)
+        if config.latent is not None:
+            mixed, cache = latent_attend(layer_params["att"], y, cache, l)
+        else:
+            mixed, cache = kv_attend(layer_params, layer_slice, y, cache, l)
         if config.ssm is not None:
             mixer = (layer_params["ssm"], y[:, 0])
             sizes = (config.ssm, config.multipliers, config.norm_eps)
@@ -394,40 +490,70 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
         x = x + mixed
         y = layers.rmsnorm_apply(layer_params["ln2"], x,
                                  eps=config.norm_eps)
-        x = x + _mlp(layer_params, y, config, rules)
+        mlp_out, counted = _mlp(
+            layer_params, y, stack_config, rules,
+            live=live[:, None] if kind == "decode" else None, held=held,
+            layer=l - first)
+        x = x + mlp_out
         if chunked:
             x = shard_constraint(x, "batch", "seq", "act_embed",
                                  rules=rules, mesh=mesh)
-        return (x, cache), None
+        return (x, cache), counted
 
-    xs = (params["layers"], jnp.arange(cache["k"].shape[0]))
-    if pool is not None:
-        xs += (pool,)
-    (x, cache), _ = jax.lax.scan(layer_body, (x, cache), xs)
+    counted = []
+    for stack_params, stack_config, first in _stacks(params, config):
+        last = first + jax.tree_util.tree_leaves(stack_params)[0].shape[0]
+        scanned, held = _split_experts(stack_params, stack_config)
+        xs = (scanned, jnp.arange(first, last))
+        if pool is not None:
+            xs += (pool if last - first == config.num_layers else
+                   jax.tree_util.tree_map(lambda leaf: leaf[first:last],
+                                          pool),)
+        (x, cache), per_layer = jax.lax.scan(
+            functools.partial(layer_body, stack_config, held, first),
+            (x, cache), xs)
+        counted.append(per_layer)
+    if with_routing:
+        return x, cache, _sum_routing(counted)
     return x, cache
 
 
 def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
-                   config, rules, mesh):
+                   config, rules, mesh, held=None, layer=None):
     """One block on the full prompt buffer [B, T, D], returning what the
     block leaves in a cache, raw: its k/v and, with ``config.ssm``, each
     row's state and convolution tail at its last real token.  Causal
     attention with the padding mask applied key-side (padded tail slots
     are later overwritten by decode before they can ever be attended);
-    the mixer masks the padding itself (``ssm.ssd_prefill``)."""
+    the mixer masks the padding itself (``ssm.ssd_prefill``).  With
+    ``config.latent`` the block leaves each token's latent row and attends
+    in the EXPANDED form (``mla.expanded_attention``).  Also returns what
+    a dropless expert layer counted of its routing (else None), padding
+    left out."""
     from cloud_tpu import ops
 
     y = layers.rmsnorm_apply(layer_params["ln1"], x, eps=config.norm_eps)
-    q, k, v = transformer.qkv_project(layer_params["att"], y, positions,
-                                      config)
-    attended = ops.flash_attention(
-        q, *transformer.repeat_kv(k, v, config), causal=True,
-        mask=prompt_mask, partitioned=mesh is not None, mesh=mesh,
-        batch_axes=rules.assignment("batch"),
-        head_axes=rules.assignment("heads"),
-    )
-    mixed = transformer.attention_out(layer_params["att"], attended, config)
-    left = {"k": k, "v": v}
+    if config.latent is not None:
+        att = layer_params["att"]
+        q_nope, q_pe, c, k_pe = mla_lib.project(att, y, positions, config)
+        attended = mla_lib.expanded_attention(
+            att, q_nope, q_pe, c, k_pe, prompt_mask, config, rules=rules,
+            mesh=mesh)
+        mixed = mla_lib.attention_out(att, attended, config)
+        left = {LATENT_LEAF: mla_lib.cache_rows(c, k_pe, config.latent,
+                                                config.dtype)}
+    else:
+        q, k, v = transformer.qkv_project(layer_params["att"], y, positions,
+                                          config)
+        attended = ops.flash_attention(
+            q, *transformer.repeat_kv(k, v, config), causal=True,
+            mask=prompt_mask, partitioned=mesh is not None, mesh=mesh,
+            batch_axes=rules.assignment("batch"),
+            head_axes=rules.assignment("heads"),
+        )
+        mixed = transformer.attention_out(layer_params["att"], attended,
+                                          config)
+        left = {"k": k, "v": v}
     if config.ssm is not None:
         ssm_out, left["ssm"], left["conv"] = ssm_lib.ssd_prefill(
             layer_params["ssm"], y, prompt_mask, prompt_lens, config.ssm,
@@ -436,10 +562,12 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
         mixed = mixed + ssm_out
     x = x + mixed
     y = layers.rmsnorm_apply(layer_params["ln2"], x, eps=config.norm_eps)
-    x = x + _mlp(layer_params, y, config, rules)
+    mlp_out, counted = _mlp(layer_params, y, config, rules,
+                            live=prompt_mask, held=held, layer=layer)
+    x = x + mlp_out
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
-    return x, left
+    return x, (left, counted)
 
 
 def _final_logits(params, x, config):
@@ -448,7 +576,7 @@ def _final_logits(params, x, config):
 
 
 def _prefill_forward(params, prompt_tokens, prompt_lens, config, rules,
-                     mesh):
+                     mesh, with_routing: bool = False):
     """The prompt forward pass alone: what every layer leaves in a
     cache, stacked and raw (pre-cast) — ``k`` / ``v``
     [L, B, T_prompt, kv_heads, hd] and, with ``config.ssm``, ``ssm``
@@ -457,7 +585,9 @@ def _prefill_forward(params, prompt_tokens, prompt_lens, config, rules,
     position.  Where they land is the caller's business
     (:func:`_write_prefill`): :func:`_prefill` writes them at the origin
     of a fresh batch cache, :func:`insert_slot_program` into one row of
-    a persistent slot grid."""
+    a persistent slot grid.  A latent-attention model leaves ``latent``
+    [L, B, T_prompt, row_width] in place of ``k`` / ``v``.
+    ``with_routing`` appends what the dropless expert layers counted."""
     b, t_prompt = prompt_tokens.shape
     positions = jnp.broadcast_to(jnp.arange(t_prompt), (b, t_prompt))
     prompt_mask = (positions < prompt_lens[:, None]).astype(jnp.int32)
@@ -465,12 +595,24 @@ def _prefill_forward(params, prompt_tokens, prompt_lens, config, rules,
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
 
-    def prefill_body(x, layer_slice):
-        layer_params, = layer_slice
+    def prefill_body(stack_config, held, x, layer_slice):
+        layer_params, *layer = layer_slice
         return _prefill_layer(layer_params, x, positions, prompt_mask,
-                              prompt_lens, config, rules, mesh)
+                              prompt_lens, stack_config, rules, mesh,
+                              held, *layer)
 
-    x, left = jax.lax.scan(prefill_body, x, (params["layers"],))
+    lefts, counted = [], []
+    for stack_params, stack_config, _ in _stacks(params, config):
+        scanned, held = _split_experts(stack_params, stack_config)
+        xs = (scanned,)
+        if held is not None:
+            xs += (jnp.arange(held["wi"].shape[0]),)
+        x, (left, per_layer) = jax.lax.scan(
+            functools.partial(prefill_body, stack_config, held), x, xs)
+        lefts.append(left)
+        counted.append(per_layer)
+    left = lefts[0] if len(lefts) == 1 else jax.tree_util.tree_map(
+        lambda *parts: jnp.concatenate(parts, axis=0), *lefts)
     last_idx = (prompt_lens - 1)[:, None, None]
     last_x = jnp.take_along_axis(
         x, jnp.broadcast_to(last_idx, (b, 1, x.shape[-1])), axis=1
@@ -482,6 +624,8 @@ def _prefill_forward(params, prompt_tokens, prompt_lens, config, rules,
     # HERE (once per forward) and nowhere else.  No-op without a mesh.
     logits0 = shard_constraint(logits0, "batch", None, rules=rules,
                                mesh=mesh)
+    if with_routing:
+        return left, logits0, _sum_routing(counted)
     return left, logits0
 
 
@@ -506,9 +650,14 @@ def _write_prefill(cache, left, start, config):
     k/v stacks (quantizing first when the cache is int8) and, where the
     cache holds them, each row's state and convolution tail WHOLE (a
     prefill starts at position 0, so the same index, cut to the leaf's
-    rank, addresses a state leaf's layer and row)."""
-    updates = _kv_leaf_updates(left["k"], left["v"], config,
-                               "k_scale" in cache)
+    rank, addresses a state leaf's layer and row, and a latent leaf's
+    layer, row and position)."""
+    if LATENT_LEAF in cache:
+        updates = {LATENT_LEAF: left[LATENT_LEAF].astype(
+            cache[LATENT_LEAF].dtype)}
+    else:
+        updates = _kv_leaf_updates(left["k"], left["v"], config,
+                                   "k_scale" in cache)
     for name in STATE_LEAVES:
         if name in cache:
             updates[name] = left[name].astype(cache[name].dtype)
@@ -566,7 +715,7 @@ def _shards_seq(rules, mesh) -> bool:
 
 
 def _prefill_into(params, cache, prompt_tokens, prompt_lens, start, config,
-                  rules, mesh):
+                  rules, mesh, with_routing: bool = False):
     """The prompt forward pass (:func:`_prefill_forward`) written into
     ``cache`` at ``start`` (:func:`_write_prefill`), doing the prompt's
     work and not the buffer's: it runs at the smallest of
@@ -580,15 +729,16 @@ def _prefill_into(params, cache, prompt_tokens, prompt_lens, start, config,
 
     A buffer with one width (every buffer under a mesh that shards
     ``seq`` among them) traces to the whole-buffer program and nothing
-    else.  Returns ``(cache, logits0)``."""
+    else.  Returns ``(cache, logits0)``, with ``with_routing`` also what
+    the dropless expert layers counted."""
     widths = prefill_widths(prompt_tokens.shape[1], rules, mesh)
 
     def at(width):
         def forward(cache):
-            left, logits0 = _prefill_forward(
+            left, *out = _prefill_forward(
                 params, prompt_tokens[:, :width], prompt_lens, config,
-                rules, mesh)
-            return _write_prefill(cache, left, start, config), logits0
+                rules, mesh, with_routing=with_routing)
+            return (_write_prefill(cache, left, start, config), *out)
         return forward
 
     if len(widths) == 1:
@@ -612,7 +762,7 @@ def _prefill(params, prompt_tokens, prompt_lens, config, s, rules, mesh,
 
 def _decode_step(params, cache, token, cur_len, config, rules, mesh,
                  write_pos=None, pool=None, block_table=None,
-                 use_pallas=None):
+                 use_pallas=None, with_routing: bool = False):
     """One single-token decode step for every row at once: embed
     ``token`` [B], run the layer stack with the cache carried
     (:func:`_scan_layers`: each row's k/v written in place at its
@@ -629,21 +779,22 @@ def _decode_step(params, cache, token, cur_len, config, rules, mesh,
     there (see ``decode_chunk_program``).
 
     ``block_table`` [B, n_pages] (with the optional prefix ``pool``)
-    routes attention through the paged read-in-place path."""
+    routes attention through the paged read-in-place path.
+    ``with_routing`` appends what the dropless expert layers counted."""
     x = transformer.embed_tokens(params, token[:, None], config, rules,
                                  mesh)
     wp = cur_len if write_pos is None else write_pos
-    x, cache = _scan_layers(
+    x, cache, *routing = _scan_layers(
         params, cache, x, cur_len[:, None], wp[:, None], config, rules,
         mesh, kind="decode", pool=pool, block_table=block_table,
-        use_pallas=use_pallas,
+        use_pallas=use_pallas, with_routing=with_routing,
     )
     logits = _final_logits(params, x, config)[:, 0]
     # Sampling boundary reshard (see _prefill_forward): vocab-sharded
     # logits gather to replicated exactly once per decode step.
     logits = shard_constraint(logits, "batch", None, rules=rules,
                               mesh=mesh)
-    return cache, logits
+    return (cache, logits, *routing)
 
 
 def _decode_tokens(params, cache, logits0, prompt_lens, config, *,
@@ -902,21 +1053,24 @@ def insert_slot_program(
     That holds for K/V rows only: a recurrent state has no positions to
     mask, so the slot's state and convolution tail are overwritten WHOLE
     with the prompt's (a reused slot carries nothing over).  Returns
-    ``(cache, state, first_token)``.
+    ``(cache, state, first_token)`` and, for a model with dropless
+    experts, a fourth result: what its expert layers counted of their
+    routing over the prompt's real tokens (``moe.ROUTING_HEAD``).
     """
     t_prompt = prompt_tokens.shape[1]
     prompt_len = jnp.clip(jnp.asarray(prompt_len, jnp.int32), 1, t_prompt)
     lens = jnp.reshape(prompt_len, (1,))
     slot = jnp.asarray(slot, jnp.int32)
     zero = jnp.int32(0)
-    cache, logits0 = _prefill_into(
+    cache, logits0, *routing = _prefill_into(
         params, cache, prompt_tokens, lens, (zero, slot, zero, zero, zero),
-        config, rules, mesh
+        config, rules, mesh,
+        with_routing=moe_lib.counts_routing(config.moe),
     )
 
     state, tok0 = _arm_slot(state, logits0, prompt_len, slot,
                             max_new_tokens, config, sample=sample, rng=rng)
-    return cache, state, tok0
+    return (cache, state, tok0, *routing)
 
 
 def _arm_slot(state, logits0, prompt_len, slot, max_new_tokens, config, *,
@@ -1002,6 +1156,11 @@ def decode_chunk_program(
     materializing the full [num_slots, chunk_size] grids at dispatch
     time.  ``False`` (default) keeps the trace byte-identical to
     today's four-tuple.
+
+    A model with dropless experts appends one more, last: what its expert
+    layers counted of their routing (``moe.ROUTING_HEAD``), summed over
+    the chunk's steps and layers, inactive slots left out — the engine
+    reads it back with the tokens.
     """
     if chunk_size < 1:
         raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
@@ -1010,6 +1169,7 @@ def decode_chunk_program(
     track_seen = sample.repetition_penalty != 1.0
     need_min = sample.eos_id is not None and sample.min_new_tokens > 0
     rows = jnp.arange(num_slots)
+    with_routing = moe_lib.counts_routing(config.moe)
 
     def step(carry, step_rng):
         cache, state = carry
@@ -1020,12 +1180,12 @@ def decode_chunk_program(
         # keeps its already-written prompt positions intact while the
         # grid decodes around it.  (Pre-chunked-prefill the write was
         # merely stale-but-harmless; now it would corrupt.)
-        s = cache["k"].shape[2]
+        s = _rows_leaf(cache).shape[2]
         write_pos = jnp.where(active, state["pos"], jnp.int32(s))
-        cache, logits = _decode_step(
+        cache, logits, *routing = _decode_step(
             params, cache, state["tok"], state["pos"], config, rules, mesh,
             write_pos=write_pos, pool=pool, block_table=block_table,
-            use_pallas=use_pallas,
+            use_pallas=use_pallas, with_routing=with_routing,
         )
         allow = (
             state["emitted"] >= sample.min_new_tokens if need_min else None
@@ -1049,18 +1209,19 @@ def decode_chunk_program(
             # Unconditional like _decode_tokens: inactive rows set the
             # pad bit in a row the next insert resets anyway.
             new_state["seen"] = state["seen"].at[rows, tok].set(True)
-        return (cache, new_state), (tok, active)
+        return (cache, new_state), (tok, active, *routing)
 
-    (cache, state), (toks, valid) = jax.lax.scan(
+    (cache, state), (toks, valid, *routing) = jax.lax.scan(
         step, (cache, state), jax.random.split(rng, chunk_size)
     )
+    routing = [counted.sum(0) for counted in routing]
     if with_summary:
         summary = jnp.stack([
             valid.sum().astype(jnp.int32),
             state["active"].sum().astype(jnp.int32),
         ])
-        return cache, state, toks.T, valid.T, summary
-    return cache, state, toks.T, valid.T
+        return (cache, state, toks.T, valid.T, summary, *routing)
+    return (cache, state, toks.T, valid.T, *routing)
 
 
 # --------------------------------------------------------------------------
@@ -1090,7 +1251,7 @@ def init_prefix_pool(config, num_blocks: int, block_tokens: int, *,
     slot cache, so copies are per-leaf slicing).  Which block holds
     which token prefix is host-side bookkeeping
     (``serving.prefix_cache.PrefixCacheManager``)."""
-    _refuse_recurrent(config, "the prefix pool")
+    _refuse_unless_kv_rows(config, "the prefix pool")
     return _init_cache(config, num_blocks, block_tokens, rules, mesh,
                        kv_quant=kv_quant)
 
@@ -1369,6 +1530,7 @@ def draft_chunk_program(
     """
     if spec_k < 1:
         raise ValueError(f"spec_k must be >= 1, got {spec_k}")
+    _refuse_unless_kv_rows(config, "a draft's proposal window")
     s = cache["k"].shape[2]
     active = state["active"]
 
@@ -1582,21 +1744,45 @@ def _check_inference_supported(config, rules, mesh, what: str):
         shape = dict(mesh.shape)
         if any(shape.get(axis, 1) > 1
                for axis in (mesh_lib.AXIS_TP, mesh_lib.AXIS_SP)):
-            _refuse_recurrent(config, f"{what} under a tp/sp mesh")
+            _refuse_unless_kv_rows(config, f"{what} under a tp/sp mesh")
+    if config.latent is not None and mesh is not None and mesh.size > 1:
+        _refuse_unless_kv_rows(config, f"{what} under a mesh")
 
 
-def _refuse_recurrent(config, what: str):
-    """The one error of every path that takes "a prefix's cache is its
-    K/V rows" for granted: a recurrent state is whole at every token, so
-    a copied, paged, chunked, rewound or head-sharded cache of rows does
-    not carry it (ROADMAP R4 keeps the list)."""
-    if config.ssm is not None:
+#: The cache kinds that are not "K and V rows per head", each with the
+#: configuration field that brings it and what its slot cache holds.  The
+#: next kind adds a line.
+CACHE_KINDS = (
+    ("ssm", "a recurrent state",
+     "a state and a convolution tail per row besides the K/V rows"),
+    ("latent", "a latent-attention cache",
+     "one latent row a token (key and value of every head at once), no "
+     "K or V per head"),
+)
+
+
+def cache_kind(config):
+    """``(field, name, holds)`` of the first of :data:`CACHE_KINDS` the
+    configuration brings, or None for plain per-head K/V rows."""
+    return next((kind for kind in CACHE_KINDS
+                 if getattr(config, kind[0]) is not None), None)
+
+
+def _refuse_unless_kv_rows(config, what: str):
+    """The one error of every path that takes "a cache row is K and V per
+    head, and a prefix's cache is its rows" for granted: a copied, paged,
+    chunked, rewound, quantized, beam-reordered or head-sharded cache of
+    such rows carries neither a recurrent state (whole at every token)
+    nor a latent row (no heads to shard, one leaf to copy).  ROADMAP R4
+    keeps the list of what is refused."""
+    kind = cache_kind(config)
+    if kind is not None:
+        field, name, holds = kind
         raise NotImplementedError(
-            f"{what} is not supported for a model with a recurrent state "
-            "(TransformerConfig.ssm): its slot cache holds a state and a "
-            "convolution tail per row besides the K/V rows, and only the "
-            "plain slot path (insert at a bucket, decode chunks, "
-            "generate()) carries them"
+            f"{what} is not supported for a model with {name} "
+            f"(TransformerConfig.{field}): its slot cache holds {holds}, "
+            "and only the plain slot path (insert at a bucket, decode "
+            "chunks, generate()) carries that"
         )
 
 
@@ -1636,7 +1822,7 @@ def beam_search(
     """
     mesh = mesh if mesh is not None else mesh_lib.get_global_mesh()
     _check_inference_supported(config, rules, mesh, "beam_search")
-    _refuse_recurrent(config, "beam_search")
+    _refuse_unless_kv_rows(config, "beam_search")
     if num_beams < 1:
         raise ValueError(f"num_beams must be >= 1, got {num_beams}")
     if max_new_tokens < 1:

@@ -1,15 +1,30 @@
-"""Mixture-of-experts MLP with capacity-based dense dispatch (GShard-style).
+"""Mixture-of-experts MLP, two routes.
 
-The dispatch/combine tensors keep everything as large einsums — exactly what
-the MXU wants — and the stacked expert weights carry the ``expert`` logical
-axis so they shard over the ``ep`` mesh axis.  Tokens overflowing an
-expert's capacity are dropped (standard top-k capacity routing).
+**Capacity** (:func:`moe_mlp_apply`, the training path's): GShard-style
+dense dispatch.  The dispatch/combine tensors keep everything as large
+einsums — exactly what the MXU wants — and the stacked expert weights carry
+the ``expert`` logical axis so they shard over the ``ep`` mesh axis.  Tokens
+overflowing an expert's capacity are dropped (standard top-k capacity
+routing).
+
+**Dropless share** (:func:`dropless_mlp_apply`, ``MoeConfig.dropless``; the
+serving path's): the layer is told which experts it HOLDS
+(``[expert_offset, expert_offset + experts_held)`` of ``num_experts``, one
+chip's share under expert parallelism), routes every token over all
+``num_experts`` at the router's published width, and computes its own
+experts' part of the result for the tokens routed to them, plus what every
+chip computes alike (a shared expert).  What the absent experts would add
+is left out and nothing stands in for their chips or their exchange.  No
+token is ever dropped: the (token, choice) pairs that land here are sorted
+by expert and go through three grouped matrix products
+(``ops.grouped_matmul``) in blocks of ``ROW_BLOCK`` rows, as many blocks as
+the routing asks for.  An expert that got no token is never read.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +41,47 @@ class MoeConfig:
     #: Router z-loss (ST-MoE): penalizes ``logsumexp(logits)^2`` to keep
     #: router logits small/stable in bf16 training.  0 disables.
     z_loss_weight: float = 0.0
+    #: The dropless share (module docstring); everything below it needs it.
+    dropless: bool = False
+    #: Experts held here, ``[expert_offset, expert_offset + experts_held)``
+    #: of ``num_experts``; None -> all of them.
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    #: The router's scores: "softmax" over the experts, or "sigmoid" of
+    #: each logit alone.
+    score: str = "softmax"
+    #: The chosen scores are renormalised to sum 1, then times this.
+    routed_scale: float = 1.0
+    #: Width of the expert every token goes through beside the routed
+    #: ones (0: none).
+    shared_hidden: int = 0
+    #: A parameter ``bias`` [num_experts] added to the scores for the
+    #: CHOICE of experts alone (the aux-loss-free balancing bias); the
+    #: weights come from the scores without it.
+    selection_bias: bool = False
+
+    def __post_init__(self):
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router score {self.score!r}")
+        share = (self.experts_held is not None or self.expert_offset
+                 or self.score != "softmax" or self.routed_scale != 1.0
+                 or self.shared_hidden or self.selection_bias)
+        if share and not self.dropless:
+            raise ValueError(
+                "experts_held, expert_offset, score, routed_scale, "
+                "shared_hidden and selection_bias describe the dropless "
+                "share (MoeConfig.dropless); the capacity route knows none "
+                "of them")
+        if not 0 <= self.expert_offset <= self.num_experts - self.held:
+            raise ValueError(
+                f"experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.held}) are not among "
+                f"{self.num_experts}")
+
+    @property
+    def held(self) -> int:
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held)
 
 
 def moe_mlp_init(rng, dim: int, hidden: int, cfg: MoeConfig):
@@ -36,7 +92,7 @@ def moe_mlp_init(rng, dim: int, hidden: int, cfg: MoeConfig):
     )
 
     def stack_init(r, i, o):
-        rs = jax.random.split(r, cfg.num_experts)
+        rs = jax.random.split(r, cfg.held)
         return jax.vmap(
             lambda rr: layers.dense_init(
                 rr, i, o, in_axis=None, out_axis=None, use_bias=False
@@ -49,16 +105,26 @@ def moe_mlp_init(rng, dim: int, hidden: int, cfg: MoeConfig):
         "wg": stack_init(r_wg, dim, hidden),
         "wo": stack_init(r_wo, hidden, dim),
     }
-    return params, moe_mlp_axes()
+    if cfg.selection_bias:
+        params["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
+    if cfg.shared_hidden:
+        params["shared"], _ = layers.mlp_block_init(
+            jax.random.fold_in(rng, 4), dim, cfg.shared_hidden)
+    return params, moe_mlp_axes(cfg)
 
 
-def moe_mlp_axes():
-    return {
+def moe_mlp_axes(cfg: Optional[MoeConfig] = None):
+    axes = {
         "router": layers.dense_axes("embed", None, use_bias=False),
         "wi": ("expert", "embed", "mlp"),
         "wg": ("expert", "embed", "mlp"),
         "wo": ("expert", "mlp", "embed"),
     }
+    if cfg is not None and cfg.selection_bias:
+        axes["bias"] = (None,)
+    if cfg is not None and cfg.shared_hidden:
+        axes["shared"] = layers.mlp_block_axes()
+    return axes
 
 
 def _capacity(tokens_per_batch: int, cfg: MoeConfig) -> int:
@@ -125,3 +191,129 @@ def moe_mlp_apply(
         z = jax.scipy.special.logsumexp(router_logits, axis=-1)  # [B, T]
         aux = aux + cfg.z_loss_weight * jnp.mean(z * z)
     return out, aux
+
+
+# -- the dropless share ---------------------------------------------------
+
+#: Rows (assignments) a block of the dropless route's grouped products: a
+#: layer call with more (token, choice) pairs than this walks the ones
+#: that landed here in blocks, as many as there are, so nothing is dropped
+#: and nothing is sized for the worst routing.
+ROW_BLOCK = 1024
+
+#: What a dropless layer call counts of its routing, as the head of an
+#: int32 vector [ROUTING_HEAD + experts_held]: the (token, choice)
+#: assignments made, those that landed on an expert held here, the held
+#: experts that got any; then the tokens each held expert got.
+ROUTING_HEAD = 3
+
+
+def counts_routing(cfg: Optional[MoeConfig]) -> bool:
+    return cfg is not None and cfg.dropless
+
+
+def route(params, flat, cfg: MoeConfig):
+    """The router on tokens ``flat`` [N, D]: the chosen experts [N, K]
+    (of all ``num_experts``) and their weights [N, K] float32 —
+    renormalised over the chosen and times ``routed_scale``.  Scores in
+    float32; with ``selection_bias`` the CHOICE is by score + bias and the
+    weights by the score alone."""
+    kernel = params["router"]["kernel"]
+    if kernel.dtype == flat.dtype == jnp.bfloat16:
+        # bfloat16 products are exact in float32: one MXU pass gives what
+        # the float32 product would.
+        logits = jnp.einsum("nd,de->ne", flat, kernel,
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.einsum(
+            "nd,de->ne", flat.astype(jnp.float32),
+            kernel.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if cfg.score == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    chosen_by = scores + params["bias"] if cfg.selection_bias else scores
+    _, idx = jax.lax.top_k(chosen_by, cfg.top_k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weights * cfg.routed_scale
+
+
+#: The routed experts' stacked matrices among an expert layer's
+#: parameters: a caller that scans the layers hands them over WHOLE,
+#: with the layer's index (:func:`dropless_mlp_apply`), so that the
+#: grouped products read them in place.
+EXPERT_LEAVES = ("wi", "wg", "wo")
+
+
+def _held_experts(params, rows, sizes, layer):
+    """SwiGLU of each row through ITS expert: ``rows`` [R, D] sorted by
+    held expert, ``sizes`` [held] rows an expert; the experts' matrices
+    one layer's [E, .., ..], or every layer's with ``layer``."""
+    from cloud_tpu.ops.grouped_matmul import grouped_matmul
+
+    def product(x, name):
+        w = layers.materialize_matrix(params, name, x.dtype)
+        return grouped_matmul(x, w, sizes, layer=layer)
+
+    gate = product(rows, "wi").astype(jnp.float32)
+    hidden = jax.nn.silu(gate) * product(rows, "wg").astype(jnp.float32)
+    return product(hidden.astype(rows.dtype), "wo")
+
+
+def dropless_mlp_apply(params, x: jnp.ndarray, cfg: MoeConfig, *,
+                       live: Optional[jnp.ndarray] = None, layer=None):
+    """The dropless share on ``x`` [B, T, D]: the sum, over a token's
+    chosen experts that are held here, of weight x expert(token), plus the
+    shared expert.  ``live`` [B, T] (nonzero = a real token) keeps
+    padding and idle rows off the experts and out of the counts (their
+    output is the shared expert's alone).  ``params`` holds one layer;
+    with ``layer`` (a traced index) its :data:`EXPERT_LEAVES` are every
+    layer's, stacked, and the grouped products read layer ``layer`` of
+    them in place (a scan's slice of them would be copied whole at every
+    call).  Returns ``(out [B, T, D], routing)`` with ``routing`` as
+    :data:`ROUTING_HEAD` lays it out."""
+    b, t, d = x.shape
+    n, k, held = b * t, cfg.top_k, cfg.held
+    flat = x.reshape(n, d)
+    idx, weights = route(params, flat, cfg)
+    real = (jnp.ones((n, 1), bool) if live is None
+            else live.reshape(n, 1) != 0)
+    local = idx - cfg.expert_offset
+    here = (local >= 0) & (local < held) & real
+    key = jnp.where(here, local, held).reshape(n * k)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    landed = jnp.sum(sizes)
+    routing = jnp.concatenate([
+        jnp.stack([jnp.sum(real) * k, landed, jnp.sum(sizes > 0)]
+                  ).astype(jnp.int32), sizes])
+
+    rows_block = min(ROW_BLOCK, n * k)
+    blocks = -(-(n * k) // rows_block)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, blocks * rows_block - n * k))
+    flat_weights = weights.reshape(n * k)
+    ends = jnp.cumsum(sizes)
+
+    def block(i, out):
+        lo = i * rows_block
+        ids = jax.lax.dynamic_slice(order, (lo,), (rows_block,))
+        tokens = ids // k
+        block_sizes = (jnp.clip(ends, lo, lo + rows_block)
+                       - jnp.clip(ends - sizes, lo, lo + rows_block))
+        y = _held_experts(params, jnp.take(flat, tokens, axis=0),
+                          block_sizes, layer)
+        valid = (lo + jnp.arange(rows_block)) < landed
+        y = jnp.where(valid[:, None],
+                      y.astype(jnp.float32)
+                      * jnp.take(flat_weights, ids)[:, None], 0.0)
+        return out.at[tokens].add(y)
+
+    out = jnp.zeros((n, d), jnp.float32)
+    if blocks == 1:
+        out = block(0, out)
+    else:
+        out = jax.lax.fori_loop(0, -(-landed // rows_block), block, out)
+    out = out.astype(x.dtype).reshape(b, t, d)
+    if cfg.shared_hidden:
+        out = out + layers.mlp_block_apply(params["shared"], x)
+    return out, routing

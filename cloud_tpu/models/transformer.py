@@ -29,7 +29,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from cloud_tpu.models import layers, moe as moe_lib, ssm as ssm_lib
+from cloud_tpu.models import layers, mla as mla_lib
+from cloud_tpu.models import moe as moe_lib, ssm as ssm_lib
 from cloud_tpu.parallel import mesh as mesh_lib
 from cloud_tpu.parallel import pipeline as pipeline_lib
 from cloud_tpu.parallel.sharding import DEFAULT_RULES, ShardingRules, shard_constraint
@@ -77,6 +78,16 @@ class TransformerConfig:
     #: same normed input and their outputs are summed before the one
     #: residual add (models/ssm.py).  None -> attention alone.
     ssm: Optional[ssm_lib.SsmConfig] = None
+    #: Latent attention (models/mla.py): low-rank query and key/value
+    #: projections, a cache row of one latent vector a token.  ``head_dim``
+    #: and ``num_kv_heads`` then size nothing.  None -> q, k, v per head.
+    latent: Optional[mla_lib.LatentConfig] = None
+    #: The first this-many layers keep a dense SwiGLU MLP of width
+    #: ``dense_mlp_hidden`` where the rest have experts (``moe``): they
+    #: are their own stack, ``params["dense_layers"]``, run before the
+    #: scanned ``params["layers"]``.
+    leading_dense_layers: int = 0
+    dense_mlp_hidden: Optional[int] = None
     multipliers: Multipliers = Multipliers()
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
@@ -121,11 +132,26 @@ class TransformerConfig:
                 f"num_kv_heads={self.num_kv_heads} must divide "
                 f"num_heads={self.num_heads}"
             )
+        if self.leading_dense_layers and (
+                self.moe is None or self.dense_mlp_hidden is None
+                or not 0 < self.leading_dense_layers < self.num_layers):
+            raise ValueError(
+                "leading_dense_layers needs expert layers after them (moe, "
+                "fewer than num_layers) and their width (dense_mlp_hidden)"
+            )
 
     @property
     def kv_heads(self) -> int:
         return (self.num_heads if self.num_kv_heads is None
                 else self.num_kv_heads)
+
+    @property
+    def dense_stack(self) -> "TransformerConfig":
+        """The configuration of the leading dense layers alone."""
+        return dataclasses.replace(
+            self, moe=None, mlp_hidden=self.dense_mlp_hidden,
+            num_layers=self.leading_dense_layers, leading_dense_layers=0,
+            dense_mlp_hidden=None)
 
     def scaled(self, **kw) -> "TransformerConfig":
         return dataclasses.replace(self, **kw)
@@ -146,10 +172,14 @@ SMALL = TransformerConfig(
 
 def _layer_init(rng, config: TransformerConfig):
     r_att, r_mlp, r_ssm, _ = jax.random.split(rng, 4)
-    att, att_axes = layers.attention_block_init(
-        r_att, config.dim, config.num_heads, config.head_dim,
-        config.num_kv_heads,
-    )
+    if config.latent is not None:
+        att, att_axes = mla_lib.attention_init(
+            r_att, config.dim, config.num_heads, config.latent)
+    else:
+        att, att_axes = layers.attention_block_init(
+            r_att, config.dim, config.num_heads, config.head_dim,
+            config.num_kv_heads,
+        )
     ln1, ln1_axes = layers.rmsnorm_init(config.dim)
     ln2, ln2_axes = layers.rmsnorm_init(config.dim)
     if config.moe is not None:
@@ -172,9 +202,15 @@ def init(rng, config: TransformerConfig) -> Dict[str, Any]:
     r_embed, r_layers, r_head, r_ln = jax.random.split(rng, 4)
     embed, _ = layers.embedding_init(r_embed, config.vocab_size, config.dim)
     layer_rngs = jax.random.split(r_layers, config.num_layers)
-    stacked = jax.vmap(lambda r: _layer_init(r, config)[0])(layer_rngs)
+    dense = config.leading_dense_layers
+    stacked = jax.vmap(lambda r: _layer_init(r, config)[0])(
+        layer_rngs[dense:])
     ln_f, _ = layers.rmsnorm_init(config.dim)
     params = {"embed": embed, "layers": stacked, "ln_f": ln_f}
+    if dense:
+        params["dense_layers"] = jax.vmap(
+            lambda r: _layer_init(r, config.dense_stack)[0]
+        )(layer_rngs[:dense])
     if not config.tied_embeddings:
         params["head"], _ = layers.dense_init(
             r_head, config.dim, config.vocab_size, in_axis="embed",
@@ -189,16 +225,20 @@ def param_logical_axes(config: TransformerConfig):
     The stacked layer dim gets the ``layers`` logical axis (maps to ``pp``
     under pipeline rules, replicated otherwise).
     """
-    _, layer_axes = _layer_init_axes(config)
-    stacked_axes = jax.tree_util.tree_map(
-        lambda ax: ("layers",) + tuple(ax), layer_axes,
-        is_leaf=lambda x: isinstance(x, tuple),
-    )
+    def stacked_axes(config):
+        _, layer_axes = _layer_init_axes(config)
+        return jax.tree_util.tree_map(
+            lambda ax: ("layers",) + tuple(ax), layer_axes,
+            is_leaf=lambda x: isinstance(x, tuple),
+        )
+
     axes = {
         "embed": {"table": ("vocab", "embed")},
-        "layers": stacked_axes,
+        "layers": stacked_axes(config),
         "ln_f": {"scale": (None,)},
     }
+    if config.leading_dense_layers:
+        axes["dense_layers"] = stacked_axes(config.dense_stack)
     if not config.tied_embeddings:
         axes["head"] = {"kernel": ("embed", "vocab")}
     return axes
@@ -208,11 +248,12 @@ def _layer_init_axes(config: TransformerConfig):
     # Single source of truth: the same axes tables the layer init functions
     # return (layers.py / moe.py companions), composed per layer.
     if config.moe is not None:
-        mlp_axes = moe_lib.moe_mlp_axes()
+        mlp_axes = moe_lib.moe_mlp_axes(config.moe)
     else:
         mlp_axes = layers.mlp_block_axes()
     axes = {
-        "att": layers.attention_block_axes(),
+        "att": (mla_lib.attention_axes() if config.latent is not None
+                else layers.attention_block_axes()),
         "ln1": {"scale": (None,)},
         "mlp": mlp_axes,
         "ln2": {"scale": (None,)},
@@ -287,6 +328,11 @@ def _attention(
     x, att_params, config: TransformerConfig, rules: ShardingRules,
     mesh, positions,
 ):
+    if config.latent is not None:
+        attended = mla_lib.expanded_attention(
+            att_params, *mla_lib.project(att_params, x, positions, config),
+            None, config, rules=rules, mesh=mesh)
+        return mla_lib.attention_out(att_params, attended, config)
     q, k, v = qkv_project(att_params, x, positions, config)
     k, v = repeat_kv(k, v, config)
     q = shard_constraint(q, "batch", "seq", "heads", None, rules=rules, mesh=mesh)
@@ -317,7 +363,10 @@ def _layer_compute(layer_params, x, aux, *, config, rules, mesh, positions):
         mixed = mixed + ssm_out
     x = x + mixed
     y = layers.rmsnorm_apply(layer_params["ln2"], x, eps=config.norm_eps)
-    if config.moe is not None:
+    if moe_lib.counts_routing(config.moe):
+        mlp_out, _ = moe_lib.dropless_mlp_apply(layer_params["mlp"], y,
+                                                config.moe)
+    elif config.moe is not None:
         mlp_out, layer_aux = moe_lib.moe_mlp_apply(
             layer_params["mlp"], y, config.moe
         )
@@ -442,6 +491,10 @@ def apply_hidden(
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules, mesh=mesh)
 
     if _is_pipelined(config, rules, mesh):
+        if config.leading_dense_layers:
+            raise NotImplementedError(
+                "pp pipelining runs ONE stack of identical layers; "
+                "leading_dense_layers makes two")
         x, aux = _pipelined_stack(params, x, config, rules, mesh)
     else:
         positions = (
@@ -449,19 +502,23 @@ def apply_hidden(
             else jnp.broadcast_to(jnp.arange(t), (b, t))
         )
 
-        def layer_body(carry, layer_params):
-            x, aux = carry
-            x, aux = _layer_compute(
-                layer_params, x, aux, config=config, rules=rules, mesh=mesh,
-                positions=positions,
-            )
-            return (x, aux), None
+        def stack(carry, stack_params, config):
+            def layer_body(carry, layer_params):
+                x, aux = carry
+                x, aux = _layer_compute(
+                    layer_params, x, aux, config=config, rules=rules,
+                    mesh=mesh, positions=positions,
+                )
+                return (x, aux), None
 
-        body = layers.remat_wrap(layer_body, config.remat,
-                                 config.remat_policy)
-        (x, aux), _ = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["layers"]
-        )
+            body = layers.remat_wrap(layer_body, config.remat,
+                                     config.remat_policy)
+            return jax.lax.scan(body, carry, stack_params)[0]
+
+        carry = (x, jnp.zeros((), jnp.float32))
+        if config.leading_dense_layers:
+            carry = stack(carry, params["dense_layers"], config.dense_stack)
+        x, aux = stack(carry, params["layers"], config)
 
     x = layers.rmsnorm_apply(params["ln_f"], x, eps=config.norm_eps)
     return x, aux

@@ -122,6 +122,20 @@ with one scheduler behind a submit/future/admission surface:
   ``insert_rows_computed`` sum, at every insert dispatch, the prompt
   buffer's rows and the rows the insert program computes for the
   prompt in it (``generation.prefill_rows_computed``).
+  A latent-attention model (``TransformerConfig.latent``) keeps ONE row
+  a token a layer, key and value of every head at once: the
+  ``kv_row_steps_*`` and ``kv_bytes_*`` count those rows (read: the
+  live slots' rows rounded up to ``ops.latent_attention``'s page where
+  its kernel reads).  A model with dropless experts
+  (``MoeConfig.dropless``) routes on the device, so every insert and
+  chunk brings its routing counts back in the same read-back as its
+  tokens: ``expert_assignments`` / ``expert_assignments_here`` (the
+  (token, choice) pairs made, and those that landed on an expert held
+  here), ``expert_steps`` / ``expert_steps_touched`` (per decode step
+  and expert layer, the held experts against those that got a token),
+  ``expert_loads`` and ``expert_load_max_over_mean`` (the tokens each
+  held expert got so far; the busiest over their mean); zeros without
+  experts; ``serve/pass`` carries ``assignments_here``.
   ``serve/qps`` and ``serve/tokens_per_sec``
   windowed-rate gauges, the ``serve/slot_occupancy`` gauge,
   slot-churn counters
@@ -681,6 +695,9 @@ class _InflightChunk:
     #: ``time.perf_counter()`` bracketing the dispatch call itself.
     dispatch_start: float
     dispatch_end: float
+    #: Device routing counts of a model with dropless experts
+    #: (``moe.ROUTING_HEAD``), read back with the tokens; else empty.
+    routing: tuple = ()
 
 
 class _DeferredPayload:
@@ -718,15 +735,22 @@ def _resolve_payload(payload):
     return payload
 
 
-def _refuse_for_recurrent_state(config, cfg: ServeConfig) -> None:
-    """Every engine feature that takes "a prefix's cache is its K/V
-    rows" for granted refuses, at construction and in words, a model
-    whose slot cache also holds a recurrent state
-    (``TransformerConfig.ssm``); the plain slot path — insert at a
-    bucket, decode chunks — serves it."""
-    if config.ssm is None:
+def _refuse_unless_kv_rows(config, cfg: ServeConfig) -> None:
+    """Every engine feature that takes "a cache row is K and V per head,
+    and a prefix's cache is its rows" for granted refuses, at
+    construction and in words, a model whose slot cache is of another
+    kind (``generation.CACHE_KINDS``: a recurrent state beside the rows,
+    ``TransformerConfig.ssm``; latent rows, ``TransformerConfig.latent``);
+    the plain slot path — insert at a bucket, decode chunks — serves
+    it."""
+    from cloud_tpu.models import generation
+
+    kind = generation.cache_kind(config)
+    if kind is None:
         return
-    asked = [name for name, on in (
+    field, name, holds = kind
+    asked = [what for what, on in (
+        ("kv_quant (an int8 cache)", cfg.kv_quant and field == "latent"),
         ("prefix_cache_blocks (the prefix pool)", cfg.prefix_cache_blocks),
         ("prefill_chunk_tokens (chunked prefill)",
          cfg.prefill_chunk_tokens is not None),
@@ -740,10 +764,10 @@ def _refuse_for_recurrent_state(config, cfg: ServeConfig) -> None:
     if asked:
         raise NotImplementedError(
             "ServeConfig asks for " + "; ".join(asked) + ", which a model "
-            "with a recurrent state (TransformerConfig.ssm) does not "
-            "support yet: a copied, chunked, paged, rewound, exported or "
-            "head-sharded cache of K/V rows does not carry the state and "
-            "the convolution tail each slot also holds (ROADMAP R4)"
+            f"with {name} (TransformerConfig.{field}) does not support "
+            "yet: a copied, chunked, paged, rewound, exported, quantized "
+            f"or head-sharded cache of K/V rows is not what its slot "
+            f"cache holds ({holds}) (ROADMAP R4)"
         )
 
 
@@ -785,7 +809,7 @@ class ServingEngine:
         #: param placement only happens for engine-owned meshes — a
         #: caller-provided mesh keeps the caller's placement).
         self._built_serving_mesh = False
-        _refuse_for_recurrent_state(config, self.serve_config)
+        _refuse_unless_kv_rows(config, self.serve_config)
         self._slice_shape, self._slice_chips = self._resolve_serving_mesh()
         generation.check_inference_supported(
             config, self.rules, self.mesh, "serving"
@@ -882,6 +906,14 @@ class ServingEngine:
             # the decoding slots' where the state kernel advances them.
             "state_row_steps_reserved": 0, "state_row_steps_in_use": 0,
             "state_row_steps_read": 0,
+            # Dropless experts (``MoeConfig.dropless``; zeros for a
+            # model without them), as the insert and chunk programs
+            # count them on the device: (token, choice) assignments
+            # made, those that landed on an expert held here; per
+            # decode step and expert layer the held experts, and those
+            # of them that got a token (whose weights the step read).
+            "expert_assignments": 0, "expert_assignments_here": 0,
+            "expert_steps": 0, "expert_steps_touched": 0,
             # QoS brownout sheds (0 unless qos arms a brownout depth).
             "brownout_shed": 0,
             # Disaggregated-serving KV handoff counters (all 0 with
@@ -1031,6 +1063,14 @@ class ServingEngine:
             cfg.num_slots * config.num_layers if state_leaves else 0
         )
         self._state_rows_in_use = 0
+        #: Dropless experts: the tokens each held expert got so far
+        #: (None for a model without them), and the assignments that
+        #: landed here in the open pass (``serve/pass``).
+        moe = config.moe if config.moe is not None and config.moe.dropless \
+            else None
+        self._expert_loads = (
+            np.zeros((moe.held,), np.int64) if moe is not None else None)
+        self._pass_assignments_here = 0
         #: Whether a decode step advances the state through
         #: ``ops.ssm_state``'s kernel, which fetches the decoding
         #: slots' rows alone (``generation._scan_layers``' own rule),
@@ -1243,10 +1283,14 @@ class ServingEngine:
         c = model_config if model_config is not None else self.config
         itemsize = 1 if cfg.kv_quant else np.dtype(c.dtype).itemsize
         # Per cached position: k + v across every layer and head (+ the
-        # two f32 scale columns when quantized).
-        per_pos = 2 * c.num_layers * c.kv_heads * (
-            c.head_dim * itemsize + (4 if cfg.kv_quant else 0)
-        )
+        # two f32 scale columns when quantized), or one latent row a
+        # layer.
+        if c.latent is not None:
+            per_pos = c.num_layers * c.latent.row_width * itemsize
+        else:
+            per_pos = 2 * c.num_layers * c.kv_heads * (
+                c.head_dim * itemsize + (4 if cfg.kv_quant else 0)
+            )
         max_len = cfg.prompt_buckets[-1] + cfg.max_new_tokens
         positions = cfg.num_slots * max_len
         if include_prefix:
@@ -2479,6 +2523,7 @@ class ServingEngine:
                 return
             self._pass_seq += 1
             self._pass_active = 0
+            self._pass_assignments_here = 0
             try:
                 for idx, (request, slot) in enumerate(inserts):
                     self._admit_request(request, slot)
@@ -2552,6 +2597,7 @@ class ServingEngine:
                     kv_rows_in_use=self._kv_rows_in_use,
                     kv_rows_reserved=self._kv_rows_reserved,
                     state_rows_in_use=self._state_rows_in_use,
+                    assignments_here=self._pass_assignments_here,
                 )
 
     def _pop_inserts_locked(self, inserts) -> None:
@@ -3089,10 +3135,11 @@ class ServingEngine:
             **_trace_attrs(request, bucket=request.bucket_len, slot=slot,
                            **{"pass": self._pass_seq}),
         ):
-            self._grid_cache, self._slot_state, tok0 = self._supervised(
-                "serve/prefill", dispatch
-            )
-            tok0 = int(self._to_host("insert_tok0", tok0)[0])
+            self._grid_cache, self._slot_state, tok0, *routing = (
+                self._supervised("serve/prefill", dispatch))
+            tok0, *routing = self._to_host("insert_tok0", tok0, *routing)
+            tok0 = int(tok0)
+        self._note_routing(routing, decode_steps=0)
         entry = _Slot(
             request=request, tokens=[tok0],
             first_token_ts=time.perf_counter(),
@@ -3145,11 +3192,12 @@ class ServingEngine:
         self._note_kv_rows()
         self._note_dispatch_gap(time.perf_counter())
         with tracing.span("serve/chunk", **span_attrs) as chunk_span:
-            self._grid_cache, self._slot_state, toks, valid = (
+            self._grid_cache, self._slot_state, toks, valid, *routing = (
                 self._supervised("serve/chunk", dispatch)
             )
             self._last_chunk_dispatch_end = time.perf_counter()
-            toks, valid = self._to_host("chunk_tokens", toks, valid)
+            toks, valid, *routing = self._to_host(
+                "chunk_tokens", toks, valid, *routing)
             emitted = int(valid.sum())
             occupancy = emitted / float(num_slots * chunk)
             chunk_span.set_attribute("tokens", emitted)
@@ -3160,7 +3208,33 @@ class ServingEngine:
             self._stats["chunks"] += 1
             self._stats["decode_slot_steps"] += num_slots * chunk
             self._stats["useful_decode_tokens"] += emitted
+        self._note_routing(routing, decode_steps=chunk)
         self._commit_emissions(toks, valid, chunk)
+
+    def _note_routing(self, routing, decode_steps: int) -> None:
+        """Add what a program's dropless expert layers counted on the
+        device (``moe.ROUTING_HEAD``; ``routing`` is empty for a model
+        without them) to the ``expert_*`` counters.  A chunk of
+        ``decode_steps`` steps also counts, per step and expert layer,
+        the held experts against those that got a token; an insert
+        (``decode_steps=0``) counts assignments and loads alone."""
+        if not routing:
+            return
+        from cloud_tpu.models import moe as moe_lib
+
+        counted = np.asarray(routing[0], np.int64)
+        made, here, touched = counted[:moe_lib.ROUTING_HEAD]
+        config = self.config
+        expert_layers = config.num_layers - config.leading_dense_layers
+        self._pass_assignments_here += int(here)
+        with self._stats_lock:
+            self._stats["expert_assignments"] += int(made)
+            self._stats["expert_assignments_here"] += int(here)
+            if decode_steps:
+                self._stats["expert_steps"] += (
+                    decode_steps * expert_layers * config.moe.held)
+                self._stats["expert_steps_touched"] += int(touched)
+            self._expert_loads += counted[moe_lib.ROUTING_HEAD:]
 
     def _feed_entry(self, entry: _Slot) -> None:
         """Deliver a slot's not-yet-streamed emissions to its request's
@@ -3312,9 +3386,17 @@ class ServingEngine:
         row of the grid.  What ``kv_row_steps_read`` counts by."""
         import jax
 
+        from cloud_tpu.models import generation
         from cloud_tpu.ops import paged_attention
 
         cfg, config = self.serve_config, self.config
+        if config.latent is not None:
+            from cloud_tpu.ops import latent_attention
+
+            rows = self._grid_cache[generation.LATENT_LEAF]
+            return latent_attention.kernel_page(jax.ShapeDtypeStruct(
+                (cfg.num_slots, config.num_heads, rows.shape[-1]),
+                config.dtype), rows)
         q = jax.ShapeDtypeStruct(
             (cfg.num_slots, 1, config.num_heads, config.head_dim),
             config.dtype)
@@ -3470,16 +3552,16 @@ class ServingEngine:
         self._note_kv_rows()
         start = time.perf_counter()
         self._note_dispatch_gap(start)
-        self._grid_cache, self._slot_state, toks, valid, summary = (
-            self._supervised("serve/chunk", dispatch)
-        )
+        (self._grid_cache, self._slot_state, toks, valid, summary,
+         *routing) = self._supervised("serve/chunk", dispatch)
         end = time.perf_counter()
         self._last_chunk_dispatch_end = end
-        self._start_host_copy(toks, valid, summary)
+        self._start_host_copy(toks, valid, summary, *routing)
         self._inflight.append(_InflightChunk(
             toks=toks, valid=valid, summary=summary, width=chunk,
             kind="chunk", active=len(self._active_slots),
             span_attrs=span_attrs, dispatch_start=start, dispatch_end=end,
+            routing=tuple(routing),
         ))
 
     def _dispatch_spec_chunk_async(self) -> None:
@@ -3551,10 +3633,12 @@ class ServingEngine:
         cfg = self.serve_config
         num_slots = cfg.num_slots
         wait0 = time.perf_counter()
-        toks, valid, summary = self._to_host(
-            f"{rec.kind}_tokens", rec.toks, rec.valid, rec.summary
+        toks, valid, summary, *routing = self._to_host(
+            f"{rec.kind}_tokens", rec.toks, rec.valid, rec.summary,
+            *rec.routing
         )
         wait1 = time.perf_counter()
+        self._note_routing(routing, decode_steps=rec.width)
         tracing.record_span("serve/host_bubble", wait0, wait1,
                             kind=rec.kind, width=rec.width)
         emitted = int(summary[0])
@@ -3925,6 +4009,14 @@ class ServingEngine:
             # stable schema next to brownout_shed above.
             snap["class_completed"] = dict(self._class_completed)
             snap["class_shed"] = dict(self._class_shed)
+            loads = (() if self._expert_loads is None
+                     else tuple(int(n) for n in self._expert_loads))
+        # Dropless experts: the tokens each held expert got so far and
+        # the busiest one's over their mean (0.0 without experts, or
+        # before any token reached one).
+        snap["expert_loads"] = loads
+        snap["expert_load_max_over_mean"] = (
+            max(loads) * len(loads) / sum(loads) if sum(loads) else 0.0)
         snap["role"] = self._role
         with self._cond:
             snap["class_backlog"] = self._class_backlog_locked()

@@ -544,3 +544,118 @@ def test_hybrid_decode_chunk_advances_the_state_leaf_in_place(
     assert not readers, readers
     assert not [ln for ln in lines if "f32[32,32,128,256]" in ln]
     assert compiled.memory_analysis().temp_size_in_bytes <= 0.81e9
+
+
+# ---------------------------------------------------------------------------
+# The latent-attention expert model (kimi-k2-ep32-stage.agent-saturated):
+# its two kernels and the flash kernel at head size 192, at the published
+# widths, and its chunk program with both carried in place
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("page", [128, 256, 512])
+def test_latent_decode(one_chip, page):
+    """64 slots x 4,608 rows of 640, layer 3 of the stack of 7, 64 heads."""
+    from cloud_tpu.ops import latent_attention
+
+    def fn(q, rows, lens, layer):
+        return latent_attention._pallas(
+            q, rows, lens, layer, page, value_dim=512, scale=0.1,
+            interpret=False)
+
+    _compile(fn, _spec((64, 64, 640), jnp.bfloat16, one_chip),
+             _spec((7, 64, 4608, 640), jnp.bfloat16, one_chip),
+             _spec((64,), jnp.int32, one_chip), _spec((), jnp.int32, one_chip))
+
+
+@pytest.mark.parametrize("rows, k, n", [(512, 7168, 2048), (512, 2048, 7168),
+                                        (1024, 7168, 2048),
+                                        (1024, 2048, 7168)])
+def test_grouped_matmul(one_chip, rows, k, n):
+    """A decode step's 512 assignments and a prompt's block of 1,024
+    against 12 held experts of a stack of 6 layers, gate/up and down."""
+    from cloud_tpu.ops import grouped_matmul
+
+    tm, tn = grouped_matmul._tiles(rows, k, n, 2)
+
+    def fn(x, w, sizes, layer):
+        return grouped_matmul._pallas(x, w, sizes, layer, tm, tn,
+                                      interpret=False)
+
+    compiled = _compile(
+        fn, _spec((rows, k), jnp.bfloat16, one_chip),
+        _spec((6, 12, k, n), jnp.bfloat16, one_chip),
+        _spec((12,), jnp.int32, one_chip), _spec((), jnp.int32, one_chip))
+    # The stack goes in whole: nothing the size of a layer's is made.
+    assert f"bf16[12,{k},{n}]" not in compiled.as_text()
+
+
+def test_flash_at_the_latent_models_head_size(one_chip):
+    """Query/key heads of 192 (128 + 64 rotated), the values zero-padded
+    to 192, 64 heads over a 2,048-row prompt under its padding mask."""
+    from cloud_tpu import ops
+
+    def fn(q, k, v, mask):
+        return ops.flash_attention(q, k, v, causal=True, mask=mask,
+                                   use_pallas=True)
+
+    qkv = _spec((1, 2048, 64, 192), jnp.bfloat16, one_chip)
+    _compile(fn, qkv, qkv, qkv, _spec((1, 2048), jnp.int32, one_chip))
+
+
+def test_latent_expert_decode_chunk_copies_neither_cache_nor_experts(
+        one_chip, monkeypatch):
+    """The cell's chunk program (64 slots x 4,608 latent rows, 1 dense +
+    6 expert layers at the published widths): the latent leaf is carried
+    in place (no copy of it or of a layer of it), the routed experts'
+    stacks reach the grouped products whole (no ``bf16[12,7168,2048]``
+    slice: each was a 352 MB copy a call when the scan sliced them), and
+    the kernels are there: the latent read of either stack and an expert
+    layer's three grouped products."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.adapters import serve_latent_moe
+    from benchmarks.harness import manifest
+    from benchmarks.references import kimi_k2
+    from cloud_tpu.models import generation
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = manifest.Cell("kimi-k2-ep32-stage.agent-saturated", root=root)
+    sizes, engine = cell.config, cell.traffic["engine"]
+    config = serve_latent_moe.model_config(sizes, cell.traffic)
+    sample = generation.SampleConfig(temperature=0.0)
+    rows = engine["prompt_buckets"][-1] + engine["max_new_tokens"]
+
+    on_chip = functools.partial(_tree_spec, one_chip)
+    params = on_chip(kimi_k2.params_shape(sizes))
+    cache = on_chip(jax.eval_shape(lambda: generation.init_slot_cache(
+        config, engine["num_slots"], rows)))
+    state = on_chip(jax.eval_shape(lambda: generation.init_slot_state(
+        config, engine["num_slots"], sample=sample)))
+
+    def fn(params, cache, state, rng):
+        return generation.decode_chunk_program(
+            params, cache, state, config, chunk_size=engine["chunk_tokens"],
+            sample=sample, rng=rng)
+
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, cache, state, _spec((2,), jnp.uint32, one_chip)).compile()
+    assert cache["latent"].shape == (7, 64, 4608, 640)
+    text = compiled.as_text()
+    names = re.findall(r"(%[\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                       text)
+    kinds = sorted(re.sub(r"\.\d+$", "", name) for name in names)
+    assert kinds == ["%grouped_matmul_decode"] * 3 + ["%latent_decode"] * 2
+    for made in ("bf16[12,7168,2048]", "bf16[12,2048,7168]",
+                 "bf16[64,4608,640]"):
+        assert made not in text, made
+    # The leaf is never copied, re-made by an update of its own or put in
+    # a fresh buffer (the row scatter is a fusion over the donated leaf).
+    leaf = re.escape("bf16[7,64,4608,640]")
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if re.match(r"\s*(?:ROOT )?%[\w.\-]+ = " + leaf
+                         + r"\S* (copy|dynamic-update-slice|custom-call)\(",
+                         ln)]
+    assert not moved, moved
+    assert compiled.memory_analysis().temp_size_in_bytes <= 0.5e9

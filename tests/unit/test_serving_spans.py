@@ -302,3 +302,54 @@ def test_collector_off_counts_kv_and_records_nothing(model):
     assert result.trace_id is None and tracing.timeline_events() == []
     assert stats["kv_row_steps_in_use"] == 2 + 1
     assert stats["kv_row_steps_reserved"] == 4 + 3
+
+
+@pytest.mark.parametrize("held, offset", [(None, 0), (2, 4)],
+                         ids=["every-expert", "a-share"])
+def test_expert_counters_on_the_full_rows_schedule(held, offset):
+    """The routing counts of a model with dropless experts on the
+    "full-rows" schedule above (A: 3 prompt tokens, 5 to make; B: 5 and 3;
+    two slots, chunks of 2): they come back from the device with each
+    insert's and each chunk's tokens.  One dense layer, then one expert
+    layer, 2 of 8 experts a token: the inserts route 3 + 5 tokens; chunk 1
+    decodes A and B for two steps, chunk 2 A alone (B has retired, and an
+    idle slot routes nothing): (8 + 4 + 2) x 2 = 28 assignments.  A chunk
+    counts 2 steps x 1 expert layer x the held experts, and those of them
+    that got a token."""
+    from cloud_tpu.models import mla, moe
+
+    config = transformer.TINY.scaled(
+        dtype=jnp.float32, num_layers=2, leading_dense_layers=1,
+        dense_mlp_hidden=96, mlp_hidden=32,
+        latent=mla.LatentConfig(q_rank=24, kv_rank=32, nope_dim=16,
+                                rope_dim=8, v_dim=16),
+        moe=moe.MoeConfig(num_experts=8, top_k=2, dropless=True,
+                          experts_held=held, expert_offset=offset,
+                          score="sigmoid", shared_hidden=32,
+                          selection_bias=True))
+    model = config, transformer.init(jax.random.PRNGKey(0), config)
+    events, results, stats = _serve(
+        model, [[1, 2, 3], [4, 5, 6, 7, 8]], [5, 3], max_new_tokens=5,
+        prompt_buckets=(8,), num_slots=2)
+    assert [len(r.tokens) for r in results] == [5, 3]
+    assert stats["chunks"] == 2
+    assert stats["expert_assignments"] == 28
+    held_here = 8 if held is None else held
+    assert stats["expert_steps"] == 2 * 2 * 1 * held_here
+    assert len(stats["expert_loads"]) == held_here
+    assert sum(stats["expert_loads"]) == stats["expert_assignments_here"]
+    if held is None:
+        assert stats["expert_assignments_here"] == 28
+        # Two tokens a step pick at least 2 experts, at most 4; one, 2.
+        assert 2 * 2 + 2 * 2 <= stats["expert_steps_touched"] <= 2 * 4 + 2 * 2
+        assert stats["expert_load_max_over_mean"] >= 1.0
+    else:
+        assert stats["expert_assignments_here"] <= 28
+        assert stats["expert_steps_touched"] <= stats["expert_steps"]
+    # The pass carries what landed here in it; a latent row is one a token.
+    passes = _named(events, "serve/pass")
+    assert sum(p["args"]["assignments_here"] for p in passes) == \
+        stats["expert_assignments_here"]
+    assert stats["kv_row_steps_reserved"] == 2 * 2 * (8 + 5)
+    assert stats["kv_row_steps_in_use"] == (3 + 1) + (5 + 1) + (3 + 3)
+    assert stats["kv_bytes_reserved"] == 2 * 2 * 13 * 128 * 4
