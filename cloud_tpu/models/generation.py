@@ -523,9 +523,11 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
     """One block on the full prompt buffer [B, T, D], returning what the
     block leaves in a cache, raw: its k/v and, with ``config.ssm``, each
     row's state and convolution tail at its last real token.  Causal
-    attention with the padding mask applied key-side (padded tail slots
-    are later overwritten by decode before they can ever be attended);
-    the mixer masks the padding itself (``ssm.ssd_prefill``).  With
+    attention told the rows' lengths (``ops.flash_attention``: the
+    kernel does no work past a length and leaves zeros there, the
+    reference masks key-side; padded tail slots are later overwritten by
+    decode before they can ever be attended); the mixer masks the padding
+    itself (``ssm.ssd_prefill``).  With
     ``config.latent`` the block leaves each token's latent row and attends
     in the EXPANDED form (``mla.expanded_attention``).  Also returns what
     a dropless expert layer counted of its routing (else None), padding
@@ -537,7 +539,7 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
         att = layer_params["att"]
         q_nope, q_pe, c, k_pe = mla_lib.project(att, y, positions, config)
         attended = mla_lib.expanded_attention(
-            att, q_nope, q_pe, c, k_pe, prompt_mask, config, rules=rules,
+            att, q_nope, q_pe, c, k_pe, prompt_lens, config, rules=rules,
             mesh=mesh)
         mixed = mla_lib.attention_out(att, attended, config)
         left = {LATENT_LEAF: mla_lib.cache_rows(c, k_pe, config.latent,
@@ -547,7 +549,7 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
                                           config)
         attended = ops.flash_attention(
             q, *transformer.repeat_kv(k, v, config), causal=True,
-            mask=prompt_mask, partitioned=mesh is not None, mesh=mesh,
+            lengths=prompt_lens, partitioned=mesh is not None, mesh=mesh,
             batch_axes=rules.assignment("batch"),
             head_axes=rules.assignment("heads"),
         )
@@ -702,6 +704,31 @@ def prefill_rows_computed(t_prompt: int, prompt_len: int,
     :func:`_prefill_into` applies, for whoever counts its work."""
     return next(w for w in prefill_widths(t_prompt, rules, mesh)
                 if w >= min(prompt_len, t_prompt))
+
+
+def prefill_flash_tiles(config, t_prompt: int, prompt_len: int,
+                        rules: ShardingRules = DEFAULT_RULES, mesh=None):
+    """``(run, width)``: the compute tiles the flash forward kernel runs a
+    head a layer for a prompt of ``prompt_len`` tokens in a ``t_prompt``
+    buffer, and the tiles of the whole causal triangle at the width the
+    prefill runs it at (:func:`prefill_rows_computed`).  The kernel's own
+    count (``ops.flash_attention.forward_tiles``) at the shapes
+    :func:`_prefill_layer` hands it, for whoever counts its work; zeros
+    where the prompt's attention is not the kernel's."""
+    from cloud_tpu.ops.flash_attention import forward_tiles, takes_kernel
+
+    width = prefill_rows_computed(t_prompt, prompt_len, rules, mesh)
+    latent = config.latent
+    d, dv = ((config.head_dim,) * 2 if latent is None
+             else (latent.qk_dim, latent.v_dim))
+    dtype = jnp.dtype(config.dtype)
+    qk = jax.ShapeDtypeStruct((1, width, config.num_heads, d), dtype)
+    v = jax.ShapeDtypeStruct((1, width, config.num_heads, dv), dtype)
+    if not takes_kernel(qk, qk, v):
+        return 0, 0
+    sizes = dict(head_dim=d, value_dim=dv, itemsize=dtype.itemsize)
+    return (forward_tiles(width, min(prompt_len, width), **sizes),
+            forward_tiles(width, **sizes))
 
 
 def _shards_seq(rules, mesh) -> bool:

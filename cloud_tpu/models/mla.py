@@ -190,15 +190,14 @@ def _up_kernel(att, config):
                           cfg.nope_dim + cfg.v_dim)
 
 
-def expanded_attention(att, q_nope, q_pe, c, k_pe, mask, config, *, rules,
-                       mesh):
+def expanded_attention(att, q_nope, q_pe, c, k_pe, lengths, config, *,
+                       rules, mesh):
     """Causal attention over a prompt in the expanded form: per-head keys
     ``[k_nope | k_pe]`` and values made from ``c``, through the flash
-    kernel.  The kernel wants q, k and v of one head size and scales by
-    that size's root: the values ride zero-padded to ``qk_dim`` (the
-    weighted sum then costs ``qk_dim / v_dim`` of what it must: +20% of
-    the attention's operations at 192 / 128) and YaRN's factor goes into
-    the queries.  Returns [B, T, H, v_dim]."""
+    kernel, which takes the values at their own head size.  The kernel
+    scales by the root of the queries' size, so YaRN's factor goes into
+    the queries.  ``lengths`` [B] (None: every row whole) says where each
+    right-padded row ends.  Returns [B, T, H, v_dim]."""
     from cloud_tpu import ops
 
     cfg = config.latent
@@ -210,16 +209,14 @@ def expanded_attention(att, q_nope, q_pe, c, k_pe, mask, config, *, rules,
         jnp.broadcast_to(k_pe[:, :, None, :],
                          (b, t, config.num_heads, cfg.rope_dim)),
     ], axis=-1)
-    v = jnp.pad(up[..., cfg.nope_dim:],
-                ((0, 0),) * 3 + ((0, cfg.qk_dim - cfg.v_dim),))
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     q = layers.scaled(q, softmax_scale(cfg) * math.sqrt(cfg.qk_dim))
-    attended = ops.flash_attention(
-        q, k, v, causal=True, mask=mask, partitioned=mesh is not None,
-        mesh=mesh, batch_axes=rules.assignment("batch"),
+    return ops.flash_attention(
+        q, k, up[..., cfg.nope_dim:], causal=True, lengths=lengths,
+        partitioned=mesh is not None, mesh=mesh,
+        batch_axes=rules.assignment("batch"),
         head_axes=rules.assignment("heads"),
     )
-    return attended[..., :cfg.v_dim]
 
 
 def absorbed_queries(att, q_nope, q_pe, config):

@@ -121,7 +121,11 @@ with one scheduler behind a submit/future/admission surface:
   ``state_rows_in_use``.  ``insert_rows_bucket`` /
   ``insert_rows_computed`` sum, at every insert dispatch, the prompt
   buffer's rows and the rows the insert program computes for the
-  prompt in it (``generation.prefill_rows_computed``).
+  prompt in it (``generation.prefill_rows_computed``), and
+  ``flash_pairs_run`` / ``flash_pairs_width`` the compute tiles the
+  flash forward kernel runs for that prompt against the tiles of its
+  width's whole causal triangle (``generation.prefill_flash_tiles``;
+  zeros where the insert's attention is not the kernel's).
   A latent-attention model (``TransformerConfig.latent``) keeps ONE row
   a token a layer, key and value of every head at once: the
   ``kv_row_steps_*`` and ``kv_bytes_*`` count those rows (read: the
@@ -901,6 +905,11 @@ class ServingEngine:
             # program against the rows it computed for them (the
             # smallest of the buffer's widths that holds the prompt).
             "insert_rows_bucket": 0, "insert_rows_computed": 0,
+            # The flash forward kernel's compute tiles (a head a layer)
+            # for those prompts against the tiles of their widths'
+            # whole causal triangles; zeros where an insert's attention
+            # is not the kernel's.
+            "flash_pairs_run": 0, "flash_pairs_width": 0,
             # The same for a recurrent state's rows, one a slot a layer
             # (0 for a model without one); read: every reserved row, or
             # the decoding slots' where the state kernel advances them.
@@ -3109,10 +3118,17 @@ class ServingEngine:
 
     def _insert_request(self, request: _Request, slot: int) -> None:
         start = request.admitted = time.perf_counter()
+        from cloud_tpu.models import generation
+
         computed = self._insert_rows(request)
+        pairs_run, pairs_width = generation.prefill_flash_tiles(
+            self.config, request.bucket_len, request.prompt_len,
+            self.rules, self.mesh)
         with self._stats_lock:
             self._stats["insert_rows_bucket"] += request.bucket_len
             self._stats["insert_rows_computed"] += computed
+            self._stats["flash_pairs_run"] += pairs_run
+            self._stats["flash_pairs_width"] += pairs_width
         tracing.record_span(
             "serve/queue_wait", request.submitted, start,
             **_trace_attrs(request, bucket=request.bucket_len, slot=slot),

@@ -178,19 +178,24 @@ def test_group_norm_mesh_route_shards_the_batch(topo):
 @pytest.mark.parametrize("shape,causal,masked", [
     ((8, 1024, 12, 64), True, False),
     ((32, 512, 12, 64), False, True),
-    ((8, 1024, 12, 64), True, True),   # serving prefill: causal + prompt mask
-], ids=["causal-1024", "masked-512", "causal-masked-1024"])
+    ((8, 1024, 12, 64), True, True),   # causal + a generic key-side mask
+    ((8, 1024, 12, 64), True, "lengths"),  # serving prefill: the lengths
+], ids=["causal-1024", "masked-512", "causal-masked-1024",
+        "causal-lengths-1024"])
 def test_flash_fwd_and_grad(one_chip, shape, causal, masked):
     qkv = _spec(shape, jnp.bfloat16, one_chip)
-    mask = _spec(shape[:2], jnp.bool_, one_chip) if masked else None
+    rows = {True: ("mask", _spec(shape[:2], jnp.bool_, one_chip)),
+            "lengths": ("lengths", _spec(shape[:1], jnp.int32, one_chip)),
+            False: None}[masked]
 
-    def loss(q, k, v, mask=None):
+    def loss(q, k, v, *row):
         out = fa_mod.flash_attention(
-            q, k, v, causal=causal, mask=mask, use_pallas=True
+            q, k, v, causal=causal, use_pallas=True,
+            **({rows[0]: row[0]} if row else {})
         )
         return jnp.sum(out.astype(jnp.float32))
 
-    args = (qkv, qkv, qkv) + ((mask,) if masked else ())
+    args = (qkv, qkv, qkv) + ((rows[1],) if rows else ())
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), *args)
 
 
@@ -380,7 +385,8 @@ def test_insert_at_two_widths_is_one_program_that_copies_no_cache(
     two = compiled_at(2048)
     text = two.as_text()
     assert len(re.findall(r" conditional\(", text)) == 1
-    flash = re.findall(r"%flash_fwd[\w.]* = \(bf16\[1,16,(\d+),128\]", text)
+    # (The kernel's output has the sequence in the lanes: [B, H, Dv, T].)
+    flash = re.findall(r"%flash_fwd[\w.]* = \(bf16\[1,16,128,(\d+)\]", text)
     assert sorted(map(int, flash)) == [1536, 2048], flash
     _assert_cache_in_place(two, cache, in_place=("dynamic-update-slice",))
     one = compiled_at(1024).as_text()
@@ -591,16 +597,69 @@ def test_grouped_matmul(one_chip, rows, k, n):
 
 
 def test_flash_at_the_latent_models_head_size(one_chip):
-    """Query/key heads of 192 (128 + 64 rotated), the values zero-padded
-    to 192, 64 heads over a 2,048-row prompt under its padding mask."""
+    """The call as the cell makes it at its 4,096 width: query/key heads
+    of 192 (128 + 64 rotated), values at their own 128, 64 heads, the
+    row's length in place of a mask; value and gradient, so the backward
+    kernels take the value head's size too."""
     from cloud_tpu import ops
 
-    def fn(q, k, v, mask):
-        return ops.flash_attention(q, k, v, causal=True, mask=mask,
-                                   use_pallas=True)
+    def loss(q, k, v, lengths):
+        out = ops.flash_attention(q, k, v, causal=True, lengths=lengths,
+                                  use_pallas=True)
+        assert out.shape == (1, 4096, 64, 128)
+        return jnp.sum(out.astype(jnp.float32))
 
-    qkv = _spec((1, 2048, 64, 192), jnp.bfloat16, one_chip)
-    _compile(fn, qkv, qkv, qkv, _spec((1, 2048), jnp.int32, one_chip))
+    qk = _spec((1, 4096, 64, 192), jnp.bfloat16, one_chip)
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)), qk, qk,
+        _spec((1, 4096, 64, 128), jnp.bfloat16, one_chip),
+        _spec((1,), jnp.int32, one_chip))
+    # The kernel's output has the sequence in the lanes: [B, H, Dv, T].
+    assert re.search(r"%\w*flash_fwd[\w.]* = \(bf16\[1,64,128,4096\]",
+                     compiled.as_text())
+
+
+def test_latent_prefill_pads_no_values(one_chip, monkeypatch):
+    """The forward pass of the cell's insert at its 4,096 width (1 dense
+    + 6 expert layers at the published widths): the flash kernel is in
+    both stacks and its output has the values' own head size, 128 (the
+    kernel's output takes the size of the values it is handed: padded to
+    the keys' 192 they came back at 192 and were sliced); of its operands
+    only the queries and the keys have heads of 192.  (Queries, values
+    and output have the sequence in the lanes: [B, H, D, T].)"""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    monkeypatch.syspath_prepend(root)
+    from benchmarks.adapters import serve_latent_moe
+    from benchmarks.harness import manifest
+    from benchmarks.references import kimi_k2
+    from cloud_tpu.models import generation
+    from cloud_tpu.parallel.sharding import DEFAULT_RULES
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = manifest.Cell("kimi-k2-ep32-stage.agent-saturated", root=root)
+    config = serve_latent_moe.model_config(cell.config, cell.traffic)
+    params = _tree_spec(one_chip, kimi_k2.params_shape(cell.config))
+
+    def fn(params, tokens, lens):
+        return generation._prefill_forward(params, tokens, lens, config,
+                                           DEFAULT_RULES, None)
+
+    text = jax.jit(fn).lower(
+        params, _spec((1, 4096), jnp.int32, one_chip),
+        _spec((1,), jnp.int32, one_chip)).compile().as_text()
+    flash = re.findall(r"%flash_fwd[\w.]* = \((bf16\[[\d,]+\])", text)
+    assert len(flash) == 2 and set(flash) == {"bf16[1,64,128,4096]"}, flash
+    calls = [ln for ln in text.splitlines()
+             if re.match(r"\s*%flash_fwd[\w.]* = ", ln)]
+    for call in calls:
+        operands = re.findall(r"%[\w.\-]+", call.split("custom-call(")[1]
+                              .split(")")[0])
+        shapes = [re.search(re.escape(name) + r" = (\w+\[[\d,]*\])", text)
+                  .group(1) for name in operands]
+        assert sorted(s for s in shapes if s.startswith("bf16")) == [
+            "bf16[1,64,128,4096]", "bf16[1,64,192,4096]",
+            "bf16[1,64,4096,192]"], shapes
 
 
 def test_latent_expert_decode_chunk_copies_neither_cache_nor_experts(

@@ -110,6 +110,202 @@ class TestFlashAttentionForward:
             )
 
 
+def make_qkv_heads(t, d, dv, b=2, h=2, dtype=jnp.float32, seed=0):
+    """q and k with heads of ``d``, v with heads of ``dv``."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (
+        jax.random.normal(k1, (b, t, h, d), dtype),
+        jax.random.normal(k2, (b, t, h, d), dtype),
+        jax.random.normal(k3, (b, t, h, dv), dtype),
+    )
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Compute tiles of 16 rows, so that blocks of 32 hold two: the
+    small stand-in for 256-row tiles inside 512- and 1024-row blocks."""
+    import sys
+
+    monkeypatch.setattr(sys.modules["cloud_tpu.ops.flash_attention"],
+                        "TILE_Q_ROWS", 16)
+
+
+class TestFlashForwardSchedule:
+    """The forward kernel's schedule: a grid over the block pairs at or
+    under the diagonal, tiles skipped past the length, masks only on a
+    tile the diagonal crosses, a value head of its own size."""
+
+    # Stand-ins for 1536, 2560 and 4096 under 512-row blocks: 3, 5 and 8
+    # blocks of 32 rows, which are and are not a power of two of them.
+    @pytest.mark.parametrize("t", [96, 160, 256])
+    @pytest.mark.parametrize("d,dv", [(64, 64), (128, 128), (192, 128)])
+    @pytest.mark.parametrize("length", [None, 1, "edge", "edge+1", "t"])
+    def test_forward_against_reference(self, small_tiles, t, d, dv, length):
+        from cloud_tpu.ops.flash_attention import (
+            _reference_with_lse,
+            flash_attention_with_lse,
+        )
+
+        b = 2
+        q, k, v = make_qkv_heads(t, d, dv, b=b)
+        length = {None: None, 1: 1, "edge": 64, "edge+1": 65, "t": t}[length]
+        # Rows of one call may differ in length: the second row is whole.
+        lengths = None if length is None else jnp.array([length, t])
+        out, lse = flash_attention_with_lse(
+            q, k, v, causal=True, lengths=lengths, block_q=32, block_k=32,
+            interpret=True)
+        assert out.shape == (b, t, 2, dv)
+        assert np.isfinite(np.asarray(lse)).all()
+        # ``lengths`` on a causal call stands for the parent's ``mask=``.
+        mask = (None if lengths is None
+                else jnp.arange(t)[None, :] < lengths[:, None])
+        ref, ref_lse = _reference_with_lse(q, k, v, causal=True, mask=mask)
+        for row in range(b):
+            real = t if lengths is None else int(lengths[row])
+            np.testing.assert_allclose(out[row, :real], ref[row, :real],
+                                       atol=2e-5, rtol=1e-4)
+            np.testing.assert_allclose(lse[row, :, :real],
+                                       ref_lse[row, :, :real],
+                                       atol=2e-5, rtol=1e-4)
+            assert not np.asarray(out[row, real:]).any()
+
+    @pytest.mark.parametrize("block_q,block_k", [(64, 32), (32, 64),
+                                                 (64, 64), (48, 16),
+                                                 (96, 48), (64, 16)])
+    @pytest.mark.parametrize("fused", [1, 2, 4])
+    def test_uneven_blocks_with_lengths(self, small_tiles, monkeypatch,
+                                        block_q, block_k, fused):
+        """Blocks that do and do not start on the fused groups' edges,
+        tiles worked one, two and four at a time."""
+        import sys
+
+        monkeypatch.setattr(sys.modules["cloud_tpu.ops.flash_attention"],
+                            "TILES_FUSED", fused)
+        q, k, v = make_qkv_heads(192, 64, 32)
+        lengths = jnp.array([70, 129])
+        out = flash_attention(q, k, v, causal=True, lengths=lengths,
+                              block_q=block_q, block_k=block_k,
+                              interpret=True)
+        mask = jnp.arange(192)[None, :] < lengths[:, None]
+        ref = _reference(q, k, v, causal=True, mask=mask)
+        for row, real in enumerate([70, 129]):
+            np.testing.assert_allclose(out[row, :real], ref[row, :real],
+                                       atol=2e-5, rtol=1e-4)
+            assert not np.asarray(out[row, real:]).any()
+
+    def test_lengths_bfloat16(self, small_tiles):
+        q, k, v = make_qkv_heads(128, 192, 128, dtype=jnp.bfloat16)
+        lengths = jnp.array([50, 128])
+        out = flash_attention(q, k, v, causal=True, lengths=lengths,
+                              block_q=32, block_k=32, interpret=True)
+        mask = jnp.arange(128)[None, :] < lengths[:, None]
+        ref = _reference(q, k, v, causal=True, mask=mask)
+        for row, real in enumerate([50, 128]):
+            np.testing.assert_allclose(
+                np.asarray(out[row, :real], np.float32),
+                np.asarray(ref[row, :real], np.float32),
+                atol=3e-2, rtol=3e-2)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_lengths_on_the_reference_path_are_the_mask(self, causal):
+        q, k, v = make_qkv_heads(64, 32, 16)
+        lengths = jnp.array([40, 64])
+        mask = jnp.arange(64)[None, :] < lengths[:, None]
+        out = flash_attention(q, k, v, causal=causal, lengths=lengths)
+        ref = _reference(q, k, v, causal=causal, mask=mask)
+        np.testing.assert_allclose(out, ref, atol=1e-6)
+
+    def test_non_causal_lengths_are_a_key_side_mask_in_the_kernel(self):
+        q, k, v = make_qkv_heads(128, 64, 64)
+        lengths = jnp.array([96, 128])
+        mask = jnp.arange(128)[None, :] < lengths[:, None]
+        out = flash_attention(q, k, v, causal=False, lengths=lengths,
+                              interpret=True)
+        ref = _reference(q, k, v, causal=False, mask=mask)
+        np.testing.assert_allclose(out[0, :96], ref[0, :96], atol=5e-4,
+                                   rtol=1e-3)
+        np.testing.assert_allclose(out[1], ref[1], atol=5e-4, rtol=1e-3)
+
+    @pytest.mark.parametrize("with_lengths", [False, True])
+    def test_grads_with_a_value_head_of_its_own(self, small_tiles,
+                                                with_lengths):
+        """The backward kernels take the value head's size from ``v``
+        (``dp`` contracts over it, ``dv`` has it); with ``lengths`` the
+        rows past a length carry no gradient."""
+        t = 128
+        q, k, v = make_qkv_heads(t, 96, 64)
+        lengths = jnp.array([70, t]) if with_lengths else None
+        mask = (None if lengths is None
+                else jnp.arange(t)[None, :] < lengths[:, None])
+        real = 1.0 if mask is None else mask.astype(jnp.float32)[
+            :, :, None, None]
+
+        def loss_flash(q, k, v):
+            out = flash_attention(q, k, v, causal=True, lengths=lengths,
+                                  block_q=64, block_k=32, interpret=True)
+            return jnp.sum(out ** 2)  # the rows past a length are zeros
+
+        def loss_ref(q, k, v):
+            out = _reference(q, k, v, causal=True, mask=mask)
+            return jnp.sum((out * real) ** 2)
+
+        g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        assert g_flash[2].shape == v.shape
+        for name, a, b in zip("qkv", g_flash, g_ref):
+            assert np.isfinite(np.asarray(a)).all(), name
+            np.testing.assert_allclose(
+                a, b, atol=5e-4, rtol=1e-3,
+                err_msg=f"grad mismatch for {name}")
+
+    @pytest.mark.parametrize("t,block_q,block_k,tile", [
+        (96, 32, 32, 16), (160, 32, 32, 16), (256, 64, 32, 16),
+        (256, 32, 64, 32), (4096, 1024, 512, 256), (2560, 512, 512, 256),
+    ])
+    def test_tiles_counted_are_the_tiles_with_work(self, monkeypatch, t,
+                                                   block_q, block_k, tile):
+        """The count behind ``flash_pairs_run`` / ``flash_pairs_width``
+        against a walk over every (tile, key block): a tile runs where
+        some real row of it attends some real key of the block.  The
+        grid has one step a block pair at or under the diagonal, and at
+        the full length every tile of it with work is counted."""
+        import sys
+
+        fa = sys.modules["cloud_tpu.ops.flash_attention"]
+        monkeypatch.setattr(fa, "TILE_Q_ROWS", tile)
+        schedule = fa._schedule(t, 128, 128, 2, block_q, block_k)
+        assert schedule[:3] == (block_q, block_k, tile)
+
+        def walk(length):
+            return sum(
+                1
+                for row0 in range(0, t, tile)
+                for key0 in range(0, t, block_k)
+                if key0 <= min(row0 + tile, length) - 1 and row0 < length)
+
+        pairs_q, pairs_k = fa._grid_pairs(t, schedule, True)
+        assert len(pairs_q) == sum(
+            -(-(i + 1) * block_q // block_k) for i in range(t // block_q))
+        assert (np.diff(pairs_q) >= 0).all()  # by query block, keys rising
+        for length in (1, tile, tile + 1, t // 2, t - block_q + 1, t):
+            assert fa._tiles_run(t, schedule, length) == walk(length), length
+        assert fa._tiles_run(t, schedule, None) == walk(t)
+        # Every grid step holds at least one counted tile at full length.
+        assert fa._tiles_run(t, schedule, None) >= len(pairs_q)
+
+    def test_forward_tiles_follows_the_dispatchs_blocks(self):
+        import sys
+
+        fa = sys.modules["cloud_tpu.ops.flash_attention"]
+        for t, d, dv in [(4096, 192, 128), (2048, 128, 128), (1536, 128, 128)]:
+            schedule = fa._schedule(t, d, dv, 2)
+            assert fa.forward_tiles(t, head_dim=d, value_dim=dv) == \
+                fa._tiles_run(t, schedule, None)
+            assert (fa.forward_tiles(t, t - 300, head_dim=d, value_dim=dv)
+                    < fa.forward_tiles(t, head_dim=d, value_dim=dv))
+        assert fa.forward_tiles(100, head_dim=64) == 0  # no block fits
+
+
 class TestFlashAttentionWithLse:
     """The (out, lse) entry point ring attention folds through: both
     outputs must match the reference AND be differentiable — g_lse flows

@@ -131,6 +131,47 @@ def test_insert_rows_count_the_bucket_against_the_width_that_ran(
         np.testing.assert_array_equal(got.tokens, ref.tokens)
 
 
+def test_flash_pairs_count_the_tiles_run_against_the_widths_triangle(
+        model, monkeypatch):
+    """Where the insert's attention is the flash kernel's (here: the
+    armed interpreter), every insert adds the compute tiles the kernel
+    runs for the prompt and the tiles of its width's whole causal
+    triangle.  Buckets of 16 and 32 in tiles of 8 (widths 16; 24 and
+    32), tiles of 8 query rows against key blocks of 8: a prompt of L
+    tokens runs n (n + 1) / 2 tiles with n = ceil(L / 8), its width of W
+    rows holds m (m + 1) / 2 with m = W / 8.  Prompts of 3, 20, 9, 30,
+    17 and 12 tokens: 1 + 6 + 3 + 10 + 6 + 3 of 3 + 6 + 3 + 10 + 6 + 3.
+    Off the kernel (the default run) both stay zero."""
+    import sys
+
+    from cloud_tpu.models import generation
+
+    fa = sys.modules["cloud_tpu.ops.flash_attention"]
+    lengths = [3, 20, 9, 30, 17, 12]
+    prompts = [list(range(1, n + 1)) for n in lengths]
+    budgets = [2, 3, 2, 3, 2, 3]
+    monkeypatch.setattr(generation, "PREFILL_TILE_ROWS", 8)
+    _, want, plain = _serve(model, prompts, budgets,
+                            prompt_buckets=(16, 32))
+    assert plain["flash_pairs_run"] == plain["flash_pairs_width"] == 0
+    assert plain["insert_rows_computed"] == 16 + 24 + 16 + 32 + 24 + 16
+
+    monkeypatch.setenv("CLOUD_TPU_FLASH_FORCE_INTERPRET", "1")
+    monkeypatch.setattr(fa, "MAX_BLOCK_Q", 16)
+    monkeypatch.setattr(fa, "MAX_BLOCK_K", 8)
+    monkeypatch.setattr(fa, "TILE_Q_ROWS", 8)
+    monkeypatch.setattr(fa, "LANES", 8)  # the interpreter has no lanes
+    traced = fa.KERNEL_TRACE_COUNT
+    _, results, stats = _serve(model, prompts, budgets,
+                               prompt_buckets=(16, 32))
+    assert fa.KERNEL_TRACE_COUNT > traced
+    assert stats["flash_pairs_run"] == 1 + 6 + 3 + 10 + 6 + 3
+    assert stats["flash_pairs_width"] == 3 + 6 + 3 + 10 + 6 + 3
+    assert stats["insert_rows_computed"] == plain["insert_rows_computed"]
+    for got, ref in zip(results, want):
+        np.testing.assert_array_equal(got.tokens, ref.tokens)
+
+
 def test_every_request_closes_under_one_id(served):
     events, results, _ = served
     ids = [r.trace_id for r in results]

@@ -243,7 +243,9 @@ class TestReport:
                 with tracing.span("build"):
                     time.sleep(0.002)
             with tracing.span("deploy"):
-                time.sleep(0.01)
+                # Well over the three builds even where a loaded machine
+                # oversleeps each of them by milliseconds.
+                time.sleep(0.05)
             return tracing.dump_timeline(str(tmp_path / "t.json"))
 
     def test_rows_aggregate_per_name(self, tmp_path):
@@ -252,8 +254,8 @@ class TestReport:
         rows = {r["name"]: r for r in report.rows()}
         assert rows["build"]["count"] == 3
         assert rows["deploy"]["count"] == 1
-        assert rows["deploy"]["total_s"] >= 0.01
-        # deploy (10ms) outweighs build (3x2ms): sorted first.
+        assert rows["deploy"]["total_s"] >= 0.05
+        # deploy (50ms) outweighs build (3x2ms): sorted first.
         assert report.rows()[0]["name"] == "deploy"
         assert 0 < rows["deploy"]["pct_wall"] <= 100.0
 
