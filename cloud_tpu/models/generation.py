@@ -113,6 +113,11 @@ def sample_logits(rng, logits, sample: SampleConfig, *, seen=None,
 #: The cache leaves that hold a recurrent state, not K/V rows.
 STATE_LEAVES = ("ssm", "conv")
 
+#: The program scope (``layers.SCOPES``) a state leaf's reads and writes in
+#: the layer loop go by: the convolution's tail with the projections
+#: around it, the recurrent state with its step.
+_STATE_SCOPES = {"ssm": "ssm_state", "conv": "ssm_proj"}
+
 #: The cache leaf of a latent-attention model (``TransformerConfig.latent``):
 #: one row a token a layer, ``[c | k_pe | 0]`` [L, B, S, row_width], key
 #: and value of every head at once (models/mla.py).
@@ -260,6 +265,13 @@ def _mlp(layer_params, y, config, rules, live=None, held=None, layer=None):
         out, _ = moe_lib.moe_mlp_apply(layer_params["mlp"], y, config.moe)
         return out, None
     return transformer.mlp_apply(layer_params["mlp"], y, config, rules), None
+
+
+def _mlp_scope(config):
+    """The program scope of what stands around a block's MLP (the
+    residual adds, the norm before it): the scope of the norm's first
+    reader, so that a fusion of them goes by a neighbour's name."""
+    return "mlp" if config.moe is None else "moe_route"
 
 
 def _stacks(params, config):
@@ -415,15 +427,18 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
 
     def latent_attend(att, y, cache, l):
         q_nope, q_pe, c, k_pe = mla_lib.project(att, y, positions, config)
-        cache = dict(cache, **{LATENT_LEAF: cache[LATENT_LEAF].at[
-            l, rows, write_cols].set(
-                mla_lib.cache_rows(c, k_pe, config.latent, config.dtype),
-                mode="drop")})
-        o_lat = latent_attention.latent_decode_attention(
-            mla_lib.absorbed_queries(att, q_nope[:, 0], q_pe[:, 0], config),
-            cache[LATENT_LEAF], jnp.where(live, attend_len, 0),
-            value_dim=config.latent.kv_rank,
-            scale=mla_lib.softmax_scale(config.latent), layer=l)
+        with layers.scope("cache_write"):
+            cache = dict(cache, **{LATENT_LEAF: cache[LATENT_LEAF].at[
+                l, rows, write_cols].set(
+                    mla_lib.cache_rows(c, k_pe, config.latent, config.dtype),
+                    mode="drop")})
+        queries = mla_lib.absorbed_queries(att, q_nope[:, 0], q_pe[:, 0],
+                                           config)
+        with layers.scope("attn_read"):
+            o_lat = latent_attention.latent_decode_attention(
+                queries, cache[LATENT_LEAF], jnp.where(live, attend_len, 0),
+                value_dim=config.latent.kv_rank,
+                scale=mla_lib.softmax_scale(config.latent), layer=l)
         attended = mla_lib.absorbed_values(att, o_lat, config)
         return mla_lib.attention_out(att, attended[:, None], config), cache
 
@@ -431,36 +446,40 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
         q, k_new, v_new = transformer.qkv_project(
             layer_params["att"], y, positions, config
         )
-        updates = _kv_leaf_updates(k_new, v_new, config, quantized)
-        cache = dict(cache, **{
-            name: cache[name].at[l, rows, write_cols].set(update,
-                                                          mode="drop")
-            for name, update in updates.items()
-        })
+        with layers.scope("cache_write"):
+            updates = _kv_leaf_updates(k_new, v_new, config, quantized)
+            cache = dict(cache, **{
+                name: cache[name].at[l, rows, write_cols].set(update,
+                                                              mode="drop")
+                for name, update in updates.items()
+            })
         kv = {name: cache[name] for name in updates}
         pool_l = layer_slice[2] if pool is not None else None
         take_kernel = (paged_attention.would_use_kernel(q, kv)
                        if use_pallas is None else use_pallas)
-        if in_place and (block_table is not None or take_kernel):
-            attended = _paged_attended(
-                kind, q, kv, jnp.where(live, attend_len, 0),
-                dict(paged, layer=l, pool_l=pool_l))
-        else:
-            cache_l = {name: layer_of(leaf, l) for name, leaf in kv.items()}
-            attended = (
-                _cache_attention(q, cache_l, attend_len,
-                                 chunk_causal=chunked)
-                if block_table is None else
-                _paged_attended(kind, q, cache_l, attend_len,
-                                dict(paged, pool_l=pool_l)))
+        with layers.scope("attn_read"):
+            if in_place and (block_table is not None or take_kernel):
+                attended = _paged_attended(
+                    kind, q, kv, jnp.where(live, attend_len, 0),
+                    dict(paged, layer=l, pool_l=pool_l))
+            else:
+                cache_l = {name: layer_of(leaf, l)
+                           for name, leaf in kv.items()}
+                attended = (
+                    _cache_attention(q, cache_l, attend_len,
+                                     chunk_causal=chunked)
+                    if block_table is None else
+                    _paged_attended(kind, q, cache_l, attend_len,
+                                    dict(paged, pool_l=pool_l)))
         return transformer.attention_out(layer_params["att"], attended,
                                          config), cache
 
     def layer_body(stack_config, held, first, carry, layer_slice):
         x, cache = carry
         layer_params, l = layer_slice[:2]
-        y = layers.rmsnorm_apply(layer_params["ln1"], x,
-                                 eps=config.norm_eps)
+        with layers.scope("attn_proj"):
+            y = layers.rmsnorm_apply(layer_params["ln1"], x,
+                                     eps=config.norm_eps)
         if config.latent is not None:
             mixed, cache = latent_attend(layer_params["att"], y, cache, l)
         else:
@@ -468,33 +487,39 @@ def _scan_layers(params, cache, x, positions, write_cols, config, rules,
         if config.ssm is not None:
             mixer = (layer_params["ssm"], y[:, 0])
             sizes = (config.ssm, config.multipliers, config.norm_eps)
-            held = {"conv": jax.lax.dynamic_index_in_dim(
-                cache["conv"], l, keepdims=False)}
+            with layers.scope(_STATE_SCOPES["conv"]):
+                held = {"conv": jax.lax.dynamic_index_in_dim(
+                    cache["conv"], l, keepdims=False)}
             if state_in_place:
                 ssm_out, cache["ssm"], tail = ssm_lib.ssm_step_in_place(
                     *mixer, cache["ssm"], l, live, held["conv"], *sizes)
                 fresh = {"conv": tail}
             else:
-                held["ssm"] = jax.lax.dynamic_index_in_dim(
-                    cache["ssm"], l, keepdims=False)
+                with layers.scope(_STATE_SCOPES["ssm"]):
+                    held["ssm"] = jax.lax.dynamic_index_in_dim(
+                        cache["ssm"], l, keepdims=False)
                 ssm_out, state, tail = ssm_lib.ssm_step(
                     *mixer, held["ssm"], held["conv"], *sizes)
                 fresh = {"ssm": state, "conv": tail}
             for name, new in fresh.items():
-                keep = live.reshape((b,) + (1,) * (new.ndim - 1))
-                new = jnp.where(keep, new.astype(held[name].dtype),
-                                held[name])
-                cache[name] = jax.lax.dynamic_update_index_in_dim(
-                    cache[name], new, l, axis=0)
-            mixed = mixed + ssm_out[:, None]
-        x = x + mixed
-        y = layers.rmsnorm_apply(layer_params["ln2"], x,
-                                 eps=config.norm_eps)
+                with layers.scope(_STATE_SCOPES[name]):
+                    keep = live.reshape((b,) + (1,) * (new.ndim - 1))
+                    new = jnp.where(keep, new.astype(held[name].dtype),
+                                    held[name])
+                    cache[name] = jax.lax.dynamic_update_index_in_dim(
+                        cache[name], new, l, axis=0)
+            with layers.scope(_STATE_SCOPES["conv"]):
+                mixed = mixed + ssm_out[:, None]
+        with layers.scope(_mlp_scope(stack_config)):
+            x = x + mixed
+            y = layers.rmsnorm_apply(layer_params["ln2"], x,
+                                     eps=config.norm_eps)
         mlp_out, counted = _mlp(
             layer_params, y, stack_config, rules,
             live=live[:, None] if kind == "decode" else None, held=held,
             layer=l - first)
-        x = x + mlp_out
+        with layers.scope(_mlp_scope(stack_config)):
+            x = x + mlp_out
         if chunked:
             x = shard_constraint(x, "batch", "seq", "act_embed",
                                  rules=rules, mesh=mesh)
@@ -534,7 +559,9 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
     left out."""
     from cloud_tpu import ops
 
-    y = layers.rmsnorm_apply(layer_params["ln1"], x, eps=config.norm_eps)
+    with layers.scope("attn_proj"):
+        y = layers.rmsnorm_apply(layer_params["ln1"], x,
+                                 eps=config.norm_eps)
     if config.latent is not None:
         att = layer_params["att"]
         q_nope, q_pe, c, k_pe = mla_lib.project(att, y, positions, config)
@@ -542,17 +569,19 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
             att, q_nope, q_pe, c, k_pe, prompt_lens, config, rules=rules,
             mesh=mesh)
         mixed = mla_lib.attention_out(att, attended, config)
-        left = {LATENT_LEAF: mla_lib.cache_rows(c, k_pe, config.latent,
-                                                config.dtype)}
+        with layers.scope("cache_write"):
+            left = {LATENT_LEAF: mla_lib.cache_rows(c, k_pe, config.latent,
+                                                    config.dtype)}
     else:
         q, k, v = transformer.qkv_project(layer_params["att"], y, positions,
                                           config)
-        attended = ops.flash_attention(
-            q, *transformer.repeat_kv(k, v, config), causal=True,
-            lengths=prompt_lens, partitioned=mesh is not None, mesh=mesh,
-            batch_axes=rules.assignment("batch"),
-            head_axes=rules.assignment("heads"),
-        )
+        with layers.scope("attn_read"):
+            attended = ops.flash_attention(
+                q, *transformer.repeat_kv(k, v, config), causal=True,
+                lengths=prompt_lens, partitioned=mesh is not None,
+                mesh=mesh, batch_axes=rules.assignment("batch"),
+                head_axes=rules.assignment("heads"),
+            )
         mixed = transformer.attention_out(layer_params["att"], attended,
                                           config)
         left = {"k": k, "v": v}
@@ -562,18 +591,22 @@ def _prefill_layer(layer_params, x, positions, prompt_mask, prompt_lens,
             config.multipliers, config.norm_eps,
         )
         mixed = mixed + ssm_out
-    x = x + mixed
-    y = layers.rmsnorm_apply(layer_params["ln2"], x, eps=config.norm_eps)
+    with layers.scope(_mlp_scope(config)):
+        x = x + mixed
+        y = layers.rmsnorm_apply(layer_params["ln2"], x,
+                                 eps=config.norm_eps)
     mlp_out, counted = _mlp(layer_params, y, config, rules,
                             live=prompt_mask, held=held, layer=layer)
-    x = x + mlp_out
+    with layers.scope(_mlp_scope(config)):
+        x = x + mlp_out
     x = shard_constraint(x, "batch", "seq", "act_embed", rules=rules,
                          mesh=mesh)
     return x, (left, counted)
 
 
 def _final_logits(params, x, config):
-    x = layers.rmsnorm_apply(params["ln_f"], x, eps=config.norm_eps)
+    with layers.scope("head"):
+        x = layers.rmsnorm_apply(params["ln_f"], x, eps=config.norm_eps)
     return transformer.lm_logits(params, x, config)
 
 
@@ -613,12 +646,14 @@ def _prefill_forward(params, prompt_tokens, prompt_lens, config, rules,
             functools.partial(prefill_body, stack_config, held), x, xs)
         lefts.append(left)
         counted.append(per_layer)
-    left = lefts[0] if len(lefts) == 1 else jax.tree_util.tree_map(
-        lambda *parts: jnp.concatenate(parts, axis=0), *lefts)
-    last_idx = (prompt_lens - 1)[:, None, None]
-    last_x = jnp.take_along_axis(
-        x, jnp.broadcast_to(last_idx, (b, 1, x.shape[-1])), axis=1
-    )
+    with layers.scope("cache_write"):
+        left = lefts[0] if len(lefts) == 1 else jax.tree_util.tree_map(
+            lambda *parts: jnp.concatenate(parts, axis=0), *lefts)
+    with layers.scope("head"):
+        last_idx = (prompt_lens - 1)[:, None, None]
+        last_x = jnp.take_along_axis(
+            x, jnp.broadcast_to(last_idx, (b, 1, x.shape[-1])), axis=1
+        )
     logits0 = _final_logits(params, last_x, config)[:, 0]
     # Sampling boundary: the one place the sharded generation path
     # resharding happens.  Under a tp mesh lm_logits comes back
@@ -654,18 +689,19 @@ def _write_prefill(cache, left, start, config):
     prefill starts at position 0, so the same index, cut to the leaf's
     rank, addresses a state leaf's layer and row, and a latent leaf's
     layer, row and position)."""
-    if LATENT_LEAF in cache:
-        updates = {LATENT_LEAF: left[LATENT_LEAF].astype(
-            cache[LATENT_LEAF].dtype)}
-    else:
-        updates = _kv_leaf_updates(left["k"], left["v"], config,
-                                   "k_scale" in cache)
-    for name in STATE_LEAVES:
-        if name in cache:
-            updates[name] = left[name].astype(cache[name].dtype)
-    for name, val in updates.items():
-        cache[name] = jax.lax.dynamic_update_slice(
-            cache[name], val, start[:val.ndim])
+    with layers.scope("cache_write"):
+        if LATENT_LEAF in cache:
+            updates = {LATENT_LEAF: left[LATENT_LEAF].astype(
+                cache[LATENT_LEAF].dtype)}
+        else:
+            updates = _kv_leaf_updates(left["k"], left["v"], config,
+                                       "k_scale" in cache)
+        for name in STATE_LEAVES:
+            if name in cache:
+                updates[name] = left[name].astype(cache[name].dtype)
+        for name, val in updates.items():
+            cache[name] = jax.lax.dynamic_update_slice(
+                cache[name], val, start[:val.ndim])
     return cache
 
 
@@ -1109,24 +1145,26 @@ def _arm_slot(state, logits0, prompt_len, slot, max_new_tokens, config, *,
     prefill).  Returns ``(state, tok0)``."""
     rng = jax.random.PRNGKey(0) if rng is None else rng
     need_min = sample.eos_id is not None and sample.min_new_tokens > 0
-    allow0 = jnp.full((1,), False) if need_min else None
-    tok0 = sample_logits(
-        rng, logits0, sample, allow_eos=allow0
-    ).astype(jnp.int32)[0]
+    with layers.scope("head"):
+        allow0 = jnp.full((1,), False) if need_min else None
+        tok0 = sample_logits(
+            rng, logits0, sample, allow_eos=allow0
+        ).astype(jnp.int32)[0]
 
-    max_new_tokens = jnp.asarray(max_new_tokens, jnp.int32)
-    active0 = max_new_tokens > 1
-    if sample.eos_id is not None:
-        active0 = active0 & (tok0 != sample.eos_id)
-    state = dict(state)
-    state["pos"] = state["pos"].at[slot].set(prompt_len)
-    state["tok"] = state["tok"].at[slot].set(tok0)
-    state["remaining"] = state["remaining"].at[slot].set(max_new_tokens - 1)
-    state["emitted"] = state["emitted"].at[slot].set(1)
-    state["active"] = state["active"].at[slot].set(active0)
-    if "seen" in state:
-        row = jnp.zeros((config.vocab_size,), bool).at[tok0].set(True)
-        state["seen"] = state["seen"].at[slot].set(row)
+        max_new_tokens = jnp.asarray(max_new_tokens, jnp.int32)
+        active0 = max_new_tokens > 1
+        if sample.eos_id is not None:
+            active0 = active0 & (tok0 != sample.eos_id)
+        state = dict(state)
+        state["pos"] = state["pos"].at[slot].set(prompt_len)
+        state["tok"] = state["tok"].at[slot].set(tok0)
+        state["remaining"] = state["remaining"].at[slot].set(
+            max_new_tokens - 1)
+        state["emitted"] = state["emitted"].at[slot].set(1)
+        state["active"] = state["active"].at[slot].set(active0)
+        if "seen" in state:
+            row = jnp.zeros((config.vocab_size,), bool).at[tok0].set(True)
+            state["seen"] = state["seen"].at[slot].set(row)
     return state, tok0
 
 
@@ -1208,47 +1246,50 @@ def decode_chunk_program(
         # grid decodes around it.  (Pre-chunked-prefill the write was
         # merely stale-but-harmless; now it would corrupt.)
         s = _rows_leaf(cache).shape[2]
-        write_pos = jnp.where(active, state["pos"], jnp.int32(s))
+        with layers.scope("head"):
+            write_pos = jnp.where(active, state["pos"], jnp.int32(s))
         cache, logits, *routing = _decode_step(
             params, cache, state["tok"], state["pos"], config, rules, mesh,
             write_pos=write_pos, pool=pool, block_table=block_table,
             use_pallas=use_pallas, with_routing=with_routing,
         )
-        allow = (
-            state["emitted"] >= sample.min_new_tokens if need_min else None
-        )
-        tok = sample_logits(
-            step_rng, logits, sample,
-            seen=state["seen"] if track_seen else None, allow_eos=allow,
-        ).astype(jnp.int32)
-        tok = jnp.where(active, tok, jnp.int32(sample.pad_id))
-        stride = active.astype(jnp.int32)
-        new_state = dict(state)
-        new_state["pos"] = state["pos"] + stride
-        new_state["remaining"] = state["remaining"] - stride
-        new_state["emitted"] = state["emitted"] + stride
-        finished = new_state["remaining"] <= 0
-        if sample.eos_id is not None:
-            finished = finished | (tok == sample.eos_id)
-        new_state["active"] = active & ~finished
-        new_state["tok"] = jnp.where(active, tok, state["tok"])
-        if track_seen:
-            # Unconditional like _decode_tokens: inactive rows set the
-            # pad bit in a row the next insert resets anyway.
-            new_state["seen"] = state["seen"].at[rows, tok].set(True)
+        with layers.scope("head"):
+            allow = (
+                state["emitted"] >= sample.min_new_tokens if need_min else None
+            )
+            tok = sample_logits(
+                step_rng, logits, sample,
+                seen=state["seen"] if track_seen else None, allow_eos=allow,
+            ).astype(jnp.int32)
+            tok = jnp.where(active, tok, jnp.int32(sample.pad_id))
+            stride = active.astype(jnp.int32)
+            new_state = dict(state)
+            new_state["pos"] = state["pos"] + stride
+            new_state["remaining"] = state["remaining"] - stride
+            new_state["emitted"] = state["emitted"] + stride
+            finished = new_state["remaining"] <= 0
+            if sample.eos_id is not None:
+                finished = finished | (tok == sample.eos_id)
+            new_state["active"] = active & ~finished
+            new_state["tok"] = jnp.where(active, tok, state["tok"])
+            if track_seen:
+                # Unconditional like _decode_tokens: inactive rows set the
+                # pad bit in a row the next insert resets anyway.
+                new_state["seen"] = state["seen"].at[rows, tok].set(True)
         return (cache, new_state), (tok, active, *routing)
 
     (cache, state), (toks, valid, *routing) = jax.lax.scan(
         step, (cache, state), jax.random.split(rng, chunk_size)
     )
-    routing = [counted.sum(0) for counted in routing]
-    if with_summary:
-        summary = jnp.stack([
-            valid.sum().astype(jnp.int32),
-            state["active"].sum().astype(jnp.int32),
-        ])
-        return (cache, state, toks.T, valid.T, summary, *routing)
-    return (cache, state, toks.T, valid.T, *routing)
+    with layers.scope("head"):
+        routing = [counted.sum(0) for counted in routing]
+        if with_summary:
+            summary = jnp.stack([
+                valid.sum().astype(jnp.int32),
+                state["active"].sum().astype(jnp.int32),
+            ])
+            return (cache, state, toks.T, valid.T, summary, *routing)
+        return (cache, state, toks.T, valid.T, *routing)
 
 
 # --------------------------------------------------------------------------

@@ -54,6 +54,36 @@ def remat_wrap(body, enabled: bool = True, policy: str = "full"):
     )
 
 
+#: The program scopes: the model's own sublayers, by which a device trace
+#: names its time (docs/observability.md, "Program scopes").  One closed,
+#: flat list.  Norms and residual adds have none of their own: XLA fuses
+#: them into a neighbour, and a fusion goes by its root.
+SCOPES = (
+    "embed",        # the token table's rows
+    "attn_proj",    # q/k/v or the latent projections, with their rotation
+    "cache_write",  # the new rows' scatter; a prefill's write into a slot
+    "attn_read",    # the paged / latent / flash kernel, or XLA's read
+    "attn_out",     # attention's output projection
+    "ssm_proj",     # the mixer's in- and out-projection, its convolution
+    "ssm_state",    # the state step; a prefill's chunked scan
+    "mlp",          # the dense SwiGLU, and the shared expert
+    "moe_route",    # router, top-k, sort, gather
+    "moe_experts",  # the grouped products
+    "moe_combine",  # weighting, scatter-add
+    "head",         # final norm, vocabulary product, sampling, slot state
+)
+
+
+def scope(name: str):
+    """The program scope ``name`` (one of :data:`SCOPES`) over the
+    operations traced inside it: the device-side twin of a span.  Metadata
+    alone (``jax.named_scope``): the operations, and the cache's key for
+    them, are what they were without it."""
+    if name not in SCOPES:
+        raise ValueError(f"no program scope {name!r}; there are {SCOPES}")
+    return jax.named_scope(name)
+
+
 def scaled(x, multiplier: float):
     """``x`` times a fixed scalar of the configuration (a muP multiplier);
     a multiplier of exactly 1 adds no operation, so a model that has none
@@ -491,7 +521,8 @@ def mlp_block_apply(params, x, *, rules: ShardingRules = DEFAULT_RULES,
     (multiplied by ``gate_multiplier`` before the silu), ``wg`` the up
     projection, ``wo`` the down projection (its product multiplied by
     ``down_multiplier``)."""
-    gate = scaled(dense_apply(params["wi"], x), gate_multiplier)
-    h = jax.nn.silu(gate) * dense_apply(params["wg"], x)
-    h = shard_constraint(h, "batch", "seq", "mlp", rules=rules)
-    return scaled(dense_apply(params["wo"], h), down_multiplier)
+    with scope("mlp"):
+        gate = scaled(dense_apply(params["wi"], x), gate_multiplier)
+        h = jax.nn.silu(gate) * dense_apply(params["wg"], x)
+        h = shard_constraint(h, "batch", "seq", "mlp", rules=rules)
+        return scaled(dense_apply(params["wo"], h), down_multiplier)

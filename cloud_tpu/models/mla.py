@@ -160,17 +160,18 @@ def project(att, u, positions, config):
     [B, T, kv_rank] (normed) and ``k_pe`` [B, T, rope] (rotated)."""
     cfg, eps = config.latent, config.norm_eps
     b, t, _ = u.shape
-    cq = layers.rmsnorm_apply(att["q_norm"],
-                              layers.dense_apply(att["q_a"], u), eps=eps)
-    q = layers.dense_apply(att["q_b"], cq).reshape(
-        b, t, config.num_heads, cfg.qk_dim)
-    q_nope, q_pe = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
-    down = layers.dense_apply(att["kv_a"], u)
-    c = layers.rmsnorm_apply(att["kv_norm"], down[..., :cfg.kv_rank],
-                             eps=eps)
-    k_pe = down[..., cfg.kv_rank:]
-    return (q_nope, rotate(q_pe, positions, cfg, config.rope_base),
-            c, rotate(k_pe, positions, cfg, config.rope_base))
+    with layers.scope("attn_proj"):
+        cq = layers.rmsnorm_apply(att["q_norm"],
+                                  layers.dense_apply(att["q_a"], u), eps=eps)
+        q = layers.dense_apply(att["q_b"], cq).reshape(
+            b, t, config.num_heads, cfg.qk_dim)
+        q_nope, q_pe = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
+        down = layers.dense_apply(att["kv_a"], u)
+        c = layers.rmsnorm_apply(att["kv_norm"], down[..., :cfg.kv_rank],
+                                 eps=eps)
+        k_pe = down[..., cfg.kv_rank:]
+        return (q_nope, rotate(q_pe, positions, cfg, config.rope_base),
+                c, rotate(k_pe, positions, cfg, config.rope_base))
 
 
 def cache_rows(c, k_pe, cfg: LatentConfig, dtype):
@@ -202,41 +203,47 @@ def expanded_attention(att, q_nope, q_pe, c, k_pe, lengths, config, *,
 
     cfg = config.latent
     b, t = c.shape[:2]
-    up = layers.dense_apply(att["kv_b"], c).reshape(
-        b, t, config.num_heads, cfg.nope_dim + cfg.v_dim)
-    k = jnp.concatenate([
-        up[..., :cfg.nope_dim],
-        jnp.broadcast_to(k_pe[:, :, None, :],
-                         (b, t, config.num_heads, cfg.rope_dim)),
-    ], axis=-1)
-    q = jnp.concatenate([q_nope, q_pe], axis=-1)
-    q = layers.scaled(q, softmax_scale(cfg) * math.sqrt(cfg.qk_dim))
-    return ops.flash_attention(
-        q, k, up[..., cfg.nope_dim:], causal=True, lengths=lengths,
-        partitioned=mesh is not None, mesh=mesh,
-        batch_axes=rules.assignment("batch"),
-        head_axes=rules.assignment("heads"),
-    )
+    with layers.scope("attn_proj"):
+        up = layers.dense_apply(att["kv_b"], c).reshape(
+            b, t, config.num_heads, cfg.nope_dim + cfg.v_dim)
+        k = jnp.concatenate([
+            up[..., :cfg.nope_dim],
+            jnp.broadcast_to(k_pe[:, :, None, :],
+                             (b, t, config.num_heads, cfg.rope_dim)),
+        ], axis=-1)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        q = layers.scaled(q, softmax_scale(cfg) * math.sqrt(cfg.qk_dim))
+        v = up[..., cfg.nope_dim:]
+    with layers.scope("attn_read"):
+        return ops.flash_attention(
+            q, k, v, causal=True, lengths=lengths,
+            partitioned=mesh is not None, mesh=mesh,
+            batch_axes=rules.assignment("batch"),
+            head_axes=rules.assignment("heads"),
+        )
 
 
 def absorbed_queries(att, q_nope, q_pe, config):
     """One token's queries against a cache row as it is stored:
     ``[Wuk^T q_nope | q_pe | 0]`` [B, H, row_width]."""
     cfg = config.latent
-    w_uk = _up_kernel(att, config)[..., :cfg.nope_dim]
-    q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w_uk)
-    return cache_rows(q_lat, q_pe, cfg, q_nope.dtype)
+    with layers.scope("attn_proj"):
+        w_uk = _up_kernel(att, config)[..., :cfg.nope_dim]
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w_uk)
+        return cache_rows(q_lat, q_pe, cfg, q_nope.dtype)
 
 
 def absorbed_values(att, o_lat, config):
     """The weighted sum of the rows' latent part ``o_lat`` [B, H, kv_rank]
     through ``Wuv``: [B, H, v_dim]."""
     cfg = config.latent
-    w_uv = _up_kernel(att, config)[..., cfg.nope_dim:]
-    return jnp.einsum("bhc,chv->bhv", o_lat.astype(w_uv.dtype), w_uv)
+    with layers.scope("attn_out"):
+        w_uv = _up_kernel(att, config)[..., cfg.nope_dim:]
+        return jnp.einsum("bhc,chv->bhv", o_lat.astype(w_uv.dtype), w_uv)
 
 
 def attention_out(att, attended, config):
     """The output projection on ``attended`` [B, T, H, v_dim]."""
     b, t = attended.shape[:2]
-    return layers.dense_apply(att["out"], attended.reshape(b, t, -1))
+    with layers.scope("attn_out"):
+        return layers.dense_apply(att["out"], attended.reshape(b, t, -1))

@@ -143,53 +143,62 @@ def moe_mlp_apply(
     e = cfg.num_experts
     c = _capacity(t, cfg)
 
-    router_logits = layers.dense_apply(params["router"], x, dtype=jnp.float32)
-    gates = jax.nn.softmax(router_logits, axis=-1)  # [B, T, E]
+    with layers.scope("moe_route"):
+        router_logits = layers.dense_apply(params["router"], x,
+                                           dtype=jnp.float32)
+        gates = jax.nn.softmax(router_logits, axis=-1)  # [B, T, E]
 
-    # Top-k expert choice per token, gates renormalized over the chosen k.
-    top_gates, top_idx = jax.lax.top_k(gates, cfg.top_k)  # [B, T, K]
-    top_gates = top_gates / jnp.clip(
-        jnp.sum(top_gates, axis=-1, keepdims=True), 1e-9
-    )
+        # Top-k expert choice per token, gates renormalized over the
+        # chosen k.
+        top_gates, top_idx = jax.lax.top_k(gates, cfg.top_k)  # [B, T, K]
+        top_gates = top_gates / jnp.clip(
+            jnp.sum(top_gates, axis=-1, keepdims=True), 1e-9
+        )
 
-    # Position of each (token, choice) in its expert's buffer, via cumsum
-    # over the flattened (T*K) routing sequence per batch row.
-    choice_mask = jax.nn.one_hot(top_idx, e, dtype=jnp.float32)  # [B, T, K, E]
-    flat_mask = choice_mask.reshape(b, t * cfg.top_k, e)
-    pos_in_expert = (
-        jnp.cumsum(flat_mask, axis=1) - flat_mask
-    ).reshape(b, t, cfg.top_k, e)
-    within_capacity = pos_in_expert < c
-    keep = choice_mask * within_capacity
+        # Position of each (token, choice) in its expert's buffer, via
+        # cumsum over the flattened (T*K) routing sequence per batch row.
+        choice_mask = jax.nn.one_hot(top_idx, e, dtype=jnp.float32)
+        flat_mask = choice_mask.reshape(b, t * cfg.top_k, e)
+        pos_in_expert = (
+            jnp.cumsum(flat_mask, axis=1) - flat_mask
+        ).reshape(b, t, cfg.top_k, e)
+        within_capacity = pos_in_expert < c
+        keep = choice_mask * within_capacity
 
-    # combine[b,t,e,cap]: gate weight of token t's slot in expert e.
-    slot_one_hot = jax.nn.one_hot(
-        pos_in_expert.astype(jnp.int32), c, dtype=jnp.float32
-    )
-    combine = jnp.einsum(
-        "btke,btk,btkec->btec", keep, top_gates.astype(jnp.float32), slot_one_hot
-    )
-    dispatch = (combine > 0.0).astype(x.dtype)  # [B, T, E, C]
+        # combine[b,t,e,cap]: gate weight of token t's slot in expert e.
+        slot_one_hot = jax.nn.one_hot(
+            pos_in_expert.astype(jnp.int32), c, dtype=jnp.float32
+        )
+        combine = jnp.einsum(
+            "btke,btk,btkec->btec", keep, top_gates.astype(jnp.float32),
+            slot_one_hot
+        )
+        dispatch = (combine > 0.0).astype(x.dtype)  # [B, T, E, C]
+        expert_in = jnp.einsum("btec,btd->becd", dispatch, x)
 
-    expert_in = jnp.einsum("btec,btd->becd", dispatch, x)
-    # materialize_matrix: quantization-aware (wi/wg/wo may be stored
-    # int8 + per-(expert, out) scales — models/quantization.py).
-    wi = layers.materialize_matrix(params, "wi", x.dtype)
-    wg = layers.materialize_matrix(params, "wg", x.dtype)
-    wo = layers.materialize_matrix(params, "wo", x.dtype)
-    h = jax.nn.silu(
-        jnp.einsum("becd,edh->bech", expert_in, wi)
-    ) * jnp.einsum("becd,edh->bech", expert_in, wg)
-    expert_out = jnp.einsum("bech,ehd->becd", h, wo)
-    out = jnp.einsum("btec,becd->btd", combine.astype(x.dtype), expert_out)
+    with layers.scope("moe_experts"):
+        # materialize_matrix: quantization-aware (wi/wg/wo may be stored
+        # int8 + per-(expert, out) scales — models/quantization.py).
+        wi = layers.materialize_matrix(params, "wi", x.dtype)
+        wg = layers.materialize_matrix(params, "wg", x.dtype)
+        wo = layers.materialize_matrix(params, "wo", x.dtype)
+        h = jax.nn.silu(
+            jnp.einsum("becd,edh->bech", expert_in, wi)
+        ) * jnp.einsum("becd,edh->bech", expert_in, wg)
+        expert_out = jnp.einsum("bech,ehd->becd", h, wo)
+    with layers.scope("moe_combine"):
+        out = jnp.einsum("btec,becd->btd", combine.astype(x.dtype),
+                         expert_out)
 
-    # Load-balance loss: encourages uniform routing (Switch/GShard form).
-    fraction_routed = jnp.mean(choice_mask[..., 0, :], axis=(0, 1))  # top-1 share
-    mean_gate = jnp.mean(gates, axis=(0, 1))
-    aux = jnp.sum(fraction_routed * mean_gate) * e * cfg.aux_loss_weight
-    if cfg.z_loss_weight:
-        z = jax.scipy.special.logsumexp(router_logits, axis=-1)  # [B, T]
-        aux = aux + cfg.z_loss_weight * jnp.mean(z * z)
+    with layers.scope("moe_route"):
+        # Load-balance loss: encourages uniform routing (Switch/GShard
+        # form).
+        fraction_routed = jnp.mean(choice_mask[..., 0, :], axis=(0, 1))
+        mean_gate = jnp.mean(gates, axis=(0, 1))
+        aux = jnp.sum(fraction_routed * mean_gate) * e * cfg.aux_loss_weight
+        if cfg.z_loss_weight:
+            z = jax.scipy.special.logsumexp(router_logits, axis=-1)  # [B, T]
+            aux = aux + cfg.z_loss_weight * jnp.mean(z * z)
     return out, aux
 
 
@@ -218,24 +227,25 @@ def route(params, flat, cfg: MoeConfig):
     renormalised over the chosen and times ``routed_scale``.  Scores in
     float32; with ``selection_bias`` the CHOICE is by score + bias and the
     weights by the score alone."""
-    kernel = params["router"]["kernel"]
-    if kernel.dtype == flat.dtype == jnp.bfloat16:
-        # bfloat16 products are exact in float32: one MXU pass gives what
-        # the float32 product would.
-        logits = jnp.einsum("nd,de->ne", flat, kernel,
-                            preferred_element_type=jnp.float32)
-    else:
-        logits = jnp.einsum(
-            "nd,de->ne", flat.astype(jnp.float32),
-            kernel.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST)
-    scores = (jax.nn.sigmoid(logits) if cfg.score == "sigmoid"
-              else jax.nn.softmax(logits, axis=-1))
-    chosen_by = scores + params["bias"] if cfg.selection_bias else scores
-    _, idx = jax.lax.top_k(chosen_by, cfg.top_k)
-    weights = jnp.take_along_axis(scores, idx, axis=-1)
-    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), weights * cfg.routed_scale
+    with layers.scope("moe_route"):
+        kernel = params["router"]["kernel"]
+        if kernel.dtype == flat.dtype == jnp.bfloat16:
+            # bfloat16 products are exact in float32: one MXU pass gives what
+            # the float32 product would.
+            logits = jnp.einsum("nd,de->ne", flat, kernel,
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum(
+                "nd,de->ne", flat.astype(jnp.float32),
+                kernel.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+        scores = (jax.nn.sigmoid(logits) if cfg.score == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        chosen_by = scores + params["bias"] if cfg.selection_bias else scores
+        _, idx = jax.lax.top_k(chosen_by, cfg.top_k)
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), weights * cfg.routed_scale
 
 
 #: The routed experts' stacked matrices among an expert layer's
@@ -274,46 +284,53 @@ def dropless_mlp_apply(params, x: jnp.ndarray, cfg: MoeConfig, *,
     :data:`ROUTING_HEAD` lays it out."""
     b, t, d = x.shape
     n, k, held = b * t, cfg.top_k, cfg.held
-    flat = x.reshape(n, d)
-    idx, weights = route(params, flat, cfg)
-    real = (jnp.ones((n, 1), bool) if live is None
-            else live.reshape(n, 1) != 0)
-    local = idx - cfg.expert_offset
-    here = (local >= 0) & (local < held) & real
-    key = jnp.where(here, local, held).reshape(n * k)
-    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
-    landed = jnp.sum(sizes)
-    routing = jnp.concatenate([
-        jnp.stack([jnp.sum(real) * k, landed, jnp.sum(sizes > 0)]
-                  ).astype(jnp.int32), sizes])
-
     rows_block = min(ROW_BLOCK, n * k)
     blocks = -(-(n * k) // rows_block)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    order = jnp.pad(order, (0, blocks * rows_block - n * k))
-    flat_weights = weights.reshape(n * k)
-    ends = jnp.cumsum(sizes)
+    flat = x.reshape(n, d)
+    idx, weights = route(params, flat, cfg)
+    with layers.scope("moe_route"):
+        real = (jnp.ones((n, 1), bool) if live is None
+                else live.reshape(n, 1) != 0)
+        local = idx - cfg.expert_offset
+        here = (local >= 0) & (local < held) & real
+        key = jnp.where(here, local, held).reshape(n * k)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        landed = jnp.sum(sizes)
+        routing = jnp.concatenate([
+            jnp.stack([jnp.sum(real) * k, landed, jnp.sum(sizes > 0)]
+                      ).astype(jnp.int32), sizes])
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, blocks * rows_block - n * k))
+        flat_weights = weights.reshape(n * k)
+        ends = jnp.cumsum(sizes)
 
     def block(i, out):
-        lo = i * rows_block
-        ids = jax.lax.dynamic_slice(order, (lo,), (rows_block,))
-        tokens = ids // k
-        block_sizes = (jnp.clip(ends, lo, lo + rows_block)
-                       - jnp.clip(ends - sizes, lo, lo + rows_block))
-        y = _held_experts(params, jnp.take(flat, tokens, axis=0),
-                          block_sizes, layer)
-        valid = (lo + jnp.arange(rows_block)) < landed
-        y = jnp.where(valid[:, None],
-                      y.astype(jnp.float32)
-                      * jnp.take(flat_weights, ids)[:, None], 0.0)
-        return out.at[tokens].add(y)
+        with layers.scope("moe_route"):
+            lo = i * rows_block
+            ids = jax.lax.dynamic_slice(order, (lo,), (rows_block,))
+            tokens = ids // k
+            block_sizes = (jnp.clip(ends, lo, lo + rows_block)
+                           - jnp.clip(ends - sizes, lo, lo + rows_block))
+            rows = jnp.take(flat, tokens, axis=0)
+        with layers.scope("moe_experts"):
+            y = _held_experts(params, rows, block_sizes, layer)
+        with layers.scope("moe_combine"):
+            valid = (lo + jnp.arange(rows_block)) < landed
+            y = jnp.where(valid[:, None],
+                          y.astype(jnp.float32)
+                          * jnp.take(flat_weights, ids)[:, None], 0.0)
+            return out.at[tokens].add(y)
 
-    out = jnp.zeros((n, d), jnp.float32)
+    with layers.scope("moe_combine"):
+        out = jnp.zeros((n, d), jnp.float32)
     if blocks == 1:
         out = block(0, out)
     else:
         out = jax.lax.fori_loop(0, -(-landed // rows_block), block, out)
-    out = out.astype(x.dtype).reshape(b, t, d)
+    with layers.scope("moe_combine"):
+        out = out.astype(x.dtype).reshape(b, t, d)
     if cfg.shared_hidden:
-        out = out + layers.mlp_block_apply(params["shared"], x)
+        shared = layers.mlp_block_apply(params["shared"], x)
+        with layers.scope("moe_combine"):
+            out = out + shared
     return out, routing

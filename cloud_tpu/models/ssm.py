@@ -227,21 +227,24 @@ def ssd_prefill(params, u, mask, prompt_lens, cfg: SsmConfig, mult, eps):
     dtype."""
     bsz, t, _ = u.shape
     w = cfg.conv_width
-    z, xbc, dt = _project(params, u, cfg, mult)
-    padded = jnp.pad(xbc, [(0, 0), (w - 1, 0), (0, 0)])
-    kernel = params["conv"]["kernel"]
-    conv_out = sum(kernel[i] * padded[:, i:i + t].astype(jnp.float32)
-                   for i in range(w))
-    # padded[j] = xbc[j - (W - 1)]: the last W - 1 real inputs.
-    tail_idx = prompt_lens[:, None] + jnp.arange(w - 1)[None, :]
-    tail = jnp.take_along_axis(padded, tail_idx[:, :, None], axis=1)
-    x, b_mat, c_mat, dt, a = _after_conv(params, conv_out, dt, cfg)
-    dt = jnp.where(mask[..., None] > 0, dt, 0.0)
-    y, state = _ssd_scan(x, dt, a, _to_heads(b_mat, cfg),
-                         _to_heads(c_mat, cfg), cfg)
-    y = y + params["D"][:, None] * x
-    out = _gate_norm_out(params, y.reshape(bsz, t, cfg.d_ssm), z, cfg, mult,
-                         eps, u.dtype)
+    with layers.scope("ssm_proj"):
+        z, xbc, dt = _project(params, u, cfg, mult)
+        padded = jnp.pad(xbc, [(0, 0), (w - 1, 0), (0, 0)])
+        kernel = params["conv"]["kernel"]
+        conv_out = sum(kernel[i] * padded[:, i:i + t].astype(jnp.float32)
+                       for i in range(w))
+        # padded[j] = xbc[j - (W - 1)]: the last W - 1 real inputs.
+        tail_idx = prompt_lens[:, None] + jnp.arange(w - 1)[None, :]
+        tail = jnp.take_along_axis(padded, tail_idx[:, :, None], axis=1)
+        x, b_mat, c_mat, dt, a = _after_conv(params, conv_out, dt, cfg)
+        dt = jnp.where(mask[..., None] > 0, dt, 0.0)
+    with layers.scope("ssm_state"):
+        y, state = _ssd_scan(x, dt, a, _to_heads(b_mat, cfg),
+                             _to_heads(c_mat, cfg), cfg)
+    with layers.scope("ssm_proj"):
+        y = y + params["D"][:, None] * x
+        out = _gate_norm_out(params, y.reshape(bsz, t, cfg.d_ssm), z, cfg,
+                             mult, eps, u.dtype)
     return out, state, tail
 
 
@@ -268,13 +271,18 @@ def ssm_step(params, u, state, conv, cfg: SsmConfig, mult, eps):
     [B, W - 1, conv_dim] the last W - 1 convolution inputs.  Returns the
     mixer's output [B, D], the new state (float32) and the new tail.
     The state is read once and written once, elementwise."""
-    z, x, b_mat, c_mat, dt, a, tail = _step_inputs(params, u, conv, cfg, mult)
-    b_mat, c_mat = _to_heads(b_mat, cfg), _to_heads(c_mat, cfg)  # [B, H, N]
-    keep = jnp.exp(dt * a)                                        # [B, H]
-    state = (keep[..., None, None] * state.astype(jnp.float32)
-             + (dt[..., None] * x)[..., None] * b_mat[:, :, None, :])
-    y = jnp.sum(state * c_mat[:, :, None, :], axis=-1)            # [B, H, P]
-    return _step_output(params, y, x, z, cfg, mult, eps, u.dtype), state, tail
+    with layers.scope("ssm_proj"):
+        z, x, b_mat, c_mat, dt, a, tail = _step_inputs(params, u, conv, cfg,
+                                                       mult)
+    with layers.scope("ssm_state"):
+        b_mat, c_mat = _to_heads(b_mat, cfg), _to_heads(c_mat, cfg)
+        keep = jnp.exp(dt * a)                                    # [B, H]
+        state = (keep[..., None, None] * state.astype(jnp.float32)
+                 + (dt[..., None] * x)[..., None] * b_mat[:, :, None, :])
+        y = jnp.sum(state * c_mat[:, :, None, :], axis=-1)        # [B, H, P]
+    with layers.scope("ssm_proj"):
+        out = _step_output(params, y, x, z, cfg, mult, eps, u.dtype)
+    return out, state, tail
 
 
 def ssm_step_in_place(params, u, states, layer, live, conv, cfg: SsmConfig,
@@ -288,8 +296,13 @@ def ssm_step_in_place(params, u, states, layer, live, conv, cfg: SsmConfig,
     convolution and the gate are :func:`ssm_step`'s own."""
     from cloud_tpu.ops import ssm_state
 
-    z, x, b_mat, c_mat, dt, a, tail = _step_inputs(params, u, conv, cfg, mult)
-    states, y = ssm_state.state_step(
-        states, layer, live, jnp.exp(dt * a), dt[..., None] * x, b_mat,
-        c_mat)
-    return _step_output(params, y, x, z, cfg, mult, eps, u.dtype), states, tail
+    with layers.scope("ssm_proj"):
+        z, x, b_mat, c_mat, dt, a, tail = _step_inputs(params, u, conv, cfg,
+                                                       mult)
+    with layers.scope("ssm_state"):
+        states, y = ssm_state.state_step(
+            states, layer, live, jnp.exp(dt * a), dt[..., None] * x, b_mat,
+            c_mat)
+    with layers.scope("ssm_proj"):
+        out = _step_output(params, y, x, z, cfg, mult, eps, u.dtype)
+    return out, states, tail

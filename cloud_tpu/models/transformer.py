@@ -270,21 +270,22 @@ def qkv_project(att_params, x, positions, config: TransformerConfig):
     equivalent to a full re-forward)."""
     b, t, _ = x.shape
     mult = config.multipliers
-    x = layers.scaled(x, mult.attention_in)
 
     def proj(p, heads):
         y = layers.dense_apply(p, x)
         return y.reshape(b, t, heads, config.head_dim)
 
-    q = layers.rotary_embedding(
-        proj(att_params["q"], config.num_heads), positions,
-        base=config.rope_base,
-    )
-    k = layers.rotary_embedding(
-        layers.scaled(proj(att_params["k"], config.kv_heads), mult.key),
-        positions, base=config.rope_base,
-    )
-    v = proj(att_params["v"], config.kv_heads)
+    with layers.scope("attn_proj"):
+        x = layers.scaled(x, mult.attention_in)
+        q = layers.rotary_embedding(
+            proj(att_params["q"], config.num_heads), positions,
+            base=config.rope_base,
+        )
+        k = layers.rotary_embedding(
+            layers.scaled(proj(att_params["k"], config.kv_heads), mult.key),
+            positions, base=config.rope_base,
+        )
+        v = proj(att_params["v"], config.kv_heads)
     return q, k, v
 
 
@@ -301,8 +302,10 @@ def repeat_kv(k, v, config: TransformerConfig):
 def attention_out(att_params, attended, config: TransformerConfig):
     """Attention's output projection on ``attended`` [B, T, H, hd]."""
     b, t = attended.shape[:2]
-    out = layers.dense_apply(att_params["out"], attended.reshape(b, t, -1))
-    return layers.scaled(out, config.multipliers.attention_out)
+    with layers.scope("attn_out"):
+        out = layers.dense_apply(att_params["out"],
+                                 attended.reshape(b, t, -1))
+        return layers.scaled(out, config.multipliers.attention_out)
 
 
 def mlp_apply(mlp_params, y, config: TransformerConfig, rules):
@@ -318,10 +321,12 @@ def embed_tokens(params, tokens, config: TransformerConfig, rules, mesh):
     """Token embeddings [..., D] in the compute dtype, times the
     embedding multiplier (sqrt(dim) unless the configuration gives
     one)."""
-    x = layers.embedding_apply(params["embed"], tokens, dtype=config.dtype,
-                               rules=rules, mesh=mesh)
-    scale = config.multipliers.embedding
-    return x * (math.sqrt(config.dim) if scale is None else scale)
+    with layers.scope("embed"):
+        x = layers.embedding_apply(params["embed"], tokens,
+                                   dtype=config.dtype, rules=rules,
+                                   mesh=mesh)
+        scale = config.multipliers.embedding
+        return x * (math.sqrt(config.dim) if scale is None else scale)
 
 
 def _attention(
@@ -339,10 +344,11 @@ def _attention(
     k = shard_constraint(k, "batch", "seq", "heads", None, rules=rules, mesh=mesh)
     v = shard_constraint(v, "batch", "seq", "heads", None, rules=rules, mesh=mesh)
 
-    attended = layers.sharded_attention(
-        q, k, v, causal=True, rules=rules, mesh=mesh,
-        zigzag=config.zigzag_sp, ulysses=config.ulysses_sp,
-    )
+    with layers.scope("attn_read"):
+        attended = layers.sharded_attention(
+            q, k, v, causal=True, rules=rules, mesh=mesh,
+            zigzag=config.zigzag_sp, ulysses=config.ulysses_sp,
+        )
 
     return attention_out(att_params, attended, config)
 
@@ -554,8 +560,9 @@ def head_table(params, config: TransformerConfig):
 
 def lm_logits(params, x, config: TransformerConfig) -> jnp.ndarray:
     """Final vocabulary projection in f32, times the head's multiplier."""
-    return layers.scaled(_head_product(params, x, config),
-                         config.multipliers.lm_head)
+    with layers.scope("head"):
+        return layers.scaled(_head_product(params, x, config),
+                             config.multipliers.lm_head)
 
 
 def _head_product(params, x, config: TransformerConfig) -> jnp.ndarray:
