@@ -67,9 +67,9 @@ SCOPES = (
     "ssm_proj",     # the mixer's in- and out-projection, its convolution
     "ssm_state",    # the state step; a prefill's chunked scan
     "mlp",          # the dense SwiGLU, and the shared expert
-    "moe_route",    # router, top-k, sort, gather
-    "moe_experts",  # the grouped products
-    "moe_combine",  # weighting, scatter-add
+    "moe_route",    # router, top-k, sort, a block's placement and rows
+    "moe_experts",  # the grouped products, the weight on their last input
+    "moe_combine",  # the placement product, the shared expert's add
     "head",         # final norm, vocabulary product, sampling, slot state
 )
 

@@ -18,12 +18,16 @@ is left out and nothing stands in for their chips or their exchange.  No
 token is ever dropped: the (token, choice) pairs that land here are sorted
 by expert and go through three grouped matrix products
 (``ops.grouped_matmul``) in blocks of ``ROW_BLOCK`` rows, as many blocks as
-the routing asks for.  An expert that got no token is never read.
+the routing asks for.  An expert that got no token is never read.  A
+block's rows find their way back to their tokens as this file's other
+route's do, through the MXU: one product with the block's 0/1 placement
+matrix (:func:`_placed`), exact, where a scatter-add went a row at a time.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -255,10 +259,18 @@ def route(params, flat, cfg: MoeConfig):
 EXPERT_LEAVES = ("wi", "wg", "wo")
 
 
-def _held_experts(params, rows, sizes, layer):
-    """SwiGLU of each row through ITS expert: ``rows`` [R, D] sorted by
-    held expert, ``sizes`` [held] rows an expert; the experts' matrices
-    one layer's [E, .., ..], or every layer's with ``layer``."""
+# Jitted (and inlined) for its tracing cache alone: a layer call traces a
+# block twice at one shape (the first block and the loop's body), and
+# tracing its three kernel calls is what a block costs at set-up.
+@functools.partial(jax.jit, inline=True)
+def _held_experts(params, rows, sizes, layer, weights):
+    """SwiGLU of each row through ITS expert, times the row's weight:
+    ``rows`` [R, D] sorted by held expert, ``sizes`` [held] rows an
+    expert, ``weights`` [R] float32; the experts' matrices one layer's
+    [E, .., ..], or every layer's with ``layer``.  The weight is a scalar
+    a row and commutes with the last product: it goes on ``hidden`` in
+    float32, before the cast that was always there, so the rows come out
+    weighted under the two roundings they had unweighted."""
     from cloud_tpu.ops.grouped_matmul import grouped_matmul
 
     def product(x, name):
@@ -267,7 +279,49 @@ def _held_experts(params, rows, sizes, layer):
 
     gate = product(rows, "wi").astype(jnp.float32)
     hidden = jax.nn.silu(gate) * product(rows, "wg").astype(jnp.float32)
-    return product(hidden.astype(rows.dtype), "wo")
+    return product((hidden * weights[:, None]).astype(rows.dtype), "wo")
+
+
+def _exact(dtype):
+    """The precision at which a product with a 0/1 matrix returns its
+    other operand's numbers as they are: bfloat16 products are exact in
+    the float32 they accumulate in; float32 takes the MXU's six passes."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _placement(tokens, valid, n: int, dtype):
+    """A block's 0/1 placement matrix [n, R]: ``place[t, r]`` is 1 where
+    sorted row ``r`` is a row of token ``t`` (``tokens[r] == t``) and
+    ``valid[r]`` (it landed), else 0.  Both of a block's index operations
+    over rows are products with it, which the MXU runs at its pace
+    whatever the rows' order."""
+    at = jnp.arange(n, dtype=tokens.dtype)[:, None]
+    return ((tokens[None, :] == at) & valid[None, :]).astype(dtype)
+
+
+def _drawn(flat, tokens, place):
+    """Each row's token out of ``flat`` [n, D], [R, D]: a gather; where
+    the rows are no fewer than the tokens (a decode step draws 512 from
+    64) the placement's transpose times ``flat``: one nonzero a row, so
+    exact, and zeros for a row that is not valid."""
+    if flat.shape[0] > tokens.shape[0]:
+        return jnp.take(flat, tokens, axis=0)
+    return jax.lax.dot_general(
+        place, flat, (((0,), (0,)), ((), ())), precision=_exact(flat.dtype),
+        preferred_element_type=jnp.float32).astype(flat.dtype)
+
+
+def _placed(y, valid, place):
+    """The rows ``y`` [R, D] summed onto their tokens, [n, D] float32:
+    ``place @ y``, ONE product in place of a scatter-add, which the chip
+    runs one row after another whatever number of them landed.  A row
+    that is not ``valid`` holds whatever the grouped product left there
+    and is selected away first (0 x NaN is NaN).  Exact as the scatter's
+    float32 adds were: every product is with 0 or 1."""
+    y = jnp.where(valid[:, None], y, 0)
+    return jax.lax.dot_general(
+        place, y, (((1,), (0,)), ((), ())), precision=_exact(y.dtype),
+        preferred_element_type=jnp.float32)
 
 
 def dropless_mlp_apply(params, x: jnp.ndarray, cfg: MoeConfig, *,
@@ -294,7 +348,10 @@ def dropless_mlp_apply(params, x: jnp.ndarray, cfg: MoeConfig, *,
         local = idx - cfg.expert_offset
         here = (local >= 0) & (local < held) & real
         key = jnp.where(here, local, held).reshape(n * k)
-        sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        # A compare-and-sum, not n x k scalar scatter-adds one after
+        # another.
+        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)
         landed = jnp.sum(sizes)
         routing = jnp.concatenate([
             jnp.stack([jnp.sum(real) * k, landed, jnp.sum(sizes > 0)]
@@ -304,29 +361,35 @@ def dropless_mlp_apply(params, x: jnp.ndarray, cfg: MoeConfig, *,
         flat_weights = weights.reshape(n * k)
         ends = jnp.cumsum(sizes)
 
-    def block(i, out):
+    def block(i):
+        """What block ``i`` of the sorted rows adds to the tokens, [n, D]
+        float32."""
         with layers.scope("moe_route"):
             lo = i * rows_block
             ids = jax.lax.dynamic_slice(order, (lo,), (rows_block,))
             tokens = ids // k
             block_sizes = (jnp.clip(ends, lo, lo + rows_block)
                            - jnp.clip(ends - sizes, lo, lo + rows_block))
-            rows = jnp.take(flat, tokens, axis=0)
-        with layers.scope("moe_experts"):
-            y = _held_experts(params, rows, block_sizes, layer)
-        with layers.scope("moe_combine"):
             valid = (lo + jnp.arange(rows_block)) < landed
-            y = jnp.where(valid[:, None],
-                          y.astype(jnp.float32)
-                          * jnp.take(flat_weights, ids)[:, None], 0.0)
-            return out.at[tokens].add(y)
+            place = _placement(tokens, valid, n, flat.dtype)
+            rows = _drawn(flat, tokens, place)
+            row_weights = jnp.take(flat_weights, ids)
+        with layers.scope("moe_experts"):
+            y = _held_experts(params, rows, block_sizes, layer, row_weights)
+        with layers.scope("moe_combine"):
+            return _placed(y, valid, place)
 
-    with layers.scope("moe_combine"):
-        out = jnp.zeros((n, d), jnp.float32)
-    if blocks == 1:
-        out = block(0, out)
-    else:
-        out = jax.lax.fori_loop(0, -(-landed // rows_block), block, out)
+    # The first block IS the result where no second one holds rows (every
+    # decode step, most prompts): no accumulator is zeroed, read or added
+    # to for it.
+    out = block(0)
+    if blocks > 1:
+        def more(i, out):
+            placed = block(i)
+            with layers.scope("moe_combine"):
+                return out + placed
+
+        out = jax.lax.fori_loop(1, -(-landed // rows_block), more, out)
     with layers.scope("moe_combine"):
         out = out.astype(x.dtype).reshape(b, t, d)
     if cfg.shared_hidden:

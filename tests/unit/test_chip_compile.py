@@ -619,14 +619,9 @@ def test_flash_at_the_latent_models_head_size(one_chip):
                      compiled.as_text())
 
 
-def test_latent_prefill_pads_no_values(one_chip, monkeypatch):
-    """The forward pass of the cell's insert at its 4,096 width (1 dense
-    + 6 expert layers at the published widths): the flash kernel is in
-    both stacks and its output has the values' own head size, 128 (the
-    kernel's output takes the size of the values it is handed: padded to
-    the keys' 192 they came back at 192 and were sliced); of its operands
-    only the queries and the keys have heads of 192.  (Queries, values
-    and output have the sequence in the lanes: [B, H, D, T].)"""
+def _latent_prefill_text(one_chip, monkeypatch):
+    """The compiled forward pass of the cell's insert at its 4,096 width
+    (1 dense + 6 expert layers at the published widths)."""
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     monkeypatch.syspath_prepend(root)
@@ -645,9 +640,19 @@ def test_latent_prefill_pads_no_values(one_chip, monkeypatch):
         return generation._prefill_forward(params, tokens, lens, config,
                                            DEFAULT_RULES, None)
 
-    text = jax.jit(fn).lower(
+    return jax.jit(fn).lower(
         params, _spec((1, 4096), jnp.int32, one_chip),
         _spec((1,), jnp.int32, one_chip)).compile().as_text()
+
+
+def test_latent_prefill_pads_no_values(one_chip, monkeypatch):
+    """The forward pass of the cell's insert at its 4,096 width: the flash
+    kernel is in both stacks and its output has the values' own head size,
+    128 (the kernel's output takes the size of the values it is handed:
+    padded to the keys' 192 they came back at 192 and were sliced); of its
+    operands only the queries and the keys have heads of 192.  (Queries,
+    values and output have the sequence in the lanes: [B, H, D, T].)"""
+    text = _latent_prefill_text(one_chip, monkeypatch)
     flash = re.findall(r"%flash_fwd[\w.]* = \((bf16\[[\d,]+\])", text)
     assert len(flash) == 2 and set(flash) == {"bf16[1,64,128,4096]"}, flash
     calls = [ln for ln in text.splitlines()
@@ -662,15 +667,10 @@ def test_latent_prefill_pads_no_values(one_chip, monkeypatch):
             "bf16[1,64,4096,192]"], shapes
 
 
-def test_latent_expert_decode_chunk_copies_neither_cache_nor_experts(
-        one_chip, monkeypatch):
-    """The cell's chunk program (64 slots x 4,608 latent rows, 1 dense +
-    6 expert layers at the published widths): the latent leaf is carried
-    in place (no copy of it or of a layer of it), the routed experts'
-    stacks reach the grouped products whole (no ``bf16[12,7168,2048]``
-    slice: each was a 352 MB copy a call when the scan sliced them), and
-    the kernels are there: the latent read of either stack and an expert
-    layer's three grouped products."""
+def _latent_chunk_program(one_chip, monkeypatch):
+    """The cell's compiled chunk program (64 slots x 4,608 latent rows, 1
+    dense + 6 expert layers at the published widths) and its cache's
+    shapes."""
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     monkeypatch.syspath_prepend(root)
@@ -700,6 +700,44 @@ def test_latent_expert_decode_chunk_copies_neither_cache_nor_experts(
 
     compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
         params, cache, state, _spec((2,), jnp.uint32, one_chip)).compile()
+    return compiled, cache
+
+
+#: A scatter onto an activation of the latent model's width, [n, 7168]:
+#: how the dropless expert layer once put its rows back on their tokens,
+#: one row after another.
+ACTIVATION_SCATTER = re.compile(r" = \w+\[\d+,7168\]\S* scatter\(")
+
+
+@pytest.mark.parametrize("program", ["chunk", "insert-4096"])
+def test_latent_expert_layers_place_their_rows_without_a_scatter(
+        one_chip, monkeypatch, program):
+    """Neither of the cell's programs scatters onto an ``[n, 7168]``
+    activation: an expert layer's rows reach their tokens through a
+    product with the block's placement matrix (``moe._placed``).  The
+    chunk program's one row-wise scatter left is the cache write's, onto
+    the latent leaf."""
+    if program == "chunk":
+        text = _latent_chunk_program(one_chip, monkeypatch)[0].as_text()
+        assert re.search(r" = bf16\[7,64,4608,640\]\S* scatter\(", text)
+    else:
+        text = _latent_prefill_text(one_chip, monkeypatch)
+    assert "%grouped_matmul" in text
+    found = [ln.strip()[:160] for ln in text.splitlines()
+             if ACTIVATION_SCATTER.search(ln)]
+    assert not found, found
+
+
+def test_latent_expert_decode_chunk_copies_neither_cache_nor_experts(
+        one_chip, monkeypatch):
+    """The cell's chunk program (64 slots x 4,608 latent rows, 1 dense +
+    6 expert layers at the published widths): the latent leaf is carried
+    in place (no copy of it or of a layer of it), the routed experts'
+    stacks reach the grouped products whole (no ``bf16[12,7168,2048]``
+    slice: each was a 352 MB copy a call when the scan sliced them), and
+    the kernels are there: the latent read of either stack and an expert
+    layer's three grouped products."""
+    compiled, cache = _latent_chunk_program(one_chip, monkeypatch)
     assert cache["latent"].shape == (7, 64, 4608, 640)
     text = compiled.as_text()
     names = re.findall(r"(%[\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",

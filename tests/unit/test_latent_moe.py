@@ -249,6 +249,85 @@ def test_no_token_is_dropped_under_a_routing_skewed_onto_one_expert(
     np.testing.assert_allclose(out[0, :10], want[:10], rtol=2e-5, atol=2e-6)
 
 
+# -- (e) the rows' way back to their tokens ---------------------------------
+
+
+def _stub_experts(params, rows, sizes, layer, weights):
+    """An expert that scales: row x (1 + its held expert's number) x
+    weight, rounded once to the rows' type; NaN past the rows the groups
+    hold, where the grouped product leaves what it finds."""
+    ends = jnp.cumsum(sizes)
+    at = jnp.arange(rows.shape[0])
+    expert = jnp.searchsorted(ends, at, side="right")
+    y = rows.astype(jnp.float32) * ((1.0 + expert) * weights)[:, None]
+    return jnp.where((at < ends[-1])[:, None], y, jnp.nan).astype(rows.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case,row_block,live_tokens", [
+    ("several-choices-in-one-block", 1024, 24),
+    ("choices-in-two-blocks", 8, 24),
+    ("live-rows", 8, 10),
+    ("no-row-landed", 1024, 0),
+])
+def test_a_blocks_rows_are_summed_onto_their_tokens(
+        monkeypatch, case, row_block, live_tokens, dtype):
+    """The combine alone (the experts stubbed by a scaling) against a
+    plain scatter-add: every choice held here reaches its token once, from
+    whichever block it fell in; rows past those that landed add nothing
+    though they hold NaN, to token 0 no more than to another; float32 to
+    2e-6, bfloat16 rows summed in float32 and rounded once."""
+    monkeypatch.setattr(moe, "ROW_BLOCK", row_block)
+    monkeypatch.setattr(moe, "_held_experts", _stub_experts)
+    sizes = _share(4, 4)
+    cfg = serve_latent_moe.model_config(sizes, MIX).moe
+    cfg = moe.MoeConfig(**{**vars(cfg), "shared_hidden": 0})
+    p = kimi_k2.layer_params(jax.random.PRNGKey(9), sizes, dtype,
+                             False)["mlp"]
+    m = _layer_input(seed=6).astype(dtype)
+    # Token 0 is not live: nothing may reach it, and the rows past the
+    # landed (padding's among them) carry its id.
+    live = (jnp.arange(24) < live_tokens) & (jnp.arange(24) > 0)
+    out, counted = jax.jit(lambda p, m, live: moe.dropless_mlp_apply(
+        p, m[None], cfg, live=live[None]))(p, m, live)
+
+    idx, weights = moe.route(p, m, cfg)
+    local = np.asarray(idx) - cfg.expert_offset
+    here = (local >= 0) & (local < cfg.held) & np.asarray(live)[:, None]
+    rows = (m.astype(jnp.float32)[:, None, :]
+            * ((1.0 + local) * weights)[..., None]).astype(dtype)
+    token = np.broadcast_to(np.arange(24)[:, None], here.shape)
+    want = jnp.zeros(m.shape, jnp.float32).at[token[here]].add(
+        rows[here].astype(jnp.float32))
+
+    assert int(counted[1]) == here.sum()
+    if case == "several-choices-in-one-block":
+        assert here.sum(axis=1).max() >= 2 and here.sum() <= row_block
+    elif case == "choices-in-two-blocks":
+        # Sorted by expert: a token with two experts here whose rows lie
+        # further apart than a block.
+        order = np.argsort(np.where(here, local, cfg.held).reshape(-1),
+                           kind="stable")
+        block_of = np.empty(order.size, int)
+        block_of[order] = np.arange(order.size) // row_block
+        block_of = np.where(here, block_of.reshape(here.shape), -1)
+        assert any(len(set(b[b >= 0])) >= 2 for b in block_of)
+    elif case == "no-row-landed":
+        assert here.sum() == 0
+    got = np.asarray(out[0].astype(jnp.float32))
+    assert np.isfinite(got).all()
+    assert not got[0].any() and not got[live_tokens:].any()
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+    else:
+        # One rounding of the float32 sum: half a unit in the last of
+        # bfloat16's 8 bits, and the sum's own order beside it.
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -8, atol=0)
+        exact = np.asarray(want.astype(jnp.bfloat16).astype(jnp.float32))
+        assert (got == exact).mean() > 0.99
+
+
 # -- (f) what refuses a latent cache ----------------------------------------
 
 
