@@ -4,16 +4,22 @@ with a jnp reference.
 A cache row is one vector a token, ``[c | k_pe | 0]`` (``models/mla.py``),
 and it is key and value at once: every head's query ``[q_lat | q_pe | 0]``
 scores against the whole row, and the values are the row's first
-``value_dim`` numbers.  So the kernel fetches a page of rows ONCE for all
-heads: one MXU product of the ``H`` queries against the page gives the
-scores, the online softmax runs in float32, and the weights go back
-through the MXU against the same page (as ``WEIGHT_PARTS`` bfloat16 parts
-stacked as rows of one product).  At 64 heads over a 640-wide row that is
-about a hundred operations a byte: MXU work and the page's DMA side by
-side, unlike the per-head K/V read of ``ops.paged_attention``, whose fetch
-plan (:func:`paged_attention._fetch_plan`: a dead page or a dead slot
-costs a grid step and no DMA) and stacked-leaf addressing (the layer a
-scalar-prefetch operand, nothing sliced out) it reuses.
+``value_dim`` numbers.  So the kernel fetches the rows ONCE for all heads:
+one MXU product of the ``H`` queries against them gives the scores, the
+online softmax runs in float32, and the weights go back through the MXU
+against the same rows (as ``WEIGHT_PARTS`` bfloat16 parts stacked as rows
+of one product).  At 64 heads over a 640-wide row that is about a hundred
+operations a byte: MXU work and the DMA side by side, unlike the per-head
+K/V read of ``ops.paged_attention``.
+
+The grid is one step a slot, and the stacked leaf stays in HBM whole (the
+layer a scalar-prefetch operand, nothing sliced out).  A slot's pages up
+to its length, and no others, are walked by a loop inside the kernel, up
+to ``STEP_ROWS`` rows a step, each step's rows fetched by the kernel's own
+DMA into one of ``BUFFERS`` buffers: the walk runs over the grid's live
+slots as one sequence, so the next slot's first rows are in flight before
+the current slot's last are scored, and a slot of length 0 costs its grid
+step and nothing else.
 
 Dispatch follows the house rule: ``use_pallas=None`` takes the kernel on a
 TPU (or under ``CLOUD_TPU_FLASH_FORCE_INTERPRET=1`` through the
@@ -31,15 +37,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from cloud_tpu.ops import dispatch as dispatch_lib
-from cloud_tpu.ops.paged_attention import NEG_INF, _fetch_plan, _split_bf16
+from cloud_tpu.ops.paged_attention import NEG_INF, _split_bf16
 
-#: Rows a page, the largest of these that divides the slot row.  Chosen on
-#: the chip among 128 / 256 / 512 at 64 slots x 4,608 rows
-#: (docs/KERNELS.md; scripts/decode_crossover.py k2): a grid step costs
-#: about 0.35 us, as long as the DMA of a 128-row page, so a larger page
-#: halves what the walk over (slot, page) costs and wastes half a page a
-#: slot at most.
-PAGE_ROWS = (512, 256, 128, 64, 32, 16, 8)
+#: Rows a page, the unit a slot's rows are fetched by: the largest of these
+#: that divides the slot row.  Chosen on the chip against pages of 512 at
+#: 64 slots x 4,608 rows (docs/KERNELS.md; scripts/decode_crossover.py k2):
+#: the walk runs at the DMA's pace, so a finer page fetches less of a
+#: slot's last page past its length for the same time a row.
+PAGE_ROWS = (256, 128, 64, 32, 16, 8)
+
+#: Rows one step of the walk scores at most (one DMA, one pass of the
+#: body), and the most pages such a step holds (a body each): the larger
+#: the step, the less its fixed cost a row (the chain of two dependent MXU
+#: products and the softmax between them), up to what fast memory holds.
+STEP_ROWS = 1536
+STEP_PAGES = 6
+
+#: Buffers the walk fetches into: the two steps after the one being scored
+#: are in flight, so a short step (a slot's last) hides the next one's DMA.
+BUFFERS = 3
 
 #: bfloat16 parts that carry the float32 softmax weights through the MXU:
 #: two hold 16 mantissa bits, and 2 x 64 heads fill the MXU's 128 rows.
@@ -64,29 +80,97 @@ def _reference(q, rows, cur_len, *, value_dim, scale):
     return out.astype(q.dtype)
 
 
-def _kernel(layer_ref, len_ref, rows_ref, first_ref, last_ref, q_ref,
-            page_ref, o_ref, m_scr, l_scr, acc_scr, *, bt, value_dim,
-            scale):
-    b, p = pl.program_id(0), pl.program_id(1)
+def _next_live(cur_len):
+    """[B + 1] int32: entry ``i`` is the first slot at or after ``i``
+    whose length is not 0, or ``B`` where there is none."""
+    b = cur_len.shape[0]
+    idx = jnp.where(cur_len > 0, jnp.arange(b, dtype=jnp.int32), b)
+    return jnp.append(jax.lax.cummin(idx, reverse=True), b).astype(jnp.int32)
+
+
+def _kernel(layer_ref, len_ref, live_ref, q_ref, rows_hbm, o_ref, buf, sems,
+            m_scr, l_scr, acc_scr, done_ref, *, bt, per_step, buffers,
+            value_dim, scale):
+    """Grid step ``b`` is slot ``b``: its live pages, and no other, walked
+    in order ``per_step`` pages at a time (one DMA, one body for each
+    number of pages a step can hold) through the ``buffers`` buffers in
+    ``buf``.  The walk runs over the grid's live slots as one sequence of
+    steps, and the ``buffers - 1`` steps after the one being scored are
+    always in flight, whichever slots they belong to: a slot's first pages
+    arrive during the slot before.  ``done_ref`` counts the steps walked
+    so far over the grid: step ``k`` of the walk lands in buffer
+    ``k % buffers``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    unit = bt * per_step
+    layer = layer_ref[0]
+
+    def pages_of(slot):
+        return jnp.minimum((len_ref[slot] + bt - 1) // bt,
+                           rows_hbm.shape[2] // bt)
+
+    def steps_of(slot):
+        """Steps of the walk in ``slot``; none in the end mark ``n_slots``."""
+        held = pages_of(jnp.minimum(slot, n_slots - 1))
+        return jnp.where(slot < n_slots,
+                         (held + per_step - 1) // per_step, 0)
+
+    def successor(slot, step):
+        """The walk's step after ``(slot, step)``: the slot's next, else
+        the first of the next live slot; the end mark stays the end."""
+        more = step + 1 < steps_of(slot)
+        following = live_ref[jnp.minimum(slot + 1, n_slots)]
+        return (jnp.where(more, slot, following),
+                jnp.where(more, step + 1, 0))
+
+    def each_size(slot, step, fn):
+        """``fn(pages)`` under the one static page count step ``step`` of
+        ``slot`` holds."""
+        held = pages_of(slot) - step * per_step
+        for n in range(1, per_step + 1):
+            fits = held >= n if n == per_step else held == n
+            pl.when(fits)(functools.partial(fn, n))
+
+    def copy(slot, step, at, n):
+        return pltpu.make_async_copy(
+            rows_hbm.at[layer, slot, pl.ds(step * unit, n * bt)],
+            buf.at[at, pl.ds(0, n * bt)], sems.at[at])
+
+    def start(slot, step, at):
+        @pl.when(slot < n_slots)
+        def _():
+            each_size(slot, step, lambda n: copy(slot, step, at, n).start())
+
+    @pl.when(b == 0)
+    def _start():
+        done_ref[0] = 0
+        slot, step = live_ref[0], jnp.int32(0)
+        for k in range(buffers - 1):
+            start(slot, step, k)
+            slot, step = successor(slot, step)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
     cur = len_ref[b]
+    steps = steps_of(b)
+    walked = done_ref[0]
+    q = q_ref[0]                                    # [H, W]
 
-    @pl.when(p == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(p * bt < cur)
-    def _page():
-        page = page_ref[...]                        # [bt, W] as stored
-        q = q_ref[0]                                # [H, W]
-        if page.dtype != q.dtype:
-            q, page = q.astype(jnp.float32), page.astype(jnp.float32)
+    def score(step, at, n):
+        copy(b, step, at, n).wait()
+        rows = n * bt
+        page = buf[at, :rows]                       # [rows, W] as stored
+        qp = q
+        if page.dtype != qp.dtype:
+            qp, page = qp.astype(jnp.float32), page.astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, page, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [H, bt]
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
-        s = jnp.where(col < cur - p * bt, s, NEG_INF)
+            qp, page, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, rows]
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        s = jnp.where(col < cur - step * unit, s, NEG_INF)
         m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         pmat = jnp.exp(s - m_new)
@@ -110,51 +194,83 @@ def _kernel(layer_ref, len_ref, rows_ref, first_ref, last_ref, q_ref,
             l_scr.shape)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
-                    ).astype(o_ref.dtype)
+    def walk_step(step, carry):
+        ahead = (b, step)
+        for _ in range(buffers - 1):
+            ahead = successor(*ahead)
+        start(*ahead, (walked + step + buffers - 1) % buffers)
+        each_size(b, step, functools.partial(score, step,
+                                             (walked + step) % buffers))
+        return carry
+
+    jax.lax.fori_loop(0, steps, walk_step, 0)
+    done_ref[0] = walked + steps
+    l = l_scr[:, :1]
+    o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
+                ).astype(o_ref.dtype)
 
 
-def _pallas(q, rows, cur_len, layer, bt, *, value_dim, scale, interpret):
+def _step_pages(bt, s_total):
+    """Pages a step of the walk holds over slot rows of ``s_total``."""
+    return max(1, min(STEP_ROWS // bt, STEP_PAGES, s_total // bt))
+
+
+def _pallas(q, rows, cur_len, layer, bt, *, value_dim, scale, interpret,
+            per_step=None, buffers=BUFFERS):
+    """``bt`` rows a page, ``per_step`` pages a step (by default as many as
+    ``STEP_ROWS`` and ``STEP_PAGES`` allow, and the slot row holds),
+    ``buffers`` buffers."""
     global KERNEL_TRACE_COUNT
     KERNEL_TRACE_COUNT += 1
+    if per_step is None:
+        per_step = _step_pages(bt, rows.shape[-2])
+    return _call(q, rows, cur_len.astype(jnp.int32),
+                 jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), bt=bt,
+                 value_dim=value_dim, scale=scale, interpret=interpret,
+                 per_step=per_step, buffers=buffers)
+
+
+# Jitted (and inlined) for its tracing cache alone: a chunk program calls
+# the kernel once a layer stack and step, and tracing it is what a call
+# costs at set-up.
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("bt", "value_dim", "scale", "interpret",
+                                    "per_step", "buffers"))
+def _call(q, rows, cur_len, layer, *, bt, value_dim, scale, interpret,
+          per_step, buffers):
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, w = q.shape
-    s_total = rows.shape[2]
-    n_pages = s_total // bt
-    cur_len = cur_len.astype(jnp.int32)
-    fetch_rows, first, last = _fetch_plan(cur_len, 1, bt, n_pages)
 
-    def q_map(b_, p_, *_):
+    def q_map(b_, *_):
         return (b_, 0, 0)
 
-    def page_map(b_, p_, lyr, ln, rows_, first_, last_):
-        page = jnp.minimum(jnp.maximum(p_, first_[b_]), last_[b_])
-        return (lyr[0], rows_[b_], page, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(b, n_pages),
+        num_scalar_prefetch=3,
+        grid=(b,),
         in_specs=[pl.BlockSpec((1, h, w), q_map),
-                  pl.BlockSpec((None, None, bt, w), page_map)],
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, h, value_dim), q_map),
-        scratch_shapes=[pltpu.VMEM((h, 128), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((buffers, bt * per_step, w),
+                                   rows.dtype),
+                        pltpu.SemaphoreType.DMA((buffers,)),
                         pltpu.VMEM((h, 128), jnp.float32),
-                        pltpu.VMEM((h, value_dim), jnp.float32)],
+                        pltpu.VMEM((h, 128), jnp.float32),
+                        pltpu.VMEM((h, value_dim), jnp.float32),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, bt=bt, value_dim=value_dim, scale=scale),
+        functools.partial(_kernel, bt=bt, per_step=per_step,
+                          buffers=buffers, value_dim=value_dim, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
+        # One walk over the grid: a slot's first rows are fetched during
+        # the slot before, so the steps run in order.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="latent_decode",
-    )(jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), cur_len,
-      fetch_rows, first, last, q, rows)
+    )(layer, cur_len, _next_live(cur_len), q, rows)
 
 
 def kernel_page(q, rows, *, use_pallas: Optional[bool] = None

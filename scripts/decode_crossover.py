@@ -11,16 +11,22 @@ its numbers).  Run it through the chip tool; it refuses to run off a TPU.
 
     python scripts/decode_crossover.py [grid ...]
 
-Two more cases, of the latent-attention expert model
-(``kimi-k2-ep32-stage.agent-saturated``), run when named: ``k2``, the
-decode read over latent rows ``[L, B, S, 640]`` (``ops.latent_attention``:
-its jnp route against the kernel by page size), and ``k2-experts``, a
-decode step's grouped products over the experts it touches
-(``ops.grouped_matmul``: ``lax.ragged_dot`` on a layer's slice against the
-kernel over the stacked matrices).  The kernel-against-jnp claims of
-docs/KERNELS.md for both are this run's.
+Three more cases run when named.  ``k2`` and ``g35``: the decode read
+over latent rows ``[L, B, S, 640]`` (``ops.latent_attention``) at
+``kimi-k2-ep32-stage.agent-saturated``'s grid and at
+``gigachat35-ep16-stage.reason-saturated``'s one latent layer: checked
+against the reference on the chip first (exits 1 on a gap), then the jnp
+route (k2), the kernel of the commit before the in-kernel walk (from
+``_parent/``: ``git archive <parent> | tar -x -C _parent``; left out where
+that directory is missing) and the walk by page rows, pages a step and
+buffers.  ``k2-experts``: a decode step's grouped products over the
+experts it touches (``ops.grouped_matmul``: ``lax.ragged_dot`` on a
+layer's slice against the kernel over the stacked matrices).  The
+kernel-against-jnp claims of docs/KERNELS.md for both models are this
+run's.
 """
 
+import importlib.util
 import json
 import os
 import sys
@@ -102,29 +108,94 @@ def timed(read, layers, q, cache, lens):
     return best / STEPS * 1e3
 
 
-#: The latent grid: (layers, slots, rows, heads, row width, value width).
-LATENT = (7, 64, 4608, 64, 640, 512)
-LATENT_PAGES = (128, 256, 512)
+#: The latent grids: name -> (layers, slots, rows, heads, row width,
+#: value width): kimi-k2-ep32-stage's 7 layers and
+#: gigachat35-ep16-stage's one latent layer.
+LATENT = {"k2": (7, 64, 4608, 64, 640, 512),
+          "g35": (1, 96, 6144, 64, 640, 512)}
+#: (page rows, pages a step, buffers) of the walks timed: one page a step
+#: in two buffers is the walk alone, then steps of three pages, then a
+#: third buffer, then the kernel's own rule (the last).
+LATENT_WALKS = ((512, 1, 2), (512, 3, 2), (512, 3, 3), (256, 6, 3))
 #: The experts a decode step meets: (expert layers, held, assignments'
 #: rows, hidden, expert width).
 EXPERTS = (6, 12, 512, 7168, 2048)
 
 
-def latent_case():
-    """64 slots at lengths like the cell's (half the rows in use) and
-    every row full: the jnp route over layer ``l`` against the kernel
-    over the stacked leaf, by page."""
+def parent_latent():
+    """The latent kernel of the commit before the in-kernel walk, from
+    ``_parent/`` (``git archive <parent> | tar -x -C _parent``), or None
+    where that directory is missing."""
+    path = os.path.join(REPO, "_parent", "cloud_tpu", "ops",
+                        "latent_attention.py")
+    if not os.path.exists(path):
+        return None
+    spec = importlib.util.spec_from_file_location("parent_latent", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def latent_lengths(grid):
+    """Lengths like the cell's (k2: half the rows in use; g35: about 30%),
+    every row full, and (k2) no slot decoding: what a grid step costs
+    with nothing to fetch."""
+    _, slots, rows = LATENT[grid][:3]
+    full = np.full(slots, rows, np.int32)
+    if grid == "k2":
+        rng = np.random.default_rng(35)
+        half = rng.integers(1100, 3500, slots).astype(np.int32)
+        return {"half-in-use": half, "full": full,
+                "none-live": np.zeros(slots, np.int32)}
+    rng = np.random.default_rng(39)
+    return {"30%-in-use": rng.integers(600, 3100, slots).astype(np.int32),
+            "full": full}
+
+
+def check_latent(la, q, cache, value):
+    """The walk against ``_reference`` on the chip: dead slots first, last
+    and between live ones, lengths at a page's edges, and rows past every
+    length (a stale page past the last live one) set to 1e4.  Exits 1 on a
+    gap over bfloat16's rounding."""
+    layers, slots, rows = cache.shape[:3]
+    rng = np.random.default_rng(40)
+    lens = rng.integers(1, rows + 1, slots).astype(np.int32)
+    lens[[0, 3, 4, slots - 1]] = 0
+    lens[[5, 6, 7, 8, 9]] = [1, 511, 512, 513, rows]
+    lens = jnp.asarray(lens)
+    past = jnp.arange(rows)[None, :, None] >= lens[:, None, None]
+    noisy = cache.at[layers - 1].set(
+        jnp.where(past, jnp.bfloat16(1e4), cache[layers - 1]))
+    want = la._reference(q, cache[layers - 1], lens, value_dim=value,
+                         scale=0.1)
+    for page, per_step, buffers in LATENT_WALKS:
+        got = jax.jit(lambda q, c, n: la._pallas(
+            q, c, n, layers - 1, page, value_dim=value, scale=0.1,
+            interpret=False, per_step=per_step, buffers=buffers))(
+                q, noisy, lens)
+        gap = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                    - want.astype(jnp.float32))))
+        print(json.dumps({"check": "latent",
+                          "walk": [page, per_step, buffers],
+                          "max_abs_gap": gap}), flush=True)
+        if not gap <= 0.05:
+            raise SystemExit(1)
+
+
+def latent_case(grid):
+    """The jnp route over layer ``l`` (k2 only), the parent's kernel and
+    the walk over the stacked leaf by each of :data:`LATENT_WALKS`, at each
+    of :func:`latent_lengths`."""
     la = sys.modules.get("cloud_tpu.ops.latent_attention") or \
         __import__("cloud_tpu.ops.latent_attention", fromlist=["x"])
-    layers, slots, rows, heads, width, value = LATENT
+    layers, slots, rows, heads, width, value = LATENT[grid]
     keys = jax.random.split(jax.random.PRNGKey(0), 2)
     cache = jax.random.normal(keys[0], (layers, slots, rows, width),
                               jnp.bfloat16)
     q = jax.random.normal(keys[1], (slots, heads, width), jnp.bfloat16)
-    rng = np.random.default_rng(35)
-    cases = {"half-in-use": rng.integers(1100, 3500, slots).astype(np.int32),
-             "full": np.full(slots, rows, np.int32)}
+    check_latent(la, q, cache, value)
     row_gb = width * 2 * layers / 1e9
+    parent = parent_latent()
 
     def jnp_read(q, cache, lens, layer):
         out = la._reference(
@@ -132,25 +203,35 @@ def latent_case():
             lens, value_dim=value, scale=0.1)
         return jnp.pad(out, ((0, 0), (0, 0), (0, width - value)))
 
-    def kernel_read(page):
+    def kernel_read(module, page, **walk):
         def read(q, cache, lens, layer):
-            out = la._pallas(q, cache, lens, layer, page, value_dim=value,
-                             scale=0.1, interpret=False)
+            out = module._pallas(q, cache, lens, layer, page,
+                                 value_dim=value, scale=0.1,
+                                 interpret=False, **walk)
             return jnp.pad(out, ((0, 0), (0, 0), (0, width - value)))
         return read
 
-    for name, lens in cases.items():
-        paths = [("jnp", None, jnp_read, slots * rows)]
-        for page in LATENT_PAGES:
-            paths.append(("kernel", page, kernel_read(page), sum(
-                min(-(-int(n) // page) * page, rows) for n in lens)))
-        for path, page, read, fetched in paths:
+    def fetched(lens, unit):
+        return sum(min(-(-int(n) // unit) * unit, rows) for n in lens)
+
+    for name, lens in latent_lengths(grid).items():
+        paths = [("jnp", None, jnp_read, slots * rows)] \
+            if grid == "k2" else []
+        if parent is not None:
+            paths.append(("parent", [512], kernel_read(parent, 512),
+                          fetched(lens, 512)))
+        for page, per_step, buffers in LATENT_WALKS:
+            paths.append(("walk", [page, per_step, buffers],
+                          kernel_read(la, page, per_step=per_step,
+                                      buffers=buffers),
+                          fetched(lens, page)))
+        for path, walk, read, rows_read in paths:
             ms = timed(read, layers, q, cache, jnp.asarray(lens))
             print(json.dumps({
-                "grid": "k2", "lengths": name, "path": path, "page": page,
+                "grid": grid, "lengths": name, "path": path, "walk": walk,
                 "ms_per_step": round(ms, 4),
-                "gb_fetched": round(fetched * row_gb, 4),
-                "gb_per_s": round(fetched * row_gb / ms * 1e3, 1),
+                "gb_fetched": round(rows_read * row_gb, 4),
+                "gb_per_s": round(rows_read * row_gb / ms * 1e3, 1),
             }), flush=True)
 
 
@@ -195,8 +276,8 @@ def experts_case():
 def main(grids):
     if jax.default_backend() != "tpu":
         raise SystemExit("decode_crossover.py times a TPU; none here")
-    if "k2" in grids:
-        latent_case()
+    for grid in (g for g in grids if g in LATENT):
+        latent_case(grid)
     if "k2-experts" in grids:
         experts_case()
     for grid in (g for g in grids if g in GRIDS):

@@ -574,6 +574,26 @@ def test_latent_decode(one_chip, page):
              _spec((64,), jnp.int32, one_chip), _spec((), jnp.int32, one_chip))
 
 
+def test_latent_decode_at_the_hybrid_models_grid(one_chip):
+    """gigachat35-ep16-stage.reason-saturated's read: 96 slots x 6,144
+    rows of 640, its one latent layer, 64 heads, at the dispatch's page."""
+    from cloud_tpu.ops import latent_attention
+
+    q = _spec((96, 64, 640), jnp.bfloat16, one_chip)
+    rows = _spec((1, 96, 6144, 640), jnp.bfloat16, one_chip)
+    page = latent_attention.kernel_page(q, rows, use_pallas=True)
+
+    def fn(q, rows, lens, layer):
+        return latent_attention._pallas(
+            q, rows, lens, layer, page, value_dim=512, scale=0.1,
+            interpret=False)
+
+    text = _compile(fn, q, rows, _spec((96,), jnp.int32, one_chip),
+                    _spec((), jnp.int32, one_chip)).as_text()
+    # The rows go in whole (the walk fetches its pages itself).
+    assert "bf16[96,6144,640]" not in text
+
+
 @pytest.mark.parametrize("rows, k, n", [(512, 7168, 2048), (512, 2048, 7168),
                                         (1024, 7168, 2048),
                                         (1024, 2048, 7168)])

@@ -17,18 +17,42 @@ def _normal(rng, shape, dtype):
     return jnp.asarray(rng.standard_normal(shape), jnp.float32).astype(dtype)
 
 
+#: Five slots' lengths, from the rows a slot holds, the kernel's page and
+#: the rows a step of its walk holds: which slots the walk visits (a dead
+#: slot, length 0, is skipped whole and reads zeros), where a step ends,
+#: and from which slot the steps after the one being scored are fetched.
+LENGTHS = {
+    "mixed": lambda rows, page, step: [0, 1, 17, rows, 32],
+    "all-dead": lambda rows, page, step: [0, 0, 0, 0, 0],
+    "live-first": lambda rows, page, step: [rows, 0, 0, 0, 0],
+    "live-last": lambda rows, page, step: [0, 0, 0, 0, page + 1],
+    "live-between": lambda rows, page, step: [0, 0, page - 1, 0, 0],
+    "page-edges": lambda rows, page, step: [1, page - 1, page, page + 1,
+                                            rows - 1],
+    "step-edges": lambda rows, page, step: [step - 1, step, 0, step + 1,
+                                            rows],
+    "whole-pages": lambda rows, page, step: [page, rows, 0, 2 * page, rows],
+}
+
+
+@pytest.mark.parametrize("lengths", list(LENGTHS))
 @pytest.mark.parametrize("dtype, tolerance", [(jnp.float32, 2e-6),
                                               (jnp.bfloat16, 2e-2)])
-@pytest.mark.parametrize("rows", [64, 48])
+@pytest.mark.parametrize("rows", [64, 48, 112])
 def test_latent_decode_reads_a_layer_of_the_stack_in_place(dtype, tolerance,
-                                                           rows):
-    """Five slots of lengths 0 (skipped whole: zeros), 1, mid-page, the
-    whole row and a page's edge; layer 1 of a stack of 3, traced."""
+                                                           rows, lengths):
+    """Five slots at each of :data:`LENGTHS` (64 rows: one page of 64; 48:
+    three pages of 16, one step; 112: seven pages of 16, steps of six and
+    one); layer 1 of a stack of 3, traced."""
     rng = np.random.default_rng(0)
     b, h, w, v = 5, 4, 128, 96
     q = _normal(rng, (b, h, w), dtype)
     stack = _normal(rng, (3, b, rows, w), dtype)
-    lens = jnp.asarray([0, 1, 17, rows, 32], jnp.int32)
+    page = la.kernel_page(q, stack, use_pallas=True)
+    assert page == {64: 64, 48: 16, 112: 16}[rows]
+    step = page * la._step_pages(page, rows)
+    lens = jnp.minimum(jnp.asarray(LENGTHS[lengths](rows, page, step),
+                                   jnp.int32), rows)
     want = la._reference(q, stack[1], lens, value_dim=v, scale=0.3)
     traced = la.KERNEL_TRACE_COUNT
     got = jax.jit(lambda q, s, n, l: la.latent_decode_attention(
@@ -38,14 +62,18 @@ def test_latent_decode_reads_a_layer_of_the_stack_in_place(dtype, tolerance,
     assert got.shape == (b, h, v) and got.dtype == dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tolerance)
-    assert not np.asarray(got[0], np.float32).any()
-    # Stale rows past a slot's length change nothing.
-    noisy = stack.at[1, 2, 17:].set(1e4)
+    dead = np.asarray(lens) == 0
+    assert not np.asarray(got, np.float32)[dead].any()
+    # Stale rows past every slot's length change nothing: the rest of a
+    # slot's last page, the pages past it, and a dead slot's rows.
+    past = jnp.arange(rows)[None, :, None] >= lens[:, None, None]
+    noisy = stack.at[1].set(jnp.where(past, jnp.asarray(1e4, dtype),
+                                      stack[1]))
     again = la.latent_decode_attention(
         q, noisy, lens, value_dim=v, scale=0.3, layer=1, use_pallas=True,
         interpret=True)
-    np.testing.assert_array_equal(np.asarray(again[2], np.float32),
-                                  np.asarray(got[2], np.float32))
+    np.testing.assert_array_equal(np.asarray(again, np.float32),
+                                  np.asarray(got, np.float32))
 
 
 def test_latent_decode_dispatch():

@@ -394,3 +394,36 @@ def test_expert_counters_on_the_full_rows_schedule(held, offset):
     assert stats["kv_row_steps_reserved"] == 2 * 2 * (8 + 5)
     assert stats["kv_row_steps_in_use"] == (3 + 1) + (5 + 1) + (3 + 3)
     assert stats["kv_bytes_reserved"] == 2 * 2 * 13 * 128 * 4
+
+
+def test_latent_rows_read_count_the_kernels_page(monkeypatch):
+    """``kv_row_steps_read`` over a latent cache, with the latent read on
+    its kernel's route (``latent_attention.kernel_page`` asked as on a
+    TPU; here interpreted): the decoding slots' rows rounded up to the
+    page the kernel fetches by, the one spelling the engine counts by.
+    The "full-rows" schedule above (A: 3 prompt tokens, 5 to make; B: 5
+    and 3; two slots, chunks of 2) over rows of 8 + 16 = 24: pages of 8.
+    Chunk 1: A holds 3+1 (8), B 5+1 (8); chunk 2: A 3+3 (8)."""
+    from cloud_tpu.models import mla
+    from cloud_tpu.ops import latent_attention
+
+    on_route = latent_attention.kernel_page
+    monkeypatch.setattr(
+        latent_attention, "kernel_page",
+        lambda q, rows, use_pallas=None: on_route(
+            q, rows, use_pallas=True if use_pallas is None else use_pallas))
+    config = transformer.TINY.scaled(
+        dtype=jnp.float32, num_layers=1,
+        latent=mla.LatentConfig(q_rank=24, kv_rank=32, nope_dim=16,
+                                rope_dim=8, v_dim=16))
+    model = config, transformer.init(jax.random.PRNGKey(0), config)
+    traced = latent_attention.KERNEL_TRACE_COUNT
+    _, results, stats = _serve(
+        model, [[1, 2, 3], [4, 5, 6, 7, 8]], [5, 3], max_new_tokens=16,
+        prompt_buckets=(8,), num_slots=2)
+    assert latent_attention.KERNEL_TRACE_COUNT > traced
+    assert [len(r.tokens) for r in results] == [5, 3]
+    assert stats["chunks"] == 2
+    assert stats["kv_row_steps_reserved"] == 2 * 2 * 24
+    assert stats["kv_row_steps_in_use"] == (3 + 1) + (5 + 1) + (3 + 3)
+    assert stats["kv_row_steps_read"] == 8 + 8 + 8
