@@ -153,6 +153,21 @@ def dense_apply(params, x, *, dtype=None):
     return y
 
 
+def dense_heads_apply(params, x, heads: int, head_dim: int):
+    """``dense_apply`` split into heads: [..., H * hd] -> [..., H, hd].
+
+    The split comes after the product, behind an optimization barrier.
+    Without it XLA folds the reshape into the product, which becomes a
+    convolution that wants its kernel contraction-minor: inside a layer
+    scan over stacked kernels that is a transposed copy of the whole stack
+    per program execution, and each layer's slice staged in fast memory
+    before the product.  With it the product is a plain dot whose operand
+    is the stored kernel sliced in place.  The barrier is the identity:
+    the numbers, and their gradients, are ``dense_apply``'s."""
+    y = jax.lax.optimization_barrier(dense_apply(params, x))
+    return y.reshape(y.shape[:-1] + (heads, head_dim))
+
+
 def embedding_init(rng, vocab: int, dim: int, *, vocab_axis="vocab",
                    embed_axis="embed"):
     table = jax.random.normal(rng, (vocab, dim), jnp.float32) * 0.02

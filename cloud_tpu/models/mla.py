@@ -170,12 +170,11 @@ def project(att, u, positions, config):
     (rotated), and the token's cache row as its two parts, ``c``
     [B, T, kv_rank] (normed) and ``k_pe`` [B, T, rope] (rotated)."""
     cfg, eps = config.latent, config.norm_eps
-    b, t, _ = u.shape
     with layers.scope("attn_proj"):
         cq = layers.rmsnorm_apply(att["q_norm"],
                                   layers.dense_apply(att["q_a"], u), eps=eps)
-        q = layers.dense_apply(att["q_b"], cq).reshape(
-            b, t, config.num_heads, cfg.qk_dim)
+        q = layers.dense_heads_apply(att["q_b"], cq, config.num_heads,
+                                     cfg.qk_dim)
         q_nope, q_pe = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
         down = layers.dense_apply(att["kv_a"], u)
         c = layers.rmsnorm_apply(att["kv_norm"], down[..., :cfg.kv_rank],

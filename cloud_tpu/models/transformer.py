@@ -356,12 +356,10 @@ def qkv_project(att_params, x, positions, config: TransformerConfig):
     training forward pass and the generation path's prefill/decode (which
     must produce bit-identical projections for the KV cache to be
     equivalent to a full re-forward)."""
-    b, t, _ = x.shape
     mult = config.multipliers
 
     def proj(p, heads):
-        y = layers.dense_apply(p, x)
-        return y.reshape(b, t, heads, config.head_dim)
+        return layers.dense_heads_apply(p, x, heads, config.head_dim)
 
     with layers.scope("attn_proj"):
         x = layers.scaled(x, mult.attention_in)

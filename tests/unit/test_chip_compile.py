@@ -776,3 +776,110 @@ def test_latent_expert_decode_chunk_copies_neither_cache_nor_experts(
                          ln)]
     assert not moved, moved
     assert compiled.memory_analysis().temp_size_in_bytes <= 0.5e9
+
+
+# ---------------------------------------------------------------------------
+# Projections into heads: one plain dot on the stored kernel, split into
+# heads after it (``layers.dense_heads_apply``)
+# ---------------------------------------------------------------------------
+
+
+def _assert_projections_read_in_place(text, kernel):
+    """No ``copy`` takes a stacked ``params`` projection kernel whose name
+    matches ``kernel`` (a transposed copy of the whole stack per program
+    execution), and no projection product of ``attn_proj`` is a
+    ``window={size=`` convolution (the head split folded into the product:
+    the layout that asks for that copy, and stages each layer's slice in
+    fast memory)."""
+    copied = [ln.strip()[:160] for ln in text.splitlines()
+              if re.search(r" copy\(%params\S*____att____" + kernel
+                           + r"____kernel", ln)]
+    assert not copied, copied
+    folded = [ln.strip()[:160] for ln in text.splitlines()
+              if re.search(r" convolution\(.*window=\{size=.*"
+                           r"attn_proj/\.\.\.i,io->\.\.\.o/", ln)]
+    assert not folded, folded
+
+
+@pytest.mark.parametrize("program", ["chunk", "insert-512"])
+def test_qkv_projections_read_the_stacked_kernels_in_place(
+        one_chip, monkeypatch, program):
+    """A decoder at baichuan-7b-l16's widths (4096, 32 heads of 128; 4
+    layers), its chunk program at chat-open's grid (12 slots x 640 rows)
+    and its insert at the 512 bucket: the q/k/v products are dots whose
+    operand is the stacked kernel sliced in place, as ``attn_out``'s is.
+    With the head split folded into them each was a convolution, and the
+    chunk program copied all three stacks transposed, ``bf16[L,4096,4096]``
+    each, once an execution (all of its temp)."""
+    from cloud_tpu.models import generation, transformer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, rows = 12, 640
+    config = transformer.TransformerConfig(
+        vocab_size=64000, num_layers=4, dim=4096, num_heads=32,
+        head_dim=128, mlp_hidden=11008, max_seq_len=rows, remat=False,
+    )
+    sample = generation.SampleConfig(temperature=0.0)
+    on_chip = functools.partial(_tree_spec, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: transformer.init(jax.random.PRNGKey(0), config)),
+        jnp.bfloat16)
+    cache = on_chip(jax.eval_shape(
+        lambda: generation.init_slot_cache(config, slots, rows)))
+    state = on_chip(jax.eval_shape(lambda: generation.init_slot_state(
+        config, slots, sample=sample)))
+    scalar = _spec((), jnp.int32, one_chip)
+    if program == "chunk":
+        def fn(params, cache, state):
+            return generation.decode_chunk_program(
+                params, cache, state, config, chunk_size=8, sample=sample)
+        args = (params, cache, state)
+    else:
+        def fn(params, cache, state, tokens, prompt_len, slot, new):
+            return generation.insert_slot_program(
+                params, cache, state, tokens, prompt_len, slot, new, config,
+                sample=sample)
+        args = (params, cache, state, _spec((1, 512), jnp.int32, one_chip),
+                scalar, scalar, scalar)
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "____att____q____kernel" in text
+    _assert_projections_read_in_place(text, r"(?:q|k|v)")
+    if program == "chunk":
+        assert compiled.memory_analysis().temp_size_in_bytes <= 0.05e9
+
+
+def test_latent_query_projection_reads_its_stacked_kernel_in_place(
+        one_chip, monkeypatch):
+    """The latent queries' up-projection ``q_b`` at kimi-k2-ep32-stage's
+    widths (7168 -> rank 1536 -> 64 heads of 192; 2 dense layers), in the
+    chunk program at the cell's 64 slots: a dot on the stacked kernel
+    sliced in place, not a convolution over a transposed copy of the
+    stack (0.23 GB at the cell's 6 expert layers)."""
+    from cloud_tpu.models import generation, mla, transformer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, rows = 64, 1024
+    config = transformer.TransformerConfig(
+        vocab_size=20480, num_layers=2, dim=7168, num_heads=64,
+        head_dim=128, mlp_hidden=2048, max_seq_len=rows, remat=False,
+        latent=mla.LatentConfig(),
+    )
+    sample = generation.SampleConfig(temperature=0.0)
+    on_chip = functools.partial(_tree_spec, one_chip)
+    params = on_chip(jax.eval_shape(
+        lambda: transformer.init(jax.random.PRNGKey(0), config)),
+        jnp.bfloat16)
+    cache = on_chip(jax.eval_shape(
+        lambda: generation.init_slot_cache(config, slots, rows)))
+    state = on_chip(jax.eval_shape(lambda: generation.init_slot_state(
+        config, slots, sample=sample)))
+
+    def fn(params, cache, state):
+        return generation.decode_chunk_program(
+            params, cache, state, config, chunk_size=8, sample=sample)
+
+    text = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, cache, state).compile().as_text()
+    assert "____att____q_b____kernel" in text and "%latent_decode" in text
+    _assert_projections_read_in_place(text, "q_b")

@@ -417,16 +417,16 @@ def test_the_refusal_names_both_kinds(params, overrides):
 
 #: sha256 (first 16 hex digits) of ``str(jax.make_jaxpr(...))`` of each
 #: configuration's insert and chunk programs at its ``tiny`` sizes (the
-#: grid of tests/unit/test_device_scopes.py), as the parent commit (PR 38)
+#: grid of tests/unit/test_device_scopes.py), as the parent commit
 #: traces them: a PR that changes one of these programs on purpose says so
 #: and writes the new digest here.
 PARENT_JAXPRS = {
-    ("kv", "insert"): "8b003b4d8879146c",
-    ("kv", "chunk"): "3e2262658837740a",
-    ("ssm", "insert"): "bcbae692b7854683",
-    ("ssm", "chunk"): "6f73dd14c96fa544",
-    ("latent_moe", "insert"): "b8c6a14b73de86ee",
-    ("latent_moe", "chunk"): "e9f95223599885f1",
+    ("kv", "insert"): "3c6d019aeab54da6",
+    ("kv", "chunk"): "10976f905f44ea64",
+    ("ssm", "insert"): "003c5f88b6cec236",
+    ("ssm", "chunk"): "daa1c8c6b4302492",
+    ("latent_moe", "insert"): "613bc93e0ff6bf2d",
+    ("latent_moe", "chunk"): "d592cc2ab7f42d0a",
 }
 KINDS = {"kv": ("baichuan-7b-l16", serve_engine),
          "ssm": ("falcon-h1-34b-stage", serve_hybrid),
